@@ -32,6 +32,7 @@ import ast
 
 import jax
 from jax import tree_util
+from jax.extend import core as jex_core
 
 from ..core.tensor import Tensor
 from .diagnostics import Report, Severity
@@ -86,11 +87,11 @@ def audit_donation(fn, args, donate_argnums, report=None, where=""):
     offsets = [sum(counts[:i]) for i in range(len(counts))]
     invars = jaxpr.invars
     outvars = set(v for v in jaxpr.outvars
-                  if not isinstance(v, jax.core.Literal))
+                  if not isinstance(v, jex_core.Literal))
     used = set()
     for eqn in jaxpr.eqns:
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 used.add(v)
     for d in donate:
         for j in range(counts[d]):

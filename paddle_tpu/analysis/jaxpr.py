@@ -16,6 +16,7 @@ import os
 import numpy as np
 import jax
 from jax import tree_util
+from jax.extend import core as jex_core
 
 from ..core import engine
 from ..core.tensor import Tensor
@@ -61,29 +62,20 @@ _DISPATCH_DIRS = tuple(
     os.path.join(_PKG_DIR, "__init__.py"),)
 
 
-def _frame_loc(frame):
-    line = (getattr(frame, "start_line", None)
-            or getattr(frame, "line_num", None))
-    return frame.file_name, line
-
-
 def eqn_anchor(eqn, default=(None, None)):
     """(file, line) of the frame that emitted this eqn, from jax
     source_info: the innermost frame outside the framework's dispatch
     layers, so `x + y` in a model anchors at the model line, not at
     engine.apply_op; falls back to the innermost frame, then to the
     function's def site."""
-    try:
-        from jax._src import source_info_util as siu
+    from jax._src import source_info_util as siu
 
-        frames = list(siu.user_frames(eqn.source_info))
-        for frame in frames:
-            if not str(frame.file_name).startswith(_DISPATCH_DIRS):
-                return _frame_loc(frame)
-        if frames:
-            return _frame_loc(frames[0])
-    except Exception:
-        pass
+    frames = list(siu.user_frames(eqn.source_info.traceback))
+    for frame in frames:
+        if not str(frame.file_name).startswith(_DISPATCH_DIRS):
+            return frame.file_name, frame.start_line
+    if frames:
+        return frames[0].file_name, frames[0].start_line
     return default
 
 
@@ -224,9 +216,9 @@ def trace_program(fn, input_spec=None, example=None):
 
 
 def _subjaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for e in v:
@@ -356,14 +348,14 @@ def analyze_dead(tp: TracedProgram, report: Report):
     forgotten return value or a stale code path."""
     jaxpr = tp.closed.jaxpr
     live = {v for v in jaxpr.outvars
-            if isinstance(v, jax.core.Var)}
+            if isinstance(v, jex_core.Var)}
     dead = []
     for eqn in reversed(jaxpr.eqns):
         outs = [v for v in eqn.outvars
                 if not isinstance(v, jax.core.DropVar)]
         if any(v in live for v in outs) or eqn.effects:
             for v in eqn.invars:
-                if isinstance(v, jax.core.Var):
+                if isinstance(v, jex_core.Var):
                     live.add(v)
         elif eqn_anchor(eqn)[0] != __file__:
             # eqns anchored in THIS file are the trace harness's own

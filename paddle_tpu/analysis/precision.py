@@ -42,6 +42,7 @@ import math
 
 import numpy as np
 import jax
+from jax.extend import core as jex_core
 
 from .diagnostics import Report, Severity
 from .jaxpr import (_LOW, _Capped, TracedProgram, eqn_anchor,
@@ -87,7 +88,7 @@ def _dtype_of(v):
 
 def _scalar_literal(v):
     """float value of a scalar jax Literal operand, else None."""
-    if not isinstance(v, jax.core.Literal):
+    if not isinstance(v, jex_core.Literal):
         return None
     val = np.asarray(v.val)
     if val.size != 1 or not np.issubdtype(val.dtype, np.floating):
@@ -116,7 +117,7 @@ def analyze_precision(tp: TracedProgram, report: Report,
         consumers = {}
         for eqn in jaxpr.eqns:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex_core.Literal):
                     consumers.setdefault(v, []).append(eqn)
             for v in eqn.outvars:
                 producers[v] = eqn
@@ -243,7 +244,7 @@ def _check_churn(eqn, producers, cap, tp):
     B narrower than A destroys mantissa bits silently; B wider is
     pure byte churn. Either way the inner cast bought nothing."""
     src = eqn.invars[0]
-    if isinstance(src, jax.core.Literal):
+    if isinstance(src, jex_core.Literal):
         return
     inner = producers.get(src)
     if inner is None or inner.primitive.name != "convert_element_type":

@@ -18,11 +18,10 @@ each other in interpret mode (tests/test_comm_compress.py):
   * `*_ref` — plain jnp, runs anywhere (this is what compiled train
     steps use on CPU and whenever PADDLE_PALLAS_FUSION is off);
   * Pallas TPU kernels behind PADDLE_PALLAS_FUSION=1 (+
-    PADDLE_PALLAS_INTERPRET=1 on CPU), grid over scale blocks.
-    int8 only — the f8e4m3 cast stays on the jnp path. Block shape
-    (1, B) favors clarity over sublane occupancy (int8 min tile is
-    (32, 128)); on-chip row-batching is a measured-on-chip follow-up,
-    like the rest of the CPU-validated kernel library.
+    PADDLE_PALLAS_INTERPRET=1 on CPU), grid over groups of
+    `_ROWS` scale blocks (the int8 tile is (32, 128), and Mosaic
+    refuses a (1, B) block of an (nblocks, B) array). int8 only —
+    the f8e4m3 cast stays on the jnp path.
 
 A zero block (absmax 0) gets scale 1.0 so the codes are exactly 0 and
 dequantize returns exactly 0 — padding is bit-neutral through the
@@ -102,20 +101,30 @@ def dequantize_ref(codes, scales, block, mode):
 
 
 # ---------------------------------------------------------------------------
-# Pallas int8 kernels (one grid step == one scale block)
+# Pallas int8 kernels (one grid step == _ROWS scale blocks)
 # ---------------------------------------------------------------------------
 
+_ROWS = 32  # scale blocks per grid step: one int8 (32, 128) tile row
+
+
 def _quant_i8_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...]
-    amax = jnp.max(jnp.abs(x))
+    x = x_ref[...]                                     # [R, B]
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)  # [R, 1]
     scale = jnp.where(amax > 0, amax / INT8_QMAX, 1.0)
-    s_ref[0, 0] = scale
+    s_ref[...] = scale
     q_ref[...] = jnp.clip(jnp.round(x / scale), -INT8_QMAX,
                           INT8_QMAX).astype(jnp.int8)
 
 
 def _dequant_i8_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
+
+
+def _pad_rows(a, rows):
+    """Zero rows up to a multiple of _ROWS: a zero block quantizes to
+    scale 1 / codes 0 and dequantizes to 0, and is sliced off."""
+    pad = -rows % _ROWS
+    return jnp.pad(a, ((0, pad), (0, 0))) if pad else a
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -124,18 +133,20 @@ def _quantize_pallas_i8(flat, block, interpret):
 
     xb = _as_blocks(flat.astype(jnp.float32), block)
     nb = xb.shape[0]
-    row = pl.BlockSpec((1, block), lambda i: (i, 0))
-    scale = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    xb = _pad_rows(xb, nb)
+    nb_pad = xb.shape[0]
+    row = pl.BlockSpec((_ROWS, block), lambda i: (i, 0))
+    scale = pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))
     q, s = pl.pallas_call(
         _quant_i8_kernel,
-        out_shape=(jax.ShapeDtypeStruct((nb, block), jnp.int8),
-                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)),
-        grid=(nb,),
+        out_shape=(jax.ShapeDtypeStruct((nb_pad, block), jnp.int8),
+                   jax.ShapeDtypeStruct((nb_pad, 1), jnp.float32)),
+        grid=(nb_pad // _ROWS,),
         in_specs=[row],
         out_specs=(row, scale),
         interpret=interpret,
     )(xb)
-    return q.reshape(flat.shape), s.reshape(nb)
+    return q[:nb].reshape(flat.shape), s[:nb, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -144,32 +155,39 @@ def _dequantize_pallas_i8(codes, scales, block, interpret):
 
     qb = _as_blocks(codes, block)
     nb = qb.shape[0]
-    row = pl.BlockSpec((1, block), lambda i: (i, 0))
-    scale = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    qb = _pad_rows(qb, nb)
+    nb_pad = qb.shape[0]
+    row = pl.BlockSpec((_ROWS, block), lambda i: (i, 0))
+    scale = pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         _dequant_i8_kernel,
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
-        grid=(nb,),
+        out_shape=jax.ShapeDtypeStruct((nb_pad, block), jnp.float32),
+        grid=(nb_pad // _ROWS,),
         in_specs=[row, scale],
         out_specs=row,
         interpret=interpret,
-    )(qb, scales.reshape(nb, 1))
-    return out.reshape(codes.shape)
+    )(qb, _pad_rows(scales.reshape(nb, 1), nb))
+    return out[:nb].reshape(codes.shape)
 
 
-def _use_pallas(mode):
+def _use_pallas(mode, block):
+    """The int8 kernels run where the fused library is armed and can
+    run (a TPU, or the interpreter); compiled, the scale block must
+    be lane-aligned."""
     if mode != "int8":
         return False
     from ...incubate.nn import pallas as _pallas
 
-    return _pallas.fusion_enabled()
+    if not _pallas.kernels_available():
+        return False
+    return block % 128 == 0 or not _pallas._on_tpu()
 
 
 def quantize_blocks(flat, block, mode):
     """Dispatching entry: Pallas int8 kernel when the fused kernel
     library is armed (PADDLE_PALLAS_FUSION=1; interpret mode off-TPU),
     jnp reference otherwise. Same results either way."""
-    if _use_pallas(mode):
+    if _use_pallas(mode, block):
         from ...incubate.nn import pallas as _pallas
 
         return _quantize_pallas_i8(
@@ -179,7 +197,7 @@ def quantize_blocks(flat, block, mode):
 
 
 def dequantize_blocks(codes, scales, block, mode):
-    if _use_pallas(mode):
+    if _use_pallas(mode, block):
         from ...incubate.nn import pallas as _pallas
 
         return _dequantize_pallas_i8(

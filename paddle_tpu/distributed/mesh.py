@@ -130,24 +130,11 @@ def get_group(gid):
 
 
 def shard_map_compat(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions, in ONE place (ring attention
-    and linalg.dist both build islands): jax.shard_map with check_vma
-    (newest) / check_rep (older), falling back to the
-    jax.experimental home on builds that predate the top-level
-    export."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 check_vma=False)
-        except TypeError:
-            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(body, mesh=mesh, in_specs=in_specs,
-               out_specs=out_specs, check_rep=False)
+    """The shard_map island builder ring attention and linalg.dist
+    share: replication checking off, because their bodies carry
+    collectives whose varying-ness the checker cannot prove."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def spec(*axes) -> PartitionSpec:

@@ -423,3 +423,28 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+def flash_attention_on_mesh(q, k, v, causal, sm_scale):
+    """flash_attention for call sites that may be traced under a
+    device mesh. Mosaic kernels cannot be partitioned by GSPMD ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call
+    in a shard_map"), so under a live multi-device mesh the call is a
+    shard_map island: q/k/v [B, H, S, D] split over 'dp' on batch and
+    'mp' on heads (each where the axis exists and divides), sequence
+    and head_dim whole on every shard — attention needs no
+    communication across batch or heads."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...distributed import mesh as mesh_mod
+    from .pallas import _partitioned
+    from .ring_attention import _pick_axis
+
+    if not _partitioned():
+        return flash_attention(q, k, v, causal, sm_scale)
+    mesh = mesh_mod.get_mesh()
+    spec = P(_pick_axis(mesh, "dp", q.shape[0]),
+             _pick_axis(mesh, "mp", q.shape[1]), None, None)
+    return mesh_mod.shard_map_compat(
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal, sm_scale),
+        mesh, (spec, spec, spec), spec)(q, k, v)

@@ -166,8 +166,8 @@ def _ln_bwd_kernel(dy_ref, ds_ref, s_ref, w_ref, b_ref, mu_ref, rs_ref,
         yln = xhat * w + b_ref[...].astype(jnp.float32)
         dy = dy * _gelu_grad(yln, approx)
     # per-block partial parameter grads; summed across blocks outside
-    dwp_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    dbp_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+    dwp_ref[0] = jnp.sum(dy * xhat, axis=0, keepdims=True)
+    dbp_ref[0] = jnp.sum(dy, axis=0, keepdims=True)
     dxhat = dy * w
     m1 = jnp.mean(dxhat, axis=-1, keepdims=True)
     m2 = jnp.mean(dxhat * xhat, axis=-1, keepdims=True)
@@ -197,7 +197,11 @@ def _ln_bwd_impl(dy2, ds2, s2, w, b, mu, rs, act, approx, interpret):
         (1, h), lambda i: (0, 0), memory_space=pltpu.VMEM)
     wb_spec = pl.BlockSpec((1, h), lambda i: (0, 0),
                            memory_space=pltpu.VMEM)
-    part_spec = pl.BlockSpec((1, h), lambda i: (i, 0),
+    # one (1, h) row of partials per grid step, as a (grid, 1, h)
+    # array: Mosaic wants a block's last two dims to be (8k, 128k) or
+    # the array's own, and (1, h) blocks of a (grid, h) array are
+    # neither
+    part_spec = pl.BlockSpec((1, 1, h), lambda i: (i, 0, 0),
                              memory_space=pltpu.VMEM)
     stat_spec = pl.BlockSpec((bn, _STAT_LANES), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
@@ -206,16 +210,16 @@ def _ln_bwd_impl(dy2, ds2, s2, w, b, mu, rs, act, approx, interpret):
     dx, dwp, dbp = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((n_pad, h), dy2.dtype),
-                   jax.ShapeDtypeStruct((grid, h), jnp.float32),
-                   jax.ShapeDtypeStruct((grid, h), jnp.float32)),
+                   jax.ShapeDtypeStruct((grid, 1, h), jnp.float32),
+                   jax.ShapeDtypeStruct((grid, 1, h), jnp.float32)),
         grid=(grid,),
         in_specs=[row_spec, ds_spec, row_spec, wb_spec, wb_spec,
                   stat_spec, stat_spec],
         out_specs=(row_spec, part_spec, part_spec),
         interpret=interpret,
     )(dyp, dsp, sp, w.reshape(1, h), b.reshape(1, h), mup, rsp)
-    dw = jnp.sum(dwp, axis=0).astype(w.dtype)
-    db = jnp.sum(dbp, axis=0).astype(b.dtype)
+    dw = jnp.sum(dwp, axis=(0, 1)).astype(w.dtype)
+    db = jnp.sum(dbp, axis=(0, 1)).astype(b.dtype)
     return dx[:n], dw, db
 
 

@@ -10,10 +10,14 @@ multi_precision, else the parameter itself), its gradient and its
 moment slots are flattened, zero-padded to a chunk multiple and
 stacked into ONE (chunks, rows, 128) buffer per role. The kernel grid
 walks chunks; per-PARAMETER scalars (Adam bias-correction
-denominators, AdamW's per-param decay mask) ride as per-chunk SMEM
-scalars so parameters with different restored beta-pow state or an
-`apply_decay_param_fun` filter still fuse. The learning rate is a
-traced (1, 1) scalar — backoff/growth/schedules never recompile.
+denominators, AdamW's per-param decay mask) ride as one (1, 128) lane
+row per chunk so parameters with different restored beta-pow state or
+an `apply_decay_param_fun` filter still fuse. (Mosaic refuses the
+(1, 1) SMEM block of a (chunks, 1) array they were first written as:
+"the last two dimensions of your block shape [must be] divisible by 8
+and 128 respectively, or be equal to the respective dimensions of the
+overall array".) The learning rate is a traced (1, 1) SMEM scalar —
+backoff/growth/schedules never recompile.
 
 Zero padding is update-invariant for every supported rule (0 params,
 0 grads, 0 moments stay 0), and unpacking slices the pads away.
@@ -48,23 +52,23 @@ _CHUNK = CHUNK_ROWS * CHUNK_LANES  # 32768 elements / 128 KB f32
 def _adam_kernel(lr_ref, d1_ref, d2_ref, wd_ref, p_ref, g_ref, m_ref,
                  v_ref, po_ref, mo_ref, vo_ref, *, b1, b2, eps, wdc):
     lr = lr_ref[0, 0]
-    d1 = d1_ref[0, 0]          # 1 - beta1^t (this step's denominator)
-    d2 = d2_ref[0, 0]
-    p = p_ref[...]
-    g = g_ref[...]
+    d1 = d1_ref[0]             # [1, 128]: 1 - beta1^t (this step's
+    d2 = d2_ref[0]             # denominator), one value on every lane
+    p = p_ref[0]               # [R, 128]
+    g = g_ref[0]
     if wdc:
         g = g + wdc * p        # coupled L2 (non-decoupled optimizers)
-    wd = wd_ref[0, 0]          # decoupled per-param coeff (AdamW)
+    wd = wd_ref[0]             # decoupled per-param coeff (AdamW)
     p = p * (1.0 - lr * wd)
-    m = b1 * m_ref[...] + (1.0 - b1) * g
-    v = b2 * v_ref[...] + (1.0 - b2) * g * g
+    m = b1 * m_ref[0] + (1.0 - b1) * g
+    v = b2 * v_ref[0] + (1.0 - b2) * g * g
     # divide (not multiply-by-reciprocal): bit-identical to the
     # per-parameter Adam._update rule
     mhat = m / d1
     vhat = v / d2
-    po_ref[...] = p - lr * mhat / (jnp.sqrt(vhat) + eps)
-    mo_ref[...] = m
-    vo_ref[...] = v
+    po_ref[0] = p - lr * mhat / (jnp.sqrt(vhat) + eps)
+    mo_ref[0] = m
+    vo_ref[0] = v
 
 
 def _sgd_kernel(lr_ref, p_ref, g_ref, po_ref, *, wdc):
@@ -95,8 +99,8 @@ def _scalar_spec():
 
 
 def _chunk_scalar_spec():
-    return pl.BlockSpec((1, 1), lambda i: (i, 0),
-                        memory_space=pltpu.SMEM)
+    return pl.BlockSpec((1, 1, CHUNK_LANES), lambda i: (i, 0, 0),
+                        memory_space=pltpu.VMEM)
 
 
 def _chunk_spec():
@@ -142,8 +146,8 @@ def _audit_aliases(aliases, ins, out_shape, where):
 def fused_adam_chunks(p, g, m, v, lr, d1, d2, wd, *, beta1, beta2, eps,
                       wd_coupled=0.0, interpret=False):
     """One launch of the fused Adam/AdamW rule over (G, R, 128) chunk
-    buffers; d1/d2/wd are (G, 1) per-chunk scalars. Returns
-    (new_p, new_m, new_v)."""
+    buffers; d1/d2/wd are (G, 1, 128) per-chunk scalars, one value
+    on every lane. Returns (new_p, new_m, new_v)."""
     G = p.shape[0]
     # ONE aliases/operands/out_shape triple shared by the audit and
     # the launch — the audit must check exactly what XLA gets
@@ -237,10 +241,12 @@ def _pack(segs, arrays):
 
 
 def _pack_scalars(segs, values):
-    """Per-param traced/plain scalars -> (G, 1) f32 per-chunk."""
-    parts = [jnp.full((nc,), jnp.asarray(values[n], jnp.float32))
+    """Per-param traced/plain scalars -> (G, 1, 128) f32: each
+    chunk's value repeated across one lane row."""
+    parts = [jnp.full((nc, 1, CHUNK_LANES),
+                      jnp.asarray(values[n], jnp.float32))
              for n, ne, nc in segs]
-    return jnp.concatenate(parts).reshape(-1, 1)
+    return jnp.concatenate(parts)
 
 
 def _unpack(segs, buf, shapes):

@@ -138,7 +138,7 @@ def _pick_axis(mesh, a, dim):
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """The cross-version shard_map shim, shared with linalg.dist
+    """The shard_map island builder shared with linalg.dist
     (distributed.mesh.shard_map_compat)."""
     from ...distributed.mesh import shard_map_compat
 

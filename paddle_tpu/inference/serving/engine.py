@@ -131,6 +131,9 @@ class LLMEngine:
                  draft_layers=None, prefix_cache=None):
         import jax
 
+        from ...jit import arm_compile_cache
+
+        arm_compile_cache()
         self.params, self.config = _mr.extract_params(model)
         cfg = self.config
         self.max_batch = int(max_batch or env_max_batch())
@@ -172,7 +175,7 @@ class LLMEngine:
 
             use_kernel = _pl.kernels_available() and \
                 _pl.paged_attention.paged_decode_supported(
-                    head_dim, self.block_size)
+                    cfg.num_heads, head_dim, self.block_size)
             self._kernel_interpret = _pl.interpret_mode()
         else:
             self._kernel_interpret = False
@@ -615,27 +618,22 @@ class LLMEngine:
     def _load_persistent(self, args):
         """First decode dispatch: route the compile through the PR-8
         persistent cache so a serving replica restart is a warm hit.
-        Best effort — any trouble keeps the plain jitted step."""
+        Cache trouble costs a miss inside load_or_compile; a lowering
+        or compile failure is the decode program's own and raises."""
         from ...jit import persistent_cache as _pcache
 
         self._decode_exe = self._decode_jit
         if not _pcache.enabled():
             self._capture_decode_cost(args)
             return
-        try:
-            lowered = self._decode_jit.lower(*args)
-            compiled, outcome = _pcache.load_or_compile(
-                lowered, self._pcache_label)
-            if outcome != "off":
-                self._decode_exe = compiled
-                # pcache just handed us the compiled executable —
-                # the ledger capture is free here
-                self._capture_decode_cost(args, compiled=compiled)
-            else:
-                self._capture_decode_cost(args)
-        except Exception:
-            self._decode_exe = self._decode_jit
-            self._capture_decode_cost(args)
+        lowered = self._decode_jit.lower(*args)
+        compiled, outcome = _pcache.load_or_compile(
+            lowered, self._pcache_label)
+        if outcome != "off":
+            self._decode_exe = compiled
+        # pcache handed us a compiled executable either way — the
+        # ledger capture is free here
+        self._capture_decode_cost(args, compiled=compiled)
 
     def _capture_decode_cost(self, args, compiled=None):
         """Roofline-ledger capture for the decode program

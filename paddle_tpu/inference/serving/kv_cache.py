@@ -72,9 +72,10 @@ NULL_BLOCK = 0  # reserved garbage-dump block, never owned
 
 _DEF_BLOCK_SIZE = 16
 _DEF_MAX_BATCH = 8
-# CPU / no-stats fallback pool budget — big enough for the tests'
-# tiny models, small enough to exercise eviction in the chaos flood
-_DEF_POOL_BYTES = 64 << 20
+# pool budget on the CPU platform (its client has no memory stats) —
+# big enough for the tests' tiny models, small enough to exercise
+# eviction in the chaos flood
+_CPU_POOL_BYTES = 64 << 20
 
 
 def _env_int(name, default):
@@ -147,21 +148,29 @@ def auto_num_blocks(per_block, pool_bytes=None, fraction=0.45):
     """Pool size in blocks: the explicit budget when given (env or
     argument), else `fraction` of the device's free HBM per the PR-5
     memory stats (bytes_limit - bytes_in_use already accounts for the
-    resident compiled programs + params), else the CPU fallback."""
+    resident compiled programs + params). Only the CPU platform, whose
+    client reports no stats, gets the fixed 64 MiB budget; an
+    accelerator that does not report `bytes_limit` is an error."""
     budget = pool_bytes if pool_bytes else env_pool_bytes()
     if not budget:
-        try:
-            from ...monitor import memory as _memory
+        import jax
 
-            stats = _memory.memory_stats() or {}
-            limit = int(stats.get("bytes_limit", 0) or 0)
-            used = int(stats.get("bytes_in_use", 0) or 0)
-            if limit > used > 0:
-                budget = int((limit - used) * fraction)
-        except Exception:
-            budget = 0
-    if not budget:
-        budget = _DEF_POOL_BYTES
+        from ...monitor import memory as _memory
+
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
+            budget = _CPU_POOL_BYTES
+        else:
+            stats = _memory.memory_stats(dev)
+            limit = int(stats.get("bytes_limit") or 0)
+            used = int(stats.get("bytes_in_use") or 0)
+            if limit <= used:
+                raise RuntimeError(
+                    f"cannot size the KV pool on {dev}: PJRT memory "
+                    f"stats report bytes_limit={limit}, bytes_in_use="
+                    f"{used} (source={stats.get('source')!r}); set "
+                    "PADDLE_SERVE_POOL_BYTES to size it explicitly")
+            budget = int((limit - used) * fraction)
     # +1: block 0 is the null block, not usable capacity
     return max(2, budget // max(1, per_block) + 1)
 

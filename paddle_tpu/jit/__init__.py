@@ -222,6 +222,16 @@ def _telemetry_name(func):
     return ".".join(parts[-2:])
 
 
+def arm_compile_cache():
+    """Arm JAX's persistent compilation cache (persistent_cache.
+    arm_native) before a compile on an accelerator backend. CPU runs
+    — the test suite — are left alone: their thousands of tiny
+    programs are not worth persisting, and an entry point that wants
+    the cache on CPU arms it itself."""
+    if jax.default_backend() != "cpu":
+        _pcache.arm_native()
+
+
 class _PersistedProgram:
     """A disk-cache executable standing in for a jitted callable
     (jit.persistent_cache): calls dispatch to the (possibly
@@ -363,6 +373,7 @@ class StaticFunction:
             # span/timer cover build + first call.
             _monitor.stat_add(f"jit/{fname}/cache_miss", 1)
             _flight.record("jit_cache_miss", fn=fname)
+            arm_compile_cache()
             compile_ev = _profiler.RecordEvent(
                 f"jit/compile/{fname}", "JitCompile")
             compile_ev.begin()
@@ -481,25 +492,23 @@ class StaticFunction:
         cache (PADDLE_COMPILE_CACHE_DIR): the trace+lower still runs
         here (cheap, process-local, fills the output box), but a warm
         entry replaces the expensive XLA backend compile with a
-        deserialize. Any trouble keeps the plain jitted entry — the
-        cache can only ever cost a miss."""
+        deserialize. Cache trouble costs a miss inside
+        load_or_compile (counted jit/persistent_cache/errors); a
+        lowering or compile failure is the program's own and raises."""
         jfn, box = entry
-        try:
-            p_structs = [jax.ShapeDtypeStruct(tuple(p._value.shape),
-                                              p._value.dtype)
-                         for p in params]
-            a_structs = [jax.ShapeDtypeStruct(
-                tuple(flat_args[i]._value.shape),
-                flat_args[i]._value.dtype) for i in tensor_pos]
-            lowered = jfn.lower(p_structs, a_structs,
-                                jax.ShapeDtypeStruct((), jnp.uint32))
-            compiled, outcome = _pcache.load_or_compile(
-                lowered, f"to_static:{self._telemetry_key}")
-            if outcome == "off":
-                return entry
-            return _PersistedProgram(compiled, jfn), box
-        except Exception:
+        p_structs = [jax.ShapeDtypeStruct(tuple(p._value.shape),
+                                          p._value.dtype)
+                     for p in params]
+        a_structs = [jax.ShapeDtypeStruct(
+            tuple(flat_args[i]._value.shape),
+            flat_args[i]._value.dtype) for i in tensor_pos]
+        lowered = jfn.lower(p_structs, a_structs,
+                            jax.ShapeDtypeStruct((), jnp.uint32))
+        compiled, outcome = _pcache.load_or_compile(
+            lowered, f"to_static:{self._telemetry_key}")
+        if outcome == "off":
             return entry
+        return _PersistedProgram(compiled, jfn), box
 
     def _build(self, target, params, args_treedef, tensor_pos,
                static_leaves, arg_sg=None):
@@ -1044,6 +1053,7 @@ class TrainStepCompiler:
             # sibling)
             _monitor.stat_add("jit/train_step/cache_miss", 1)
             _flight.record("jit_cache_miss", fn="train_step")
+            arm_compile_cache()
             t0 = _time.perf_counter()
             with _profiler.RecordEvent("jit/compile/train_step",
                                        "JitCompile"), \
@@ -1069,30 +1079,24 @@ class TrainStepCompiler:
         the freshly built step over the live values (shared with the
         call path) and swap in the cached executable when the on-disk
         cache has this exact program — fleet rollouts, bench reruns
-        and reshape-resume relaunches skip the backend compile. Best
-        effort: any trouble keeps the plain jitted step."""
-        try:
-            pvals = {k: p._value for k, p in trainable.items()}
-            fvals = {k: p._value for k, p in frozen.items()}
-            bvals = {k: b._value for k, b in bufs.items()}
-            avals = self._place_batch(batch)
-            lr = np.float32(self._opt.get_lr())
-            rngc = np.uint32(self._step)
-            lowered = self._compiled.lower(
-                pvals, self._opt_state, self._accum_state,
-                self._comm_state, fvals, bvals, avals, lr, rngc,
-                self._loss_scale())
-            label = f"train_step:{type(self._model).__name__}"
-            k = self._steps_per_dispatch
-            if k != 1:
-                label += f"@k{k}"
-            compiled, outcome = _pcache.load_or_compile(
-                lowered, label, extra=self._pcache_extra())
-            if outcome != "off":
-                self._compiled = _PersistedProgram(compiled,
-                                                   self._compiled)
-        except Exception:
-            pass
+        and reshape-resume relaunches skip the backend compile.
+        Cache trouble costs a miss inside load_or_compile; a lowering
+        or compile failure is the step's own and raises."""
+        pvals = {k: p._value for k, p in trainable.items()}
+        fvals = {k: p._value for k, p in frozen.items()}
+        bvals = {k: b._value for k, b in bufs.items()}
+        avals = self._place_batch(batch)
+        lr = np.float32(self._opt.get_lr())
+        rngc = np.uint32(self._step)
+        lowered = self._compiled.lower(
+            pvals, self._opt_state, self._accum_state,
+            self._comm_state, fvals, bvals, avals, lr, rngc,
+            self._loss_scale())
+        compiled, outcome = _pcache.load_or_compile(
+            lowered, self._perf_name, extra=self._pcache_extra())
+        if outcome != "off":
+            self._compiled = _PersistedProgram(compiled,
+                                               self._compiled)
 
     def _pcache_extra(self):
         """Extra persistent-cache digest legs beyond the lowered

@@ -1,4 +1,22 @@
-"""Persistent on-disk XLA compile cache.
+"""Persistent on-disk XLA compile caches.
+
+Two layers, each placed from OUTSIDE the program:
+
+* JAX's own persistent compilation cache, armed by `arm_native()` at
+  `native_cache_dir()`: `$JAX_COMPILATION_CACHE_DIR` when the
+  environment sets it (code then sets no directory at all), else the
+  fixed `.jax_cache/` at the root of the checkout that holds this
+  package. Never a temp, pid or timestamp name — the next process can
+  only hit a directory it can find again. `chip_smoke.py` arms it
+  itself; the library's compile sites arm it on accelerator backends
+  (`jit.arm_compile_cache`), which is how `bench.py` and user scripts
+  get it on the chip; `bench.py` reports `native_cache_stats()`. All
+  go through this one resolver. Counters: jit/native_cache/{requests,
+  hits}.
+* the `.pdx` executable store below, on only where
+  `PADDLE_COMPILE_CACHE_DIR` is set.
+
+The `.pdx` store:
 
 Reference capability: the reference framework's compiled-program cache
 (CompiledProgram / ExecutorCache) keeps programs across steps; here we
@@ -42,10 +60,58 @@ from ..monitor import chaos as _chaos
 from ..monitor import flight as _flight
 
 __all__ = ["enabled", "cache_dir", "max_bytes", "load_or_compile",
-           "cache_stats", "clear"]
+           "cache_stats", "clear", "native_cache_dir", "arm_native",
+           "native_cache_stats"]
 
-_SCHEMA = "paddle_tpu.compile_cache/1"
+_SCHEMA = "paddle_tpu.compile_cache/2"
 _SUFFIX = ".pdx"
+
+
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+_native_armed = False
+
+
+def native_cache_dir():
+    """The directory JAX's persistent compilation cache uses."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE
+
+
+def _on_jax_event(event, **kwargs):
+    if event == "/jax/compilation_cache/compile_requests_use_cache":
+        _monitor.stat_add("jit/native_cache/requests", 1)
+    elif event == "/jax/compilation_cache/cache_hits":
+        _monitor.stat_add("jit/native_cache/hits", 1)
+
+
+def arm_native():
+    """Arm JAX's persistent compilation cache at native_cache_dir()
+    (idempotent) and return that directory. Every program is cached,
+    however quick its compile: an eager run is hundreds of sub-second
+    programs whose sum is what a warm start saves."""
+    global _native_armed
+    import jax
+
+    d = native_cache_dir()
+    if not _native_armed:
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", d)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+        jax.monitoring.register_event_listener(_on_jax_event)
+        _native_armed = True
+    return d
+
+
+def native_cache_stats():
+    """{dir, requests, hits, misses} of the native cache in this
+    process (misses = requests that found no entry)."""
+    req = _monitor.stat_get("jit/native_cache/requests")
+    hits = _monitor.stat_get("jit/native_cache/hits")
+    return {"dir": native_cache_dir(), "armed": _native_armed,
+            "requests": req, "hits": hits, "misses": req - hits}
 
 
 def cache_dir():
@@ -148,12 +214,13 @@ def _read_entry(path):
         return None
 
 
-def _write_entry(path, label, payload, in_tree, out_tree):
+def _write_entry(path, label, payload, in_tree, out_tree, device_ids):
     from .. import framework
 
     blob = pickle.dumps({
         "schema": _SCHEMA, "label": label, "env": _env_legs(),
         "payload": payload, "in_tree": in_tree, "out_tree": out_tree,
+        "device_ids": device_ids,
     }, protocol=4)
     # chaos site "cache_write": enospc/delay/stall enact inside hit();
     # "torn" comes back for us to enact — a PARTIAL entry written
@@ -192,11 +259,18 @@ def load_or_compile(lowered, label, extra=()):
     ent = _read_entry(path)
     if ent is not None:
         try:
+            import jax
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
 
+            # load onto the devices the program was compiled for, not
+            # every device of the backend: a one-device executable
+            # loaded over an 8-device client fails at dispatch
+            by_id = {dv.id: dv for dv in jax.devices()}
             compiled = deserialize_and_load(
-                ent["payload"], ent["in_tree"], ent["out_tree"])
+                ent["payload"], ent["in_tree"], ent["out_tree"],
+                execution_devices=[by_id[i]
+                                   for i in ent["device_ids"]])
             _monitor.stat_add("jit/persistent_cache/hits", 1)
             _flight.record("compile_cache", event="hit", fn=label,
                            bytes=len(ent["payload"]))
@@ -223,7 +297,10 @@ def load_or_compile(lowered, label, extra=()):
         from jax.experimental.serialize_executable import serialize
 
         payload, in_tree, out_tree = serialize(compiled)
-        n = _write_entry(path, label, payload, in_tree, out_tree)
+        device_ids = [dv.id for dv in
+                      compiled.runtime_executable().local_devices()]
+        n = _write_entry(path, label, payload, in_tree, out_tree,
+                         device_ids)
         _flight.record("compile_cache", event="miss", fn=label, bytes=n)
         _evict_lru(d)
     except Exception as e:

@@ -199,7 +199,7 @@ def _arg_sig(args):
 
 
 def shard_map(body, mesh, in_specs, out_specs):
-    """The cross-version shard_map shim, shared with ring attention
+    """The shard_map island builder shared with ring attention
     (distributed.mesh.shard_map_compat)."""
     return _mesh_mod.shard_map_compat(body, mesh, in_specs,
                                       out_specs)

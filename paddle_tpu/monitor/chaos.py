@@ -63,6 +63,8 @@ import threading
 import time
 import zlib
 
+import jax
+
 from ..core import monitor as _cmon
 from . import flight as _flight
 
@@ -175,9 +177,9 @@ class ChaosBadSample(ValueError):
     """The `bad_sample` fault — what a corrupt record raises."""
 
 
-class XlaRuntimeError(RuntimeError):
-    """Synthetic stand-in for jaxlib's XlaRuntimeError: the NAME is
-    what monitor.memory.is_oom_error classifies on, so an injected
+class XlaRuntimeError(jax.errors.JaxRuntimeError):
+    """Synthetic XLA runtime error: a subclass of the type
+    monitor.memory.is_oom_error classifies on, so an injected
     `resource_exhausted` exercises the real OOM forensics path."""
 
 

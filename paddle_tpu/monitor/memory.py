@@ -26,7 +26,7 @@ this module reads memory three ways instead of hooking allocations:
     `mem/program/<fn>/...` and `jit.cache_report()` carries the same
     numbers into every flight dump bundle.
 
-OOM forensics: `is_oom_error()` classifies XlaRuntimeError
+OOM forensics: `is_oom_error()` classifies JaxRuntimeError
 RESOURCE_EXHAUSTED; `oom_observer()` (auto-armed by `hapi.Model.fit`)
 writes an "oom" flight bundle whose memory section holds device
 stats, per-program footprints and the top-K census before re-raising;
@@ -165,9 +165,7 @@ def _resolve_device(device):
     if isinstance(device, _Place):
         # the package's own Place objects (what get_device_place()
         # returns) resolve through the device-context pool so the
-        # accounted device is the SAME one tensor placement uses —
-        # including its fallback (TPUPlace on a CPU-only host reads
-        # the device eager tensors actually land on, not an error)
+        # accounted device is the SAME one tensor placement uses
         return _place_device_of(device)
     if isinstance(device, int):
         return jax.devices()[device]
@@ -436,14 +434,12 @@ def memory_section(census=True, jit_report=None):
 
 def is_oom_error(exc):
     """True when `exc` is the XLA runtime's RESOURCE_EXHAUSTED (the
-    HBM-exhaustion crash on TPU). Classified by type NAME + message —
-    jaxlib moves XlaRuntimeError between modules across versions, and
-    message matching keeps `Out of memory` variants (BFC allocator
-    text) classified even if the canonical code string changes."""
-    if exc is None:
-        return False
-    name = type(exc).__name__
-    if name not in ("XlaRuntimeError", "JaxRuntimeError"):
+    HBM-exhaustion crash on TPU): a jax.errors.JaxRuntimeError whose
+    message carries the status code or the BFC allocator's
+    `Out of memory` text."""
+    import jax
+
+    if not isinstance(exc, jax.errors.JaxRuntimeError):
         return False
     msg = str(exc)
     return "RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg

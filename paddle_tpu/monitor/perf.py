@@ -217,30 +217,34 @@ _KIND_TAGS = (
 def device_peaks():
     """The roofline ceilings for THIS process's default device:
     {"device_kind", "matched", "peak_tflops", "hbm_gbps",
-    "ici_gbps"}. device_kind comes from PJRT; unknown kinds (and the
-    CPU client) fall back to the cpu row. PADDLE_PEAK_TFLOPS /
-    PADDLE_HBM_GBPS / PADDLE_ICI_GBPS override individual legs —
-    both the bench MFU column and the per-program MFU read THIS
-    function, so the two can never disagree on the peak."""
-    kind = "cpu"
-    try:
-        # evidence-gathering rule (shared with flight's dump path and
-        # the /perfz handler): NEVER initialize a backend just to read
-        # its kind — a debug page touching jax.devices() first could
-        # pick a platform mid-rendezvous. Uninitialized reads as cpu.
-        if _flight._jax_backends_live():
-            import jax
+    "ici_gbps"}. device_kind comes from PJRT; the CPU client takes
+    the cpu row, and an accelerator whose kind is not in PEAK_TABLE
+    raises — a utilization against a guessed peak is worse than none.
+    PADDLE_PEAK_TFLOPS / PADDLE_HBM_GBPS / PADDLE_ICI_GBPS override
+    individual legs — both the bench MFU column and the per-program
+    MFU read THIS function, so the two can never disagree on the
+    peak."""
+    kind, platform = "cpu", "cpu"
+    # evidence-gathering rule (shared with flight's dump path and
+    # the /perfz handler): NEVER initialize a backend just to read
+    # its kind — a debug page touching jax.devices() first could
+    # pick a platform mid-rendezvous. Uninitialized reads as cpu.
+    if _flight._jax_backends_live():
+        import jax
 
-            kind = str(getattr(jax.devices()[0], "device_kind", "")
-                       or jax.devices()[0].platform)
-    except Exception:
-        pass
+        dev = jax.devices()[0]
+        platform = dev.platform
+        kind = str(dev.device_kind or platform)
     low = kind.lower()
-    matched = "cpu"
+    matched = "cpu" if platform == "cpu" else None
     for subs, tag in _KIND_TAGS:
         if any(s in low for s in subs):
             matched = tag
             break
+    if matched is None:
+        raise ValueError(
+            f"device kind {kind!r} (platform {platform!r}) is not in "
+            "monitor.perf.PEAK_TABLE — add its published peaks there")
     tf, hbm, ici = PEAK_TABLE[matched]
     return {
         "device_kind": kind,

@@ -134,14 +134,10 @@ def _env_host():
 def _in_trace():
     """True inside a jax trace — serve() must never arm there (a
     traced fit would start one server per TRACE, a classic trace-time
-    side effect). Total fallback: an unimportable/old jax reads as
-    not-tracing."""
-    try:
-        import jax
+    side effect)."""
+    import jax
 
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 # ---------------------------------------------------------------------------

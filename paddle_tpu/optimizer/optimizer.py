@@ -181,7 +181,7 @@ class Optimizer:
         if self._pallas_fused_kind is not None:
             from ..incubate.nn import pallas as _pallas
 
-            if _pallas.kernels_available():
+            if _pallas.optim_supported():
                 out = _pallas.optim.apply_fused(self, params, grads,
                                                 state, lr)
                 if out is not None:

@@ -259,3 +259,31 @@ def test_persisted_program_survives_differentiable_call(cache_dir):
     assert not prog._fallback
     c = _counters()
     assert c["hits"] >= 1 and c["errors"] == 0
+
+
+def test_warm_hit_dispatches_on_the_devices_it_was_compiled_for(cache_dir):
+    """The 8-virtual-device regression: a deserialized executable must
+    load onto the devices its program was compiled for — one device,
+    or a 4-device mesh in a non-default order — not onto every device
+    of the backend (`Expected args to execute_sharded_on_local_devices
+    to have 8 shards, got: [1]` at the first dispatch)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    assert jax.device_count() == 8
+    devs = [jax.devices()[i] for i in (5, 2, 7, 0)]
+    mesh = Mesh(np.array(devs).reshape(2, 2), ("a", "b"))
+    sharded = jax.device_put(jnp.arange(64.0).reshape(8, 8),
+                             NamedSharding(mesh, P("a", "b")))
+    single = jnp.arange(16.0).reshape(4, 4)
+    fn = jax.jit(lambda x: x @ x.T + x)
+    for label, x in (("mesh", sharded), ("single", single)):
+        cold, out_c = pcache.load_or_compile(fn.lower(x), label)
+        warm, out_w = pcache.load_or_compile(fn.lower(x), label)
+        assert (out_c, out_w) == ("miss", "hit")
+        got = warm(x)                      # the dispatch that crashed
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(cold(x)))
+        assert got.sharding.device_set == x.sharding.device_set
+    assert _counters()["errors"] == 0

@@ -296,14 +296,18 @@ class TestZeroOverhead:
         assert not any(t.name == "paddle-monitor-serve"
                        for t in threading.enumerate())
 
-    def test_lowering_bit_identical_with_and_without_server(self):
+    def test_lowering_bit_identical_with_and_without_server(
+            self, hlo_sans_locations):
         x = paddle.to_tensor(np.ones((4, 4), np.float32))
         y = paddle.to_tensor(np.zeros((4,), dtype="int64"))
-        plain1 = _zeroed_step().lower_compiled(x, y).as_text()
-        plain2 = _zeroed_step().lower_compiled(x, y).as_text()
+        plain1 = hlo_sans_locations(
+            _zeroed_step().lower_compiled(x, y).as_text())
+        plain2 = hlo_sans_locations(
+            _zeroed_step().lower_compiled(x, y).as_text())
         assert plain1 == plain2  # deterministic baseline
         mserver.serve(port=0, host="127.0.0.1")
-        armed = _zeroed_step().lower_compiled(x, y).as_text()
+        armed = hlo_sans_locations(
+            _zeroed_step().lower_compiled(x, y).as_text())
         assert armed == plain1  # the server never touches lowering
 
     def test_env_falsy_spellings_disarm_but_zero_is_a_port(

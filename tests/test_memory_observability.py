@@ -18,7 +18,7 @@ from paddle_tpu import device
 from paddle_tpu.core import monitor as core_monitor
 from paddle_tpu.monitor import flight, memory
 from paddle_tpu.monitor.cli import main as cli_main
-from jaxlib.xla_extension import XlaRuntimeError
+from jax.errors import JaxRuntimeError
 
 OOM_MSG = ("RESOURCE_EXHAUSTED: Out of memory allocating "
            "1099511627776 bytes (simulated)")
@@ -412,8 +412,8 @@ def test_profiler_step_mem_env_off(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_is_oom_error_classification():
-    assert memory.is_oom_error(XlaRuntimeError(OOM_MSG))
-    assert not memory.is_oom_error(XlaRuntimeError("INTERNAL: boom"))
+    assert memory.is_oom_error(JaxRuntimeError(OOM_MSG))
+    assert not memory.is_oom_error(JaxRuntimeError("INTERNAL: boom"))
     assert not memory.is_oom_error(ValueError(OOM_MSG))
     assert not memory.is_oom_error(None)
 
@@ -421,14 +421,14 @@ def test_is_oom_error_classification():
 def test_oom_observer_writes_bundle_with_census(tmp_path):
     held = jax.device_put(np.ones((333, 333), np.float32))
     try:
-        with pytest.raises(XlaRuntimeError):
+        with pytest.raises(JaxRuntimeError):
             with memory.oom_observer():
-                raise XlaRuntimeError(OOM_MSG)
+                raise JaxRuntimeError(OOM_MSG)
         paths = glob.glob(str(tmp_path / "oom_*.json"))
         assert len(paths) == 1
         bundle = json.load(open(paths[0]))
         assert bundle["reason"] == "oom"
-        assert bundle["exception"]["type"] == "XlaRuntimeError"
+        assert bundle["exception"]["type"] == "JaxRuntimeError"
         mem = bundle["memory"]
         assert mem["device"]["allocated_bytes"] >= held.nbytes
         assert any(tuple(g["shape"]) == (333, 333)
@@ -443,8 +443,8 @@ def test_oom_observer_writes_bundle_with_census(tmp_path):
 
 def test_excepthook_classifies_oom_reason(tmp_path):
     flight.install_excepthook()
-    flight._flight_excepthook(XlaRuntimeError,
-                              XlaRuntimeError(OOM_MSG), None)
+    flight._flight_excepthook(JaxRuntimeError,
+                              JaxRuntimeError(OOM_MSG), None)
     assert glob.glob(str(tmp_path / "oom_*.json"))
     assert not glob.glob(str(tmp_path / "crash_*.json"))
 
@@ -453,20 +453,20 @@ def test_excepthook_skips_already_dumped_oom(tmp_path):
     """oom_observer bundles first (census while arrays live); the
     excepthook must not shadow it with a second dump."""
     flight.install_excepthook()
-    exc = XlaRuntimeError(OOM_MSG)
-    with pytest.raises(XlaRuntimeError):
+    exc = JaxRuntimeError(OOM_MSG)
+    with pytest.raises(JaxRuntimeError):
         with memory.oom_observer():
             raise exc
-    flight._flight_excepthook(XlaRuntimeError, exc, None)
+    flight._flight_excepthook(JaxRuntimeError, exc, None)
     assert len(glob.glob(str(tmp_path / "*_rank*_pid*.json"))) == 1
 
 
 def test_oom_observer_custom_reason_keeps_census(tmp_path):
     """oom_observer(reason=...) exists to be renamed — the bundle
     must keep the census regardless of the reason string."""
-    with pytest.raises(XlaRuntimeError):
+    with pytest.raises(JaxRuntimeError):
         with memory.oom_observer(reason="train_oom"):
-            raise XlaRuntimeError(OOM_MSG)
+            raise JaxRuntimeError(OOM_MSG)
     paths = glob.glob(str(tmp_path / "train_oom_*.json"))
     assert len(paths) == 1
     assert "census" in json.load(open(paths[0]))["memory"]
@@ -498,11 +498,11 @@ def test_fit_oom_leaves_bundle(tmp_path, monkeypatch):
     monkeypatch.setattr(
         Model, "_train_batch_tail",
         lambda self, ins, lbls: (_ for _ in ()).throw(
-            XlaRuntimeError(OOM_MSG)))
+            JaxRuntimeError(OOM_MSG)))
     x = np.random.randn(8, 4).astype(np.float32)
     y = np.random.randint(0, 2, (8,)).astype(np.int64)
     ds = [(x[i], y[i]) for i in range(8)]
-    with pytest.raises(XlaRuntimeError):
+    with pytest.raises(JaxRuntimeError):
         m.fit(ds, batch_size=4, epochs=1, verbose=0)
     paths = glob.glob(str(tmp_path / "oom_*.json"))
     assert len(paths) == 1
@@ -527,11 +527,11 @@ def test_fit_oom_observer_respects_autoarm_off(tmp_path, monkeypatch):
     monkeypatch.setattr(
         Model, "_train_batch_tail",
         lambda self, ins, lbls: (_ for _ in ()).throw(
-            XlaRuntimeError(OOM_MSG)))
+            JaxRuntimeError(OOM_MSG)))
     x = np.random.randn(8, 4).astype(np.float32)
     y = np.random.randint(0, 2, (8,)).astype(np.int64)
     ds = [(x[i], y[i]) for i in range(8)]
-    with pytest.raises(XlaRuntimeError):
+    with pytest.raises(JaxRuntimeError):
         m.fit(ds, batch_size=4, epochs=1, verbose=0)
     assert not glob.glob(str(tmp_path / "oom_*.json"))
 
