@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import profiler as _profiler
 from ..core import monitor as _monitor
 from ..core.engine import apply_op, in_trace_mode
 from ..monitor import chaos as _chaos
@@ -129,12 +128,11 @@ def _group_of(args, kwargs):
 def _instrumented(op):
     """Per-collective telemetry + forensics (reference: RecordEvent at
     every c_* op + STAT_ADD comm counters + the distributed hang
-    diagnosis around collectives): a `comm/<op>` host span when a
-    profiler is capturing, `comm/<op>/{calls,bytes,host_us}` registry
-    counters always, and a flight-recorder in-flight span
-    (collective_begin/_end events with op/group/bytes) so the watchdog
-    can
-    name the exact collective a wedged rank is sitting in — asymmetric
+    diagnosis around collectives): `comm/<op>/{calls,bytes,host_us}`
+    registry counters, and a flight-recorder in-flight span
+    (collective_begin/_end events with op/group/bytes; the program
+    span `comm/<op>`, so also in a profiler's capture) so the watchdog
+    can name the exact collective a wedged rank is sitting in — asymmetric
     participation hangs silently rather than erroring. `host_us` is
     host-side dispatch/transport wall time — inside a compiled trace
     that is trace-time, the device time lives in the XPlane capture."""
@@ -183,16 +181,14 @@ def _instrumented(op):
             _wire_tls.value = None  # compress path overrides below
             t0 = _time.perf_counter()
             try:
-                with _profiler.RecordEvent(f"comm/{op}",
-                                           "Communication"):
-                    # chaos site "collective" sits INSIDE the flight
-                    # in-flight span, so an injected stall is exactly
-                    # what the watchdog sees for a real wedged
-                    # collective (and an injected raise rides the
-                    # same finally-cleanup path)
-                    if _chaos._armed:
-                        _chaos.hit("collective", op=op)
-                    out = fn(*args, **kwargs)
+                # chaos site "collective" sits INSIDE the flight
+                # in-flight span, so an injected stall is exactly
+                # what the watchdog sees for a real wedged
+                # collective (and an injected raise rides the
+                # same finally-cleanup path)
+                if _chaos._armed:
+                    _chaos.hit("collective", op=op)
+                out = fn(*args, **kwargs)
             finally:
                 # the flight exit must fire even when the collective
                 # raises — a leaked in-flight entry would look like a

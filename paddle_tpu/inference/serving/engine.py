@@ -88,6 +88,7 @@ the router's per-replica health signal.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import time
@@ -97,6 +98,7 @@ import numpy as np
 from ...core import monitor as _cmon
 from ...monitor import chaos as _chaos
 from ...monitor import flight as _flight
+from ...monitor import memory as _memory
 from ...monitor import perf as _perf
 from ...monitor import sanitize as _san
 from ...monitor import trace as _trace
@@ -107,6 +109,8 @@ from .scheduler import (EngineOverloaded, EXPORTED, FINISHED,
                         Request, SamplingParams, Scheduler)
 
 __all__ = ["LLMEngine", "EngineTimeout"]
+
+_NOT_COMPILING = contextlib.nullcontext()
 
 
 class EngineTimeout(TimeoutError):
@@ -221,6 +225,11 @@ class LLMEngine:
             f"serve_decode:{type(model).__name__}")
         self._prefill_label = (
             f"serve_prefill:{type(model).__name__}")
+        self._tail_label = (
+            f"serve_prefill_tail:{type(model).__name__}")
+        self._draft_label = f"serve_draft:{type(model).__name__}"
+        self._verify_label = f"serve_verify:{type(model).__name__}"
+        self._steps = 0              # engine steps begun (span id)
         # padded len -> ledger ordinal: each prefill bucket is its
         # own compiled program and gets its own perf/program entry
         # (first bucket keeps the plain label, later ones "#n" —
@@ -309,6 +318,28 @@ class LLMEngine:
         its tokens would double-serve requests replaying elsewhere."""
         if self._fenced:
             return {}
+        self._steps += 1
+        with _flight.span("serve/step", step=self._steps):
+            return self._step()
+
+    def _program(self, name, fresh):
+        """Count one dispatch of a jitted program of the engine as
+        `jit/<name>/cache_miss` or `/cache_hit`, as jit/__init__.py
+        counts its own; the first dispatch, which compiles (or loads
+        what a cache kept), runs inside the span `compile/<name>`."""
+        if fresh:
+            _cmon.stat_add(f"jit/{name}/cache_miss", 1)
+            return _flight.span(f"compile/{name}", program=name)
+        _cmon.stat_add(f"jit/{name}/cache_hit", 1)
+        return _NOT_COMPILING
+
+    def _step(self):
+        """step() inside its span `serve/step` (id `step`). Children:
+        `serve/schedule` (with a `serve/prefill` for each admission),
+        `serve/decode/prepare`, `serve/decode` around
+        `serve/decode/enqueue` and `serve/decode/fetch`,
+        `serve/decode/emit`, and `serve/evict` where a dispatch ran
+        out of memory."""
         emitted = {}
 
         def _on_admit(req):
@@ -317,7 +348,8 @@ class LLMEngine:
             # with never-written K/V
             self._emit(req, self._prefill(req), emitted)
 
-        admitted = self.scheduler.schedule(on_admit=_on_admit)
+        with _flight.span("serve/schedule"):
+            admitted = self.scheduler.schedule(on_admit=_on_admit)
         if not admitted and not self.scheduler.running \
                 and self.scheduler.waiting:
             # an idle engine that can't admit its queue head will
@@ -439,7 +471,9 @@ class LLMEngine:
         fresh_bucket = padded not in self._prefill_jits
         t0 = time.perf_counter()
         with _flight.in_flight("serve_prefill", req.req_id,
-                               tokens=plen):
+                               req=req.trace_id or req.req_id,
+                               padded=padded, tokens=plen), \
+                self._program(self._prefill_name(padded), fresh_bucket):
             tok, self.cache.k, self.cache.v = self._prefill_fn(padded)(
                 self.params, jnp.asarray(ids), np.int32(plen),
                 self.cache.k, self.cache.v, jnp.asarray(table),
@@ -471,6 +505,15 @@ class LLMEngine:
         self.cache.register_prefix(req.req_id, ctx)
         self.heartbeat = time.monotonic()
         return tok
+
+    def _prefill_name(self, padded):
+        """A prefill bucket's program name: each bucket is its own
+        compiled program; the first keeps the plain label, later ones
+        "#n" in the order they first ran."""
+        n = self._prefill_captured.get(padded,
+                                       len(self._prefill_captured))
+        return (self._prefill_label if n == 0
+                else f"{self._prefill_label}#{n}")
 
     def _tail_fn(self, t_pad, draft):
         import jax
@@ -512,7 +555,11 @@ class LLMEngine:
         s = req.sampling
         t0 = time.perf_counter()
         with _flight.in_flight("serve_prefill", req.req_id,
-                               tokens=len(tail), cached=cached):
+                               req=req.trace_id or req.req_id,
+                               padded=t_pad, tokens=len(tail),
+                               cached=cached), \
+                self._program(f"{self._tail_label}@{t_pad}",
+                              t_pad not in self._tail_jits):
             tok, self.cache.k, self.cache.v = \
                 self._tail_fn(t_pad, draft=False)(
                     self.params, jnp.asarray(ids), np.int32(cached),
@@ -545,24 +592,25 @@ class LLMEngine:
         """Roofline-ledger capture for one prefill bucket: an AOT
         lower+compile over the just-dispatched shapes (the NEW pools
         stand in for the donated-away ones — same avals), then
-        `perf/program/serve_prefill:<Model>[#n]/*`. One extra backend
+        `perf/program/serve_prefill:<Model>[#n]/*` and
+        `mem/program/serve_prefill:<Model>[#n]/*`. One extra backend
         compile per bucket, first dispatch only — the jit capture
-        discipline; PADDLE_PERF_PROGRAM=0 opts out. Never raises."""
+        discipline; PADDLE_PERF_PROGRAM=0 + PADDLE_MEM_PROGRAM=0
+        together opt out. Never raises."""
         import jax.numpy as jnp
 
-        if not _perf.program_capture_enabled():
+        if not (_perf.program_capture_enabled()
+                or _memory.program_capture_enabled()):
             return
         try:
-            n = self._prefill_captured[padded]
-            name = (self._prefill_label if n == 0
-                    else f"{self._prefill_label}#{n}")
-            with _flight.in_flight("perf_capture", name):
+            name = self._prefill_name(padded)
+            with _flight.in_flight("perf_capture", name, program=name):
                 compiled = self._prefill_fn(padded).lower(
                     self.params, jnp.asarray(ids), np.int32(plen),
                     self.cache.k, self.cache.v, jnp.asarray(table),
                     np.float32(s.temperature), np.int32(s.top_k),
                     np.uint32(0)).compile()
-            _perf.record_program_cost(name, compiled)
+            self._record_program(name, compiled)
         except Exception:
             pass  # the ledger is observability, never a serving error
 
@@ -597,23 +645,27 @@ class LLMEngine:
         import jax.numpy as jnp
 
         ids, pos, tables, lens, temp, topk, seeds = arrays
-        args = (self.params, jnp.asarray(ids), jnp.asarray(pos),
-                self.cache.k, self.cache.v, jnp.asarray(tables),
-                jnp.asarray(lens), jnp.asarray(temp),
-                jnp.asarray(topk), jnp.asarray(seeds))
-        if self._decode_exe is None:
-            self._load_persistent(args)
-        fn = self._decode_exe or self._decode_jit
-        try:
-            toks, self.cache.k, self.cache.v = fn(*args)
-        except TypeError:
-            if fn is not self._decode_jit:   # stale cached executable
-                self._decode_exe = self._decode_jit
-                toks, self.cache.k, self.cache.v = \
-                    self._decode_jit(*args)
-            else:
-                raise
-        return np.asarray(toks)
+        with self._program(self._pcache_label,
+                           self._decode_exe is None), \
+                _flight.span("serve/decode/enqueue"):
+            args = (self.params, jnp.asarray(ids), jnp.asarray(pos),
+                    self.cache.k, self.cache.v, jnp.asarray(tables),
+                    jnp.asarray(lens), jnp.asarray(temp),
+                    jnp.asarray(topk), jnp.asarray(seeds))
+            if self._decode_exe is None:
+                self._load_persistent(args)
+            fn = self._decode_exe or self._decode_jit
+            try:
+                toks, self.cache.k, self.cache.v = fn(*args)
+            except TypeError:
+                if fn is not self._decode_jit:   # stale executable
+                    self._decode_exe = self._decode_jit
+                    toks, self.cache.k, self.cache.v = \
+                        self._decode_jit(*args)
+                else:
+                    raise
+        with _flight.span("serve/decode/fetch"):
+            return np.asarray(toks)
 
     def _load_persistent(self, args):
         """First decode dispatch: route the compile through the PR-8
@@ -636,21 +688,35 @@ class LLMEngine:
         self._capture_decode_cost(args, compiled=compiled)
 
     def _capture_decode_cost(self, args, compiled=None):
-        """Roofline-ledger capture for the decode program
-        (`perf/program/serve_decode:<Model>/*`). Reuses the
+        """Roofline-ledger and memory-footprint capture for the
+        decode program (`perf/program/serve_decode:<Model>/*`,
+        `mem/program/serve_decode:<Model>/*`). Reuses the
         persistent-cache executable when one exists; otherwise one
         extra AOT backend compile at first dispatch —
-        PADDLE_PERF_PROGRAM=0 opts out. Never raises."""
-        if not _perf.program_capture_enabled():
+        PADDLE_PERF_PROGRAM=0 + PADDLE_MEM_PROGRAM=0 together opt
+        out. Never raises."""
+        if not (_perf.program_capture_enabled()
+                or _memory.program_capture_enabled()):
             return
         try:
             if compiled is None:
                 with _flight.in_flight("perf_capture",
-                                       self._pcache_label):
+                                       self._pcache_label,
+                                       program=self._pcache_label):
                     compiled = self._decode_jit.lower(*args).compile()
-            _perf.record_program_cost(self._pcache_label, compiled)
+            self._record_program(self._pcache_label, compiled)
         except Exception:
             pass  # the ledger is observability, never a serving error
+
+    @staticmethod
+    def _record_program(name, compiled):
+        """The gauges of one compiled program: its cost_analysis()
+        ledger and its memory_analysis() footprint (temp_bytes is
+        what the program needs beyond its arguments while it runs)."""
+        if _perf.program_capture_enabled():
+            _perf.record_program_cost(name, compiled)
+        if _memory.program_capture_enabled():
+            _memory.record_program_memory(name, compiled)
 
     def _pools_deleted(self):
         """Did a failed DONATING dispatch consume the pools? (A real
@@ -681,11 +747,12 @@ class LLMEngine:
         # growing request A can evict request B later in the
         # snapshot, and growing an evicted B would strand blocks on
         # a request the dispatch no longer covers
-        for req in list(self.scheduler.running.values()):
-            self.scheduler.ensure_capacity(req, new_tokens=1)
-        if not self.scheduler.running:
-            return
-        arrays = self._batch_arrays()
+        with _flight.span("serve/decode/prepare"):
+            for req in list(self.scheduler.running.values()):
+                self.scheduler.ensure_capacity(req, new_tokens=1)
+            if not self.scheduler.running:
+                return
+            arrays = self._batch_arrays()
         # first decode dispatch compiles (and runs _load_persistent)
         # — keep it out of the dispatch histogram like prefill
         fresh_decode = self._decode_exe is None
@@ -698,26 +765,8 @@ class LLMEngine:
                                batch=len(self.scheduler.running))
                 toks = self._dispatch_decode(arrays)
         except Exception as e:
-            from ...monitor import memory as _memory
-
-            if not _memory.is_oom_error(e):
-                raise
-            self._oom_streak += 1
-            if self._oom_streak > max(3, self.max_batch):
-                raise
-            _cmon.stat_add("serve/oom_evictions", 1)
-            if self._pools_deleted():
-                _cmon.stat_add("serve/pool_resets", 1)
-                _flight.record("serve_pool_reset",
-                               batch=len(self.scheduler.running))
-                for req in list(self.scheduler.running.values()):
-                    self.scheduler.evict(req)
-                self.cache.reset_pools()
+            if not self._evict_for_oom(e):
                 return                # next step() re-prefills
-            victim = self.scheduler._pick_victim()
-            if victim is None:
-                raise
-            self.scheduler.evict(victim)
             return self._decode_batch(emitted)
         self._oom_streak = 0
         self.heartbeat = time.monotonic()
@@ -727,8 +776,41 @@ class LLMEngine:
             # _dispatch_decode's np.asarray(toks) already blocked —
             # measured device time for the roofline, like prefill
             _perf.observe_dispatch(self._pcache_label, decode_us)
-        for slot, req in list(self.scheduler.running.items()):
-            self._emit(req, int(toks[slot]), emitted)
+        with _flight.span("serve/decode/emit"):
+            for slot, req in list(self.scheduler.running.items()):
+                self._emit(req, int(toks[slot]), emitted)
+
+    def _evict_for_oom(self, e):
+        """What a decode dispatch that failed with `e` costs: re-raises
+        anything but an out-of-memory, and that too once it persists;
+        else makes room, as the span `serve/evict`. True: a victim was
+        evicted, dispatch again with the smaller batch. False: the
+        failed dispatch had consumed the donated pools, so every
+        running request was evicted and the pools rebuilt. An
+        out-of-memory that the compiler raised (the program cannot
+        fit, whatever the batch holds) counts `serve/compile_oom`."""
+        if not _memory.is_oom_error(e):
+            raise e
+        if _memory.is_compile_oom_error(e):
+            _cmon.stat_add("serve/compile_oom", 1)
+        self._oom_streak += 1
+        if self._oom_streak > max(3, self.max_batch):
+            raise e
+        with _flight.span("serve/evict"):
+            _cmon.stat_add("serve/oom_evictions", 1)
+            if self._pools_deleted():
+                _cmon.stat_add("serve/pool_resets", 1)
+                _flight.record("serve_pool_reset",
+                               batch=len(self.scheduler.running))
+                for req in list(self.scheduler.running.values()):
+                    self.scheduler.evict(req)
+                self.cache.reset_pools()
+                return False
+            victim = self.scheduler._pick_victim()
+            if victim is None:
+                raise e
+            self.scheduler.evict(victim)
+            return True
 
     # -- speculative decode (spec_k > 1) -----------------------------
     def _wide_tables(self, tables):
@@ -847,12 +929,14 @@ class LLMEngine:
             for t in range(k):
                 v_seeds[slot, t] = _mr.seed_for(req.sampling.seed,
                                                 len(ctx) + t)
-        toks, self.cache.k, self.cache.v = self._verify_jit(
-            self.params, jnp.asarray(v_ids), jnp.asarray(pos),
-            self.cache.k, self.cache.v, wide_j, jnp.asarray(lens),
-            jnp.asarray(temp), jnp.asarray(topk),
-            jnp.asarray(v_seeds))
-        return np.asarray(toks)
+        with _flight.span("serve/decode/enqueue"):
+            toks, self.cache.k, self.cache.v = self._verify_jit(
+                self.params, jnp.asarray(v_ids), jnp.asarray(pos),
+                self.cache.k, self.cache.v, wide_j, jnp.asarray(lens),
+                jnp.asarray(temp), jnp.asarray(topk),
+                jnp.asarray(v_seeds))
+        with _flight.span("serve/decode/fetch"):
+            return np.asarray(toks)
 
     def _spec_decode_batch(self, emitted):
         """One speculative round: k draft dispatches propose, one
@@ -866,17 +950,19 @@ class LLMEngine:
         import jax.numpy as jnp
 
         k = self.spec_k
-        for req in list(self.scheduler.running.values()):
-            # k-aware growth, capped so an almost-finished sequence
-            # never asks for blocks past max_seq_len's table width
-            self.scheduler.ensure_capacity(req, new_tokens=min(
-                k, max(1, self.max_seq_len - req.context_len)))
-        if not self.scheduler.running:
-            return
-        arrays = self._batch_arrays()
-        wide_j = jnp.asarray(self._wide_tables(arrays[2]))
-        running = dict(self.scheduler.running)
-        self._check_spec_cow(running)
+        with _flight.span("serve/decode/prepare"):
+            for req in list(self.scheduler.running.values()):
+                # k-aware growth, capped so an almost-finished
+                # sequence never asks for blocks past max_seq_len's
+                # table width
+                self.scheduler.ensure_capacity(req, new_tokens=min(
+                    k, max(1, self.max_seq_len - req.context_len)))
+            if not self.scheduler.running:
+                return
+            arrays = self._batch_arrays()
+            wide_j = jnp.asarray(self._wide_tables(arrays[2]))
+            running = dict(self.scheduler.running)
+            self._check_spec_cow(running)
         fresh_decode = self._verify_jit is not None \
             and not getattr(self, "_spec_warm", False)
         t0 = time.perf_counter()
@@ -885,7 +971,9 @@ class LLMEngine:
                                    batch=len(running), k=k):
                 if _chaos._armed:
                     _chaos.hit("serve_decode", batch=len(running))
-                drafts = self._draft_propose(running, wide_j)
+                with self._program(self._draft_label, fresh_decode), \
+                        _flight.span("serve/decode/draft"):
+                    drafts = self._draft_propose(running, wide_j)
                 if _chaos._armed:
                     rule = _chaos.hit("serve_spec_verify",
                                       batch=len(running), k=k)
@@ -898,29 +986,12 @@ class LLMEngine:
                         drafts = {
                             slot: [(d + 1) % vocab for d in ds]
                             for slot, ds in drafts.items()}
-                toks = self._dispatch_verify(running, drafts,
-                                             wide_j, arrays)
+                with self._program(self._verify_label, fresh_decode):
+                    toks = self._dispatch_verify(running, drafts,
+                                                 wide_j, arrays)
         except Exception as e:
-            from ...monitor import memory as _memory
-
-            if not _memory.is_oom_error(e):
-                raise
-            self._oom_streak += 1
-            if self._oom_streak > max(3, self.max_batch):
-                raise
-            _cmon.stat_add("serve/oom_evictions", 1)
-            if self._pools_deleted():
-                _cmon.stat_add("serve/pool_resets", 1)
-                _flight.record("serve_pool_reset",
-                               batch=len(self.scheduler.running))
-                for req in list(self.scheduler.running.values()):
-                    self.scheduler.evict(req)
-                self.cache.reset_pools()
+            if not self._evict_for_oom(e):
                 return                # next step() re-prefills
-            victim = self.scheduler._pick_victim()
-            if victim is None:
-                raise
-            self.scheduler.evict(victim)
             return self._spec_decode_batch(emitted)
         self._oom_streak = 0
         self._spec_warm = True
@@ -929,23 +1000,24 @@ class LLMEngine:
         _cmon.stat_add("serve/decode_us", decode_us)
         if not fresh_decode and _perf.dispatch_timing_enabled():
             _perf.observe_dispatch(self._pcache_label, decode_us)
-        for slot, req in sorted(running.items()):
-            ds = drafts[slot]
-            row = toks[slot]
-            m = 0
-            while m < len(ds) and ds[m] == int(row[m]):
-                m += 1
-            _cmon.stat_add("serve/spec/proposed", len(ds))
-            _cmon.stat_add("serve/spec/accepted", m)
-            _cmon.hist_observe("serve/hist/accept_len", m + 1)
-            # all proposals accepted -> one draft-KV position was
-            # never written (verify writes only TARGET KV); the next
-            # round's realign step fills it
-            req._spec_gap = (m == len(ds))
-            for t in range(m + 1):
-                self._emit(req, int(row[t]), emitted)
-                if req.finished:
-                    break
+        with _flight.span("serve/decode/emit"):
+            for slot, req in sorted(running.items()):
+                ds = drafts[slot]
+                row = toks[slot]
+                m = 0
+                while m < len(ds) and ds[m] == int(row[m]):
+                    m += 1
+                _cmon.stat_add("serve/spec/proposed", len(ds))
+                _cmon.stat_add("serve/spec/accepted", m)
+                _cmon.hist_observe("serve/hist/accept_len", m + 1)
+                # all proposals accepted -> one draft-KV position was
+                # never written (verify writes only TARGET KV); the
+                # next round's realign step fills it
+                req._spec_gap = (m == len(ds))
+                for t in range(m + 1):
+                    self._emit(req, int(row[t]), emitted)
+                    if req.finished:
+                        break
 
     # -- token emission / stop conditions ----------------------------
     def _emit(self, req, token, emitted):

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import tree_util
 
-from .. import profiler as _profiler
 from ..core import engine
 from ..core import monitor as _monitor
 from ..core.tensor import Tensor
@@ -355,7 +354,7 @@ class StaticFunction:
                max_loop_iterations())
         fname = self._telemetry_key
         entry = self._compiled.get(key)
-        compile_ev = None
+        compiling = False
         compile_tok = None
         if entry is None:
             # opt-in static analysis at build time (PADDLE_ANALYSIS=1,
@@ -374,13 +373,11 @@ class StaticFunction:
             _monitor.stat_add(f"jit/{fname}/cache_miss", 1)
             _flight.record("jit_cache_miss", fn=fname)
             arm_compile_cache()
-            compile_ev = _profiler.RecordEvent(
-                f"jit/compile/{fname}", "JitCompile")
-            compile_ev.begin()
-            # watchdog-visible compile span (a pathological XLA
-            # compile is a hang from the outside; same lifetime as
-            # compile_ev — build + first lazy jfn invocation)
-            compile_tok = _flight.begin("compile", fname)
+            compiling = True
+            # the span `compile/<fn>`, watchdog-visible (a
+            # pathological XLA compile is a hang from the outside):
+            # build + first lazy jfn invocation
+            compile_tok = _flight.begin("compile", fname, program=fname)
             t_compile0 = _time.perf_counter()
             try:
                 entry = self._build(target, params, args_treedef,
@@ -390,7 +387,6 @@ class StaticFunction:
                 # finally below is never reached, and a leaked
                 # in-flight compile looks like a permanent hang to
                 # the watchdog
-                compile_ev.end()
                 _flight.end(compile_tok)
                 raise
             if _pcache.enabled():
@@ -413,7 +409,7 @@ class StaticFunction:
             # FIRST call — it runs jfn's lazy XLA compile, and a
             # compile-laced sample would dominate the p99 of a
             # program dispatched a handful of times
-            timing = compile_ev is None \
+            timing = not compiling \
                 and _perf.dispatch_timing_enabled()
             if requires:
                 # differentiable boundary: the compiled forward is one
@@ -463,8 +459,7 @@ class StaticFunction:
             call_ok = True
             return tree_util.tree_unflatten(box["treedef"], flat_out)
         finally:
-            if compile_ev is not None:
-                compile_ev.end()
+            if compiling:
                 _flight.end(compile_tok)
                 compile_us = int(
                     (_time.perf_counter() - t_compile0) * 1e6)
@@ -584,8 +579,8 @@ class StaticFunction:
             # and jit/<fn>/mem_capture_us attribute the time instead
             # of leaving an unexplained first-call gap
             t0 = _time.perf_counter()
-            with _flight.in_flight("mem_capture",
-                                   self._telemetry_key):
+            with _flight.in_flight("mem_capture", self._telemetry_key,
+                                   program=self._telemetry_key):
                 compiled = jfn.lower(p_structs, a_structs,
                                      rng).compile()
             _monitor.stat_add(
@@ -1031,9 +1026,20 @@ class TrainStepCompiler:
                     f" got shape {tuple(shape)}")
 
     def __call__(self, *batch):
-        self._check_microbatch_axis(batch)
-        trainable, frozen, bufs = self._params_and_buffers()
-        self._prepare_call(trainable, frozen, bufs)
+        """One dispatch, as the span `train/step` (id `step`). Its
+        children say what the host was doing: `train/prepare` (here,
+        and again in _run_compiled), `train/enqueue`, `train/block`,
+        `train/finish`; on the first call `compile/train_step` around
+        build, cache load and first dispatch, then
+        `compile/capture/<program>`."""
+        with _flight.span("train/step", step=self._step):
+            return self._step_call(batch)
+
+    def _step_call(self, batch):
+        with _flight.span("train/prepare"):
+            self._check_microbatch_axis(batch)
+            trainable, frozen, bufs = self._params_and_buffers()
+            self._prepare_call(trainable, frozen, bufs)
         if self._compiled is None:
             # opt-in analysis of the model forward about to be fused
             # into the step (PADDLE_ANALYSIS=1, gated inside the
@@ -1055,9 +1061,8 @@ class TrainStepCompiler:
             _flight.record("jit_cache_miss", fn="train_step")
             arm_compile_cache()
             t0 = _time.perf_counter()
-            with _profiler.RecordEvent("jit/compile/train_step",
-                                       "JitCompile"), \
-                    _flight.in_flight("compile", "train_step"):
+            with _flight.in_flight("compile", "train_step",
+                                   program=self._perf_name):
                 self._build(trainable, frozen, bufs, batch)
                 if _pcache.enabled():
                     self._load_persistent(trainable, frozen, bufs,
@@ -1145,7 +1150,7 @@ class TrainStepCompiler:
             # must show in the watchdog's in-flight table, not as an
             # unattributed first-step stall
             t0 = _time.perf_counter()
-            with _flight.in_flight("mem_capture", name):
+            with _flight.in_flight("mem_capture", name, program=name):
                 compiled = self.lower_compiled(*batch)
             _monitor.stat_add(
                 "jit/train_step/mem_capture_us",
@@ -1177,54 +1182,68 @@ class TrainStepCompiler:
 
     def _run_compiled(self, trainable, frozen, bufs, batch,
                       fresh=False):
-        # chaos site "dispatch": a synthetic RESOURCE_EXHAUSTED here
-        # exercises the real OOM-forensics path (is_oom_error
-        # classifies by exception NAME + message)
-        if _chaos._armed:
-            _chaos.hit("dispatch", steps=self._steps_per_dispatch)
-        pvals = {k: p._value for k, p in trainable.items()}
-        fvals = {k: p._value for k, p in frozen.items()}
-        bvals = {k: b._value for k, b in bufs.items()}
-        avals = self._place_batch(batch)
-        # PTA04x donation sanitizer (PADDLE_SANITIZE=donation): scan
-        # the dispatch inputs for already-deleted donated buffers
-        # BEFORE XLA sees them — a stale reference fed back in (the
-        # PR-8 clobbered-_jit_step shape) raises a PTA041 report
-        # naming the donating dispatch instead of the opaque
-        # "buffer has been deleted" crash
-        san_site = None
-        if _sanitize._donation:
-            san_site = (f"train_step:{type(self._model).__name__}"
-                        f" dispatch#{self._step}")
-            _sanitize.check_args(
-                (pvals, self._opt_state, self._accum_state,
-                 self._comm_state, fvals, bvals, avals),
-                site=san_site)
-        # host scalars (jit globalizes them under any mesh/process set)
-        lr = np.float32(self._opt.get_lr())
-        rngc = np.uint32(self._step)
-        prev_opt, prev_acc = self._opt_state, self._accum_state
-        prev_comm = self._comm_state
+        with _flight.span("train/prepare"):
+            # chaos site "dispatch": a synthetic RESOURCE_EXHAUSTED
+            # here exercises the real OOM-forensics path
+            # (is_oom_error classifies by exception NAME + message)
+            if _chaos._armed:
+                _chaos.hit("dispatch", steps=self._steps_per_dispatch)
+            pvals = {k: p._value for k, p in trainable.items()}
+            fvals = {k: p._value for k, p in frozen.items()}
+            bvals = {k: b._value for k, b in bufs.items()}
+            avals = self._place_batch(batch)
+            # PTA04x donation sanitizer (PADDLE_SANITIZE=donation):
+            # scan the dispatch inputs for already-deleted donated
+            # buffers BEFORE XLA sees them — a stale reference fed
+            # back in (the PR-8 clobbered-_jit_step shape) raises a
+            # PTA041 report naming the donating dispatch instead of
+            # the opaque "buffer has been deleted" crash
+            san_site = None
+            if _sanitize._donation:
+                san_site = (f"train_step:{type(self._model).__name__}"
+                            f" dispatch#{self._step}")
+                _sanitize.check_args(
+                    (pvals, self._opt_state, self._accum_state,
+                     self._comm_state, fvals, bvals, avals),
+                    site=san_site)
+            # host scalars (jit globalizes them under any mesh/process
+            # set)
+            lr = np.float32(self._opt.get_lr())
+            rngc = np.uint32(self._step)
+            prev_opt, prev_acc = self._opt_state, self._accum_state
+            prev_comm = self._comm_state
         # skip the fresh (first) dispatch — it runs the lazy XLA
         # compile, and a compile-laced sample would poison the p99
         t_d0 = (_time.perf_counter()
                 if not fresh and _perf.dispatch_timing_enabled()
                 else None)
-        n_traces0 = self._jit_cache_size() if t_d0 is not None \
-            else None
-        try:
-            (new_p, new_opt, new_acc, new_comm, new_b, loss, skips,
-             nstats) = self._compiled(
-                pvals, self._opt_state, self._accum_state,
-                self._comm_state, fvals, bvals, avals, lr, rngc,
-                self._loss_scale())
-        except RuntimeError as e:
-            if _sanitize._donation:
-                better = _sanitize.explain_deleted(
-                    e, site=san_site or "train_step dispatch")
-                if better is not None:
-                    raise better from e
-            raise
+        n_traces0 = None if fresh else self._jit_cache_size()
+        retraced = False
+        with _flight.span("train/enqueue") as enqueue:
+            try:
+                (new_p, new_opt, new_acc, new_comm, new_b, loss, skips,
+                 nstats) = self._compiled(
+                    pvals, self._opt_state, self._accum_state,
+                    self._comm_state, fvals, bvals, avals, lr, rngc,
+                    self._loss_scale())
+                # A dispatch that grew the jit cache retraced (e.g. the
+                # second call, where the freshly initialized opt state's
+                # weak types strengthen): its enqueue was a compile, and
+                # the ring says so, so a trace tells which step recompiled
+                retraced = n_traces0 is not None \
+                    and self._jit_cache_size() != n_traces0
+                if retraced:
+                    _flight.closed_span(
+                        "compile/train_step", enqueue.t0,
+                        _time.perf_counter(), program=self._perf_name,
+                        retrace=1)
+            except RuntimeError as e:
+                if _sanitize._donation:
+                    better = _sanitize.explain_deleted(
+                        e, site=san_site or "train_step dispatch")
+                    if better is not None:
+                        raise better from e
+                raise
         if _sanitize._donation and self._donate:
             # the program just donated argnums (0, 1, 2, 3): register
             # the OLD params/opt-state/accumulators/comm residuals
@@ -1232,22 +1251,29 @@ class TrainStepCompiler:
             # reference reports PTA041 with both ends named
             _sanitize.note_donated((pvals, prev_opt, prev_acc,
                                     prev_comm), site=san_site)
-        if t_d0 is not None \
-                and self._jit_cache_size() == n_traces0:
+        if t_d0 is not None and not retraced:
             # measured roofline leg: block on the loss (the whole
             # program has executed once any output is ready) so the
             # histogram sees device time, not the async enqueue. One
             # ring event per dispatch feeds the StepTimer step-time
             # decomposition and the fleet straggler's top-span table.
-            # A dispatch that grew the jit cache retraced (e.g. the
-            # second call, where the freshly initialized opt state's
-            # weak types strengthen) — compile-laced, skip it like
-            # the fresh dispatch
-            jax.block_until_ready(loss)
+            # A retraced dispatch is compile-laced — skip it like the
+            # fresh dispatch
+            with _flight.span("train/block"):
+                jax.block_until_ready(loss)
             dus = int((_time.perf_counter() - t_d0) * 1e6)
             _perf.observe_dispatch(self._perf_name, dus)
             _flight.record("dispatch_end", name=self._perf_name,
                            dur_us=dus)
+        with _flight.span("train/finish"):
+            return self._finish_dispatch(
+                trainable, bufs, new_p, new_opt, new_acc, new_comm,
+                new_b, loss, skips, nstats)
+
+    def _finish_dispatch(self, trainable, bufs, new_p, new_opt, new_acc,
+                         new_comm, new_b, loss, skips, nstats):
+        """Write the dispatch's state back and do its host-side
+        accounting: what `train/finish` spans."""
         self._opt_state = new_opt
         self._accum_state = new_acc
         self._comm_state = new_comm

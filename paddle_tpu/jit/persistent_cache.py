@@ -46,8 +46,8 @@ LRU-by-mtime eviction keeps the directory under
 PADDLE_COMPILE_CACHE_MAX_BYTES (hits touch mtime).
 
 Counters: jit/persistent_cache/{hits,misses,bytes,errors}; flight
-events `compile_cache` with the outcome + entry size so the PR 1
-`jit/compile_us` spans can be read against what the cache did.
+events `compile_cache` with the outcome + entry size, and the program
+span `cache/load/<label>` inside the caller's `compile/<program>`.
 """
 from __future__ import annotations
 
@@ -242,7 +242,14 @@ def load_or_compile(lowered, label, extra=()):
 
     Returns (compiled, outcome) with outcome in {"off", "hit",
     "miss"}. Never raises on cache trouble — worst case is a plain
-    lowered.compile()."""
+    lowered.compile(). The whole of it is the span
+    `cache/load/<label>`: the load, or the compile and the write
+    that a miss costs."""
+    with _flight.span(f"cache/load/{label}", program=label):
+        return _load_or_compile(lowered, label, extra)
+
+
+def _load_or_compile(lowered, label, extra):
     d = cache_dir()
     if d is None:
         return lowered.compile(), "off"

@@ -8,7 +8,16 @@ stack, distinct from the opt-in profiler: it is ALWAYS on, cheap
 enough to leave armed in production, and it answers "what were the
 last things this rank did" after the fact.
 
-Four pieces:
+Five pieces:
+
+  * program spans — span(name, **ids) is THE span primitive of the
+    program: one call feeds a bounded ring of closed spans on
+    time.perf_counter() (read with spans(); name, start, end, the
+    span that caused it, ids) and a jax.profiler.TraceAnnotation
+    "paddle_tpu/<layer>/<what>", so whenever any profiler session
+    runs the span lies in the trace's host plane on the device's
+    clock. in_flight()/begin()/end() and profiler.RecordEvent go
+    through it. Layers: compile, cache, train, serve, io, comm.
 
   * FlightRecorder — a process-wide bounded ring of structured events
     (step begin/end, jit cache hit/miss, compile begin/end, collective
@@ -41,8 +50,9 @@ Four pieces:
     PADDLE_FLIGHT_AUTOARM=0/1.
 
 Counters (exporter + bench.py pick these up with every snapshot):
-flight/events, flight/ring/dropped, flight/watchdog/fires,
-flight/dumps_written, flight/watchdog/errors.
+flight/events, flight/ring/dropped, flight/spans,
+flight/spans/dropped, flight/watchdog/fires, flight/dumps_written,
+flight/watchdog/errors.
 """
 from __future__ import annotations
 
@@ -58,13 +68,15 @@ import time
 import traceback
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from ..core import monitor as _cmon
 from . import sanitize as _sanitize
 
 __all__ = [
     "DUMP_SCHEMA", "FlightRecorder", "recorder", "record", "tail",
-    "sync_stats", "begin", "end", "in_flight", "inflight_snapshot",
-    "Watchdog",
+    "sync_stats", "SPAN_PREFIX", "Span", "span", "spans", "closed_span",
+    "begin", "end", "in_flight", "inflight_snapshot", "Watchdog",
     "start_watchdog", "stop_watchdog", "get_watchdog", "write_dump",
     "dump_dir", "install_excepthook", "uninstall_excepthook",
     "dump_on_crash", "install_signal_handler",
@@ -153,8 +165,13 @@ def dump_dir():
 # Flight recorder ring
 # ---------------------------------------------------------------------------
 
+SPAN_PREFIX = "paddle_tpu/"
+SPAN_CAPACITY = 32768
+
+
 class FlightRecorder:
-    """Bounded ring of (ts, tid, kind, data) events.
+    """Bounded ring of (ts, tid, kind, data) events, and beside it the
+    bounded ring of closed program spans.
 
     record() is the always-on hot path: one lock acquisition, one
     deque append, one stat bump — cheap enough to ride every jit cache
@@ -173,6 +190,15 @@ class FlightRecorder:
         self._lock = _sanitize.lock("flight.ring")
         self._seq = 0
         self._dropped = 0
+        # closed program spans (see span()). A fixed size, not a knob:
+        # a whole run of the longest benchmark cell, set-up included,
+        # closes a few thousand spans. Appended without the lock:
+        # deque.append and next() on a count are atomic (the total
+        # may lag by a span while two threads close at once), and the
+        # dropped figure is closed spans minus what the ring holds
+        self._spans = deque(maxlen=SPAN_CAPACITY)
+        self._spans_closed = itertools.count(1)
+        self._n_spans = 0
         self.enabled = bool(enabled)
 
     @property
@@ -203,6 +229,33 @@ class FlightRecorder:
             seq, dropped = self._seq, self._dropped
         _cmon.stat_set("flight/events", seq)
         _cmon.stat_set("flight/ring/dropped", dropped)
+        st = self.span_stats()
+        _cmon.stat_set("flight/spans", st["closed"])
+        _cmon.stat_set("flight/spans/dropped", st["dropped"])
+
+    def record_span(self, rec):
+        """Append one closed span (Span.end's tuple) to the span ring."""
+        self._spans.append(rec)
+        self._n_spans = next(self._spans_closed)
+
+    def spans(self, since=None, last=None):
+        """The closed spans the ring still holds, in the order they
+        closed, as dicts: id, parent (0 for a thread's outermost),
+        tid, name, start and end (time.perf_counter() seconds), ids.
+        `since` keeps the spans that ended at or after that reading,
+        `last` the newest that many (none when <= 0)."""
+        recs = list(self._spans)
+        if last is not None:
+            recs = recs[-int(last):] if int(last) > 0 else []
+        return [{"id": sid, "parent": parent, "tid": tid, "name": name,
+                 "start": t0, "end": t1, "ids": dict(ids or ())}
+                for sid, parent, tid, name, t0, t1, ids in recs
+                if since is None or t1 >= since]
+
+    def span_stats(self):
+        closed = self._n_spans
+        return {"closed": closed, "capacity": self._spans.maxlen,
+                "dropped": max(0, closed - self._spans.maxlen)}
 
     def tail(self, n=None):
         """The newest `n` events (all when n is None, none when
@@ -221,6 +274,9 @@ class FlightRecorder:
             self._ring.clear()
             self._seq = 0
             self._dropped = 0
+            self._spans.clear()
+            self._spans_closed = itertools.count(1)
+            self._n_spans = 0
 
     def stats(self):
         with self._lock:
@@ -246,6 +302,120 @@ def sync_stats():
 
 
 # ---------------------------------------------------------------------------
+# Program spans
+# ---------------------------------------------------------------------------
+
+_span_ids = itertools.count(1)
+_span_tls = threading.local()
+
+
+class Span:
+    """One program span: `with span(...)`, or begin()/end() where a
+    `with` does not fit. Its parent is the innermost span open on the
+    thread when it began. end() on another thread, or before a span
+    opened later on the same thread has ended, still closes the ring
+    record; only the annotation of such a span is not to be relied
+    on (the profiler writes it where and when end() ran)."""
+
+    __slots__ = ("name", "ids", "sid", "parent", "tid", "t0", "_ann",
+                 "_stack")
+
+    def __init__(self, name, ids):
+        self.name = SPAN_PREFIX + name
+        self.ids = ids
+        self.t0 = None
+
+    def begin(self):
+        try:
+            stack = _span_tls.stack
+        except AttributeError:
+            stack = _span_tls.stack = []
+        self.parent = stack[-1].sid if stack else 0
+        self.sid = next(_span_ids)
+        self.tid = threading.get_ident()
+        self._stack = stack
+        stack.append(self)
+        self._ann = TraceAnnotation(self.name, **self.ids)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def end(self):
+        if self.t0 is None:
+            return
+        t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        stack = self._stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        else:
+            try:
+                stack.remove(self)
+            except ValueError:
+                pass
+        recorder.record_span((self.sid, self.parent, self.tid, self.name,
+                              self.t0, t1, self.ids or None))
+        self.t0 = None
+
+    __enter__ = begin
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
+class _NoSpan:
+    """What span() hands out while the recorder is off."""
+
+    __slots__ = ()
+    t0 = None
+
+    def begin(self):
+        return self
+
+    def end(self):
+        pass
+
+    __enter__ = begin
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name, **ids):
+    """A program span `paddle_tpu/<name>`, name being `<layer>/<what>`
+    with layer one of compile, cache, train, serve, io, comm. `ids`
+    say whose work it is: `step=` of a train or engine step, `req=` a
+    request's trace id, `program=` of a compile; they are kept with
+    the ring record and become the annotation's stats. Off, at the
+    cost of this call, with PADDLE_FLIGHT_ENABLE=0."""
+    if not recorder.enabled:
+        return _NO_SPAN
+    return Span(name, ids)
+
+
+def spans(since=None):
+    """The closed spans the ring holds (FlightRecorder.spans)."""
+    return recorder.spans(since)
+
+
+def closed_span(name, start, end, **ids):
+    """A span whose extent is known only afterwards (a dispatch that
+    turned out to retrace): a child of the innermost span open on the
+    thread, in the ring alone. An annotation cannot be backdated."""
+    if not recorder.enabled:
+        return
+    stack = getattr(_span_tls, "stack", None)
+    recorder.record_span(
+        (next(_span_ids), stack[-1].sid if stack else 0,
+         threading.get_ident(), SPAN_PREFIX + name, start, end,
+         ids or None))
+
+
+# ---------------------------------------------------------------------------
 # In-flight registry (what the watchdog watches)
 # ---------------------------------------------------------------------------
 
@@ -253,23 +423,45 @@ _inflight: dict = {}
 _inflight_lock = _sanitize.lock("flight.inflight")
 _token_seq = itertools.count(1)
 
+# in-flight kind -> the span it is; {name} is begin()'s `name`, left
+# out where it is an id of the work (a request, a checkpoint step)
+# rather than the name of a program or an operation
+_KIND_SPAN = {
+    "compile": "compile/{name}",
+    "linalg_compile": "compile/linalg:{name}",
+    "mem_capture": "compile/capture/{name}",
+    "perf_capture": "compile/capture/{name}",
+    "collective": "comm/{name}",
+    "linalg": "comm/linalg:{name}",
+    "bootstrap": "comm/bootstrap",
+    "ckpt_write": "io/ckpt_write",
+    "serve_prefill": "serve/prefill",
+    "serve_decode": "serve/decode",
+    "serve_drain": "serve/drain",
+    "serve_failover": "serve/failover",
+    "serve_scale_down": "serve/scale_down",
+}
+
 
 def begin(kind, name, **data):
     """Mark this thread entering a potentially-blocking operation.
-    Records a `<kind>_begin` flight event and registers the op so the
+    Records a `<kind>_begin` flight event, opens the operation's span
+    (`_KIND_SPAN`; `data` are its ids) and registers the op so the
     watchdog can see it wedge. Returns a token for end(); None when
     the recorder is disabled (end(None) is a no-op)."""
     if not recorder.enabled:
         return None
     recorder.record(f"{kind}_begin", name=name, **data)
     token = next(_token_seq)
+    sp = Span(_KIND_SPAN.get(kind, "{kind}/{name}").format(
+        kind=kind, name=name), data).begin()
     # t0 is wall clock for display; ages/durations measure against
     # the MONOTONIC clock — an NTP step or VM suspend must not fire
     # false watchdog dumps or yield negative dur_us
     entry = dict({"kind": kind, "name": name,
                   "tid": threading.get_ident(),
                   "t0": round(time.time(), 6),
-                  "_t0m": time.monotonic()}, **data)
+                  "_t0m": time.monotonic(), "_span": sp}, **data)
     with _inflight_lock:
         _inflight[token] = entry
     return token
@@ -277,13 +469,14 @@ def begin(kind, name, **data):
 
 def end(token):
     """Complete the operation begin() registered: drops it from the
-    in-flight table and records the `<kind>_end` event with its
-    duration."""
+    in-flight table, closes its span and records the `<kind>_end`
+    event with its duration."""
     if token is None:
         return
     with _inflight_lock:
         entry = _inflight.pop(token, None)
     if entry is not None:
+        entry["_span"].end()
         recorder.record(
             f"{entry['kind']}_end", name=entry["name"],
             dur_us=int((time.monotonic() - entry["_t0m"]) * 1e6))
@@ -304,10 +497,16 @@ def inflight_snapshot(now=None):
     time.monotonic() reading (the age clock)."""
     now = time.monotonic() if now is None else now
     with _inflight_lock:
-        entries = [dict(e) for e in _inflight.values()]
-    for e in entries:
-        e["age_s"] = round(now - e.pop("_t0m"), 3)
-    return entries
+        entries = list(_inflight.values())
+    return [_with_age(e, now) for e in entries]
+
+
+def _with_age(entry, now):
+    """An in-flight entry as dumps show it: its age in place of the
+    registry's own fields."""
+    e = {k: v for k, v in entry.items() if not k.startswith("_")}
+    e["age_s"] = round(now - entry["_t0m"], 3)
+    return e
 
 
 # ---------------------------------------------------------------------------
@@ -436,6 +635,7 @@ def write_dump(reason, extra=None, path=None, full_memory=None):
         in_flight    — ops currently inside begin()/end() with ages
         threads      — formatted stacks of every live thread
         flight_tail  — newest PADDLE_FLIGHT_DUMP_EVENTS ring events
+        span_tail    — as many of the newest closed program spans
         telemetry    — monitor.telemetry_snapshot() (full registry)
         jit_caches   — per-function compiled-program cache keys
         memory       — device stats + per-program footprints (+ the
@@ -450,6 +650,7 @@ def write_dump(reason, extra=None, path=None, full_memory=None):
     under flight/dumps_written, echoed at VLOG(0)."""
     ts = time.time()
     caches = _jit_cache_info()
+    n_tail = _env_int("PADDLE_FLIGHT_DUMP_EVENTS", 256)
     payload = {
         "schema": DUMP_SCHEMA,
         "reason": reason,
@@ -463,8 +664,10 @@ def write_dump(reason, extra=None, path=None, full_memory=None):
         "device": _device_info(),
         "in_flight": inflight_snapshot(),
         "threads": _thread_stacks(),
-        "flight_tail": recorder.tail(
-            _env_int("PADDLE_FLIGHT_DUMP_EVENTS", 256)),
+        "flight_tail": recorder.tail(n_tail),
+        # the newest closed program spans (perf_counter seconds): what
+        # the host was inside of, and for how long, before the incident
+        "span_tail": recorder.spans(last=n_tail),
         "jit_caches": caches,
         "memory": _memory_section(
             reason, full=full_memory,
@@ -637,10 +840,7 @@ class Watchdog:
                  and tok not in self._reported]
         if not stuck:
             return None
-        detail = [dict(e, age_s=round(now - e["_t0m"], 3))
-                  for _, e in stuck]
-        for e in detail:
-            e.pop("_t0m", None)
+        detail = [_with_age(e, now) for _, e in stuck]
         # ring event once per stuck op (recorded BEFORE the dump so
         # its tail shows it) — NOT once per retry: a persistently
         # failing dump would otherwise flood the ring with watchdog

@@ -56,7 +56,8 @@ __all__ = [
     "program_capture_enabled", "step_tracking_enabled",
     "step_reading",
     "program_footprints", "memory_report", "memory_section",
-    "is_oom_error", "oom_observer", "auto_oom_observer",
+    "is_oom_error", "is_compile_oom_error", "oom_observer",
+    "auto_oom_observer",
     "census_top_k",
 ]
 
@@ -443,6 +444,16 @@ def is_oom_error(exc):
         return False
     msg = str(exc)
     return "RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg
+
+
+def is_compile_oom_error(exc):
+    """True when the out-of-memory `exc` (is_oom_error) came from the
+    compiler: the program itself does not fit the device (XLA:TPU
+    "compile permanent error. Ran out of memory in memory space hbm"),
+    whatever is live on it, so evicting live buffers cannot cure it."""
+    msg = str(exc)
+    return is_oom_error(exc) and (
+        "ompile" in msg or "Ran out of memory in memory space" in msg)
 
 
 @contextlib.contextmanager

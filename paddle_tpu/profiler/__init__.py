@@ -1,7 +1,9 @@
 """paddle.profiler (reference: paddle/fluid/platform/profiler/ —
 Profiler, RecordEvent, chrome-trace export; python/paddle/profiler/).
 
-TPU-native: host events via perf_counter spans (HostTracer analog);
+TPU-native: host events are the program spans of monitor.flight
+(HostTracer analog; one ring, perf_counter, and the jax.profiler
+host plane);
 device timeline via jax.profiler (XPlane — the TPU-native equivalent of
 CUPTI activity records), exportable to TensorBoard; chrome-trace JSON
 export of host events for tools/timeline.py parity."""
@@ -12,6 +14,8 @@ import json
 import os
 import threading
 import time
+
+from ..monitor import flight as _flight
 
 __all__ = ["Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
@@ -34,42 +38,27 @@ class ProfilerState:
 
 
 class _Recorder:
-    """Process-wide span/counter recorder.
-
-    The active flag is shared by ALL threads — the previous
-    threading.local recorder silently dropped spans opened on
-    dataloader/worker threads, because each new thread saw
-    active=False. Events append to per-thread buffers (registered
-    under a lock, appended lock-free — the GIL serializes list.append)
-    and are merged at export; each event already carries its tid."""
+    """The capture a Profiler runs: whether one is on (shared by ALL
+    threads), its counter time series, and its host spans. The spans
+    are not kept here: RecordEvent goes through monitor.flight.span,
+    and events() reads that one ring (every thread's spans, each with
+    its tid), cut to the capture."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.active = False
-        self._tls = threading.local()
-        self._buffers = []   # one event list per recording thread
+        self._t0 = self._t1 = None   # perf_counter at start / stop
         self._counters = []  # (name, ts, value) time series (ph "C")
 
     def start(self):
         with self._lock:
-            self._tls = threading.local()  # drop stale thread buffers
-            self._buffers = []
             self._counters = []
+            self._t0, self._t1 = time.perf_counter(), None
             self.active = True
 
     def stop(self):
         self.active = False
-
-    def record(self, ev):
-        if not self.active:
-            return
-        buf = getattr(self._tls, "buf", None)
-        if buf is None:
-            buf = []
-            self._tls.buf = buf
-            with self._lock:
-                self._buffers.append(buf)
-        buf.append(ev)
+        self._t1 = time.perf_counter()
 
     def record_counter(self, name, value, ts=None):
         if not self.active:
@@ -80,13 +69,20 @@ class _Recorder:
                  float(value)))
 
     def events(self):
-        """Merged snapshot of every thread's spans, sorted by begin
-        time."""
-        with self._lock:
-            bufs = list(self._buffers)
+        """(name, category, begin, end, tid, args) of every program
+        span that began and ended inside the last capture, sorted by
+        begin time; names without the `paddle_tpu/` prefix."""
+        if self._t0 is None:
+            return []
+        t1 = self._t1 if self._t1 is not None else time.perf_counter()
         out = []
-        for b in bufs:
-            out.extend(list(b))
+        for sp in _flight.spans(since=self._t0):
+            if sp["start"] < self._t0 or sp["end"] > t1:
+                continue
+            args = sp["ids"]
+            cat = args.pop("cat", "Program")
+            out.append((sp["name"][len(_flight.SPAN_PREFIX):], cat,
+                        sp["start"], sp["end"], sp["tid"], args))
         out.sort(key=lambda e: e[2])
         return out
 
@@ -113,26 +109,29 @@ def record_counter(name, value, ts=None):
 
 class RecordEvent:
     """RAII host-event annotation (reference: platform/profiler.h
-    RecordEvent, used at every TraceOp). `args` (a small dict of
-    scalars, e.g. {"batch_size": 32}) exports into the chrome-trace
-    event's args field."""
+    RecordEvent, used at every TraceOp). One program span
+    (monitor.flight.span) named `paddle_tpu/<name>`: in the span ring
+    always, in a Profiler's chrome-trace export under `name` with
+    `event_type` as its category, and in the host plane of any
+    jax.profiler trace. `args` (a small dict of scalars, e.g.
+    {"batch_size": 32}) are the span's ids and export into the
+    chrome-trace event's args field."""
 
     def __init__(self, name, event_type="UserDefined", args=None):
         self.name = name
         self.event_type = event_type
         self.args = args
-        self._begin = None
+        self._span = None
 
     def begin(self):
-        self._begin = time.perf_counter()
+        self._span = _flight.span(self.name, cat=self.event_type,
+                                  **(self.args or {})).begin()
 
     def end(self):
-        if self._begin is None:
+        if self._span is None:
             return
-        _recorder.record(
-            (self.name, self.event_type, self._begin,
-             time.perf_counter(), threading.get_ident(), self.args))
-        self._begin = None
+        self._span.end()
+        self._span = None
 
     def __enter__(self):
         self.begin()
