@@ -10,7 +10,10 @@ Ragged Paged Attention design (PAPERS.md arxiv 2604.15464).
 Decode shape: one query token per sequence.
 
     q            [B, H, D]           this step's query rows
-    k/v pool     [N, BS, H, D]       one layer's paged pool
+    k/v pool     [N, BS, H, D]       a paged pool: one layer's, or every
+                                     layer's stacked on the block axis
+                                     with the tables shifted to the
+                                     layer (serving.model_runner)
     block_tables [B, MAXB] int32     pool block id per (seq, slot)
     context_lens [B]       int32     real tokens per sequence
 
@@ -216,20 +219,28 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     return out.reshape(b, h, d)
 
 
+def _gather_context(pool, block_tables):
+    """pool [N, BS, H, D] -> every sequence's context [B, MAXB*BS, H,
+    D] in table order. Blocks are gathered as whole [BS, H*D] rows,
+    the pools' own minor dimension (`kv_cache`), so a pool handed in
+    as a view of that form is read where it lies."""
+    n, bs, h, d = pool.shape
+    b, maxb = block_tables.shape
+    return pool.reshape(n, bs, h * d)[block_tables].reshape(
+        b, maxb * bs, h, d)
+
+
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
                               context_lens, sm_scale=1.0):
     """Dense gather reference — the math the kernel must match, and
     the engine's CPU fallback. Mirrors the training `_attention`
     softmax exactly (f32 scores, -1e30 mask, softmax, cast, PV) so a
     paged decode step reproduces the full re-forward loop's tokens."""
-    seq_k = k_pool[block_tables]           # [B, MAXB, BS, H, D]
-    seq_v = v_pool[block_tables]
-    b, maxb, bs, h, d = seq_k.shape
-    seq_k = seq_k.reshape(b, maxb * bs, h, d)
-    seq_v = seq_v.reshape(b, maxb * bs, h, d)
+    seq_k = _gather_context(k_pool, block_tables)
+    seq_v = _gather_context(v_pool, block_tables)
     s = jnp.einsum("bhd,bshd->bhs", q, seq_k,
                    preferred_element_type=jnp.float32) * sm_scale
-    mask = jnp.arange(maxb * bs)[None, :] < context_lens[:, None]
+    mask = jnp.arange(seq_k.shape[1])[None, :] < context_lens[:, None]
     s = jnp.where(mask[:, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhs,bshd->bhd", p, seq_v)
@@ -341,15 +352,12 @@ def paged_attention_multi_reference(q, k_pool, v_pool, block_tables,
     prefill's attention (slot t at absolute position
     context_lens[b] - 1 + t sees context_lens[b] + t tokens — the
     same convention for both uses)."""
-    seq_k = k_pool[block_tables]           # [B, MAXB, BS, H, D]
-    seq_v = v_pool[block_tables]
-    b, maxb, bs, h, d = seq_k.shape
+    seq_k = _gather_context(k_pool, block_tables)
+    seq_v = _gather_context(v_pool, block_tables)
     t = q.shape[1]
-    seq_k = seq_k.reshape(b, maxb * bs, h, d)
-    seq_v = seq_v.reshape(b, maxb * bs, h, d)
     s = jnp.einsum("bthd,bshd->bths", q, seq_k,
                    preferred_element_type=jnp.float32) * sm_scale
-    pos = jnp.arange(maxb * bs)[None, None, :]
+    pos = jnp.arange(seq_k.shape[1])[None, None, :]
     ctx = context_lens[:, None, None] \
         + jnp.arange(t)[None, :, None]     # [B, T, 1]
     mask = pos < ctx                       # [B, T, S]

@@ -191,6 +191,10 @@ class LLMEngine:
             eps=cfg.layer_norm_eps, block_size=self.block_size,
             use_kernel=self.use_kernel,
             interpret=self._kernel_interpret)
+        # the pools ride the layer scan's carry and are only ever
+        # scattered into (model_runner._scan_layers_paged), so
+        # donating them makes the program's output pools its input
+        # buffers: no second copy of the pools among its temporaries
         self._decode_jit = jax.jit(
             decode, donate_argnums=(3, 4) if self._donate else ())
         self._decode_exe = None      # persistent-cache hit, if any

@@ -6,7 +6,15 @@ tensors — at 8+ concurrent mixed-length requests that wastes most of
 HBM on padding. Instead the cache is a FIXED device pool of
 fixed-size blocks per layer:
 
-    k/v pools:  [num_layers, num_blocks, block_size, n_head, head_dim]
+    k/v pools:  [num_layers, num_blocks, block_size, n_head * head_dim]
+
+(heads and head_dim share the minor dimension: a token's K or V row
+is `n_head * head_dim` contiguous values, stored row-major and
+unpadded on the device. With `head_dim` alone as the minor dimension
+— 64 of a TPU's 128 lanes — the device either pads every row to
+twice its size or puts the BLOCK axis on the lanes, and every
+compiled program then transposes whole pools on its way in and out:
+PERF.md, PR 26.)
 
 and every request owns a host-side BLOCK TABLE — the ordered list of
 pool block ids covering its tokens. Token `t` of a request lives at
@@ -400,20 +408,25 @@ class PagedKVCache:
             num_blocks = auto_num_blocks(per_block,
                                          pool_bytes=pool_bytes)
         self.num_blocks = int(num_blocks)
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
         # draft-model twin pools address through the SAME allocator
         # and tables — the chain-hash identity that lets two requests
         # share target KV holds for draft KV too, so one refcount
         # covers both
         self.k_draft = self.v_draft = None
+        self._zero_pools()
+        self.allocator = BlockAllocator(self.num_blocks)
+
+    def _zero_pools(self):
+        import jax.numpy as jnp
+
+        shape = (self.num_layers, self.num_blocks, self.block_size,
+                 self.num_heads * self.head_dim)
+        self.k = jnp.zeros(shape, self.dtype)
+        self.v = jnp.zeros(shape, self.dtype)
         if self.draft_layers:
             dshape = (self.draft_layers,) + shape[1:]
             self.k_draft = jnp.zeros(dshape, self.dtype)
             self.v_draft = jnp.zeros(dshape, self.dtype)
-        self.allocator = BlockAllocator(self.num_blocks)
 
     # -- geometry ----------------------------------------------------
     def blocks_for_tokens(self, n_tokens):
@@ -505,16 +518,7 @@ class PagedKVCache:
         mid-execution deletes donated buffers). The caller must
         re-prefill every sequence: allocator state survives but the
         K/V contents are gone."""
-        import jax.numpy as jnp
-
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
-        if self.draft_layers:
-            dshape = (self.draft_layers,) + shape[1:]
-            self.k_draft = jnp.zeros(dshape, self.dtype)
-            self.v_draft = jnp.zeros(dshape, self.dtype)
+        self._zero_pools()
         # zeroed pools invalidate every published prefix — serving a
         # pre-reset digest would share garbage KV
         self.allocator.clear_hash_index()

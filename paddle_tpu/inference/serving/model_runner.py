@@ -12,7 +12,28 @@ this module owns the two compiled programs of the generation path:
     layer stack reading/writing K/V through the pools, ragged paged
     attention over each request's cached context, sample.
 
-Both reuse the training model's own math helpers (`_layer_norm`,
+`verify_step` (speculative verification) and `prefill_tail_step`
+(prefix-cache tail) are decode-shaped too: they write K/V per layer
+and attend through the pools.
+
+How the pools go through the layer scan (`_scan_layers_paged`, the
+one place the pattern lives): the WHOLE `[L, N, BS, H*D]` pools ride
+the scan's CARRY; the scan's `xs` are a layer's weights and its index
+`l`. Layer `l` scatters its new K/V rows into the carried pool at
+`(l, blk, off)` and attends through the pool viewed as
+`[L*N, BS, ...]` (a reshape of leading dimensions) with the block
+tables shifted by `l * N`. Nothing slices a layer out of a pool or
+stacks one back, so with the pools donated at the jit boundary XLA's
+while-loop aliasing updates them in place: the program's output
+pools ARE its input buffers, and no op moves a pool-sized or
+layer-sized buffer. (A scan that takes the pools as `xs` and returns
+them as `ys` moves every layer out and in on every dispatch and
+holds a second copy of both pools — `ys` cannot alias `xs`. And on
+the TPU a pool whose minor dimension is head_dim alone is stored
+with the BLOCK axis on the lanes, so each of those layers is also
+transposed both ways: hence `H*D` as one dimension, `kv_cache`.)
+
+All reuse the training model's own math helpers (`_layer_norm`,
 `_residual_layer_norm`, `_attention` from `text.models.gpt`) so the
 serving path computes EXACTLY what the training forward computes —
 the e2e contract is greedy tokens identical to a sequential
@@ -127,9 +148,6 @@ def prefill_step(params, ids, prompt_len, k_pool, v_pool, block_table,
     x = jnp.take(params["wte"], ids, axis=0)
     x = x + jnp.take(params["wpe"], jnp.arange(p_len), axis=0)
 
-    b, s = ids.shape
-    d = params["wte"].shape[1] // n_head
-
     def body(carry, bp):
         h = _layer_norm(carry, bp["ln1_w"], bp["ln1_b"], eps)
         qkv = h @ bp["qkv_w"] + bp["qkv_b"]
@@ -145,17 +163,20 @@ def prefill_step(params, ids, prompt_len, k_pool, v_pool, block_table,
         ffn = jax.nn.gelu(ffn)
         ffn = ffn @ bp["fc2_w"] + bp["fc2_b"]
         out = x2 + ffn
-        return out, (k.reshape(b, s, n_head, d),
-                     v.reshape(b, s, n_head, d))
+        return out, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-    # ks/vs [L, 1, P, H, D] -> scatter every position through the
-    # table in one batched update per pool
+    # ks/vs [L, 1, P, H*D] -> scatter every position through the
+    # table in one batched update per pool. The layer is an index
+    # like blk and off (L x P rows of H*D), not a window dimension:
+    # a scatter whose window spans the layer axis makes the TPU
+    # compiler transpose the whole pool there and back
     positions = jnp.arange(p_len)
     blk, off = _scatter_positions(block_table, positions, block_size)
-    k_pool = k_pool.at[:, blk, off].set(
+    layers = jnp.arange(k_pool.shape[0])[:, None]
+    k_pool = k_pool.at[layers, blk, off].set(
         ks[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[:, blk, off].set(
+    v_pool = v_pool.at[layers, blk, off].set(
         vs[:, 0].astype(v_pool.dtype))
 
     x = _layer_norm(x, params["lnf_w"], params["lnf_b"], eps)
@@ -167,6 +188,57 @@ def prefill_step(params, ids, prompt_len, k_pool, v_pool, block_table,
     return token, k_pool, v_pool
 
 
+def _scan_layers_paged(params, x, k_pool, v_pool, blk, off, attend,
+                       *, n_head, eps):
+    """The layer stack of a decode-shaped program, with the pools in
+    the scan's carry so that they are updated in place.
+
+    x [..., hidden] holds one row per query token; blk/off (shape
+    `x.shape[:-1]`) say where each row's K/V goes inside a layer's
+    pool. Layer `l` writes its rows at `(l, blk, off)` of the carried
+    `[L, N, BS, H*D]` pools BEFORE attending, then calls
+
+        attend(q [..., H, D], k_flat, v_flat, first_block) -> [..., H, D]
+
+    with the pools viewed as `[L*N, BS, H, D]` and `first_block ==
+    l * N`: the caller looks its block tables up at `tables +
+    first_block`, through the dense reference or the Pallas kernels
+    alike (their index maps resolve one block id per grid step, and
+    both read a block as `[BS, H*D]`, so the view's split of the
+    minor dimension folds away). No layer is sliced out or stacked
+    back. L and N come from the pools handed in (the target's, or
+    the shallower draft's). Returns (x after the last layer, k_pool,
+    v_pool)."""
+    n_layers, n_blocks, block_size = k_pool.shape[:3]
+    heads = (n_head, x.shape[-1] // n_head)
+    flat = (n_layers * n_blocks, block_size) + heads
+
+    def body(carry, xs):
+        x, kp, vp = carry
+        bp, layer = xs
+        h = _layer_norm(x, bp["ln1_w"], bp["ln1_b"], eps)
+        qkv = h @ bp["qkv_w"] + bp["qkv_b"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        kp = kp.at[layer, blk, off].set(k.astype(kp.dtype))
+        vp = vp.at[layer, blk, off].set(v.astype(vp.dtype))
+        attn = attend(q.reshape(x.shape[:-1] + heads),
+                      kp.reshape(flat), vp.reshape(flat),
+                      layer * n_blocks)
+        attn = attn.reshape(x.shape)
+        attn = attn @ bp["proj_w"] + bp["proj_b"]
+        h2, x2 = _residual_layer_norm(attn, x, bp["ln2_w"],
+                                      bp["ln2_b"], eps)
+        ffn = h2 @ bp["fc1_w"] + bp["fc1_b"]
+        ffn = jax.nn.gelu(ffn)
+        ffn = ffn @ bp["fc2_w"] + bp["fc2_b"]
+        return (x2 + ffn, kp, vp), None
+
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, k_pool, v_pool),
+        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, k_pool, v_pool
+
+
 def decode_step(params, ids, positions, k_pool, v_pool, block_tables,
                 context_lens, temperature, top_k, seeds, *, n_head,
                 eps, block_size, use_kernel=False, interpret=False):
@@ -176,14 +248,12 @@ def decode_step(params, ids, positions, k_pool, v_pool, block_tables,
     token included). Each layer writes this token's K/V at
     (tables[b, pos // BS], pos % BS) BEFORE attending — so the
     current token sees itself, and garbage a block-padded prefill
-    left in that slot is overwritten before any read. Returns
-    (next tokens [B], k_pool, v_pool)."""
+    left in that slot is overwritten before any read. The pools ride
+    the layer scan's carry (`_scan_layers_paged`): donated, they are
+    updated in place. Returns (next tokens [B], k_pool, v_pool)."""
     from ...incubate.nn.pallas import paged_attention as _pa
 
-    bsz = ids.shape[0]
-    hidden = params["wte"].shape[1]
-    d = hidden // n_head
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(params["wte"].shape[1] // n_head)
     x = jnp.take(params["wte"], ids, axis=0)
     x = x + jnp.take(params["wpe"], positions, axis=0)
 
@@ -191,35 +261,18 @@ def decode_step(params, ids, positions, k_pool, v_pool, block_tables,
         block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
     off = positions % block_size
 
-    def body(carry, xs):
-        bp, kc, vc = xs
-        h = _layer_norm(carry, bp["ln1_w"], bp["ln1_b"], eps)
-        qkv = h @ bp["qkv_w"] + bp["qkv_b"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(bsz, n_head, d)
-        kc = kc.at[blk, off].set(
-            k.reshape(bsz, n_head, d).astype(kc.dtype))
-        vc = vc.at[blk, off].set(
-            v.reshape(bsz, n_head, d).astype(vc.dtype))
+    def attend(q, k_flat, v_flat, first_block):
+        tables = block_tables + first_block
         if use_kernel:
-            attn = _pa.paged_attention(q, kc, vc, block_tables,
+            return _pa.paged_attention(q, k_flat, v_flat, tables,
                                        context_lens, sm_scale=scale,
                                        interpret=interpret)
-        else:
-            attn = _pa.paged_attention_reference(
-                q, kc, vc, block_tables, context_lens,
-                sm_scale=scale)
-        attn = attn.reshape(bsz, hidden)
-        attn = attn @ bp["proj_w"] + bp["proj_b"]
-        h2, x2 = _residual_layer_norm(attn, carry, bp["ln2_w"],
-                                      bp["ln2_b"], eps)
-        ffn = h2 @ bp["fc1_w"] + bp["fc1_b"]
-        ffn = jax.nn.gelu(ffn)
-        ffn = ffn @ bp["fc2_w"] + bp["fc2_b"]
-        return x2 + ffn, (kc, vc)
+        return _pa.paged_attention_reference(
+            q, k_flat, v_flat, tables, context_lens, sm_scale=scale)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], k_pool, v_pool))
+    x, k_pool, v_pool = _scan_layers_paged(
+        params, x, k_pool, v_pool, blk, off, attend, n_head=n_head,
+        eps=eps)
     x = _layer_norm(x, params["lnf_w"], params["lnf_b"], eps)
     logits = x @ params["wte"].T                       # [B, V]
     tokens = sample_tokens(logits, temperature, top_k, seeds)
@@ -249,13 +302,13 @@ def verify_step(params, ids, start_positions, k_pool, v_pool,
     before any masked read could see them. `block_tables` may carry
     a trailing guaranteed-NULL column: positions past the table's
     real width clamp into it, so an at-cap sequence's overflow slots
-    write garbage to the NULL block instead of its own live tail."""
+    write garbage to the NULL block instead of its own live tail.
+    The pools ride the layer scan's carry (`_scan_layers_paged`),
+    as in `decode_step`."""
     from ...incubate.nn.pallas import paged_attention as _pa
 
     bsz, t_q = ids.shape
-    hidden = params["wte"].shape[1]
-    d = hidden // n_head
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(params["wte"].shape[1] // n_head)
     positions = start_positions[:, None] \
         + jnp.arange(t_q)[None, :]                     # [B, T]
     x = jnp.take(params["wte"], ids, axis=0)
@@ -266,35 +319,18 @@ def verify_step(params, ids, start_positions, k_pool, v_pool,
     blk = jnp.take_along_axis(block_tables, slot_idx, axis=1)
     off = positions % block_size
 
-    def body(carry, xs):
-        bp, kc, vc = xs
-        h = _layer_norm(carry, bp["ln1_w"], bp["ln1_b"], eps)
-        qkv = h @ bp["qkv_w"] + bp["qkv_b"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(bsz, t_q, n_head, d)
-        kc = kc.at[blk, off].set(
-            k.reshape(bsz, t_q, n_head, d).astype(kc.dtype))
-        vc = vc.at[blk, off].set(
-            v.reshape(bsz, t_q, n_head, d).astype(vc.dtype))
+    def attend(q, k_flat, v_flat, first_block):
+        tables = block_tables + first_block
         if use_kernel:
-            attn = _pa.paged_attention_multi(
-                q, kc, vc, block_tables, context_lens,
+            return _pa.paged_attention_multi(
+                q, k_flat, v_flat, tables, context_lens,
                 sm_scale=scale, interpret=interpret)
-        else:
-            attn = _pa.paged_attention_multi_reference(
-                q, kc, vc, block_tables, context_lens,
-                sm_scale=scale)
-        attn = attn.reshape(bsz, t_q, hidden)
-        attn = attn @ bp["proj_w"] + bp["proj_b"]
-        h2, x2 = _residual_layer_norm(attn, carry, bp["ln2_w"],
-                                      bp["ln2_b"], eps)
-        ffn = h2 @ bp["fc1_w"] + bp["fc1_b"]
-        ffn = jax.nn.gelu(ffn)
-        ffn = ffn @ bp["fc2_w"] + bp["fc2_b"]
-        return x2 + ffn, (kc, vc)
+        return _pa.paged_attention_multi_reference(
+            q, k_flat, v_flat, tables, context_lens, sm_scale=scale)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], k_pool, v_pool))
+    x, k_pool, v_pool = _scan_layers_paged(
+        params, x, k_pool, v_pool, blk, off, attend, n_head=n_head,
+        eps=eps)
     x = _layer_norm(x, params["lnf_w"], params["lnf_b"], eps)
     logits = x @ params["wte"].T                       # [B, T, V]
     vocab = logits.shape[-1]
@@ -319,47 +355,30 @@ def prefill_tail_step(params, ids, start, total_len, k_pool, v_pool,
     the multi-query reference (slot t sees start + t + 1 tokens).
     Samples from the last REAL tail row (`total_len - 1 - start`).
     The tail is never empty — the engine caps sharing below the full
-    context, so the sampling row always exists. Returns
-    (first sampled token [], k_pool, v_pool)."""
+    context, so the sampling row always exists. The pools ride the
+    layer scan's carry (`_scan_layers_paged`), as in `decode_step`.
+    Returns (first sampled token [], k_pool, v_pool)."""
     from ...incubate.nn.pallas import paged_attention as _pa
 
     t_pad = ids.shape[1]
-    hidden = params["wte"].shape[1]
-    d = hidden // n_head
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(params["wte"].shape[1] // n_head)
     positions = start + jnp.arange(t_pad)
     x = jnp.take(params["wte"], ids, axis=0)
     x = x + jnp.take(params["wpe"], positions, axis=0)[None]
 
     blk, off = _scatter_positions(block_table, positions, block_size)
 
-    def body(carry, xs):
-        bp, kc, vc = xs
-        h = _layer_norm(carry, bp["ln1_w"], bp["ln1_b"], eps)
-        qkv = h @ bp["qkv_w"] + bp["qkv_b"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(1, t_pad, n_head, d)
-        kc = kc.at[blk, off].set(
-            k[0].reshape(t_pad, n_head, d).astype(kc.dtype))
-        vc = vc.at[blk, off].set(
-            v[0].reshape(t_pad, n_head, d).astype(vc.dtype))
+    def attend(q, k_flat, v_flat, first_block):
         # dense multi-query reference (T can be a whole prompt tail —
         # too long for the unrolled kernel): slot t's context is
         # (start + 1) + t tokens, cached prefix included
-        attn = _pa.paged_attention_multi_reference(
-            q, kc, vc, block_table[None], jnp.asarray([start + 1]),
-            sm_scale=scale)
-        attn = attn.reshape(1, t_pad, hidden)
-        attn = attn @ bp["proj_w"] + bp["proj_b"]
-        h2, x2 = _residual_layer_norm(attn, carry, bp["ln2_w"],
-                                      bp["ln2_b"], eps)
-        ffn = h2 @ bp["fc1_w"] + bp["fc1_b"]
-        ffn = jax.nn.gelu(ffn)
-        ffn = ffn @ bp["fc2_w"] + bp["fc2_b"]
-        return x2 + ffn, (kc, vc)
+        return _pa.paged_attention_multi_reference(
+            q, k_flat, v_flat, (block_table + first_block)[None],
+            jnp.asarray([start + 1]), sm_scale=scale)
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], k_pool, v_pool))
+    x, k_pool, v_pool = _scan_layers_paged(
+        params, x, k_pool, v_pool, blk[None], off[None], attend,
+        n_head=n_head, eps=eps)
     x = _layer_norm(x, params["lnf_w"], params["lnf_b"], eps)
     last = jax.lax.dynamic_index_in_dim(
         x[0], total_len - 1 - start, axis=0, keepdims=False)

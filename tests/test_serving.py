@@ -145,17 +145,17 @@ class TestPagedKVCache:
         a, b = c.allocator.alloc("a", 3), c.allocator.alloc("b", 3)
         # stamp each block with its id so moves are detectable
         c.k = jnp.arange(c.num_blocks, dtype=c.k.dtype).reshape(
-            1, -1, 1, 1, 1) * jnp.ones_like(c.k)
+            1, -1, 1, 1) * jnp.ones_like(c.k)
         c.v = 100.0 + c.k
         c.allocator.release("a")  # holes at the front
-        stamps = {blk: float(c.k[0, blk, 0, 0, 0]) for blk in b}
+        stamps = {blk: float(c.k[0, blk, 0, 0]) for blk in b}
         moved = c.defrag()
         assert moved > 0
         newb = c.allocator.owned("b")
         assert sorted(newb) == [1, 2, 3]  # compacted to the front
         for old, new in zip(b, newb):
-            assert float(c.k[0, new, 0, 0, 0]) == stamps[old]
-            assert float(c.v[0, new, 0, 0, 0]) == stamps[old] + 100.0
+            assert float(c.k[0, new, 0, 0]) == stamps[old]
+            assert float(c.v[0, new, 0, 0]) == stamps[old] + 100.0
         # free list contiguous after the compacted region
         assert sorted(c.allocator._free) == list(range(4, 12))
         assert c.defrag() == 0  # already compact
@@ -992,10 +992,10 @@ class TestRefcountsAndPrefixIndex:
         c.allocator.release("hole")  # holes at the front
         # stamp each block with its id so moves are detectable
         c.k = jnp.arange(c.num_blocks, dtype=c.k.dtype).reshape(
-            1, -1, 1, 1, 1) * jnp.ones_like(c.k)
+            1, -1, 1, 1) * jnp.ones_like(c.k)
         a_before = c.allocator.owned("a")
         b_before = c.allocator.owned("b")
-        stamps = {blk: float(c.k[0, blk, 0, 0, 0])
+        stamps = {blk: float(c.k[0, blk, 0, 0])
                   for blk in set(a_before + b_before)}
         digest_of = dict(c.allocator._hash_of)
         assert c.defrag() > 0
@@ -1005,9 +1005,9 @@ class TestRefcountsAndPrefixIndex:
         assert a_after[:2] == b_after[:2]
         assert a_after[2] != b_after[2]  # private tails stay private
         for old, new in zip(a_before, a_after):
-            assert float(c.k[0, new, 0, 0, 0]) == stamps[old]
+            assert float(c.k[0, new, 0, 0]) == stamps[old]
         for old, new in zip(b_before, b_after):
-            assert float(c.k[0, new, 0, 0, 0]) == stamps[old]
+            assert float(c.k[0, new, 0, 0]) == stamps[old]
         # refcounts and the content-hash index moved with the blocks
         for blk in a_after[:2]:
             assert c.allocator.refcount(blk) == 2
@@ -1365,3 +1365,171 @@ class TestPTA074:
         # the allocator module itself is exempt
         assert lint_kv_source(
             bad, filename="kv_cache.py").findings == []
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 26: the pools ride the layer scan's carry and are updated in place
+# ---------------------------------------------------------------------------
+
+class _PagedRig:
+    """Arguments for model_runner's three decode-shaped programs at a
+    toy width, over pools of any depth (`draft_params` cuts the
+    weights to match, as the engine does for its draft)."""
+
+    B, T, BS, MAXB = 4, 3, 4, 8
+
+    def __init__(self, pool_layers, num_blocks):
+        import jax.numpy as jnp
+
+        from paddle_tpu.inference.serving import model_runner as mr
+
+        model = tiny_model(layers=3)
+        params, cfg = mr.extract_params(model)
+        self.params = mr.draft_params(params, pool_layers)
+        self.kw = dict(n_head=cfg.num_heads, eps=cfg.layer_norm_eps,
+                       block_size=self.BS)
+        rng = np.random.RandomState(3)
+        shape = (pool_layers, num_blocks, self.BS, cfg.hidden_size)
+        # garbage, not zeros: a stale slot read or a write that lands
+        # one layer off changes the result
+        self.k = jnp.asarray(rng.randn(*shape), jnp.float32)
+        self.v = jnp.asarray(rng.randn(*shape), jnp.float32)
+        self.tables = jnp.asarray(
+            rng.permutation(np.arange(1, num_blocks))
+            [:self.B * self.MAXB].reshape(self.B, self.MAXB), jnp.int32)
+        self.pos = jnp.asarray([3, 9, 14, 7], jnp.int32)
+        self.ids = jnp.asarray(rng.randint(1, 128, (self.B, self.T)),
+                               jnp.int32)
+        self.temp = jnp.zeros(self.B, jnp.float32)
+        self.top_k = jnp.zeros(self.B, jnp.int32)
+        self.seeds = jnp.zeros((self.B, self.T), jnp.int32)
+
+    def call(self, program, k, v, step=0, ids=None):
+        """(function, positional arguments, pool argnums) of the
+        `step`-th dispatch of one program over pools k, v."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.inference.serving import model_runner as mr
+
+        ids = self.ids if ids is None else ids
+        pos = self.pos + step * (self.T if program == "verify" else 1)
+        if program == "decode":
+            return mr.decode_step, (
+                self.params, ids[:, 0], pos, k, v, self.tables, pos + 1,
+                self.temp, self.top_k, self.seeds[:, 0]), (3, 4)
+        if program == "verify":
+            return mr.verify_step, (
+                self.params, ids, pos, k, v, self.tables, pos + 1,
+                self.temp, self.top_k, self.seeds), (3, 4)
+        # tail: one request whose first 8 + 4 * step tokens are cached
+        start = 8 + self.BS * step
+        return mr.prefill_tail_step, (
+            self.params, jnp.tile(ids[:1], (1, 4))[:, :8],
+            jnp.int32(start), jnp.int32(start + 6), k, v,
+            self.tables[0], self.temp[0], self.top_k[0],
+            self.seeds[0, 0]), (4, 5)
+
+
+def _plain_layers(params, x, k_pool, v_pool, blk, off, attend, *,
+                  n_head, eps):
+    """What `model_runner._scan_layers_paged` must equal bit for bit,
+    behind the same signature: a Python loop over layers that takes
+    each layer's pool out as [N, BS, H, D], writes the new rows,
+    attends over that one layer (its first block is block 0) and
+    puts the layer back."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.text.models.gpt import (_layer_norm,
+                                            _residual_layer_norm)
+
+    n_layers, n, bs, hd = k_pool.shape
+    heads = (n_head, hd // n_head)
+    rows = x.shape[:-1] + heads
+    for layer in range(n_layers):
+        bp = jax.tree_util.tree_map(lambda a: a[layer],
+                                    params["blocks"])
+        kc = k_pool[layer].reshape(n, bs, *heads)
+        vc = v_pool[layer].reshape(n, bs, *heads)
+        h = _layer_norm(x, bp["ln1_w"], bp["ln1_b"], eps)
+        q, k, v = jnp.split(h @ bp["qkv_w"] + bp["qkv_b"], 3,
+                            axis=-1)
+        kc = kc.at[blk, off].set(k.reshape(rows))
+        vc = vc.at[blk, off].set(v.reshape(rows))
+        attn = attend(q.reshape(rows), kc, vc, 0).reshape(x.shape)
+        attn = attn @ bp["proj_w"] + bp["proj_b"]
+        h2, x2 = _residual_layer_norm(attn, x, bp["ln2_w"],
+                                      bp["ln2_b"], eps)
+        ffn = jax.nn.gelu(h2 @ bp["fc1_w"] + bp["fc1_b"])
+        x = x2 + (ffn @ bp["fc2_w"] + bp["fc2_b"])
+        k_pool = k_pool.at[layer].set(kc.reshape(n, bs, hd))
+        v_pool = v_pool.at[layer].set(vc.reshape(n, bs, hd))
+    return x, k_pool, v_pool
+
+
+class TestPoolsInPlace:
+    @pytest.mark.parametrize("pool_layers", [3, 1])
+    @pytest.mark.parametrize("program", ["decode", "verify", "tail"])
+    def test_compiled_program_holds_no_second_pool(self, program,
+                                                   pool_layers):
+        """What keeps the per-step pool copy from coming back: with
+        the pools donated, the compiled program's outputs alias both
+        of them and its temporaries stay under half of ONE pool (a
+        scan that takes the pools as xs and stacks them as ys reads
+        1.3x BOTH pools here; pools in the carry, 0.1x one) — for
+        the target's depth and for a draft's. What the TPU's own
+        layouts add is in test_serving_tpu_compile.py."""
+        import functools
+
+        import jax
+
+        rig = _PagedRig(pool_layers, num_blocks=2048)
+        fn, args, pools = rig.call(program, rig.k, rig.v)
+        mem = jax.jit(functools.partial(fn, **rig.kw),
+                      donate_argnums=pools) \
+            .lower(*args).compile().memory_analysis()
+        one_pool = rig.k.size * rig.k.dtype.itemsize
+        assert mem.alias_size_in_bytes >= 2 * one_pool
+        assert mem.temp_size_in_bytes < one_pool // 2, (
+            mem.temp_size_in_bytes / one_pool)
+
+    @pytest.mark.parametrize("program,kernel", [
+        ("decode", False), ("decode", True), ("verify", False),
+        ("verify", True), ("tail", False)])
+    def test_scan_equals_plain_loop_over_layers(self, program, kernel,
+                                                monkeypatch):
+        """Tokens of three dispatches, each over the pools the one
+        before left and fed its first token, AND both pools at the
+        end are bit-identical to the same program over the plain
+        per-layer loop — dense reference and interpret-mode kernel
+        alike (the tail has no kernel path)."""
+        import functools
+
+        import jax
+
+        from paddle_tpu.inference.serving import model_runner as mr
+
+        rig = _PagedRig(pool_layers=3, num_blocks=40)
+        step_fn = rig.call(program, rig.k, rig.v)[0]
+        path = dict(use_kernel=kernel, interpret=kernel) \
+            if program != "tail" else {}
+
+        def three_dispatches():
+            # a jit of its own: it traces whichever layer loop
+            # model_runner holds at its first call
+            fn = jax.jit(functools.partial(step_fn, **rig.kw, **path))
+            tokens, ids, k, v = [], rig.ids, rig.k, rig.v
+            for step in range(3):
+                tok, k, v = fn(*rig.call(program, k, v, step, ids)[1])
+                tokens.append(np.asarray(tok).ravel())
+                ids = (rig.ids + tok.ravel()[0]) % 128
+            return np.concatenate(tokens), np.asarray(k), np.asarray(v)
+
+        got = three_dispatches()
+        monkeypatch.setattr(mr, "_scan_layers_paged", _plain_layers)
+        want = three_dispatches()
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        # the dispatches did write, and not the same token each time
+        assert not np.array_equal(got[1], np.asarray(rig.k))
+        assert len(set(got[0].tolist())) > 1

@@ -1,0 +1,83 @@
+"""ISSUE 26: the serving programs compiled FOR THE TPU, without one.
+
+The CPU cannot see what made the old decode program move 12 GB a
+step: the device layout of the KV pools. With head_dim (64) as the
+pools' minor dimension the TPU put the block axis on the lanes and
+every program transposed pools on the way in and out. These tests
+compile the four pool-writing programs for a described v5e at the
+real widths (hidden 1024, 16 heads x 64, block 16; depth, vocabulary
+and pool cut down — nothing is allocated) and hold them to: outputs
+alias both donated pools, temporaries under half of ONE pool.
+
+All in one file, the topology in a fixture: only the xdist worker
+that runs this file loads libtpu (on-chip-measurement guide, s. 2).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.inference.serving import model_runner as mr
+
+L, HID, HEADS, VOCAB, SEQ, BS, B, T = 2, 1024, 16, 512, 1024, 16, 8, 4
+BLOCKS, MAXB = 8192, SEQ // BS
+KW = dict(n_head=HEADS, eps=1e-5, block_size=BS)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _programs(sd):
+    i32, f32 = jnp.int32, jnp.float32
+    blocks = dict(
+        ln1_w=(HID,), ln1_b=(HID,), qkv_w=(HID, 3 * HID),
+        qkv_b=(3 * HID,), proj_w=(HID, HID), proj_b=(HID,),
+        ln2_w=(HID,), ln2_b=(HID,), fc1_w=(HID, 4 * HID),
+        fc1_b=(4 * HID,), fc2_w=(4 * HID, HID), fc2_b=(HID,))
+    params = dict(
+        wte=sd((VOCAB, HID)), wpe=sd((SEQ, HID)), lnf_w=sd((HID,)),
+        lnf_b=sd((HID,)),
+        blocks={k: sd((L,) + s) for k, s in blocks.items()})
+    pool = sd((L, BLOCKS, BS, HID))
+    batch = (sd((B, MAXB), i32), sd((B,), i32), sd((B,), f32),
+             sd((B,), i32))
+    one = (sd((MAXB,), i32), sd((), f32), sd((), i32), sd((), i32))
+    return pool, {
+        "decode": (mr.decode_step, (3, 4), (
+            params, sd((B,), i32), sd((B,), i32), pool, pool, *batch,
+            sd((B,), i32))),
+        "verify": (mr.verify_step, (3, 4), (
+            params, sd((B, T), i32), sd((B,), i32), pool, pool,
+            *batch, sd((B, T), i32))),
+        "tail": (mr.prefill_tail_step, (4, 5), (
+            params, sd((1, 64), i32), sd((), i32), sd((), i32), pool,
+            pool, *one)),
+        "prefill": (mr.prefill_step, (3, 4), (
+            params, sd((1, 160), i32), sd((), i32), pool, pool, *one)),
+    }
+
+
+@pytest.mark.parametrize("program",
+                         ["decode", "verify", "tail", "prefill"])
+def test_tpu_program_updates_pools_in_place(one_chip, program):
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool, programs = _programs(sd)
+    fn, donated, args = programs[program]
+    mem = jax.jit(functools.partial(fn, **KW), donate_argnums=donated) \
+        .lower(*args).compile().memory_analysis()
+    one_pool = pool.size * pool.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * one_pool
+    assert mem.temp_size_in_bytes < one_pool // 2, (
+        f"{mem.temp_size_in_bytes / one_pool:.2f} of one pool")
