@@ -1,0 +1,89 @@
+"""Dropless expert FFN: sigmoid scores, bias-corrected top-k, grouped
+matmuls over the experts held.
+
+The GShard layer next door (`MoELayer`) gives every expert a static
+capacity and drops what does not fit. This one drops nothing: the
+(token, expert) assignments are sorted by expert, the rows of one
+expert lie together, and `jax.lax.ragged_dot` multiplies each group by
+its own expert's matrices — M rows in, M rows out, whatever the
+routing's skew. The shapes stay static (M = tokens x top_k); only the
+group sizes are data.
+
+Routing is DeepSeek-V3's `noaux_tc` with one group (what
+`glm4_moe_lite` publishes): scores `s = sigmoid(u W_r)` in float32,
+the `top_k` largest of `s + b` chosen (`b`, the
+`e_score_correction_bias`, is a buffer that steers the CHOICE and
+never enters the weights), weights `scale * s_i / (sum of the chosen
+s + 1e-20)`.
+
+Stacked layers: the experts of a layer scan are stored `[L, E, ...]`.
+Slicing layer `l` out for the grouped matmul makes the TPU compiler
+copy all of its experts (0.75 GiB a layer at 64 x 2048 x 3072 bf16:
+compiled for the v5e, PR 27), so the stack is instead VIEWED as
+`[L*E, ...]` groups (a reshape of leading dimensions) and the group
+sizes of every other layer are zero — the block tables' `l * N`
+shift of `serving.model_runner`, for weights.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["sigmoid_topk_route", "dropless_expert_ffn", "expert_counts"]
+
+
+def sigmoid_topk_route(u, router_w, bias, top_k, scale):
+    """u [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
+    The matmul runs in true float32 (`HIGHEST`: the TPU's default is
+    one bf16 pass, which flips near-ties between the k-th and the
+    next expert)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights
+
+
+def expert_counts(idx, n_experts, live=None):
+    """Tokens per expert [E] int32 from the chosen ids [T, k]; rows
+    where `live` [T] is False (padding, inactive batch slots) are
+    left out."""
+    hot = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32)
+    if live is not None:
+        hot = hot * live.astype(jnp.int32)[:, None, None]
+    return hot.sum((0, 1))
+
+
+def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None):
+    """sum_i weights[t, i] * SwiGLU_{idx[t, i]}(u[t]) for every token.
+
+    u [T, H]; idx/weights [T, k]; `w13` holds each expert's gate and
+    up projections side by side, `[E, H, 2F]`, `w2` its down
+    projection `[E, F, H]`. With `layer` (a traced index) the two are
+    stacks `[L, E, H, 2F]` / `[L, E, F, H]` and layer `layer`'s
+    experts are used, without slicing them out (module docstring).
+    No token is dropped: an expert that takes every token gets a
+    group of T rows."""
+    t, k = idx.shape
+    n_experts = w13.shape[-3]
+    flat = idx.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    rows = jnp.take(u, order // k, axis=0)              # [T*k, H]
+    sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    if layer is not None:
+        groups = w13.shape[0] * n_experts
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), sizes,
+            (layer * n_experts,))
+        w13 = w13.reshape((groups,) + w13.shape[2:])
+        w2 = w2.reshape((groups,) + w2.shape[2:])
+    gate, up = jnp.split(jax.lax.ragged_dot(rows, w13, sizes), 2,
+                         axis=-1)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes)
+    # back to token order: row j of the sorted list is assignment
+    # order[j]; its inverse gathers instead of scattering
+    out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
+    return jnp.einsum("tkh,tk->th", out.astype(jnp.float32),
+                      weights).astype(u.dtype)

@@ -10,8 +10,14 @@ the Gemma-on-Cloud-TPU serving comparison (arxiv 2605.25645):
                     tables, alloc/free/defrag + admission control
   * `scheduler`     continuous batching: FIFO admit / youngest-first
                     evict / preempt between fused decode dispatches
-  * `model_runner`  the compiled prefill + paged decode programs
-                    (gpt2), per-request in-program sampling
+  * `model_runner`  the RUNNER the engine reads of a model (params,
+                    cache rows, prefill/decode/verify/tail programs),
+                    chosen by the model's type; GPT-2's compiled
+                    prefill + paged decode programs, per-request
+                    in-program sampling
+  * `mla_runner`    the second runner: latent attention (MLA) over
+                    one pool, sigmoid-routed experts
+                    (`text/models/glm4_moe_lite.py`)
   * `engine`        `LLMEngine.generate()` / `add_request()`
                     streaming front end, donated decode step through
                     the persistent compile cache; ISSUE-13 lifecycle

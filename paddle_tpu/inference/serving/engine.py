@@ -1,10 +1,15 @@
 """LLMEngine — the TPU-native generation front end.
 
 The user surface of the serving subsystem (ROADMAP item 1): a
-GPTForCausalLM plus a paged KV cache, a continuous-batching
-scheduler, and two compiled programs — per-bucket prefill and ONE
-fixed-shape decode step covering all `max_batch` slots — that
-together serve many concurrent mixed-length requests:
+causal LM plus a paged KV cache, a continuous-batching scheduler,
+and two compiled programs — per-bucket prefill and ONE fixed-shape
+decode step covering all `max_batch` slots — that together serve
+many concurrent mixed-length requests. What the engine knows of the
+model (its parameters, the cache's pools and row widths, the
+programs) it reads from the RUNNER the model's type selects
+(`model_runner.runner_for`: GPT-2, or `mla_runner` for
+`glm4_moe_lite`); a runner without a verify or tail program makes
+`spec_k > 1` / `prefix_cache` raise at construction:
 
     engine = LLMEngine(model)
     engine.add_request([1, 2, 3], SamplingParams(max_new_tokens=8),
@@ -132,17 +137,24 @@ class LLMEngine:
                  num_blocks=None, pool_bytes=None, dtype=None,
                  static_batching=False, use_kernel=None,
                  donate=True, max_queue=None, spec_k=None,
-                 draft_layers=None, prefix_cache=None):
+                 draft_layers=None, prefix_cache=None,
+                 max_seq_len=None):
         import jax
 
         from ...jit import arm_compile_cache
 
         arm_compile_cache()
-        self.params, self.config = _mr.extract_params(model)
+        # everything the engine knows of the model it reads from the
+        # runner its type selects (model_runner.runner_for)
+        self.runner = runner = _mr.runner_for(model)
+        self.params, self.config = runner.params, runner.config
         cfg = self.config
         self.max_batch = int(max_batch or env_max_batch())
-        self.max_seq_len = int(cfg.max_seq_len)
-        head_dim = cfg.hidden_size // cfg.num_heads
+        # a deployment's limit on prompt + answer, at most the
+        # model's positions (which a rotary model publishes in the
+        # hundreds of thousands: table widths follow THIS number)
+        self.max_seq_len = int(min(max_seq_len or cfg.max_seq_len,
+                                   cfg.max_seq_len))
         # speculative-decode width: 1 = off (the verify kernel
         # unrolls its query slots, so k is capped at 8)
         self.spec_k = max(1, min(
@@ -158,8 +170,18 @@ class LLMEngine:
         self.prefix_cache = bool(
             prefix_cache if prefix_cache is not None
             else env_prefix_cache())
+        name = type(runner).__name__
+        if self.spec_k > 1 and (runner.verify_step is None
+                                or runner.draft_params is None):
+            raise NotImplementedError(
+                f"spec_k={self.spec_k}: {name} has no verify program "
+                "or draft model; serve this model with spec_k=1")
+        if self.prefix_cache and runner.prefill_tail_step is None:
+            raise NotImplementedError(
+                f"prefix_cache: {name} has no tail-prefill program; "
+                "serve this model with prefix_cache=False")
         self.cache = PagedKVCache(
-            cfg.num_layers, cfg.num_heads, head_dim,
+            cfg.num_layers, rows=runner.pool_rows,
             block_size=block_size, num_blocks=num_blocks,
             pool_bytes=pool_bytes, dtype=dtype,
             draft_layers=self.draft_layers,
@@ -177,9 +199,7 @@ class LLMEngine:
         if use_kernel is None:
             from ...incubate.nn import pallas as _pl
 
-            use_kernel = _pl.kernels_available() and \
-                _pl.paged_attention.paged_decode_supported(
-                    cfg.num_heads, head_dim, self.block_size)
+            use_kernel = runner.kernel_supported(self.block_size)
             self._kernel_interpret = _pl.interpret_mode()
         else:
             self._kernel_interpret = False
@@ -187,16 +207,16 @@ class LLMEngine:
         self._donate = bool(donate)
 
         decode = functools.partial(
-            _mr.decode_step, n_head=cfg.num_heads,
-            eps=cfg.layer_norm_eps, block_size=self.block_size,
+            runner.decode_step, block_size=self.block_size,
             use_kernel=self.use_kernel,
             interpret=self._kernel_interpret)
-        # the pools ride the layer scan's carry and are only ever
-        # scattered into (model_runner._scan_layers_paged), so
-        # donating them makes the program's output pools its input
-        # buffers: no second copy of the pools among its temporaries
+        # the pools (one argument, a tuple) ride the layer scan's
+        # carry and are only ever scattered into
+        # (model_runner._scan_layers_paged), so donating them makes
+        # the program's output pools its input buffers: no second
+        # copy of the pools among its temporaries
         self._decode_jit = jax.jit(
-            decode, donate_argnums=(3, 4) if self._donate else ())
+            decode, donate_argnums=(3,) if self._donate else ())
         self._decode_exe = None      # persistent-cache hit, if any
         self._prefill_jits = {}      # padded len -> jitted prefill
         # -- speculative-decode programs (spec_k > 1 only; the k=1
@@ -205,22 +225,19 @@ class LLMEngine:
         self._verify_jit = self._draft_jit = None
         self._draft_prefill_jits = {}
         if self.spec_k > 1:
-            self._draft_params = _mr.draft_params(self.params,
-                                                  self.draft_layers)
+            self._draft_params = runner.draft_params(
+                self.params, self.draft_layers)
             verify = functools.partial(
-                _mr.verify_step, n_head=cfg.num_heads,
-                eps=cfg.layer_norm_eps, block_size=self.block_size,
+                runner.verify_step, block_size=self.block_size,
                 use_kernel=self.use_kernel,
                 interpret=self._kernel_interpret)
             self._verify_jit = jax.jit(
-                verify,
-                donate_argnums=(3, 4) if self._donate else ())
+                verify, donate_argnums=(3,) if self._donate else ())
             # a separate jit instance for the draft's decode steps:
             # its donations consume the DRAFT pools, never the
             # target's
             self._draft_jit = jax.jit(
-                decode,
-                donate_argnums=(3, 4) if self._donate else ())
+                decode, donate_argnums=(3,) if self._donate else ())
             _cmon.stat_set("serve/spec/k", self.spec_k)
         # prefix-cache tail-prefill programs (tail length bucketed)
         self._tail_jits = {}
@@ -240,6 +257,9 @@ class LLMEngine:
         # the jit shape-specialization naming)
         self._prefill_captured = {}
         self._oom_streak = 0         # consecutive OOM'd dispatches
+        # (signature, device arrays): the next decode step's inputs,
+        # prepared while the last one ran (_prepare_ahead)
+        self._ahead = None
         self._spec_warm = False      # first spec round compiles
         # finished requests kept for result retrieval — bounded so a
         # long-lived replica's host memory doesn't grow with total
@@ -425,12 +445,10 @@ class LLMEngine:
 
         jfn = self._prefill_jits.get(padded_len)
         if jfn is None:
-            cfg = self.config
-            fn = functools.partial(
-                _mr.prefill_step, n_head=cfg.num_heads,
-                eps=cfg.layer_norm_eps, block_size=self.block_size)
+            fn = functools.partial(self.runner.prefill_step,
+                                   block_size=self.block_size)
             jfn = jax.jit(
-                fn, donate_argnums=(3, 4) if self._donate else ())
+                fn, donate_argnums=(3,) if self._donate else ())
             self._prefill_jits[padded_len] = jfn
         return jfn
 
@@ -439,12 +457,10 @@ class LLMEngine:
 
         jfn = self._draft_prefill_jits.get(padded_len)
         if jfn is None:
-            cfg = self.config
-            fn = functools.partial(
-                _mr.prefill_step, n_head=cfg.num_heads,
-                eps=cfg.layer_norm_eps, block_size=self.block_size)
+            fn = functools.partial(self.runner.prefill_step,
+                                   block_size=self.block_size)
             jfn = jax.jit(
-                fn, donate_argnums=(3, 4) if self._donate else ())
+                fn, donate_argnums=(3,) if self._donate else ())
             self._draft_prefill_jits[padded_len] = jfn
         return jfn
 
@@ -478,18 +494,19 @@ class LLMEngine:
                                req=req.trace_id or req.req_id,
                                padded=padded, tokens=plen), \
                 self._program(self._prefill_name(padded), fresh_bucket):
-            tok, self.cache.k, self.cache.v = self._prefill_fn(padded)(
+            tok, self.cache.pools, stats = self._prefill_fn(padded)(
                 self.params, jnp.asarray(ids), np.int32(plen),
-                self.cache.k, self.cache.v, jnp.asarray(table),
+                self.cache.pools, jnp.asarray(table),
                 np.float32(s.temperature), np.int32(s.top_k),
                 np.uint32(_mr.seed_for(s.seed, plen)))
             tok = int(tok)
+            self._count_stats(stats)
             if self._draft_params is not None:
-                _, self.cache.k_draft, self.cache.v_draft = \
+                _, self.cache.draft_pools, _ = \
                     self._draft_prefill_fn(padded)(
                         self._draft_params, jnp.asarray(ids),
-                        np.int32(plen), self.cache.k_draft,
-                        self.cache.v_draft, jnp.asarray(table),
+                        np.int32(plen), self.cache.draft_pools,
+                        jnp.asarray(table),
                         np.float32(0.0), np.int32(0), np.uint32(0))
                 req._spec_gap = False
         dur_us = int((time.perf_counter() - t0) * 1e6)
@@ -525,12 +542,10 @@ class LLMEngine:
         jits = self._draft_tail_jits if draft else self._tail_jits
         jfn = jits.get(t_pad)
         if jfn is None:
-            cfg = self.config
-            fn = functools.partial(
-                _mr.prefill_tail_step, n_head=cfg.num_heads,
-                eps=cfg.layer_norm_eps, block_size=self.block_size)
+            fn = functools.partial(self.runner.prefill_tail_step,
+                                   block_size=self.block_size)
             jfn = jax.jit(
-                fn, donate_argnums=(4, 5) if self._donate else ())
+                fn, donate_argnums=(4,) if self._donate else ())
             jits[t_pad] = jfn
         return jfn
 
@@ -564,20 +579,21 @@ class LLMEngine:
                                cached=cached), \
                 self._program(f"{self._tail_label}@{t_pad}",
                               t_pad not in self._tail_jits):
-            tok, self.cache.k, self.cache.v = \
+            tok, self.cache.pools, stats = \
                 self._tail_fn(t_pad, draft=False)(
                     self.params, jnp.asarray(ids), np.int32(cached),
-                    np.int32(plen), self.cache.k, self.cache.v,
+                    np.int32(plen), self.cache.pools,
                     jnp.asarray(table), np.float32(s.temperature),
                     np.int32(s.top_k),
                     np.uint32(_mr.seed_for(s.seed, plen)))
             tok = int(tok)
+            self._count_stats(stats)
             if self._draft_params is not None:
-                _, self.cache.k_draft, self.cache.v_draft = \
+                _, self.cache.draft_pools, _ = \
                     self._tail_fn(t_pad, draft=True)(
                         self._draft_params, jnp.asarray(ids),
                         np.int32(cached), np.int32(plen),
-                        self.cache.k_draft, self.cache.v_draft,
+                        self.cache.draft_pools,
                         jnp.asarray(table), np.float32(0.0),
                         np.int32(0), np.uint32(0))
                 req._spec_gap = False
@@ -611,7 +627,7 @@ class LLMEngine:
             with _flight.in_flight("perf_capture", name, program=name):
                 compiled = self._prefill_fn(padded).lower(
                     self.params, jnp.asarray(ids), np.int32(plen),
-                    self.cache.k, self.cache.v, jnp.asarray(table),
+                    self.cache.pools, jnp.asarray(table),
                     np.float32(s.temperature), np.int32(s.top_k),
                     np.uint32(0)).compile()
             self._record_program(name, compiled)
@@ -619,10 +635,12 @@ class LLMEngine:
             pass  # the ledger is observability, never a serving error
 
     # -- decode ------------------------------------------------------
-    def _batch_arrays(self):
+    def _batch_arrays(self, ahead=0):
         """Fixed-shape [max_batch] dispatch inputs; inactive slots
         decode garbage against the NULL block and are dropped on the
-        host side."""
+        host side. `ahead=1`: the inputs of the step AFTER the one in
+        flight, every context one token longer; `ids`, the one input
+        that waits for that step's tokens, stays zero."""
         b = self.max_batch
         ids = np.zeros((b,), np.int32)
         pos = np.zeros((b,), np.int32)
@@ -633,43 +651,119 @@ class LLMEngine:
         topk = np.zeros((b,), np.int32)
         seeds = np.zeros((b,), np.uint32)
         for slot, req in self.scheduler.running.items():
-            ctx = req.prompt_ids + req.output_ids
-            ids[slot] = ctx[-1]
-            pos[slot] = len(ctx) - 1
+            # the last token and the length, not the joined lists:
+            # this runs every step for every slot, whatever the
+            # context's length
+            n = req.context_len + ahead
+            if not ahead:
+                ids[slot] = (req.output_ids or req.prompt_ids)[-1]
+            pos[slot] = n - 1
             tables[slot] = self.cache.block_table(
                 req.req_id, self.max_blocks_per_seq)
-            lens[slot] = len(ctx)
+            lens[slot] = n
             s = req.sampling
             temp[slot] = s.temperature
             topk[slot] = s.top_k
-            seeds[slot] = _mr.seed_for(s.seed, len(ctx))
+            seeds[slot] = _mr.seed_for(s.seed, n)
         return ids, pos, tables, lens, temp, topk, seeds
 
-    def _dispatch_decode(self, arrays):
-        import jax.numpy as jnp
+    def _signature(self, ahead=0):
+        """What the dispatch inputs are a function of: the slots'
+        requests, their lengths (`ahead` tokens from now) and the
+        pool's numbering of blocks."""
+        return (self.cache.epoch,) + tuple(
+            (slot, req.req_id, req.context_len + ahead)
+            for slot, req in self.scheduler.running.items())
 
-        ids, pos, tables, lens, temp, topk, seeds = arrays
+    def _prepare_ahead(self):
+        """Called with a decode dispatch in flight: builds the NEXT
+        step's inputs, all but `ids`, and starts their transfer, so
+        that the device waits for neither between two steps. Nothing
+        here depends on the tokens in flight: every running request
+        will be one token longer. Skipped (the next step prepares as
+        ever) when a request is certain to end with this token, or
+        when growing the tables now would have to evict. Kept under
+        the signature the next step must still show."""
+        import jax
+
+        self._ahead = None
+        sched, cache = self.scheduler, self.cache
+        grow = 0
+        for req in sched.running.values():
+            n = req.context_len
+            if len(req.output_ids) + 1 >= req.sampling.max_new_tokens \
+                    or n + 2 > self.max_seq_len:
+                return
+            grow += max(0, cache.blocks_for_tokens(n + 2)
+                        - len(cache.allocator.owned(req.req_id)))
+        if not cache.allocator.can_alloc(grow):
+            return
+        for req in list(sched.running.values()):
+            sched.ensure_capacity(req, new_tokens=2)
+        self._ahead = (self._signature(ahead=1),
+                       jax.device_put(self._batch_arrays(ahead=1)[1:]))
+
+    def _next_arrays(self):
+        """This step's dispatch inputs: what `_prepare_ahead` made
+        while the last step ran, if the batch is still what it was
+        made for (no request finished, aborted, evicted or admitted
+        since, no block renumbered), with the tokens now known as
+        `ids`; else built from scratch."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None or ahead[0] != self._signature():
+            return self._batch_arrays()
+        ids = np.zeros((self.max_batch,), np.int32)
+        for slot, req in self.scheduler.running.items():
+            ids[slot] = req.output_ids[-1]
+        return (ids,) + ahead[1]
+
+    def _dispatch_decode(self, arrays):
+        import jax
+
         with self._program(self._pcache_label,
                            self._decode_exe is None), \
                 _flight.span("serve/decode/enqueue"):
-            args = (self.params, jnp.asarray(ids), jnp.asarray(pos),
-                    self.cache.k, self.cache.v, jnp.asarray(tables),
-                    jnp.asarray(lens), jnp.asarray(temp),
-                    jnp.asarray(topk), jnp.asarray(seeds))
+            # the seven small arrays in ONE batched transfer: each
+            # transfer of its own costs a round of the runtime's
+            # latency with the device idle (0.2-0.3 ms on a v5e host)
+            ids, pos, tables, lens, temp, topk, seeds = \
+                jax.device_put(arrays)
+            args = (self.params, ids, pos, self.cache.pools, tables,
+                    lens, temp, topk, seeds)
             if self._decode_exe is None:
                 self._load_persistent(args)
             fn = self._decode_exe or self._decode_jit
             try:
-                toks, self.cache.k, self.cache.v = fn(*args)
+                toks, self.cache.pools, stats = fn(*args)
             except TypeError:
                 if fn is not self._decode_jit:   # stale executable
                     self._decode_exe = self._decode_jit
-                    toks, self.cache.k, self.cache.v = \
+                    toks, self.cache.pools, stats = \
                         self._decode_jit(*args)
                 else:
                     raise
         with _flight.span("serve/decode/fetch"):
-            return np.asarray(toks)
+            # the wait for the device, used: the next step's inputs
+            self._prepare_ahead()
+            toks, stats = jax.device_get((toks, stats))
+            self._count_stats(stats)
+            return toks
+
+    @staticmethod
+    def _count_stats(stats):
+        """What a program returned beside its tokens, into the
+        counters. `moe_counts` [expert layers, experts]: the live
+        tokens each expert of each layer took in this dispatch."""
+        counts = stats.get("moe_counts")
+        if counts is None:
+            return
+        counts = np.asarray(counts)
+        _cmon.stat_add("serve/moe/assignments", int(counts.sum()))
+        _cmon.stat_add("serve/moe/experts_hit",
+                       int((counts > 0).sum()))
+        _cmon.stat_add("serve/moe/layer_steps", counts.shape[0])
+        _cmon.stat_add("serve/moe/max_load",
+                       int(counts.max(axis=-1).sum()))
 
     def _load_persistent(self, args):
         """First decode dispatch: route the compile through the PR-8
@@ -727,12 +821,8 @@ class LLMEngine:
         RESOURCE_EXHAUSTED mid-execution deletes donated buffers —
         retrying with them is the PTA041 use-after-donate crash.)"""
         try:
-            dead = bool(self.cache.k.is_deleted()
-                        or self.cache.v.is_deleted())
-            if not dead and self.cache.k_draft is not None:
-                dead = bool(self.cache.k_draft.is_deleted()
-                            or self.cache.v_draft.is_deleted())
-            return dead
+            return any(p.is_deleted() for p in self.cache.pools
+                       + (self.cache.draft_pools or ()))
         except Exception:
             return False
 
@@ -756,7 +846,7 @@ class LLMEngine:
                 self.scheduler.ensure_capacity(req, new_tokens=1)
             if not self.scheduler.running:
                 return
-            arrays = self._batch_arrays()
+            arrays = self._next_arrays()
         # first decode dispatch compiles (and runs _load_persistent)
         # — keep it out of the dispatch histogram like prefill
         fresh_decode = self._decode_exe is None
@@ -872,10 +962,10 @@ class LLMEngine:
             r_ids[slot] = ctx[-back]
             r_pos[slot] = len(ctx) - back
             r_lens[slot] = len(ctx) - back + 1
-        _, self.cache.k_draft, self.cache.v_draft = self._draft_jit(
+        _, self.cache.draft_pools, _ = self._draft_jit(
             self._draft_params, jnp.asarray(r_ids),
-            jnp.asarray(r_pos), self.cache.k_draft,
-            self.cache.v_draft, wide_j, jnp.asarray(r_lens),
+            jnp.asarray(r_pos), self.cache.draft_pools,
+            wide_j, jnp.asarray(r_lens),
             jnp.asarray(zeros_f), jnp.asarray(zeros_i),
             jnp.asarray(zeros_u))
         drafts = {slot: [] for slot in running}
@@ -895,11 +985,11 @@ class LLMEngine:
             topk[slot] = s.top_k
             seeds[slot] = _mr.seed_for(s.seed, len(ctx))
         for _ in range(self.spec_k - 1):
-            toks, self.cache.k_draft, self.cache.v_draft = \
+            toks, self.cache.draft_pools, _ = \
                 self._draft_jit(
                     self._draft_params, jnp.asarray(ids),
-                    jnp.asarray(pos), self.cache.k_draft,
-                    self.cache.v_draft, wide_j, jnp.asarray(lens),
+                    jnp.asarray(pos), self.cache.draft_pools,
+                    wide_j, jnp.asarray(lens),
                     jnp.asarray(temp), jnp.asarray(topk),
                     jnp.asarray(seeds))
             toks = np.asarray(toks)
@@ -934,9 +1024,9 @@ class LLMEngine:
                 v_seeds[slot, t] = _mr.seed_for(req.sampling.seed,
                                                 len(ctx) + t)
         with _flight.span("serve/decode/enqueue"):
-            toks, self.cache.k, self.cache.v = self._verify_jit(
+            toks, self.cache.pools, _ = self._verify_jit(
                 self.params, jnp.asarray(v_ids), jnp.asarray(pos),
-                self.cache.k, self.cache.v, wide_j, jnp.asarray(lens),
+                self.cache.pools, wide_j, jnp.asarray(lens),
                 jnp.asarray(temp), jnp.asarray(topk),
                 jnp.asarray(v_seeds))
         with _flight.span("serve/decode/fetch"):

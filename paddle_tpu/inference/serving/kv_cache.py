@@ -8,6 +8,13 @@ fixed-size blocks per layer:
 
     k/v pools:  [num_layers, num_blocks, block_size, n_head * head_dim]
 
+What a token's row holds is the model's business: the cache is told
+its pools by their row widths (`rows`, from the model's runner).
+GPT-2 has two, keys and values of `n_head * head_dim` each; a
+latent-attention model has ONE, `[normalised latent | rotated key]`
+(576 values for GLM-4.7-Flash), and no V pool. Allocator, tables and
+admission do not know the difference.
+
 (heads and head_dim share the minor dimension: a token's K or V row
 is `n_head * head_dim` contiguous values, stored row-major and
 unpadded on the device. With `head_dim` alone as the minor dimension
@@ -146,10 +153,15 @@ def prefix_hashes(tokens, block_size, n_blocks=None):
     return out
 
 
-def bytes_per_block(num_layers, block_size, n_head, head_dim, dtype):
-    """HBM cost of ONE block id across all layers, K and V."""
+def bytes_per_block(num_layers, block_size, n_head=None, head_dim=None,
+                    dtype=np.float32, rows=None):
+    """HBM cost of ONE block id across all layers and pools: `rows`
+    are the pools' row widths, by default a K and a V pool of
+    `n_head * head_dim` values each."""
+    if rows is None:
+        rows = (n_head * head_dim,) * 2
     itemsize = np.dtype(dtype).itemsize
-    return 2 * num_layers * block_size * n_head * head_dim * itemsize
+    return num_layers * block_size * sum(rows) * itemsize
 
 
 def auto_num_blocks(per_block, pool_bytes=None, fraction=0.45):
@@ -390,20 +402,22 @@ class BlockAllocator:
 class PagedKVCache:
     """The device pools + the allocator + per-request block tables."""
 
-    def __init__(self, num_layers, num_heads, head_dim,
+    def __init__(self, num_layers, num_heads=None, head_dim=None,
                  block_size=None, num_blocks=None, pool_bytes=None,
-                 dtype=None, draft_layers=0, prefix_cache=False):
+                 dtype=None, draft_layers=0, prefix_cache=False,
+                 rows=None):
         import jax.numpy as jnp
 
         self.block_size = int(block_size or env_block_size())
         self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        # one pool per entry, each `[L, N, BS, width]`
+        self.rows = tuple(int(w) for w in (
+            rows or (num_heads * head_dim,) * 2))
         self.dtype = jnp.dtype(dtype or jnp.float32)
         self.draft_layers = int(draft_layers)
         self.prefix_cache = bool(prefix_cache)
         per_block = bytes_per_block(num_layers, self.block_size,
-                                    num_heads, head_dim, self.dtype)
+                                    dtype=self.dtype, rows=self.rows)
         if num_blocks is None:
             num_blocks = auto_num_blocks(per_block,
                                          pool_bytes=pool_bytes)
@@ -412,21 +426,45 @@ class PagedKVCache:
         # and tables — the chain-hash identity that lets two requests
         # share target KV holds for draft KV too, so one refcount
         # covers both
-        self.k_draft = self.v_draft = None
+        self.draft_pools = None
+        # bumped whenever block ids are renumbered (defrag): a table
+        # built before is stale after
+        self.epoch = 0
         self._zero_pools()
         self.allocator = BlockAllocator(self.num_blocks)
+        _cmon.stat_set("serve/kv/row_values", sum(self.rows))
+        _cmon.stat_set("serve/kv/bytes_per_token",
+                       per_block // self.block_size)
 
     def _zero_pools(self):
         import jax.numpy as jnp
 
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads * self.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        def zeros(layers):
+            return tuple(
+                jnp.zeros((layers, self.num_blocks, self.block_size, w),
+                          self.dtype) for w in self.rows)
+
+        self.pools = zeros(self.num_layers)
         if self.draft_layers:
-            dshape = (self.draft_layers,) + shape[1:]
-            self.k_draft = jnp.zeros(dshape, self.dtype)
-            self.v_draft = jnp.zeros(dshape, self.dtype)
+            self.draft_pools = zeros(self.draft_layers)
+
+    def _pool_by_name(group, i):
+        """The i-th pool of `pools` / `draft_pools` as an attribute:
+        the two pools of a keys-and-values cache, by name."""
+        def get(self):
+            pools = getattr(self, group)
+            return pools and pools[i]
+
+        def put(self, value):
+            pools = list(getattr(self, group))
+            pools[i] = value
+            setattr(self, group, tuple(pools))
+        return property(get, put)
+
+    k, v = _pool_by_name("pools", 0), _pool_by_name("pools", 1)
+    k_draft = _pool_by_name("draft_pools", 0)
+    v_draft = _pool_by_name("draft_pools", 1)
+    del _pool_by_name
 
     # -- geometry ----------------------------------------------------
     def blocks_for_tokens(self, n_tokens):
@@ -541,6 +579,7 @@ class PagedKVCache:
         moved = sum(1 for old, new in mapping.items() if old != new)
         if not moved:
             return 0
+        self.epoch += 1
         # perm[new] = old; untouched tail keeps identity so freed
         # block contents (never read — reads are context-masked) need
         # no care beyond staying in range
@@ -550,11 +589,10 @@ class PagedKVCache:
         import jax.numpy as jnp
 
         idx = jnp.asarray(perm)
-        self.k = self.k[:, idx]
-        self.v = self.v[:, idx]
-        if self.k_draft is not None:
-            self.k_draft = self.k_draft[:, idx]
-            self.v_draft = self.v_draft[:, idx]
+        self.pools = tuple(p[:, idx] for p in self.pools)
+        if self.draft_pools is not None:
+            self.draft_pools = tuple(p[:, idx]
+                                     for p in self.draft_pools)
         for owner in owners:
             self.allocator._owned[owner] = [
                 mapping[b] for b in self.allocator._owned[owner]]

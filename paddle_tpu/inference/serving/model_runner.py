@@ -1,4 +1,22 @@
-"""GPT-2 paged-serving forward passes (prefill + single-token decode).
+"""The runners LLMEngine reads, and GPT-2's paged-serving forward
+passes (prefill + single-token decode): the first runner.
+
+A RUNNER is what the engine knows of a model (`runner_for(model)`
+picks it from the model's type; nothing else selects it):
+
+    runner.params, runner.config     the raw jnp tree and the config
+    runner.pool_rows                 row width of each cache pool
+    runner.prefill_step(params, ids, prompt_len, pools, table, temp, top_k, seed, *, block_size)
+    runner.decode_step(params, ids, positions, pools, tables, lens, temp, top_k, seeds, *, block_size, use_kernel, interpret)
+    runner.verify_step, .prefill_tail_step, .draft_params    or None: no speculation / prefix cache
+
+Every step returns `(tokens, pools, stats)`: `pools` the tuple it
+was handed (donated, updated in place), `stats` a dict of small
+arrays fetched with the tokens (routing counts; empty for GPT-2).
+`GPT2Runner` hands the engine the programs below as they are —
+`_pooled` only packs their `k_pool, v_pool` into the tuple, so they
+lower to the HLO they always did. `mla_runner.MLARunner` is the
+second runner.
 
 The serving engine never calls `GPTModel.forward` — re-running the
 full prompt for every generated token is O(S^2) per request. Instead
@@ -53,6 +71,7 @@ persistent compile cache keys their StableHLO like any other program.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -63,7 +82,7 @@ from ...text.models.gpt import (_attention, _layer_norm,
 
 __all__ = ["extract_params", "prefill_step", "decode_step",
            "verify_step", "prefill_tail_step", "draft_params",
-           "sample_tokens", "seed_for"]
+           "sample_tokens", "seed_for", "GPT2Runner", "runner_for"]
 
 
 def extract_params(model):
@@ -386,3 +405,60 @@ def prefill_tail_step(params, ids, start, total_len, k_pool, v_pool,
     token = sample_tokens(logits[None], temperature[None],
                           top_k[None], seed[None])[0]
     return token, k_pool, v_pool
+
+
+# -- the runner ----------------------------------------------------------------
+
+def _pooled(step, at):
+    """`step(..., k_pool, v_pool, ...)` behind the engine's calling
+    convention: the pools are ONE argument (a tuple, at position
+    `at`) and the result is `(tokens, pools, stats)`. Flattened, the
+    arguments and results are what `step` itself has."""
+    def run(*args, **kw):
+        out = step(*args[:at], *args[at], *args[at + 1:], **kw)
+        return out[0], tuple(out[1:]), {}
+    return run
+
+
+class GPT2Runner:
+    """How LLMEngine serves a GPTForCausalLM / GPTModel: a K and a V
+    pool of `hidden_size` values a token a layer, and the four
+    programs above."""
+
+    draft_params = staticmethod(draft_params)
+
+    def __init__(self, model):
+        self.params, self.config = extract_params(model)
+        c = self.config
+        kw = dict(n_head=c.num_heads, eps=c.layer_norm_eps)
+        self.pool_rows = (c.hidden_size,) * 2
+        self.prefill_step = functools.partial(
+            _pooled(prefill_step, 3), **kw)
+        self.decode_step = functools.partial(
+            _pooled(decode_step, 3), **kw)
+        self.verify_step = functools.partial(
+            _pooled(verify_step, 3), **kw)
+        self.prefill_tail_step = functools.partial(
+            _pooled(prefill_tail_step, 4), **kw)
+
+    def kernel_supported(self, block_size):
+        """Does the Pallas paged-attention kernel take this model's
+        heads at this block size, here?"""
+        from ...incubate.nn import pallas as _pl
+
+        c = self.config
+        return _pl.kernels_available() and \
+            _pl.paged_attention.paged_decode_supported(
+                c.num_heads, c.hidden_size // c.num_heads, block_size)
+
+
+def runner_for(model):
+    """The runner of a model, by its type."""
+    from ...text.models import glm4_moe_lite as _glm
+
+    if isinstance(model, (_glm.Glm4MoeLiteForCausalLM,
+                          _glm.Glm4MoeLiteModel)):
+        from .mla_runner import MLARunner
+
+        return MLARunner(model)
+    return GPT2Runner(model)

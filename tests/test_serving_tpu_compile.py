@@ -81,3 +81,56 @@ def test_tpu_program_updates_pools_in_place(one_chip, program):
     assert mem.alias_size_in_bytes >= 2 * one_pool
     assert mem.temp_size_in_bytes < one_pool // 2, (
         f"{mem.temp_size_in_bytes / one_pool:.2f} of one pool")
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 27: the latent (MLA) runner's programs, GLM-4.7-Flash's widths
+# ---------------------------------------------------------------------------
+
+def _mla_programs(sd):
+    """decode and one prefill of `mla_runner` at the published widths
+    (hidden 2048, 20 heads, ranks 768/512, 64 experts of 1536, block
+    16, batch 64, 4096 positions); depth 1 + 2 layers, vocabulary and
+    pool cut down. The pool's row is what `MLARunner` stores."""
+    from paddle_tpu.inference.serving import mla_runner as mla
+    from paddle_tpu.text.models.glm4_moe_lite import (Glm4MoeLiteConfig,
+                                                      Glm4MoeLiteModel)
+
+    cfg = Glm4MoeLiteConfig(num_hidden_layers=3, vocab_size=2048,
+                            dtype="bfloat16")
+    shapes = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda p: p._value, Glm4MoeLiteModel(cfg)._tree))
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
+    row = -(-cfg.latent_row // 128) * 128
+    i32, f32, bsz, maxb = jnp.int32, jnp.float32, 64, 4096 // 16
+    pool = sd((3, 16385, 16, row), jnp.bfloat16)
+    kw = dict(cfg=cfg, block_size=16)
+    return pool, {
+        "decode": (mla.decode_step, (
+            params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
+            sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
+            sd((bsz,), i32), sd((bsz,), i32))),
+        "prefill": (mla.prefill_step, (
+            params, sd((1, 1536), i32), sd((), i32), (pool,),
+            sd((maxb,), i32), sd((), f32), sd((), i32), sd((), i32))),
+    }, kw
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_tpu_latent_program_updates_its_pool_in_place(one_chip, program):
+    """The one pool is aliased input to output, and the temporaries
+    together are smaller than the pool and than one layer's routed
+    experts (0.75 GiB: what slicing a layer out of the stack would
+    copy), so neither is copied."""
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool, programs, kw = _mla_programs(sd)
+    fn, args = programs[program]
+    mem = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
+        .lower(*args).compile().memory_analysis()
+    one_pool = pool.size * pool.dtype.itemsize
+    one_layers_experts = 64 * 2048 * 3072 * 2
+    assert mem.alias_size_in_bytes >= one_pool
+    assert mem.temp_size_in_bytes < min(one_pool, one_layers_experts), (
+        f"{mem.temp_size_in_bytes / 2 ** 20:.0f} MiB of temporaries")
