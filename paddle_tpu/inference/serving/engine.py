@@ -744,10 +744,20 @@ class LLMEngine:
                     raise
         with _flight.span("serve/decode/fetch"):
             # the wait for the device, used: the next step's inputs
+            self._count_sample_case()
             self._prepare_ahead()
             toks, stats = jax.device_get((toks, stats))
             self._count_stats(stats)
             return toks
+
+    def _count_sample_case(self):
+        """One target dispatch (decode or verify) into the counter of
+        the case its sampler takes for this batch:
+        `serve/sample/steps_{greedy,drawn,ranked}`."""
+        case = _mr.sample_case(
+            req.sampling for req in self.scheduler.running.values())
+        _cmon.stat_add(
+            "serve/sample/steps_" + _mr.SAMPLE_CASES[case], 1)
 
     @staticmethod
     def _count_stats(stats):
@@ -1030,6 +1040,7 @@ class LLMEngine:
                 jnp.asarray(temp), jnp.asarray(topk),
                 jnp.asarray(v_seeds))
         with _flight.span("serve/decode/fetch"):
+            self._count_sample_case()
             return np.asarray(toks)
 
     def _spec_decode_batch(self, emitted):

@@ -82,7 +82,8 @@ from ...text.models.gpt import (_attention, _layer_norm,
 
 __all__ = ["extract_params", "prefill_step", "decode_step",
            "verify_step", "prefill_tail_step", "draft_params",
-           "sample_tokens", "seed_for", "GPT2Runner", "runner_for"]
+           "sample_tokens", "sample_case", "SAMPLE_CASES", "seed_for",
+           "GPT2Runner", "runner_for"]
 
 
 def extract_params(model):
@@ -129,22 +130,64 @@ def sample_tokens(logits, temperature, top_k, seeds):
 
     temperature[b] == 0 -> exact argmax (greedy decode);
     temperature[b] > 0  -> categorical over logits/temperature with
-    ranks >= top_k[b] masked out when top_k[b] > 0. The rank trick
-    (double argsort) keeps k per-request and traced — `lax.top_k`
-    would force one compiled program per distinct k."""
+    all but the top_k[b] largest masked out when top_k[b] > 0 (ties
+    at the k-th place go to the lower token ids). k is per-request
+    and traced — `lax.top_k` would force one compiled program per
+    distinct k.
+
+    What runs follows what the BATCH asks for, chosen on the device
+    by one `lax.switch` on `_batch_case` (`sample_case` on the
+    host): no row draws -> the argmax alone; rows draw, none of them
+    filters -> the draw too; a drawing row filters -> also the one
+    sort that finds each row's k-th largest logit. The switch stands
+    outside every `vmap`: under one it would be a select that runs
+    all three."""
     vocab = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    draws = temperature > 0
 
-    def draw(lg, t, k, seed):
-        ranks = jnp.argsort(jnp.argsort(-lg))
-        keep = ranks < jnp.where(k > 0, k, vocab)
-        lg = jnp.where(keep, lg, -jnp.inf)
+    def draw_one(lg, t, seed):
         key = jax.random.fold_in(jax.random.PRNGKey(0), seed)
         return jax.random.categorical(
             key, lg / jnp.maximum(t, 1e-6)).astype(jnp.int32)
 
-    sampled = jax.vmap(draw)(logits, temperature, top_k, seeds)
-    return jnp.where(temperature > 0, sampled, greedy)
+    def drawn(lg):
+        sampled = jax.vmap(draw_one)(lg, temperature, seeds)
+        return jnp.where(draws, sampled, greedy)
+
+    def ranked():
+        # the set a stable descending argsort ranks under k: all above
+        # the k-th largest value, and of its equals the first by index
+        k = jnp.where(top_k > 0, jnp.minimum(top_k, vocab),
+                      vocab)[:, None]
+        kth = jnp.take_along_axis(
+            jnp.sort(logits, axis=-1, stable=False), vocab - k, axis=-1)
+        above, ties = logits > kth, logits == kth
+        room = k - above.sum(-1, keepdims=True)
+        keep = above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+        return drawn(jnp.where(keep, logits, -jnp.inf))
+
+    return jax.lax.switch(
+        _batch_case(temperature, top_k),
+        (lambda: greedy, lambda: drawn(logits), ranked))
+
+
+SAMPLE_CASES = ("greedy", "drawn", "ranked")
+
+
+def _batch_case(temperature, top_k):
+    """The index into SAMPLE_CASES of a batch's [B] arrays, on the
+    device: two reductions over the batch."""
+    draws = temperature > 0
+    return jnp.any(draws).astype(jnp.int32) \
+        + jnp.any(draws & (top_k > 0)).astype(jnp.int32)
+
+
+def sample_case(samplings):
+    """`_batch_case` on the host, over the batch's SamplingParams:
+    the case the program will take, told without a fetch."""
+    drawing = [s for s in samplings if s.temperature > 0]
+    return (len(drawing) > 0) + any(s.top_k > 0 for s in drawing)
 
 
 def _scatter_positions(block_table, positions, block_size):

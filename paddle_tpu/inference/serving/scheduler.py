@@ -133,9 +133,11 @@ class SamplingParams:
     (higher survives eviction longer).
 
     Every field is validated HERE, at intake — a negative `top_k`
-    would otherwise flow uncaught into the compiled double-argsort
-    sampler and mask every logit, and a float `seed` would crash the
-    uint32 cast inside a dispatch instead of at the API edge."""
+    would otherwise flow uncaught into the compiled sampler
+    (`model_runner.sample_tokens`), which reads any k <= 0 as "no
+    filter" and would ignore it in silence, and a float `seed` would
+    crash the uint32 cast inside a dispatch instead of at the API
+    edge."""
 
     def __init__(self, max_new_tokens=16, temperature=0.0, top_k=0,
                  eos_token_id=None, stop_token_ids=(), seed=0,
@@ -151,8 +153,8 @@ class SamplingParams:
         if top_k < 0:
             raise ValueError(
                 f"top_k must be >= 0 (0 = no filtering), got "
-                f"{top_k} — a negative k would mask every logit in "
-                "the compiled rank-filter sampler")
+                f"{top_k} — the compiled sampler reads a negative k "
+                "as no filter and would ignore it in silence")
         if not _int_like(seed):
             raise ValueError(
                 f"seed must be an int, got {type(seed).__name__} "

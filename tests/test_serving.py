@@ -172,7 +172,7 @@ class TestSamplingParamsValidation:
 
     def test_negative_top_k_rejected(self):
         # a negative k used to flow uncaught into the compiled
-        # double-argsort sampler (ranks < k masks EVERY logit)
+        # sampler, which reads k <= 0 as "no filter": ignored in silence
         with pytest.raises(ValueError, match="top_k"):
             SamplingParams(top_k=-1)
 
@@ -1533,3 +1533,217 @@ class TestPoolsInPlace:
         # the dispatches did write, and not the same token each time
         assert not np.array_equal(got[1], np.asarray(rig.k))
         assert len(set(got[0].tolist())) > 1
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 28: the sampler ranks the vocabulary only for a batch that
+# asks for it
+# ---------------------------------------------------------------------------
+
+def _double_argsort_sample_tokens(logits, temperature, top_k, seeds):
+    """`sample_tokens` as it stood before ISSUE 28, verbatim: the
+    reference the batch-level switch must reproduce token for token."""
+    import jax
+    import jax.numpy as jnp
+
+    vocab = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw(lg, t, k, seed):
+        ranks = jnp.argsort(jnp.argsort(-lg))
+        keep = ranks < jnp.where(k > 0, k, vocab)
+        lg = jnp.where(keep, lg, -jnp.inf)
+        key = jax.random.fold_in(jax.random.PRNGKey(0), seed)
+        return jax.random.categorical(
+            key, lg / jnp.maximum(t, 1e-6)).astype(jnp.int32)
+
+    sampled = jax.vmap(draw)(logits, temperature, top_k, seeds)
+    return jnp.where(temperature > 0, sampled, greedy)
+
+
+_SV, _SB = 61, 24      # vocabulary and batch of the sampler cases
+
+
+def _sampler_case(name):
+    """(logits, temperature, top_k, seeds, the switch's case) of one
+    named batch."""
+    rng = np.random.RandomState(len(name))
+    logits = rng.randn(_SB, _SV).astype(np.float32)
+    temp = np.full((_SB,), 0.8, np.float32)
+    topk = np.zeros((_SB,), np.int32)
+    seeds = rng.randint(0, 2 ** 31, _SB).astype(np.uint32)
+    case = 2
+    if name == "all_greedy":
+        temp[:], case = 0.0, 0
+        topk[::2] = 3                  # a greedy row's k asks nothing
+    elif name == "all_sampling_top_k_0":
+        case = 1
+    elif name.startswith("top_k_"):
+        topk[:] = {"1": 1, "4": 4, "vocab": _SV,
+                   "vocab_plus_3": _SV + 3}[name[len("top_k_"):]]
+    elif name == "mixed_rows":
+        temp[0::3], topk[2::3] = 0.0, 5
+    elif name == "greedy_rows_filter_sampling_rows_do_not":
+        temp[0::2], topk[0::2], case = 0.0, 5, 1
+    elif name == "ties_across_kth":
+        # every row the same few values, +0.0 and -0.0 among them, so
+        # that the k-th place always falls inside a run of equals;
+        # a high temperature spreads the draws over the whole kept set
+        logits = rng.choice(
+            np.array([-1.0, -0.0, 0.0, 0.5, 2.0], np.float32),
+            size=(_SB, _SV))
+        temp[:] = 6.0
+        topk[:] = 2 + np.arange(_SB) % 11
+    elif name == "verify_repeats":
+        # what verify_step hands over: per-request values repeated
+        # for each of its t_q slots
+        t_q = 4
+        temp = np.repeat(np.array([0.0, 0.9, 1.3] * 2, np.float32), t_q)
+        topk = np.repeat(np.array([0, 0, 7] * 2, np.int32), t_q)
+    else:
+        raise KeyError(name)
+    return logits, temp, topk, seeds, case
+
+
+_SAMPLER_CASES = [
+    "all_greedy", "all_sampling_top_k_0", "top_k_1", "top_k_4",
+    "top_k_vocab", "top_k_vocab_plus_3", "mixed_rows",
+    "greedy_rows_filter_sampling_rows_do_not", "ties_across_kth",
+    "verify_repeats"]
+
+
+class TestSamplerFollowsTheBatch:
+    @pytest.mark.parametrize("name", _SAMPLER_CASES)
+    def test_tokens_identical_to_the_double_argsort(self, name):
+        import jax
+
+        from paddle_tpu.inference.serving import model_runner as mr
+
+        logits, temp, topk, seeds, _ = _sampler_case(name)
+        new = jax.jit(mr.sample_tokens)
+        old = jax.jit(_double_argsort_sample_tokens)
+        drew = set()
+        for round_ in range(8):        # eight seeds a row
+            s = seeds + np.uint32(round_ * 7919)
+            got = np.asarray(new(logits, temp, topk, s))
+            np.testing.assert_array_equal(
+                got, np.asarray(old(logits, temp, topk, s)))
+            drew.update(got[temp > 0].tolist())
+        if name != "all_greedy" and name != "top_k_1":
+            assert len(drew) > 3       # the draws did vary
+
+    @pytest.mark.parametrize("name", _SAMPLER_CASES)
+    def test_host_tells_the_case_the_program_takes(self, name):
+        """`sample_case` over the requests' SamplingParams is the
+        index the program computes from its two arrays."""
+        from paddle_tpu.inference.serving import model_runner as mr
+
+        _, temp, topk, _, case = _sampler_case(name)
+        assert mr.sample_case(
+            SamplingParams(temperature=float(t), top_k=int(k))
+            for t, k in zip(temp, topk)) == case
+        assert int(mr._batch_case(temp, topk)) == case
+
+    def test_sort_only_inside_the_ranked_branch(self):
+        """The structure that makes greedy decode cheap: at the top
+        level no sort, no random bits and no logits-sized select, but
+        ONE switch whose index is reduced over the batch (outside any
+        vmap: under one a cond is a select that runs every branch);
+        the sort in branch 2 alone, once, without an index payload."""
+        import jax
+        import jax.extend
+
+        from paddle_tpu.inference.serving import model_runner as mr
+
+        logits, temp, topk, seeds, _ = _sampler_case("mixed_rows")
+        jaxpr = jax.make_jaxpr(mr.sample_tokens)(
+            logits, temp, topk, seeds).jaxpr
+
+        def eqns(jp, through_cond):
+            """Every equation of `jp` and of the jaxprs nested in it
+            (jit, vmap's closed calls, ...), a cond's branches only
+            with `through_cond`."""
+            for e in jp.eqns:
+                yield e
+                if e.primitive.name == "cond" and not through_cond:
+                    continue
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from eqns(sub, through_cond)
+
+        top = list(eqns(jaxpr, through_cond=False))
+        names = [e.primitive.name for e in top]
+        assert names.count("cond") == 1
+        for heavy in ("sort", "random_bits", "threefry2x32", "cumsum"):
+            assert heavy not in names, heavy
+        assert not [e for e in top if e.primitive.name == "select_n"
+                    and e.outvars[0].aval.shape == logits.shape]
+        switch = top[names.index("cond")]
+        assert len(switch.params["branches"]) == 3
+        # the index: scalar, made of reductions over the batch alone
+        made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+        seen, todo, reduced = set(), [switch.invars[0]], 0
+        while todo:
+            v = todo.pop()
+            e = made_by.get(v)
+            if e is None or id(e) in seen:
+                continue
+            seen.add(id(e))
+            if e.primitive.name.startswith("reduce_"):
+                assert e.invars[0].aval.shape == (_SB,)
+                reduced += 1
+                continue
+            assert v.aval.shape == ()
+            todo.extend(x for x in e.invars
+                        if isinstance(x, jax.extend.core.Var))
+        assert reduced == 2
+        sorts = [[e for e in eqns(b.jaxpr, through_cond=True)
+                  if e.primitive.name == "sort"]
+                 for b in switch.params["branches"]]
+        assert [len(s) for s in sorts] == [0, 0, 1]
+        assert len(sorts[2][0].invars) == 1      # values alone
+        assert not switch.params["branches"][0].jaxpr.eqns
+
+
+class TestSampleCaseCounters:
+    def test_counters_follow_the_batches(self):
+        """Greedy requests, then a sampling one among them, then one
+        that filters: each target dispatch counts under the case its
+        batch takes, and under no other."""
+        eng = LLMEngine(tiny_model(), max_batch=4, block_size=8,
+                        num_blocks=32)
+        greedy = SamplingParams(max_new_tokens=5)
+
+        def steps(sampling):
+            def run():
+                eng.add_request([5, 6], greedy)
+                eng.add_request([7, 8, 9], sampling)
+                while eng.has_unfinished():
+                    eng.step()
+            return _counter_deltas(("serve/sample/",), run)[1]
+
+        # 5 tokens a request: the prefill's and 4 decode dispatches;
+        # with a request of 3 tokens, 2 dispatches hold both
+        assert steps(greedy) == {"serve/sample/steps_greedy": 4}
+        assert steps(SamplingParams(
+            max_new_tokens=3, temperature=1.0, seed=3)) == {
+                "serve/sample/steps_greedy": 2,
+                "serve/sample/steps_drawn": 2}
+        assert steps(SamplingParams(
+            max_new_tokens=3, temperature=1.0, top_k=4, seed=3)) == {
+                "serve/sample/steps_greedy": 2,
+                "serve/sample/steps_ranked": 2}
+        assert eng.check_drained() == {}
+
+    def test_verify_dispatches_count_too(self, rig):
+        """Speculation: one count a round, the verify dispatch's."""
+        rounds = cmon.hist_get("serve/hist/accept_len").count
+        _, deltas = _counter_deltas(
+            ("serve/sample/",),
+            lambda: rig.engine("k4", spec_k=4).generate(
+                [[5, 6, 7]], sampling=SamplingParams(
+                    max_new_tokens=6, temperature=0.9, top_k=5,
+                    seed=1)))
+        # one request: one acceptance length observed a round
+        rounds = cmon.hist_get("serve/hist/accept_len").count - rounds
+        assert rounds > 0
+        assert deltas == {"serve/sample/steps_ranked": rounds}

@@ -134,3 +134,39 @@ def test_tpu_latent_program_updates_its_pool_in_place(one_chip, program):
     assert mem.alias_size_in_bytes >= one_pool
     assert mem.temp_size_in_bytes < min(one_pool, one_layers_experts), (
         f"{mem.temp_size_in_bytes / 2 ** 20:.0f} MiB of temporaries")
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 28: the sampler's switch survives the TPU compiler
+# ---------------------------------------------------------------------------
+
+def test_tpu_sampler_keeps_its_switch_and_sorts_in_one_branch(one_chip):
+    """At GLM-4.7-Flash's batch and vocabulary the optimised HLO still
+    holds the `conditional` with its three branch computations (not
+    flattened into a select that runs them all), and the one sort of
+    [64, 154880] values, without an index payload, lies in a branch
+    and not in the entry computation that every batch runs."""
+    import re
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = jax.jit(mr.sample_tokens).lower(
+        sd((64, 154880)), sd((64,)), sd((64,), jnp.int32),
+        sd((64,), jnp.uint32)).compile().as_text()
+    (switch,) = re.findall(r" conditional\(.*branch_computations=\{(.*?)\}",
+                           hlo)
+    branches = [b.strip().lstrip("%") for b in switch.split(",")]
+    assert len(branches) == 3
+    body = {}          # computation name -> its text
+    for block in hlo.split("\n\n"):
+        head = block.lstrip().split("\n", 1)[0]
+        name = re.match(r"(?:ENTRY )?%?([\w.\-]+) ", head)
+        if name:
+            body[name.group(1)] = block
+    entry, = [b for b in body.values() if b.lstrip().startswith("ENTRY")]
+    big = r"= f32\[64,154880\]\S* sort\("
+    assert not re.search(r" sort\(", entry)
+    assert not re.search(r" sort\(", body[branches[0]])
+    assert not re.search(r" sort\(", body[branches[1]])
+    assert len(re.findall(big, body[branches[2]])) == 1
