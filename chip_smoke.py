@@ -638,7 +638,6 @@ def main(argv=None):
         return 2
 
     import paddle_tpu
-    from paddle_tpu.core.monitor import stat_get
     from paddle_tpu.jit import persistent_cache
 
     here = os.path.dirname(os.path.abspath(__file__))
@@ -676,14 +675,11 @@ def main(argv=None):
     phase_multichip(width, train["batch"], train["seq"])
 
     native = persistent_cache.native_cache_stats()
-    errors = stat_get("jit/persistent_cache/errors")
     say("env", f"compile cache {native['dir']}: {native['requests']} "
-               f"requests, {native['hits']} hits, {native['misses']} misses; "
-               f".pdx store errors {errors}")
+               f"requests, {native['hits']} hits, {native['misses']} misses")
     say("env", f"compile seconds: train {t['compile_s']:.1f}, serve "
                f"{s['compile_s']:.1f}; whole run "
                f"{time.perf_counter() - t_start:.0f} s")
-    assert errors == 0, f"jit/persistent_cache/errors = {errors}"
     if _PREFLIGHT:
         print("preflight ok — not a chip run; no result line", flush=True)
         return 0

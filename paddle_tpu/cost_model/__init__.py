@@ -56,25 +56,14 @@ class CostModel:
         """The compiled executable for (fn, arg signature) — compiled
         on first use, cached for every later static_cost /
         memory_cost / profile_measure probe of the same candidate.
-        With PADDLE_COMPILE_CACHE_DIR set, the compile also consults
-        the persistent on-disk cache (jit.persistent_cache), so a
-        planner sweep doesn't recompile candidates the fleet (or a
-        previous sweep) already built."""
+        Where JAX's persistent compilation cache is armed
+        (jit.persistent_cache), a planner sweep doesn't recompile
+        candidates a previous process already built."""
         treedef, sig = _sig_of(args)
         key = (id(fn), treedef, sig)
         ent = self._cache.get(key)
         if ent is None or ent[0] is not fn:
-            lowered = jax.jit(fn).lower(*args)
-            from ..jit import persistent_cache as _pcache
-
-            if _pcache.enabled():
-                label = "cost_model:" + (
-                    getattr(fn, "__qualname__", None)
-                    or getattr(fn, "__name__", "fn"))
-                compiled, _ = _pcache.load_or_compile(lowered, label)
-            else:
-                compiled = lowered.compile()
-            ent = (fn, compiled)
+            ent = (fn, jax.jit(fn).lower(*args).compile())
             self._cache[key] = ent
             while len(self._cache) > _CACHE_MAX:
                 self._cache.popitem(last=False)

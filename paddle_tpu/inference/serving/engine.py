@@ -24,14 +24,16 @@ slots ride along pointed at the NULL block. Stop conditions
 returned tokens; finished requests free their blocks before the next
 admission pass.
 
-Compiled-step contract: the decode step is `jax.jit` with BOTH pools
-DONATED (the engine re-adopts the returned pools each dispatch — the
-PR-8/PR-9 donation discipline), and its first dispatch routes
-through the persistent compile cache (`jit.persistent_cache`) under
-the label `serve_decode:<Model>` — a serving replica restarting
-against a warm PADDLE_SERVE-sized pool skips the backend compile
-entirely (the ROADMAP cold-start story). Prefill compiles once per
-block-rounded prompt-length bucket, so prompt-length cardinality is
+Compiled-step contract: every program is a `jit.Program` over the
+runner's step with the pools DONATED (the engine re-adopts the
+returned pools each dispatch), named `serve_decode:<Model>`,
+`serve_prefill:<Model>[#n]`, ...; the Program counts its dispatches,
+spans its first one `compile/<name>` and records its footprint. On
+an accelerator that compile goes through JAX's persistent
+compilation cache (`jit.persistent_cache`), so a serving replica
+restarting against the same model and pool loads its programs
+instead of compiling them. Prefill compiles once per block-rounded
+prompt-length bucket, so prompt-length cardinality is
 `max_seq_len / block_size`, not `max_seq_len`.
 
 Failure path: a RESOURCE_EXHAUSTED dispatch (real, or injected at
@@ -93,7 +95,6 @@ the router's per-replica health signal.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 import time
@@ -101,6 +102,7 @@ import time
 import numpy as np
 
 from ...core import monitor as _cmon
+from ...jit.program import Program, arm_compile_cache, specialised
 from ...monitor import chaos as _chaos
 from ...monitor import flight as _flight
 from ...monitor import memory as _memory
@@ -115,7 +117,54 @@ from .scheduler import (EngineOverloaded, EXPORTED, FINISHED,
 
 __all__ = ["LLMEngine", "EngineTimeout"]
 
-_NOT_COMPILING = contextlib.nullcontext()
+# kind -> (the runner's step, the program's label, the argument that
+# holds the pools it donates, whether it takes the kernel switches).
+# The draft model runs the same steps over its own twin pools.
+_PROGRAM_KINDS = {
+    "decode": ("decode_step", "serve_decode", 3, True),
+    "prefill": ("prefill_step", "serve_prefill", 3, False),
+    "prefill_tail": ("prefill_tail_step", "serve_prefill_tail", 4, False),
+    "verify": ("verify_step", "serve_verify", 3, True),
+    "draft": ("decode_step", "serve_draft", 3, True),
+    "draft_prefill": ("prefill_step", "serve_draft_prefill", 3, False),
+    "draft_tail": ("prefill_tail_step", "serve_draft_prefill_tail", 4,
+                   False),
+}
+
+
+class _Programs:
+    """The engine's compiled programs, `jit.Program`s by (kind,
+    width), each built where first needed from the runner's step of
+    its kind. `width` is the padded length of a prefill bucket or of
+    a prefix-cache tail (each its own compiled program); None for the
+    fixed-shape ones. Names: `<label>:<Model>`; a prefill bucket
+    after the first `#n` in the order first run, a tail `@<width>`."""
+
+    def __init__(self, runner, model_name, **kernel):
+        self._runner = runner
+        self._model = model_name
+        self._kernel = kernel
+        self._table = {}
+
+    def label(self, kind):
+        return f"{_PROGRAM_KINDS[kind][1]}:{self._model}"
+
+    def __call__(self, kind, width=None):
+        prog = self._table.get((kind, width))
+        if prog is None:
+            step, _, pools, kernel = _PROGRAM_KINDS[kind]
+            kw = self._kernel if kernel else {
+                "block_size": self._kernel["block_size"]}
+            name = self.label(kind)
+            if kind.endswith("tail"):
+                name = f"{name}@{width}"
+            elif width is not None:
+                name = specialised(name, sum(
+                    k == kind for k, _ in self._table))
+            prog = self._table[kind, width] = Program(
+                functools.partial(getattr(self._runner, step), **kw),
+                name, donate_argnums=(pools,))
+        return prog
 
 
 class EngineTimeout(TimeoutError):
@@ -136,13 +185,9 @@ class LLMEngine:
     def __init__(self, model, max_batch=None, block_size=None,
                  num_blocks=None, pool_bytes=None, dtype=None,
                  static_batching=False, use_kernel=None,
-                 donate=True, max_queue=None, spec_k=None,
+                 max_queue=None, spec_k=None,
                  draft_layers=None, prefix_cache=None,
                  max_seq_len=None):
-        import jax
-
-        from ...jit import arm_compile_cache
-
         arm_compile_cache()
         # everything the engine knows of the model it reads from the
         # runner its type selects (model_runner.runner_for)
@@ -204,63 +249,27 @@ class LLMEngine:
         else:
             self._kernel_interpret = False
         self.use_kernel = bool(use_kernel)
-        self._donate = bool(donate)
-
-        decode = functools.partial(
-            runner.decode_step, block_size=self.block_size,
-            use_kernel=self.use_kernel,
-            interpret=self._kernel_interpret)
         # the pools (one argument, a tuple) ride the layer scan's
         # carry and are only ever scattered into
-        # (model_runner._scan_layers_paged), so donating them makes
-        # the program's output pools its input buffers: no second
-        # copy of the pools among its temporaries
-        self._decode_jit = jax.jit(
-            decode, donate_argnums=(3,) if self._donate else ())
-        self._decode_exe = None      # persistent-cache hit, if any
-        self._prefill_jits = {}      # padded len -> jitted prefill
-        # -- speculative-decode programs (spec_k > 1 only; the k=1
-        # decode program above stays byte-identical either way)
+        # (model_runner._scan_layers_paged), so every program donates
+        # them: its output pools are its input buffers, with no
+        # second copy of the pools among its temporaries
+        self._programs = _Programs(
+            runner, type(model).__name__, block_size=self.block_size,
+            use_kernel=self.use_kernel,
+            interpret=self._kernel_interpret)
+        # the draft model of speculative decode (spec_k > 1 only; the
+        # k=1 programs stay byte-identical either way)
         self._draft_params = None
-        self._verify_jit = self._draft_jit = None
-        self._draft_prefill_jits = {}
         if self.spec_k > 1:
             self._draft_params = runner.draft_params(
                 self.params, self.draft_layers)
-            verify = functools.partial(
-                runner.verify_step, block_size=self.block_size,
-                use_kernel=self.use_kernel,
-                interpret=self._kernel_interpret)
-            self._verify_jit = jax.jit(
-                verify, donate_argnums=(3,) if self._donate else ())
-            # a separate jit instance for the draft's decode steps:
-            # its donations consume the DRAFT pools, never the
-            # target's
-            self._draft_jit = jax.jit(
-                decode, donate_argnums=(3,) if self._donate else ())
             _cmon.stat_set("serve/spec/k", self.spec_k)
-        # prefix-cache tail-prefill programs (tail length bucketed)
-        self._tail_jits = {}
-        self._draft_tail_jits = {}
-        self._pcache_label = (
-            f"serve_decode:{type(model).__name__}")
-        self._prefill_label = (
-            f"serve_prefill:{type(model).__name__}")
-        self._tail_label = (
-            f"serve_prefill_tail:{type(model).__name__}")
-        self._draft_label = f"serve_draft:{type(model).__name__}"
-        self._verify_label = f"serve_verify:{type(model).__name__}"
         self._steps = 0              # engine steps begun (span id)
-        # padded len -> ledger ordinal: each prefill bucket is its
-        # own compiled program and gets its own perf/program entry
-        # (first bucket keeps the plain label, later ones "#n" —
-        # the jit shape-specialization naming)
-        self._prefill_captured = {}
         self._oom_streak = 0         # consecutive OOM'd dispatches
         # (signature, device arrays): the next decode step's inputs,
         # prepared while the last one ran (_prepare_ahead)
         self._ahead = None
-        self._spec_warm = False      # first spec round compiles
         # finished requests kept for result retrieval — bounded so a
         # long-lived replica's host memory doesn't grow with total
         # traffic (generate() releases its own as it returns)
@@ -346,17 +355,6 @@ class LLMEngine:
         with _flight.span("serve/step", step=self._steps):
             return self._step()
 
-    def _program(self, name, fresh):
-        """Count one dispatch of a jitted program of the engine as
-        `jit/<name>/cache_miss` or `/cache_hit`, as jit/__init__.py
-        counts its own; the first dispatch, which compiles (or loads
-        what a cache kept), runs inside the span `compile/<name>`."""
-        if fresh:
-            _cmon.stat_add(f"jit/{name}/cache_miss", 1)
-            return _flight.span(f"compile/{name}", program=name)
-        _cmon.stat_add(f"jit/{name}/cache_hit", 1)
-        return _NOT_COMPILING
-
     def _step(self):
         """step() inside its span `serve/step` (id `step`). Children:
         `serve/schedule` (with a `serve/prefill` for each admission),
@@ -440,30 +438,6 @@ class LLMEngine:
         return outs
 
     # -- prefill -----------------------------------------------------
-    def _prefill_fn(self, padded_len):
-        import jax
-
-        jfn = self._prefill_jits.get(padded_len)
-        if jfn is None:
-            fn = functools.partial(self.runner.prefill_step,
-                                   block_size=self.block_size)
-            jfn = jax.jit(
-                fn, donate_argnums=(3,) if self._donate else ())
-            self._prefill_jits[padded_len] = jfn
-        return jfn
-
-    def _draft_prefill_fn(self, padded_len):
-        import jax
-
-        jfn = self._draft_prefill_jits.get(padded_len)
-        if jfn is None:
-            fn = functools.partial(self.runner.prefill_step,
-                                   block_size=self.block_size)
-            jfn = jax.jit(
-                fn, donate_argnums=(3,) if self._donate else ())
-            self._draft_prefill_jits[padded_len] = jfn
-        return jfn
-
     def _prefill(self, req):
         """Causal forward over the (re)admitted request's context —
         prompt plus any generation an eviction preserved — writing
@@ -485,39 +459,39 @@ class LLMEngine:
         table = self.cache.block_table(req.req_id,
                                        self.max_blocks_per_seq)
         s = req.sampling
-        # a fresh bucket's first dispatch runs the lazy XLA compile —
-        # keep that sample out of the dispatch histogram (it would
-        # poison the p99), but still count it in serve/prefill_us
-        fresh_bucket = padded not in self._prefill_jits
+        prefill = self._programs("prefill", padded)
         t0 = time.perf_counter()
         with _flight.in_flight("serve_prefill", req.req_id,
                                req=req.trace_id or req.req_id,
                                padded=padded, tokens=plen), \
-                self._program(self._prefill_name(padded), fresh_bucket):
-            tok, self.cache.pools, stats = self._prefill_fn(padded)(
+                prefill.dispatch():
+            tok, self.cache.pools, stats = prefill.bind(
                 self.params, jnp.asarray(ids), np.int32(plen),
                 self.cache.pools, jnp.asarray(table),
                 np.float32(s.temperature), np.int32(s.top_k),
-                np.uint32(_mr.seed_for(s.seed, plen)))
+                np.uint32(_mr.seed_for(s.seed, plen)))()
             tok = int(tok)
             self._count_stats(stats)
             if self._draft_params is not None:
-                _, self.cache.draft_pools, _ = \
-                    self._draft_prefill_fn(padded)(
+                draft = self._programs("draft_prefill", padded)
+                with draft.dispatch():
+                    _, self.cache.draft_pools, _ = draft.bind(
                         self._draft_params, jnp.asarray(ids),
                         np.int32(plen), self.cache.draft_pools,
                         jnp.asarray(table),
-                        np.float32(0.0), np.int32(0), np.uint32(0))
+                        np.float32(0.0), np.int32(0), np.uint32(0))()
                 req._spec_gap = False
         dur_us = int((time.perf_counter() - t0) * 1e6)
         _cmon.stat_add("serve/prefill_us", dur_us)
-        if not fresh_bucket and _perf.dispatch_timing_enabled():
+        # a bucket's first dispatch compiled: that sample stays out
+        # of the dispatch histogram (it would poison the p99) but is
+        # still counted in serve/prefill_us
+        if not prefill.compiled() and _perf.dispatch_timing_enabled():
             # `int(tok)` above already blocked on the dispatch —
             # this wall time is device time, not the enqueue
-            _perf.observe_dispatch(self._prefill_label, dur_us)
-        if padded not in self._prefill_captured:
-            self._prefill_captured[padded] = len(self._prefill_captured)
-            self._capture_prefill_cost(padded, ids, plen, table, s)
+            _perf.observe_dispatch(self._programs.label("prefill"),
+                                   dur_us)
+        prefill.capture()
         if _trace._armed:
             # replayed > 0 marks an eviction-recompute or a failover/
             # drain replay leg (the preserved output_ids re-prefill)
@@ -526,28 +500,6 @@ class LLMEngine:
         self.cache.register_prefix(req.req_id, ctx)
         self.heartbeat = time.monotonic()
         return tok
-
-    def _prefill_name(self, padded):
-        """A prefill bucket's program name: each bucket is its own
-        compiled program; the first keeps the plain label, later ones
-        "#n" in the order they first ran."""
-        n = self._prefill_captured.get(padded,
-                                       len(self._prefill_captured))
-        return (self._prefill_label if n == 0
-                else f"{self._prefill_label}#{n}")
-
-    def _tail_fn(self, t_pad, draft):
-        import jax
-
-        jits = self._draft_tail_jits if draft else self._tail_jits
-        jfn = jits.get(t_pad)
-        if jfn is None:
-            fn = functools.partial(self.runner.prefill_tail_step,
-                                   block_size=self.block_size)
-            jfn = jax.jit(
-                fn, donate_argnums=(4,) if self._donate else ())
-            jits[t_pad] = jfn
-        return jfn
 
     def _prefill_tail(self, req, ctx, plen):
         """Prefix-cache hit: the leading `req.cached_tokens` (a block
@@ -572,34 +524,35 @@ class LLMEngine:
             for bid in private:
                 self.cache.allocator.check_cow(bid)
         s = req.sampling
+        prefill = self._programs("prefill_tail", t_pad)
         t0 = time.perf_counter()
         with _flight.in_flight("serve_prefill", req.req_id,
                                req=req.trace_id or req.req_id,
                                padded=t_pad, tokens=len(tail),
                                cached=cached), \
-                self._program(f"{self._tail_label}@{t_pad}",
-                              t_pad not in self._tail_jits):
-            tok, self.cache.pools, stats = \
-                self._tail_fn(t_pad, draft=False)(
-                    self.params, jnp.asarray(ids), np.int32(cached),
-                    np.int32(plen), self.cache.pools,
-                    jnp.asarray(table), np.float32(s.temperature),
-                    np.int32(s.top_k),
-                    np.uint32(_mr.seed_for(s.seed, plen)))
+                prefill.dispatch():
+            tok, self.cache.pools, stats = prefill.bind(
+                self.params, jnp.asarray(ids), np.int32(cached),
+                np.int32(plen), self.cache.pools,
+                jnp.asarray(table), np.float32(s.temperature),
+                np.int32(s.top_k),
+                np.uint32(_mr.seed_for(s.seed, plen)))()
             tok = int(tok)
             self._count_stats(stats)
             if self._draft_params is not None:
-                _, self.cache.draft_pools, _ = \
-                    self._tail_fn(t_pad, draft=True)(
+                draft = self._programs("draft_tail", t_pad)
+                with draft.dispatch():
+                    _, self.cache.draft_pools, _ = draft.bind(
                         self._draft_params, jnp.asarray(ids),
                         np.int32(cached), np.int32(plen),
                         self.cache.draft_pools,
                         jnp.asarray(table), np.float32(0.0),
-                        np.int32(0), np.uint32(0))
+                        np.int32(0), np.uint32(0))()
                 req._spec_gap = False
         dur_us = int((time.perf_counter() - t0) * 1e6)
         _cmon.stat_add("serve/prefill_us", dur_us)
         _cmon.stat_add("serve/prefix/prefill_tokens_saved", cached)
+        prefill.capture()
         if _trace._armed:
             _trace.note(req, "prefill", tokens=len(tail),
                         cached=cached, dur_us=dur_us,
@@ -607,32 +560,6 @@ class LLMEngine:
         self.cache.register_prefix(req.req_id, ctx)
         self.heartbeat = time.monotonic()
         return tok
-
-    def _capture_prefill_cost(self, padded, ids, plen, table, s):
-        """Roofline-ledger capture for one prefill bucket: an AOT
-        lower+compile over the just-dispatched shapes (the NEW pools
-        stand in for the donated-away ones — same avals), then
-        `perf/program/serve_prefill:<Model>[#n]/*` and
-        `mem/program/serve_prefill:<Model>[#n]/*`. One extra backend
-        compile per bucket, first dispatch only — the jit capture
-        discipline; PADDLE_PERF_PROGRAM=0 + PADDLE_MEM_PROGRAM=0
-        together opt out. Never raises."""
-        import jax.numpy as jnp
-
-        if not (_perf.program_capture_enabled()
-                or _memory.program_capture_enabled()):
-            return
-        try:
-            name = self._prefill_name(padded)
-            with _flight.in_flight("perf_capture", name, program=name):
-                compiled = self._prefill_fn(padded).lower(
-                    self.params, jnp.asarray(ids), np.int32(plen),
-                    self.cache.pools, jnp.asarray(table),
-                    np.float32(s.temperature), np.int32(s.top_k),
-                    np.uint32(0)).compile()
-            self._record_program(name, compiled)
-        except Exception:
-            pass  # the ledger is observability, never a serving error
 
     # -- decode ------------------------------------------------------
     def _batch_arrays(self, ahead=0):
@@ -720,28 +647,19 @@ class LLMEngine:
     def _dispatch_decode(self, arrays):
         import jax
 
-        with self._program(self._pcache_label,
-                           self._decode_exe is None), \
-                _flight.span("serve/decode/enqueue"):
+        decode = self._programs("decode")
+        # the first dispatch's `compile/serve_decode:<Model>` covers
+        # the transfer too, and the capture lies inside it
+        with decode.dispatch(), _flight.span("serve/decode/enqueue"):
             # the seven small arrays in ONE batched transfer: each
             # transfer of its own costs a round of the runtime's
             # latency with the device idle (0.2-0.3 ms on a v5e host)
             ids, pos, tables, lens, temp, topk, seeds = \
                 jax.device_put(arrays)
-            args = (self.params, ids, pos, self.cache.pools, tables,
-                    lens, temp, topk, seeds)
-            if self._decode_exe is None:
-                self._load_persistent(args)
-            fn = self._decode_exe or self._decode_jit
-            try:
-                toks, self.cache.pools, stats = fn(*args)
-            except TypeError:
-                if fn is not self._decode_jit:   # stale executable
-                    self._decode_exe = self._decode_jit
-                    toks, self.cache.pools, stats = \
-                        self._decode_jit(*args)
-                else:
-                    raise
+            toks, self.cache.pools, stats = decode.bind(
+                self.params, ids, pos, self.cache.pools, tables,
+                lens, temp, topk, seeds)()
+            decode.capture()
         with _flight.span("serve/decode/fetch"):
             # the wait for the device, used: the next step's inputs
             self._count_sample_case()
@@ -775,57 +693,6 @@ class LLMEngine:
         _cmon.stat_add("serve/moe/max_load",
                        int(counts.max(axis=-1).sum()))
 
-    def _load_persistent(self, args):
-        """First decode dispatch: route the compile through the PR-8
-        persistent cache so a serving replica restart is a warm hit.
-        Cache trouble costs a miss inside load_or_compile; a lowering
-        or compile failure is the decode program's own and raises."""
-        from ...jit import persistent_cache as _pcache
-
-        self._decode_exe = self._decode_jit
-        if not _pcache.enabled():
-            self._capture_decode_cost(args)
-            return
-        lowered = self._decode_jit.lower(*args)
-        compiled, outcome = _pcache.load_or_compile(
-            lowered, self._pcache_label)
-        if outcome != "off":
-            self._decode_exe = compiled
-        # pcache handed us a compiled executable either way — the
-        # ledger capture is free here
-        self._capture_decode_cost(args, compiled=compiled)
-
-    def _capture_decode_cost(self, args, compiled=None):
-        """Roofline-ledger and memory-footprint capture for the
-        decode program (`perf/program/serve_decode:<Model>/*`,
-        `mem/program/serve_decode:<Model>/*`). Reuses the
-        persistent-cache executable when one exists; otherwise one
-        extra AOT backend compile at first dispatch —
-        PADDLE_PERF_PROGRAM=0 + PADDLE_MEM_PROGRAM=0 together opt
-        out. Never raises."""
-        if not (_perf.program_capture_enabled()
-                or _memory.program_capture_enabled()):
-            return
-        try:
-            if compiled is None:
-                with _flight.in_flight("perf_capture",
-                                       self._pcache_label,
-                                       program=self._pcache_label):
-                    compiled = self._decode_jit.lower(*args).compile()
-            self._record_program(self._pcache_label, compiled)
-        except Exception:
-            pass  # the ledger is observability, never a serving error
-
-    @staticmethod
-    def _record_program(name, compiled):
-        """The gauges of one compiled program: its cost_analysis()
-        ledger and its memory_analysis() footprint (temp_bytes is
-        what the program needs beyond its arguments while it runs)."""
-        if _perf.program_capture_enabled():
-            _perf.record_program_cost(name, compiled)
-        if _memory.program_capture_enabled():
-            _memory.record_program_memory(name, compiled)
-
     def _pools_deleted(self):
         """Did a failed DONATING dispatch consume the pools? (A real
         RESOURCE_EXHAUSTED mid-execution deletes donated buffers —
@@ -857,9 +724,6 @@ class LLMEngine:
             if not self.scheduler.running:
                 return
             arrays = self._next_arrays()
-        # first decode dispatch compiles (and runs _load_persistent)
-        # — keep it out of the dispatch histogram like prefill
-        fresh_decode = self._decode_exe is None
         t0 = time.perf_counter()
         try:
             with _flight.in_flight("serve_decode", "decode",
@@ -876,10 +740,12 @@ class LLMEngine:
         self.heartbeat = time.monotonic()
         decode_us = int((time.perf_counter() - t0) * 1e6)
         _cmon.stat_add("serve/decode_us", decode_us)
-        if not fresh_decode and _perf.dispatch_timing_enabled():
-            # _dispatch_decode's np.asarray(toks) already blocked —
-            # measured device time for the roofline, like prefill
-            _perf.observe_dispatch(self._pcache_label, decode_us)
+        decode = self._programs("decode")
+        if not decode.compiled() and _perf.dispatch_timing_enabled():
+            # _dispatch_decode's fetch already blocked: measured
+            # device time for the roofline, like prefill; a dispatch
+            # that compiled stays out of the histogram
+            _perf.observe_dispatch(decode.name, decode_us)
         with _flight.span("serve/decode/emit"):
             for slot, req in list(self.scheduler.running.items()):
                 self._emit(req, int(toks[slot]), emitted)
@@ -972,12 +838,13 @@ class LLMEngine:
             r_ids[slot] = ctx[-back]
             r_pos[slot] = len(ctx) - back
             r_lens[slot] = len(ctx) - back + 1
-        _, self.cache.draft_pools, _ = self._draft_jit(
+        draft = self._programs("draft")
+        _, self.cache.draft_pools, _ = draft.bind(
             self._draft_params, jnp.asarray(r_ids),
             jnp.asarray(r_pos), self.cache.draft_pools,
             wide_j, jnp.asarray(r_lens),
             jnp.asarray(zeros_f), jnp.asarray(zeros_i),
-            jnp.asarray(zeros_u))
+            jnp.asarray(zeros_u))()
         drafts = {slot: [] for slot in running}
         ids = np.zeros((b,), np.int32)
         pos = np.zeros((b,), np.int32)
@@ -995,13 +862,12 @@ class LLMEngine:
             topk[slot] = s.top_k
             seeds[slot] = _mr.seed_for(s.seed, len(ctx))
         for _ in range(self.spec_k - 1):
-            toks, self.cache.draft_pools, _ = \
-                self._draft_jit(
-                    self._draft_params, jnp.asarray(ids),
-                    jnp.asarray(pos), self.cache.draft_pools,
-                    wide_j, jnp.asarray(lens),
-                    jnp.asarray(temp), jnp.asarray(topk),
-                    jnp.asarray(seeds))
+            toks, self.cache.draft_pools, _ = draft.bind(
+                self._draft_params, jnp.asarray(ids),
+                jnp.asarray(pos), self.cache.draft_pools,
+                wide_j, jnp.asarray(lens),
+                jnp.asarray(temp), jnp.asarray(topk),
+                jnp.asarray(seeds))()
             toks = np.asarray(toks)
             for slot, req in running.items():
                 d = int(toks[slot])
@@ -1034,11 +900,11 @@ class LLMEngine:
                 v_seeds[slot, t] = _mr.seed_for(req.sampling.seed,
                                                 len(ctx) + t)
         with _flight.span("serve/decode/enqueue"):
-            toks, self.cache.pools, _ = self._verify_jit(
+            toks, self.cache.pools, _ = self._programs("verify").bind(
                 self.params, jnp.asarray(v_ids), jnp.asarray(pos),
                 self.cache.pools, wide_j, jnp.asarray(lens),
                 jnp.asarray(temp), jnp.asarray(topk),
-                jnp.asarray(v_seeds))
+                jnp.asarray(v_seeds))()
         with _flight.span("serve/decode/fetch"):
             self._count_sample_case()
             return np.asarray(toks)
@@ -1068,15 +934,15 @@ class LLMEngine:
             wide_j = jnp.asarray(self._wide_tables(arrays[2]))
             running = dict(self.scheduler.running)
             self._check_spec_cow(running)
-        fresh_decode = self._verify_jit is not None \
-            and not getattr(self, "_spec_warm", False)
+        draft, verify = self._programs("draft"), self._programs("verify")
         t0 = time.perf_counter()
         try:
             with _flight.in_flight("serve_decode", "spec_decode",
                                    batch=len(running), k=k):
                 if _chaos._armed:
                     _chaos.hit("serve_decode", batch=len(running))
-                with self._program(self._draft_label, fresh_decode), \
+                # the k draft dispatches of a round count as one
+                with draft.dispatch(), \
                         _flight.span("serve/decode/draft"):
                     drafts = self._draft_propose(running, wide_j)
                 if _chaos._armed:
@@ -1091,7 +957,7 @@ class LLMEngine:
                         drafts = {
                             slot: [(d + 1) % vocab for d in ds]
                             for slot, ds in drafts.items()}
-                with self._program(self._verify_label, fresh_decode):
+                with verify.dispatch():
                     toks = self._dispatch_verify(running, drafts,
                                                  wide_j, arrays)
         except Exception as e:
@@ -1099,12 +965,12 @@ class LLMEngine:
                 return                # next step() re-prefills
             return self._spec_decode_batch(emitted)
         self._oom_streak = 0
-        self._spec_warm = True
         self.heartbeat = time.monotonic()
         decode_us = int((time.perf_counter() - t0) * 1e6)
         _cmon.stat_add("serve/decode_us", decode_us)
-        if not fresh_decode and _perf.dispatch_timing_enabled():
-            _perf.observe_dispatch(self._pcache_label, decode_us)
+        if not verify.compiled() and _perf.dispatch_timing_enabled():
+            _perf.observe_dispatch(self._programs.label("decode"),
+                                   decode_us)
         with _flight.span("serve/decode/emit"):
             for slot, req in sorted(running.items()):
                 ds = drafts[slot]

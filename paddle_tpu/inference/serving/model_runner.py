@@ -66,8 +66,8 @@ replays the same random choices it would have made uninterrupted,
 whatever batch it lands in.
 
 Functions take the raw jnp parameter tree (`extract_params`), not
-Layers: the engine jits them with donated pools, and the PR-8
-persistent compile cache keys their StableHLO like any other program.
+Layers: the engine wraps each in a `jit.Program` with the pools
+donated.
 """
 from __future__ import annotations
 

@@ -27,8 +27,8 @@ the watchdog incident hook (emergency drain-and-export), or it has
 live work with a heartbeat older than `heartbeat_timeout_s`
 (`PADDLE_SERVE_HEARTBEAT_S`) — a dispatch wedged inside XLA stops the
 clock. Set the timeout ABOVE the worst-case single dispatch
-(first-dispatch compiles included, unless the persistent cache
-pre-warms them); as a backstop, a heartbeat timeout never retires
+(first-dispatch compiles included, unless JAX's persistent
+compilation cache holds them); as a backstop, a heartbeat timeout never retires
 the LAST healthy replica — a slow compile on the survivor must not
 cascade one wedge into total fleet loss. `serve/replica/<i>/healthy`
 gauges track the fleet.
@@ -167,8 +167,8 @@ class Router:
         _mserver.maybe_auto_serve("serving.Router")
         self._replicas = []
         for i in range(n):
-            # every replica after the first warm-boots off the
-            # persistent-cache entry the first one published
+            # every replica after the first loads the programs the
+            # first one compiled (JAX's persistent compilation cache)
             eng = LLMEngine(model, **engine_kwargs)
             if incident_export:
                 eng.arm_incident_export()
@@ -441,8 +441,8 @@ class Router:
         """Scale UP by one replica; returns its index, or None when
         the router is stopping/draining. The engine builds OUTSIDE
         the router lock — boot is a warm start off the
-        `serve_decode:<Model>` persistent-cache entry the first
-        replica published, but even a cache load must not stall
+        programs the first replica compiled (JAX's persistent
+        compilation cache), but even a cache load must not stall
         submit/health traffic — then joins the fleet under the lock
         with the same spec negotiation the boot fleet ran."""
         if self._stop or self._draining:

@@ -32,12 +32,12 @@ from ..monitor import flight as _flight
 from ..monitor import perf as _perf
 from ..monitor import sanitize as _sanitize
 from ..ops import random as _random
-from . import persistent_cache as _pcache
 from . import state as _jstate
+from .program import Program, specialised
 
 __all__ = ["to_static", "not_to_static", "save", "load", "TracedLayer",
            "TrainStepCompiler", "InputSpec", "set_max_loop_iterations",
-           "cache_report"]
+           "cache_report", "Program"]
 
 from .dy2static import set_max_loop_iterations  # noqa: E402
 
@@ -177,6 +177,8 @@ def cache_report():
         try:
             if isinstance(obj, StaticFunction):
                 keys = list(obj._compiled.keys())
+                progs = [obj._compiled[k][0]
+                         for k in keys[:_CACHE_REPORT_MAX_KEYS]]
                 out.append({"kind": "to_static",
                             "fn": obj._telemetry_key,
                             "entries": len(keys),
@@ -186,23 +188,22 @@ def cache_report():
                             # aligned with "keys" (None where capture
                             # was off/failed) — the HBM-footprint leg
                             # of an OOM post-mortem
-                            "memory": [obj._mem.get(k) for k in
-                                       keys[:_CACHE_REPORT_MAX_KEYS]],
+                            "memory": [p.memory for p in progs],
                             # per-entry cost_analysis() dicts, same
                             # alignment — the roofline ledger's
                             # bundle-portable copy (monitor perf
                             # reads these offline)
-                            "cost": [obj._cost.get(k) for k in
-                                     keys[:_CACHE_REPORT_MAX_KEYS]]})
+                            "cost": [p.cost for p in progs]})
             elif isinstance(obj, TrainStepCompiler):
+                prog = obj._program
                 out.append({"kind": "train_step",
                             "fn": type(obj._model).__name__,
-                            "entries": int(obj._compiled is not None),
+                            "entries": int(prog is not None),
                             "steps": obj._step,
                             "steps_per_dispatch":
                                 getattr(obj, "_steps_per_dispatch", 1),
-                            "memory": obj._mem_analysis,
-                            "cost": obj._cost_analysis})
+                            "memory": prog and prog.memory,
+                            "cost": prog and prog.cost})
         except Exception:
             pass  # a half-torn-down object must not break a dump
     out.sort(key=lambda d: (d["kind"], d["fn"]))
@@ -219,50 +220,6 @@ def _telemetry_name(func):
           or getattr(func, "__name__", None) or "fn")
     parts = [p for p in qn.split(".") if p != "<locals>"]
     return ".".join(parts[-2:])
-
-
-def arm_compile_cache():
-    """Arm JAX's persistent compilation cache (persistent_cache.
-    arm_native) before a compile on an accelerator backend. CPU runs
-    — the test suite — are left alone: their thousands of tiny
-    programs are not worth persisting, and an entry point that wants
-    the cache on CPU arms it itself."""
-    if jax.default_backend() != "cpu":
-        _pcache.arm_native()
-
-
-class _PersistedProgram:
-    """A disk-cache executable standing in for a jitted callable
-    (jit.persistent_cache): calls dispatch to the (possibly
-    deserialized) executable; `.lower` stays on the jitted original so
-    the memory-footprint capture path is unchanged. A signature
-    surprise latches a permanent fallback to the jitted fn — which
-    recompiles exactly as if the cache never existed."""
-
-    def __init__(self, compiled, jfn):
-        self._compiled = compiled
-        self._jfn = jfn
-        self._fallback = False
-
-    def __call__(self, *args):
-        if not self._fallback:
-            if any(isinstance(leaf, jax.core.Tracer)
-                   for leaf in tree_util.tree_leaves(args)):
-                # a trace context (the differentiable to_static path:
-                # apply_op's vjp traces through us) — an AOT
-                # executable can't be traced, but the jitted fn can
-                # and inlines into the outer program. Per-call detour,
-                # NOT a latch: concrete calls keep the cached
-                # executable
-                return self._jfn(*args)
-            try:
-                return self._compiled(*args)
-            except TypeError:
-                self._fallback = True
-        return self._jfn(*args)
-
-    def lower(self, *args, **kwargs):
-        return self._jfn.lower(*args, **kwargs)
 
 
 class StaticFunction:
@@ -287,9 +244,7 @@ class StaticFunction:
         # from the source so ordinary traces don't pay the vjp cost
         self._needs_tape = _source_calls_grad(func)
         self._input_spec = input_spec
-        self._compiled = {}
-        self._mem = {}  # cache key -> memory_analysis() byte dict
-        self._cost = {}  # cache key -> cost_analysis() flop/byte dict
+        self._compiled = {}  # cache key -> (Program, output box)
         # computed once — __call__ is the per-train-step hot path
         self._telemetry_key = _telemetry_name(func)
         _live_compiled.add(self)
@@ -304,9 +259,7 @@ class StaticFunction:
         bound._trace_target = self._trace_target.__get__(instance, owner) \
             if self._trace_target is not self._func else bound._func
         bound._input_spec = self._input_spec
-        bound._compiled = self._compiled
-        bound._mem = self._mem  # shared like _compiled: ONE cache
-        bound._cost = self._cost
+        bound._compiled = self._compiled  # shared: ONE cache
         bound._needs_tape = self._needs_tape
         bound._telemetry_key = self._telemetry_key
         functools.update_wrapper(bound, bound._func,
@@ -354,8 +307,6 @@ class StaticFunction:
                max_loop_iterations())
         fname = self._telemetry_key
         entry = self._compiled.get(key)
-        compiling = False
-        compile_tok = None
         if entry is None:
             # opt-in static analysis at build time (PADDLE_ANALYSIS=1,
             # gated inside the hook): preflight + jaxpr lint of the
@@ -365,155 +316,78 @@ class StaticFunction:
 
             trace_build_hook(target, args=args, kwargs=kwargs,
                              where=f"to_static:{fname}")
-            # telemetry (reference: program cache stats in
-            # program_translator): a miss triggers a fresh trace + XLA
-            # compile — spanned and timed below. The real work happens
-            # on the first jfn invocation (jax.jit is lazy), so the
-            # span/timer cover build + first call.
-            _monitor.stat_add(f"jit/{fname}/cache_miss", 1)
-            _flight.record("jit_cache_miss", fn=fname)
-            arm_compile_cache()
-            compiling = True
-            # the span `compile/<fn>`, watchdog-visible (a
-            # pathological XLA compile is a hang from the outside):
-            # build + first lazy jfn invocation
-            compile_tok = _flight.begin("compile", fname, program=fname)
-            t_compile0 = _time.perf_counter()
-            try:
-                entry = self._build(target, params, args_treedef,
-                                    tensor_pos, static_leaves, arg_sg)
-            except BaseException:
-                # a failed build must still close the spans — the
-                # finally below is never reached, and a leaked
-                # in-flight compile looks like a permanent hang to
-                # the watchdog
-                _flight.end(compile_tok)
-                raise
-            if _pcache.enabled():
-                entry = self._load_persistent(entry, params, flat_args,
-                                              tensor_pos)
-            self._compiled[key] = entry
-        else:
-            _monitor.stat_add(f"jit/{fname}/cache_hit", 1)
-            _flight.record("jit_cache_hit", fn=fname)
-        call_ok = False
-        try:
-            jfn, box = entry
-            arg_ts = [flat_args[i] for i in tensor_pos]
-            rngc = jnp.asarray(_random._rng.counter, jnp.uint32)
-            requires = engine.is_grad_enabled() \
-                and not engine.in_trace_mode() \
-                and (any(not p.stop_gradient for p in params)
-                     or any(not t.stop_gradient for t in arg_ts))
-            # dispatch wall-time attribution (ISSUE 16): skip the
-            # FIRST call — it runs jfn's lazy XLA compile, and a
-            # compile-laced sample would dominate the p99 of a
-            # program dispatched a handful of times
-            timing = not compiling \
-                and _perf.dispatch_timing_enabled()
+            # shape-specialized entries of one fn share its counters
+            # (the family) and keep gauges of their own: entry 0 the
+            # plain name, later ones "#n", n the entry's position in
+            # _compiled — the index program_footprints() derives
+            # bundle names from
+            jfn, box = self._build(target, params, args_treedef,
+                                   tensor_pos, static_leaves, arg_sg)
+            entry = self._compiled[key] = (
+                Program(jfn, specialised(fname, len(self._compiled)),
+                        family=fname), box)
+        # the first dispatch traces and compiles (jax.jit is lazy):
+        # the Program counts it a miss, spans it `compile/<fn>` and
+        # keeps its wall time out of the dispatch histogram below
+        prog, box = entry
+        arg_ts = [flat_args[i] for i in tensor_pos]
+        rngc = jnp.asarray(_random._rng.counter, jnp.uint32)
+        requires = engine.is_grad_enabled() \
+            and not engine.in_trace_mode() \
+            and (any(not p.stop_gradient for p in params)
+                 or any(not t.stop_gradient for t in arg_ts))
+        timing = _perf.dispatch_timing_enabled()
+        t_d0 = _time.perf_counter()
+        with prog.dispatch():
             if requires:
-                # differentiable boundary: the compiled forward is one
-                # tape op, so loss.backward() after a @to_static
+                # differentiable boundary: the compiled forward is
+                # one tape op, so loss.backward() after a @to_static
                 # forward flows grads into params/inputs (reference:
-                # ProgramTranslator builds the backward program for the
-                # whole block)
+                # ProgramTranslator builds the backward program for
+                # the whole block)
                 def kernel(pv, av, rc):
-                    out_vals, new_bufs, _ = jfn(pv, av, rc)
+                    out_vals, new_bufs, _ = prog.bind(pv, av, rc)()
                     return tuple(out_vals), tuple(new_bufs)
 
-                t_d0 = _time.perf_counter() if timing else None
                 outs, buf_outs = engine.apply_op(
                     "run_program", kernel, list(params), arg_ts, rngc)
-                if timing:
-                    # block on the forward's outputs so the sample is
-                    # device time, not the async enqueue
-                    jax.block_until_ready([o._value for o in outs])
-                    _perf.observe_dispatch(
-                        fname,
-                        int((_time.perf_counter() - t_d0) * 1e6))
-                _random._rng.counter += 1
-                for (buf, _), nv in zip(box["buf_refs"], buf_outs):
-                    buf._value = nv._value
-                call_ok = True
-                return tree_util.tree_unflatten(box["treedef"],
-                                                list(outs))
-            pvals = [p._value for p in params]
-            avals = [t._value for t in arg_ts]
-            if timing:
-                # measured attribution leg of the roofline: wall time
-                # blocked on the outputs (async dispatch returns
-                # futures — an unblocked timer measures the enqueue)
-                t_d0 = _time.perf_counter()
-                out_vals, new_buf_vals, _ = jfn(pvals, avals, rngc)
-                jax.block_until_ready(out_vals)
-                _perf.observe_dispatch(
-                    fname, int((_time.perf_counter() - t_d0) * 1e6))
+                out_vals = [o._value for o in outs]
+                new_buf_vals = [nv._value for nv in buf_outs]
+                flat_out = list(outs)
             else:
-                out_vals, new_buf_vals, _ = jfn(pvals, avals, rngc)
-            _random._rng.counter += 1
-            # commit buffer updates (BatchNorm stats)
-            for (buf, _), nv in zip(box["buf_refs"], new_buf_vals):
-                buf._value = nv
-            flat_out = [Tensor(v, stop_gradient=True, _internal=True)
-                        for v in out_vals]
-            call_ok = True
-            return tree_util.tree_unflatten(box["treedef"], flat_out)
-        finally:
-            if compiling:
-                _flight.end(compile_tok)
-                compile_us = int(
-                    (_time.perf_counter() - t_compile0) * 1e6)
-                _monitor.stat_add(f"jit/{fname}/compile_us",
-                                  compile_us)
-                # ONE compile-time distribution across every jitted
-                # fn (ISSUE 15) — the per-fn counters fan out too
-                # wide to read a fleet p99 from
-                _monitor.hist_observe("jit/hist/compile_us",
-                                      compile_us)
-                # footprint capture only AFTER the first successful
-                # execution: capturing at build time would run the
-                # function's first-ever trace, and a user-code raise
-                # inside a swallowed trace leaks a buffer scope the
-                # real call would otherwise clean up on its way out.
-                # call_ok (not sys.exc_info) — the latter also sees a
-                # CALLER's in-flight handled exception and would skip
-                # capture for a first call made inside an except block
-                if call_ok:
-                    self._capture_memory(key, entry[0], params,
-                                         flat_args, tensor_pos)
-
-    def _load_persistent(self, entry, params, flat_args, tensor_pos):
-        """Route a fresh build through the persistent on-disk compile
-        cache (PADDLE_COMPILE_CACHE_DIR): the trace+lower still runs
-        here (cheap, process-local, fills the output box), but a warm
-        entry replaces the expensive XLA backend compile with a
-        deserialize. Cache trouble costs a miss inside
-        load_or_compile (counted jit/persistent_cache/errors); a
-        lowering or compile failure is the program's own and raises."""
-        jfn, box = entry
-        p_structs = [jax.ShapeDtypeStruct(tuple(p._value.shape),
-                                          p._value.dtype)
-                     for p in params]
-        a_structs = [jax.ShapeDtypeStruct(
-            tuple(flat_args[i]._value.shape),
-            flat_args[i]._value.dtype) for i in tensor_pos]
-        lowered = jfn.lower(p_structs, a_structs,
-                            jax.ShapeDtypeStruct((), jnp.uint32))
-        compiled, outcome = _pcache.load_or_compile(
-            lowered, f"to_static:{self._telemetry_key}")
-        if outcome == "off":
-            return entry
-        return _PersistedProgram(compiled, jfn), box
+                out_vals, new_buf_vals, _ = prog.bind(
+                    [p._value for p in params],
+                    [t._value for t in arg_ts], rngc)()
+                flat_out = [Tensor(v, stop_gradient=True,
+                                   _internal=True) for v in out_vals]
+        if not prog.compiled() and timing:
+            # measured attribution leg of the roofline: wall time
+            # blocked on the outputs (async dispatch returns futures
+            # — an unblocked timer measures the enqueue)
+            jax.block_until_ready(out_vals)
+            _perf.observe_dispatch(
+                fname, int((_time.perf_counter() - t_d0) * 1e6))
+        _random._rng.counter += 1
+        # commit buffer updates (BatchNorm stats)
+        for (buf, _), nv in zip(box["buf_refs"], new_buf_vals):
+            buf._value = nv
+        # footprint capture only AFTER the first successful
+        # execution: a user-code raise inside the first trace is the
+        # call's own to report
+        prog.capture()
+        return tree_util.tree_unflatten(box["treedef"], flat_out)
 
     def _build(self, target, params, args_treedef, tensor_pos,
                static_leaves, arg_sg=None):
+        """The function one cache entry jits (a Program's), and the
+        box its trace fills with the output tree and the buffers it
+        updated."""
         box = {}
         import contextlib
 
         tape_ctx = (engine.trace_tape if self._needs_tape
                     else contextlib.nullcontext)
 
-        @jax.jit
         def jfn(pvals, avals, rng_counter):
             with engine.trace_mode(), tape_ctx():
                 prev_key = _random.push_traced_key(
@@ -548,71 +422,6 @@ class StaticFunction:
                     _random.pop_traced_key(prev_key)
 
         return jfn, box
-
-    def _capture_memory(self, key, jfn, params, flat_args, tensor_pos):
-        """Record the fresh cache entry's memory_analysis() byte
-        breakdown (argument/output/temp/generated-code) under
-        mem/program/<fn>/* and in self._mem for cache_report(), plus
-        its cost_analysis() flop/byte ledger under perf/program/<fn>/*
-        and self._cost — both read off ONE shared compiled object.
-        Lowers via ShapeDtypeStructs — no array materialization; the
-        lowering is shared with the call path, the XLA backend pass
-        is one extra compile, so PADDLE_MEM_PROGRAM=0 +
-        PADDLE_PERF_PROGRAM=0 together opt out of the compile (either
-        alone only skips its own gauges)."""
-        from ..monitor import memory as _memory
-
-        want_mem = _memory.program_capture_enabled()
-        want_cost = _perf.program_capture_enabled()
-        if not (want_mem or want_cost):
-            return
-        try:
-            p_structs = [jax.ShapeDtypeStruct(p._value.shape,
-                                              p._value.dtype)
-                         for p in params]
-            a_structs = [jax.ShapeDtypeStruct(flat_args[i]._value.shape,
-                                              flat_args[i]._value.dtype)
-                         for i in tensor_pos]
-            rng = jax.ShapeDtypeStruct((), jnp.uint32)
-            # the capture's extra backend compile can stall as long as
-            # the real one — span it so the watchdog's in-flight table
-            # and jit/<fn>/mem_capture_us attribute the time instead
-            # of leaving an unexplained first-call gap
-            t0 = _time.perf_counter()
-            with _flight.in_flight("mem_capture", self._telemetry_key,
-                                   program=self._telemetry_key):
-                compiled = jfn.lower(p_structs, a_structs,
-                                     rng).compile()
-            _monitor.stat_add(
-                f"jit/{self._telemetry_key}/mem_capture_us",
-                int((_time.perf_counter() - t0) * 1e6))
-            # shape-specialized cache entries of one fn must not share
-            # a gauge name — the tail-batch entry would overwrite the
-            # full-batch footprint (last-writer-wins); entry 0 keeps
-            # the plain name, later entries get an ordinal suffix.
-            # The ordinal is the entry's position in _compiled — the
-            # same index program_footprints() derives bundle names
-            # from — NOT len(_mem): a first-call failure leaves no
-            # _mem entry, and a length-based ordinal would then let
-            # gauge and bundle names drift out of lockstep
-            try:
-                ordinal = list(self._compiled).index(key)
-            except ValueError:
-                ordinal = len(self._mem)
-            name = (self._telemetry_key if ordinal == 0
-                    else f"{self._telemetry_key}#{ordinal}")
-            if want_mem:
-                self._mem[key] = _memory.record_program_memory(
-                    name, compiled)
-            if want_cost:
-                self._cost[key] = _perf.record_program_cost(
-                    name, compiled)
-        except Exception:
-            # footprint capture is observability, never a build error
-            if want_mem:
-                self._mem[key] = None
-            if want_cost:
-                self._cost[key] = None
 
     def concrete_program(self):
         return None
@@ -945,16 +754,23 @@ class TrainStepCompiler:
         # no inputs, so the lowered program is unchanged
         self._comm_state = None
         self._compress = None  # set by DistributedTrainStepCompiler
-        self._compiled = None
+        self._program = None  # the jitted step (jit.Program), by _build
         self._names = None
         self._opt_state = None
         self._step = 0
-        self._mem_analysis = None  # memory_analysis() byte dict
-        self._cost_analysis = None  # cost_analysis() flop/byte dict
-        # telemetry label shared by the cost ledger, the dispatch
-        # histogram and the persistent cache: model class + fused
-        # dispatch width (K=1 siblings must not alias the fused
-        # program's gauges — see _capture_memory)
+        # the program's name, shared by its gauges and the dispatch
+        # histogram: model class (compilers over different model
+        # CLASSES must not share one gauge — the last one compiled
+        # would overwrite the others' footprints) + fused dispatch
+        # width K (Model.fit's fused K-step program and its K=1 tail
+        # sibling are live together; the tail compiles last and would
+        # overwrite the fused footprint with a ~K-times-smaller one).
+        # Two instances of the SAME class at the same K still share a
+        # gauge (last writer wins) — deliberate: per-instance names
+        # would grow the persistent registry unboundedly across a
+        # sweep's recompiles, and the bundle path
+        # (program_footprints) keeps every live footprint via its
+        # "(n)" suffixing, so dumps never lose one
         self._perf_name = f"train_step:{type(model).__name__}"
         if self._steps_per_dispatch != 1:
             self._perf_name += f"@k{self._steps_per_dispatch}"
@@ -978,11 +794,15 @@ class TrainStepCompiler:
         return tuple(b._value if isinstance(b, Tensor) else jnp.asarray(b)
                      for b in batch)
 
-    def _jit_step(self, step_fn, trainable, frozen, bufs, batch):
+    def _jit_step(self, step_fn, trainable, frozen, bufs, batch,
+                  **shardings):
+        """The step's Program: its counters and compile span are the
+        family `train_step`'s, its gauges `self._perf_name`'s."""
         # argnums (0, 1, 2, 3): params, optimizer slots, grad-merge
         # accumulators, comm-compression residuals
         donate = (0, 1, 2, 3) if self._donate else ()
-        return jax.jit(step_fn, donate_argnums=donate)
+        return Program(step_fn, self._perf_name, donate_argnums=donate,
+                       family="train_step", **shardings)
 
     def lower_compiled(self, *batch):
         """Build + lower + compile the step WITHOUT executing it —
@@ -990,7 +810,7 @@ class TrainStepCompiler:
         result (per-device flops/bytes of the partitioned module)."""
         trainable, frozen, bufs = self._params_and_buffers()
         self._prepare_call(trainable, frozen, bufs)
-        if self._compiled is None:
+        if self._program is None:
             self._build(trainable, frozen, bufs, batch)
         pvals = {k: p._value for k, p in trainable.items()}
         fvals = {k: p._value for k, p in frozen.items()}
@@ -998,7 +818,7 @@ class TrainStepCompiler:
         avals = self._place_batch(batch)
         lr = np.float32(self._opt.get_lr())
         rngc = np.uint32(self._step)
-        return self._compiled.lower(
+        return self._program.lower(
             pvals, self._opt_state, self._accum_state,
             self._comm_state, fvals, bvals, avals, lr, rngc,
             self._loss_scale()).compile()
@@ -1030,8 +850,7 @@ class TrainStepCompiler:
         children say what the host was doing: `train/prepare` (here,
         and again in _run_compiled), `train/enqueue`, `train/block`,
         `train/finish`; on the first call `compile/train_step` around
-        build, cache load and first dispatch, then
-        `compile/capture/<program>`."""
+        the first dispatch, then `compile/capture/<program>`."""
         with _flight.span("train/step", step=self._step):
             return self._step_call(batch)
 
@@ -1040,7 +859,7 @@ class TrainStepCompiler:
             self._check_microbatch_axis(batch)
             trainable, frozen, bufs = self._params_and_buffers()
             self._prepare_call(trainable, frozen, bufs)
-        if self._compiled is None:
+        if self._program is None:
             # opt-in analysis of the model forward about to be fused
             # into the step (PADDLE_ANALYSIS=1, gated inside the
             # hook) — observational only. Batch elements are placed
@@ -1053,135 +872,16 @@ class TrainStepCompiler:
             trace_build_hook(self._model, args=fwd_args,
                              where="train_step",
                              arrays_as_tensors=True)
-            # first call traces + XLA-compiles the whole fused step:
-            # span it and record the wall time under jit/train_step/...
-            # (the per-StaticFunction counters' TrainStepCompiler
-            # sibling)
-            _monitor.stat_add("jit/train_step/cache_miss", 1)
-            _flight.record("jit_cache_miss", fn="train_step")
-            arm_compile_cache()
-            t0 = _time.perf_counter()
-            with _flight.in_flight("compile", "train_step",
-                                   program=self._perf_name):
-                self._build(trainable, frozen, bufs, batch)
-                if _pcache.enabled():
-                    self._load_persistent(trainable, frozen, bufs,
-                                          batch)
-                out = self._run_compiled(trainable, frozen, bufs,
-                                         batch, fresh=True)
-            compile_us = int((_time.perf_counter() - t0) * 1e6)
-            _monitor.stat_add("jit/train_step/compile_us",
-                              compile_us)
-            _monitor.hist_observe("jit/hist/compile_us", compile_us)
-            self._capture_memory(batch)
-            return out
-        _monitor.stat_add("jit/train_step/cache_hit", 1)
-        _flight.record("jit_cache_hit", fn="train_step")
-        return self._run_compiled(trainable, frozen, bufs, batch)
+            self._build(trainable, frozen, bufs, batch)
+        # the first dispatch traces + XLA-compiles the whole fused
+        # step: the Program counts it under jit/train_step/... and
+        # spans it, prepare and finish included
+        with self._program.dispatch():
+            out = self._run_compiled(trainable, frozen, bufs, batch)
+        self._program.capture()
+        return out
 
-    def _load_persistent(self, trainable, frozen, bufs, batch):
-        """Persistent-compile-cache leg of the first dispatch: lower
-        the freshly built step over the live values (shared with the
-        call path) and swap in the cached executable when the on-disk
-        cache has this exact program — fleet rollouts, bench reruns
-        and reshape-resume relaunches skip the backend compile.
-        Cache trouble costs a miss inside load_or_compile; a lowering
-        or compile failure is the step's own and raises."""
-        pvals = {k: p._value for k, p in trainable.items()}
-        fvals = {k: p._value for k, p in frozen.items()}
-        bvals = {k: b._value for k, b in bufs.items()}
-        avals = self._place_batch(batch)
-        lr = np.float32(self._opt.get_lr())
-        rngc = np.uint32(self._step)
-        lowered = self._compiled.lower(
-            pvals, self._opt_state, self._accum_state,
-            self._comm_state, fvals, bvals, avals, lr, rngc,
-            self._loss_scale())
-        compiled, outcome = _pcache.load_or_compile(
-            lowered, self._perf_name, extra=self._pcache_extra())
-        if outcome != "off":
-            self._compiled = _PersistedProgram(compiled,
-                                               self._compiled)
-
-    def _pcache_extra(self):
-        """Extra persistent-cache digest legs beyond the lowered
-        module text. The distributed subclass adds the mesh's device
-        assignment — two processes can lower identical StableHLO over
-        DIFFERENT device orders, and a serialized executable is bound
-        to its assignment."""
-        return ()
-
-    def _capture_memory(self, batch):
-        """Record the freshly compiled step's memory_analysis()
-        (argument/output/temp/generated-code bytes) in
-        self._mem_analysis (cache_report()'s "memory" field) and the
-        mem/program/train_step:<Model>/* gauges — the per-program HBM
-        footprint an OOM bundle names — plus its cost_analysis()
-        flop/byte ledger (self._cost_analysis, the
-        perf/program/train_step:<Model>/* gauges) off the SAME
-        compiled object. Reuses lower_compiled(), so the lowering is
-        shared with the call path and the cost is one extra XLA
-        backend compile; PADDLE_MEM_PROGRAM=0 + PADDLE_PERF_PROGRAM=0
-        together opt out of the compile. Never raises: footprints are
-        observability."""
-        from ..monitor import memory as _memory
-
-        want_mem = _memory.program_capture_enabled()
-        want_cost = _perf.program_capture_enabled()
-        if not (want_mem or want_cost):
-            return
-        try:
-            # the gauge name carries the model class (compilers over
-            # different model CLASSES must not share one gauge — the
-            # last one compiled would overwrite the others'
-            # footprints) and the dispatch width K (Model.fit's fused
-            # K-step program and its K=1 tail sibling are live
-            # together; the tail compiles last and would overwrite
-            # the fused footprint with a ~K-times-smaller one). Two
-            # instances of the SAME class at the same K still share a
-            # gauge (last writer wins) — deliberate: per-instance
-            # names would grow the persistent registry unboundedly
-            # across a sweep's recompiles, and the bundle path
-            # (program_footprints) keeps every live footprint via
-            # its "(n)" suffixing, so dumps never lose one
-            name = self._perf_name
-            # span the capture's extra backend compile — it runs after
-            # the "compile" span closed, and a multi-minute capture
-            # must show in the watchdog's in-flight table, not as an
-            # unattributed first-step stall
-            t0 = _time.perf_counter()
-            with _flight.in_flight("mem_capture", name, program=name):
-                compiled = self.lower_compiled(*batch)
-            _monitor.stat_add(
-                "jit/train_step/mem_capture_us",
-                int((_time.perf_counter() - t0) * 1e6))
-            if want_mem:
-                self._mem_analysis = _memory.record_program_memory(
-                    name, compiled)
-            if want_cost:
-                self._cost_analysis = _perf.record_program_cost(
-                    name, compiled)
-        except Exception:
-            if want_mem:
-                self._mem_analysis = None
-            if want_cost:
-                self._cost_analysis = None
-
-    def _jit_cache_size(self):
-        """Trace-cache entry count of the jitted step (via the jitted
-        original when a _PersistedProgram fronts it) — a dispatch
-        that grows it recompiled inline, so its wall time is not a
-        dispatch sample. None when jax stops exposing the probe
-        (observations then include rare retraces rather than vanish
-        entirely)."""
-        jfn = getattr(self._compiled, "_jfn", self._compiled)
-        try:
-            return jfn._cache_size()
-        except Exception:
-            return None
-
-    def _run_compiled(self, trainable, frozen, bufs, batch,
-                      fresh=False):
+    def _run_compiled(self, trainable, frozen, bufs, batch):
         with _flight.span("train/prepare"):
             # chaos site "dispatch": a synthetic RESOURCE_EXHAUSTED
             # here exercises the real OOM-forensics path
@@ -1212,31 +912,20 @@ class TrainStepCompiler:
             rngc = np.uint32(self._step)
             prev_opt, prev_acc = self._opt_state, self._accum_state
             prev_comm = self._comm_state
-        # skip the fresh (first) dispatch — it runs the lazy XLA
-        # compile, and a compile-laced sample would poison the p99
-        t_d0 = (_time.perf_counter()
-                if not fresh and _perf.dispatch_timing_enabled()
-                else None)
-        n_traces0 = None if fresh else self._jit_cache_size()
-        retraced = False
-        with _flight.span("train/enqueue") as enqueue:
+        timed = _perf.dispatch_timing_enabled()
+        t_d0 = _time.perf_counter()
+        with _flight.span("train/enqueue"):
             try:
                 (new_p, new_opt, new_acc, new_comm, new_b, loss, skips,
-                 nstats) = self._compiled(
+                 nstats) = self._program.bind(
                     pvals, self._opt_state, self._accum_state,
                     self._comm_state, fvals, bvals, avals, lr, rngc,
-                    self._loss_scale())
-                # A dispatch that grew the jit cache retraced (e.g. the
-                # second call, where the freshly initialized opt state's
-                # weak types strengthen): its enqueue was a compile, and
-                # the ring says so, so a trace tells which step recompiled
-                retraced = n_traces0 is not None \
-                    and self._jit_cache_size() != n_traces0
-                if retraced:
-                    _flight.closed_span(
-                        "compile/train_step", enqueue.t0,
-                        _time.perf_counter(), program=self._perf_name,
-                        retrace=1)
+                    self._loss_scale())()
+                # a dispatch that compiled (the first; the second,
+                # where the freshly initialized opt state's weak types
+                # strengthen; a new batch shape) says so in the ring,
+                # under this span
+                compiled = self._program.compiled()
             except RuntimeError as e:
                 if _sanitize._donation:
                     better = _sanitize.explain_deleted(
@@ -1251,14 +940,14 @@ class TrainStepCompiler:
             # reference reports PTA041 with both ends named
             _sanitize.note_donated((pvals, prev_opt, prev_acc,
                                     prev_comm), site=san_site)
-        if t_d0 is not None and not retraced:
+        if timed and not compiled:
             # measured roofline leg: block on the loss (the whole
             # program has executed once any output is ready) so the
             # histogram sees device time, not the async enqueue. One
             # ring event per dispatch feeds the StepTimer step-time
             # decomposition and the fleet straggler's top-span table.
-            # A retraced dispatch is compile-laced — skip it like the
-            # fresh dispatch
+            # A dispatch that compiled is skipped: a compile-laced
+            # sample would poison the p99
             with _flight.span("train/block"):
                 jax.block_until_ready(loss)
             dus = int((_time.perf_counter() - t_d0) * 1e6)
@@ -1653,8 +1342,8 @@ class TrainStepCompiler:
                     (avals, rcs))
                 return p, s, acc, cm, bv, losses, skips, nstats
 
-        self._compiled = self._jit_step(step_fn, trainable, frozen, bufs,
-                                        batch)
+        self._program = self._jit_step(step_fn, trainable, frozen, bufs,
+                                       batch)
 
     def _grads_and_loss(self, loss_of, pvals, fvals, bvals, avals,
                         rngc, scale, comm):

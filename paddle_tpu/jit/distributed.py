@@ -392,25 +392,6 @@ class DistributedTrainStepCompiler(TrainStepCompiler):
                 self._accum_state[k] = jax.device_put(
                     self._hostify(self._accum_state[k]), sh)
 
-    def _pcache_extra(self):
-        """Persistent-compile-cache digest legs: GSPMD shardings ride
-        the lowered module text already, but the executable is ALSO
-        bound to the mesh's physical device assignment — key on it so
-        a relaunch with a reordered/reshaped device list can never
-        load a stale executable (the elastic reshape-resume path hits
-        this: dp=8 and dp=4 x sharding=2 meshes must not collide)."""
-        m = self._mesh
-        comp = self._compress
-        return (tuple(m.axis_names),
-                tuple(int(m.shape[a]) for a in m.axis_names),
-                tuple(str(d) for d in np.ravel(m.devices)),
-                # compression policy leg: the quantized program's
-                # module text already differs, but the spec makes the
-                # digest self-describing (and block-size changes that
-                # only move padding can never collide)
-                (f"{comp.spec()}@{comp.block}" if comp is not None
-                 else ""))
-
     def _lint_shardings(self, batch):
         """PTA05x sharding-spec lints just before the first compile:
         hand-written batch_specs/dist_specs that name unknown mesh
@@ -459,6 +440,6 @@ class DistributedTrainStepCompiler(TrainStepCompiler):
         out_shardings = (param_sh, self._slot_shardings,
                         self._accum_shardings, self._comm_shardings,
                         buf_sh, repl, repl, repl)
-        donate = (0, 1, 2, 3) if self._donate else ()
-        return jax.jit(step_fn, in_shardings=in_shardings,
-                       out_shardings=out_shardings, donate_argnums=donate)
+        return super()._jit_step(step_fn, trainable, frozen, bufs, batch,
+                                 in_shardings=in_shardings,
+                                 out_shardings=out_shardings)

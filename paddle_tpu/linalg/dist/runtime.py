@@ -11,9 +11,9 @@ here, in one place:
   Finding/Report counters when PADDLE_ANALYSIS/PADDLE_SANITIZE arms
   them).
 - the program cache + compile path: programs lower through jax.jit
-  like every other subsystem and consult the PR-8 persistent compile
-  cache (`linalg:<label>` entries, mesh device assignment as a digest
-  leg), with `linalg_compile` flight spans.
+  like every other subsystem (JAX's persistent compilation cache
+  serves them where it is armed), with `linalg_compile` flight
+  spans.
 - the dispatch path: `linalg_dispatch` chaos site, `linalg` flight
   in-flight spans (watchdog-visible), and the
   linalg/{matmuls,factorizations,eigensolves,bytes} counters.
@@ -210,10 +210,9 @@ def compile_program(label, build, grid_, args, extra_key=()):
 
     `build()` returns the traceable global-array function (usually a
     shard_map island). Keyed by (label, grid signature, arg
-    shapes/dtypes, extra_key); fresh compiles lower through jax.jit
-    and consult the persistent compile cache under `linalg:<label>`
-    with the grid signature as a digest leg — a planner/bench rerun
-    (or replica N of a fleet) boots warm."""
+    shapes/dtypes, extra_key); fresh compiles lower through jax.jit,
+    and JAX's persistent compilation cache (jit.persistent_cache)
+    serves them where it is armed."""
     key = (label, grid_.sig(), _arg_sig(args), tuple(extra_key))
     ent = _programs.get(key)
     if ent is not None:
@@ -224,15 +223,7 @@ def compile_program(label, build, grid_, args, extra_key=()):
     tok = _flight.begin("linalg_compile", label) \
         if _flight.recorder.enabled else None
     try:
-        lowered = jax.jit(build()).lower(*args)
-        from ...jit import persistent_cache as _pcache
-
-        if _pcache.enabled():
-            compiled, _ = _pcache.load_or_compile(
-                lowered, f"linalg:{label}",
-                extra=(repr(grid_.sig()),))
-        else:
-            compiled = lowered.compile()
+        compiled = jax.jit(build()).lower(*args).compile()
     finally:
         _flight.end(tok)
     _monitor.stat_add("linalg/compiles", 1)

@@ -94,7 +94,7 @@ def choose_block_size(a: ShardedMatrix, b: ShardedMatrix,
     Precedence: PADDLE_LINALG_BLOCK (validated against the candidate
     set) > cached autotune result > PADDLE_LINALG_AUTOTUNE=1 profile
     sweep over up to `max_probes` candidates via CostModel
-    (persistent-cache-warm) > largest capped divisor."""
+    > largest capped divisor."""
     grid_ = a.grid
     K = a.shape[1]
     cands = (list(candidates) if candidates

@@ -15,7 +15,6 @@ Sites (see SITES; `python -m paddle_tpu.monitor chaos` lists them):
     store_put    TCP-store rendezvous write (StoreGroupComm puts)
     rendezvous   get_store() bootstrap connect
     ckpt_write   checkpoint snapshot write (incubate.checkpoint.elastic)
-    cache_write  persistent compile-cache entry write (jit.persistent_cache)
     io_fetch     DataLoader sample fetch (mp worker loop + in-process)
     dispatch     compiled train-step dispatch (jit.TrainStepCompiler)
     serve_admit  serving-scheduler request admission
@@ -82,8 +81,6 @@ SITES = {
     "rendezvous": "get_store() bootstrap connect",
     "ckpt_write": "checkpoint snapshot write "
                   "(incubate.checkpoint.elastic._write_snapshot)",
-    "cache_write": "persistent compile-cache entry write "
-                   "(jit.persistent_cache._write_entry)",
     "io_fetch": "DataLoader sample fetch (mp worker loop + "
                 "single-process _fetch)",
     "dispatch": "compiled train-step dispatch "
@@ -123,7 +120,7 @@ FAULTS = {
     "raise": "raise exc= (default ChaosInjected) with msg=",
     "enospc": "raise OSError(ENOSPC) — full checkpoint/log filesystem",
     "torn": "site-interpreted torn write: the site persists a partial "
-            "artifact, then raises (ckpt_write, cache_write)",
+            "artifact, then raises (ckpt_write)",
     "crash": "os._exit(3) THIS process — meant for mp DataLoader "
              "workers",
     "bad_sample": "raise ChaosBadSample — feeds the DataLoader "
@@ -196,7 +193,7 @@ _FLOAT_PARAMS = ("p", "ms", "secs")
 # site-interpreted faults only make sense where a call site enacts
 # the returned Rule — arming them elsewhere would count `triggered`
 # injections that never happened, corrupting the chaos/* provenance
-_SITE_INTERPRETED = {"torn": ("ckpt_write", "cache_write"),
+_SITE_INTERPRETED = {"torn": ("ckpt_write",),
                      "bitflip": ("comm_compress",),
                      "corrupt": ("serve_spec_verify",)}
 

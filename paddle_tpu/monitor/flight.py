@@ -17,10 +17,10 @@ Five pieces:
     "paddle_tpu/<layer>/<what>", so whenever any profiler session
     runs the span lies in the trace's host plane on the device's
     clock. in_flight()/begin()/end() and profiler.RecordEvent go
-    through it. Layers: compile, cache, train, serve, io, comm.
+    through it. Layers: compile, train, serve, io, comm.
 
   * FlightRecorder — a process-wide bounded ring of structured events
-    (step begin/end, jit cache hit/miss, compile begin/end, collective
+    (step begin/end, jit cache miss, compile begin/end, collective
     begin/end with op/group/bytes, io fetch, exception, dump), fed by
     the same layers the monitor counters instrument. Appending is one
     lock + deque append (registry gauges amortize to every 256th
@@ -387,7 +387,7 @@ _NO_SPAN = _NoSpan()
 
 def span(name, **ids):
     """A program span `paddle_tpu/<name>`, name being `<layer>/<what>`
-    with layer one of compile, cache, train, serve, io, comm. `ids`
+    with layer one of compile, train, serve, io, comm. `ids`
     say whose work it is: `step=` of a train or engine step, `req=` a
     request's trace id, `program=` of a compile; they are kept with
     the ring record and become the annotation's stats. Off, at the
@@ -429,8 +429,7 @@ _token_seq = itertools.count(1)
 _KIND_SPAN = {
     "compile": "compile/{name}",
     "linalg_compile": "compile/linalg:{name}",
-    "mem_capture": "compile/capture/{name}",
-    "perf_capture": "compile/capture/{name}",
+    "capture": "compile/capture/{name}",
     "collective": "comm/{name}",
     "linalg": "comm/linalg:{name}",
     "bootstrap": "comm/bootstrap",

@@ -145,6 +145,76 @@ def test_armed_native_cache_writes_where_the_environment_says(tmp_path):
     assert req2 == req and hits2 == req2 and misses2 == 0
 
 
+_WARM_START = {
+    # the donated fwd+bwd+update program: the loss after two steps
+    "train_step": (
+        "import numpy as np\n"
+        "import paddle_tpu as paddle\n"
+        "import paddle_tpu.nn as nn, paddle_tpu.optimizer as optim\n"
+        "from paddle_tpu.jit import TrainStepCompiler\n"
+        "paddle.seed(0)\n"
+        "net = nn.Linear(16, 4)\n"
+        "ce = nn.CrossEntropyLoss()\n"
+        "opt = optim.Adam(learning_rate=1e-3,"
+        " parameters=net.parameters())\n"
+        "step = TrainStepCompiler(net, opt, lambda o, t: ce(o, t))\n"
+        "rng = np.random.RandomState(0)\n"
+        "x = paddle.to_tensor(rng.randn(4, 16).astype(np.float32))\n"
+        "y = paddle.to_tensor(rng.randint(0, 4, (4,)).astype(np.int64))\n"
+        "step(x, y)\n"
+        "print('RESULT', repr(float(step(x, y).item())))\n"),
+    # the engine's prefill and decode programs: the tokens they emit
+    "engine_decode": (
+        "import paddle_tpu as paddle\n"
+        "from paddle_tpu.inference.serving import LLMEngine,"
+        " SamplingParams\n"
+        "from paddle_tpu.text.models.gpt import GPTConfig,"
+        " GPTForCausalLM\n"
+        "paddle.seed(0)\n"
+        "model = GPTForCausalLM(GPTConfig(vocab_size=128,"
+        " hidden_size=32, num_layers=2, num_heads=2, ffn_hidden=64,"
+        " max_seq_len=32, dropout=0.0, use_flash_attention=False))\n"
+        "model.eval()\n"
+        "engine = LLMEngine(model, max_batch=2, block_size=4,"
+        " num_blocks=16)\n"
+        "print('RESULT', engine.generate([[1, 2, 3], [4, 5]],"
+        " SamplingParams(max_new_tokens=6)))\n"),
+}
+
+
+@pytest.mark.parametrize("what", sorted(_WARM_START))
+def test_a_second_process_starts_warm_from_the_native_cache(tmp_path,
+                                                            what):
+    """The warm start a user keeps: with the native cache armed, the
+    programs a first process compiled (a TrainStepCompiler step, an
+    LLMEngine's prefill and decode) are loaded by a second one, which
+    computes the same loss / tokens."""
+    script = (
+        "import sys; sys.path.insert(0, sys.argv[1])\n"
+        "from paddle_tpu.jit import persistent_cache as p\n"
+        "p.arm_native()\n" + _WARM_START[what] +
+        "s = p.native_cache_stats()\n"
+        "print('STATS', s['requests'], s['hits'], s['misses'])\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"))
+
+    def run():
+        out = subprocess.run([sys.executable, "-c", script, REPO],
+                             env=env, capture_output=True, text=True,
+                             timeout=300, cwd=str(tmp_path))
+        assert out.returncode == 0, out.stderr[-2000:]
+        lines = {ln.split()[0]: ln.split(None, 1)[1]
+                 for ln in out.stdout.splitlines()
+                 if ln.startswith(("RESULT", "STATS"))}
+        return lines["RESULT"], [int(v) for v in lines["STATS"].split()]
+
+    cold, (req, hits, misses) = run()
+    assert req >= 2 and hits == 0 and misses == req
+    warm, (req2, hits2, misses2) = run()
+    assert warm == cold
+    assert req2 == req and hits2 == req2 and misses2 == 0
+
+
 # -- bench exit code ---------------------------------------------------------
 
 def test_bench_main_fails_when_a_config_raised():

@@ -243,10 +243,14 @@ _COMM_KEYS = ("comm/all_reduce/calls", "comm/all_reduce/bytes",
               "comm/all_reduce/wire_bytes")
 
 
-def _train(compress, steps=10, **kw):
+def _train(compress, steps=10, target=None, **kw):
+    """`target(x)` gives the batch's targets; by default they are
+    fresh noise (nothing to learn: parity and counters only)."""
     rng = np.random.RandomState(0)
     xs = [rng.randn(16, 64).astype(np.float32) for _ in range(steps)]
     ys = [rng.randn(16, 8).astype(np.float32) for _ in range(steps)]
+    if target is not None:
+        ys = [target(x) for x in xs]
     model, step = _build_dp8(compress, **kw)
     read = _delta(_COMM_KEYS)
     losses = [float(step(paddle.to_tensor(x),
@@ -260,9 +264,13 @@ def _train(compress, steps=10, **kw):
 def test_e2e_int8_ef_wire_ratio_and_loss_parity():
     """THE acceptance gate: int8:ef vs the explicit fp32 twin on the
     8-device mesh — wire_bytes <= 0.3x, loss curve parity, both
-    train."""
-    l_fp32, c_fp32, _ = _train("fp32")
-    l_int8, c_int8, _ = _train("int8:ef:block=256")
+    train. The target is a fixed linear map of the inputs: a falling
+    loss on fresh batches is then the model learning it, whatever
+    the installed jax draws for the initial weights."""
+    w = (np.random.RandomState(1).randn(64, 8) / 8).astype(np.float32)
+    l_fp32, c_fp32, _ = _train("fp32", target=lambda x: x @ w)
+    l_int8, c_int8, _ = _train("int8:ef:block=256",
+                               target=lambda x: x @ w)
     # the twins price the same logical payload...
     assert c_int8["comm/all_reduce/bytes"] == \
         c_fp32["comm/all_reduce/bytes"] > 0

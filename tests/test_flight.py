@@ -116,20 +116,17 @@ def test_in_flight_cleared_on_exception():
     assert flight.inflight_snapshot() == []
 
 
-def test_jit_build_failure_clears_inflight(monkeypatch):
-    """A failed to_static build must not leak its in-flight compile
-    entry — the watchdog would report it as a permanent hang and it
-    would pollute every later dump's in_flight section."""
-    from paddle_tpu.jit import StaticFunction, to_static
+def test_jit_build_failure_clears_inflight():
+    """A to_static function whose first trace raises must not leak
+    its in-flight compile entry — the watchdog would report it as a
+    permanent hang and it would pollute every later dump's in_flight
+    section."""
+    from paddle_tpu.jit import to_static
 
     @to_static
     def f(x):
-        return x + 1
-
-    def boom(self, *a, **k):
         raise RuntimeError("build-fail")
 
-    monkeypatch.setattr(StaticFunction, "_build", boom)
     with pytest.raises(RuntimeError, match="build-fail"):
         f(paddle.to_tensor(np.ones((2,), np.float32)))
     assert flight.inflight_snapshot() == []
@@ -172,23 +169,38 @@ def test_watchdog_dumps_stalled_collective(tmp_path):
         entered.set()
         release.wait(30)
 
+    # the watchdog runs its incident hooks once the dump is on disk
+    # and counted: an event to wait on, however slow a loaded worker
+    # is to write the bundle (a sleep-and-glob poll gave up at times
+    # under six workers)
+    dumped = threading.Event()
+
+    def on_incident(reason):
+        if any(e["name"] == "fake_stall" and e["age_s"] > 0.3
+               for e in flight.inflight_snapshot()):
+            dumped.set()
+
     monitor.stat_reset()
     t = threading.Thread(target=stalled_collective, daemon=True,
                          name="stalled-collective")
+    flight.add_incident_hook(on_incident)
     wd = flight.start_watchdog(timeout_s=0.3, poll_s=0.05)
     try:
         t.start()
         assert entered.wait(5)
-        assert _wait_for(lambda: glob.glob(
-            str(tmp_path / "watchdog_rank0_*.json")))
+        assert dumped.wait(120), "watchdog wrote no dump"
     finally:
         release.set()
         flight.stop_watchdog()
+        flight.remove_incident_hook(on_incident)
         t.join(5)
 
-    dumps = glob.glob(str(tmp_path / "watchdog_rank0_*.json"))
-    assert dumps, "watchdog wrote no dump"
-    bundle = json.load(open(dumps[0]))
+    # the dump that names the stalled op (an op some earlier test's
+    # thread left in flight may have drawn one of its own)
+    bundles = [json.load(open(p)) for p in glob.glob(
+        str(tmp_path / "watchdog_rank0_*.json"))]
+    bundle = next(b for b in bundles if any(
+        e["name"] == "fake_stall" for e in b["stuck"]))
     assert bundle["schema"] == flight.DUMP_SCHEMA
     assert bundle["reason"] == "watchdog"
     assert bundle["rank"] == 0 and bundle["pid"] == os.getpid()

@@ -417,30 +417,6 @@ def test_chaos_linalg_dispatch_site(mesh24):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_persistent_compile_cache_warm_hit(mesh24, tmp_path):
-    """A dist program lowered once lands in the persistent cache; a
-    fresh in-process program cache then boots from a warm hit."""
-    a, b = _f32(32, 32), _f32(32, 16)
-    prev = os.environ.get("PADDLE_COMPILE_CACHE_DIR")
-    os.environ["PADDLE_COMPILE_CACHE_DIR"] = str(tmp_path)
-    try:
-        dla.clear_program_cache()
-        misses0 = cmon.stat_get("jit/persistent_cache/misses")
-        c1 = dla.matmul(dla.shard(a), dla.shard(b))
-        assert cmon.stat_get("jit/persistent_cache/misses") > misses0
-        dla.clear_program_cache()
-        hits0 = cmon.stat_get("jit/persistent_cache/hits")
-        c2 = dla.matmul(dla.shard(a), dla.shard(b))
-        assert cmon.stat_get("jit/persistent_cache/hits") > hits0
-        np.testing.assert_array_equal(c1.gather(), c2.gather())
-    finally:
-        if prev is None:
-            del os.environ["PADDLE_COMPILE_CACHE_DIR"]
-        else:
-            os.environ["PADDLE_COMPILE_CACHE_DIR"] = prev
-        dla.clear_program_cache()
-
-
 def test_program_cache_reuses_executables(mesh24):
     a, b = _f32(16, 32), _f32(32, 16)
     A, B = dla.shard(a), dla.shard(b)
