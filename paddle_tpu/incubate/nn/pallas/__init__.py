@@ -14,14 +14,19 @@ XLA fusions:
   Momentum) over the flattened parameter set — one kernel launch per
   step instead of a per-parameter tree of fusions.
 
-Everything is OFF by default and numerics-neutral when off:
+These two are OFF by default and numerics-neutral when off:
 `PADDLE_PALLAS_FUSION=1` arms the fused paths on TPU backends;
 `PADDLE_PALLAS_INTERPRET=1` additionally lets them run through the
 Pallas interpreter on CPU (parity tests / debugging — slow, never for
 production CPU runs). Every wired call site picks the kernel from a
-static shape/platform predicate (`ln_supported`, `optim_supported`,
-`paged_decode_supported`) and takes the unfused composition when it
-says no; a selected kernel that fails to compile fails the step.
+static shape/platform predicate (`ln_supported`, `optim_supported`)
+and takes the unfused composition when it says no; a selected kernel
+that fails to compile fails the step.
+
+- `paged_attention`: the serving engine's decode and verify attention
+  through the block tables. It reads no switch: its predicate
+  (`paged_attention.paged_decode_supported`) answers from platform,
+  mesh and shape alone (on the CPU, from `PADDLE_PALLAS_INTERPRET`).
 """
 from __future__ import annotations
 

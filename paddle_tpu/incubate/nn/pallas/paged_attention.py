@@ -21,38 +21,49 @@ Multi-query decode (`paged_attention_multi`): the speculative-decode
 verify dispatch feeds T consecutive query tokens per sequence — q is
 [B, T, H, D], query slot `t` of sequence `b` sits at absolute
 position `context_lens[b] - 1 + t` and may attend over
-`context_lens[b] + t` tokens (itself included). Same grid, same
-block streaming: the per-slot causal offset is a compile-time
-constant (the T-loop is python-unrolled, T <= 8), so one launch
-verifies a whole draft window per sequence with per-slot position
-masking instead of T separate dispatches.
+`context_lens[b] + t` tokens (itself included). ONE kernel body
+serves both: decode is the T = 1 case, and a window's slots are more
+rows of the same tiles, each masked at its own depth (T <= 8).
 
-Grid: (B, MAXB). `block_tables`/`context_lens` ride as SCALAR
-PREFETCH arguments (pltpu.PrefetchScalarGridSpec) so the K/V
-BlockSpec index maps resolve `tables[b, j]` BEFORE the kernel body —
-the DMA engine fetches exactly the blocks each sequence owns, in
-table order, nothing else. Dead blocks (slots past the sequence's
-context length) are grid-skipped with `pl.when`, the pad-and-mask
-discipline the PR-8 flash kernel established: a fully-dead block
-costs its (skipped) grid step, never a matmul; the tail block masks
-`k_pos >= context_len` scores to -inf so padded slots contribute
-exactly zero weight. Online softmax (running max/denominator in VMEM
-scratch) accumulates across a sequence's blocks, so nothing
-[S, S]-shaped ever materializes.
+Grid: (B,), one sequence a grid step; the body walks the sequence's
+LIVE page groups itself (`fori_loop` to `ceil(context / R)`), G
+pages a group, G from the shape: enough pages for a 128-row tile (8
+at block 16; the table is padded to a multiple of G where it is not
+one). The pools stay where they are (`pl.ANY`);
+`block_tables`/`context_lens` ride as SCALAR PREFETCH arguments
+(pltpu.PrefetchScalarGridSpec) and the body copies page
+`tables[b, j*G + g]` into row g*BS of one of two [R, H*D] tiles: the
+pages of group j + 1 (or, from a sequence's last group, of the next
+sequence's first) are on their way while group j is multiplied. Only
+pages that hold a visible token are copied — exactly the blocks each
+sequence owns, in table order, nothing else — and only groups that
+hold one are walked: a dead page costs nothing, not even a grid
+step. Inside the last live group the positions past the context
+mask to -inf (and the rows no copy wrote are zeroed in V), so they
+contribute exactly zero weight. Online softmax (running
+max/denominator in VMEM scratch) accumulates across a sequence's
+groups, so nothing [S, S]-shaped ever materializes. (PR 10's grid,
+(B, MAXB) with a page a step through `BlockSpec`s, was 2048 steps a
+layer at the offline cell's shape, 17 of them live for 270 tokens;
+the same through G `BlockSpec`s a step spent a third of its time on
+the pipeline's per-operand bookkeeping of dead steps: PERF.md, PR 30.)
 
-Layout inside the kernel: everything is 2-D and lane-dense. A pool
-block is read as [BS, H*D] (the pool's own memory order, no
-transpose) and each head's D lanes are addressed through two small
-0/1 matrices on the MXU instead of 3-D reshapes, which Mosaic refuses
-("infer-vector-layout: unsupported shape cast ... vector<1x16x64xbf16>
--> vector<16x1x64xbf16>" — the first version's `q[:, None, :]` and
-its batched M=1 dot_general):
+Layout inside the kernel: everything is 2-D and lane-dense, heads on
+the sublanes. A pool block is read as [BS, H*D] (the pool's own
+memory order, no transpose; Mosaic refuses 3-D reshapes of small
+tiles) and the G pages of a step lie row over row in one [R, H*D]
+tile. Head h's query sits on its own D lanes of row h, zeros on the
+others:
 
-    scores   [BS, H]   = K[BS, H*D] @ Qx[H*D, H]     Qx block-diagonal:
-                         column h holds head h's query on its D rows
-    weights  [BS, H*D] = P[BS, H] @ SEL[H, H*D]      SEL row h is 1 on
-                         head h's D lanes
-    output   [1, H*D] += sum over BS of weights * V[BS, H*D]
+    scores   [T*H, R]   = Qx[T*H, H*D] . K[R, H*D]^T   row t*H + h:
+                          slot t, head h; one product for all heads
+    output   [T*H, H*D] += P[T*H, R] @ V[R, H*D]       every row meets
+                          every head's lanes; the last group's step
+                          keeps row h's own D lanes (a 0/1 mask) and
+                          sums the H rows into [T, H*D]
+
+so there is no per-head selector product and no rounding but the
+reference's own. An f32 pool is multiplied at `Precision.HIGHEST`.
 
 `interpret=True` runs the same kernel through the Pallas interpreter
 for CPU parity tests (the PR-8 contract; see
@@ -75,148 +86,227 @@ _NEG_INF = -1e30
 
 
 def paged_decode_supported(num_heads, head_dim, block_size):
-    """Can the compiled TPU kernels (decode and multi-query verify)
-    take this geometry here? A pool block is one [BS, H*D] tile, so
-    H*D must fill whole 128-lane rows and BS whole sublane groups;
-    the interpreter (CPU parity) takes anything."""
-    from . import interpret_mode, kernels_available
+    """Do the decode and verify programs attend through the kernel
+    here? Answered from what the code can observe, with no switch of
+    its own: the platform (a TPU; on the CPU only the interpreter,
+    PADDLE_PALLAS_INTERPRET=1, which takes any shape: parity tests),
+    no live multi-device mesh (GSPMD cannot partition a Mosaic call,
+    and this one has no shard_map island), and the shape: a page is
+    one [BS, H*D] tile, so H*D must fill whole 128-lane rows and BS
+    whole sublane groups."""
+    from . import _on_tpu, _partitioned, interpret_mode
 
-    if not kernels_available():
-        return False
-    if interpret_mode():
-        return True
-    return (num_heads * head_dim) % 128 == 0 and block_size % 8 == 0
-
-
-def _head_selector(h, d, dtype):
-    """SEL [H, H*D]: row h is 1 on head h's D lanes, 0 elsewhere."""
-    return jnp.repeat(jnp.eye(h, dtype=dtype), d, axis=1)
+    if not _on_tpu():
+        return interpret_mode()
+    return not _partitioned() and (num_heads * head_dim) % 128 == 0 \
+        and block_size % 8 == 0
 
 
-def _block_diag_q(q, sel):
-    """q [..., H, D] -> Qx [..., H*D, H]: column h is head h's query
-    on its own D rows (a 0/1 mask, exact in any dtype)."""
-    h, d = q.shape[-2:]
-    flat = q.reshape(q.shape[:-2] + (h * d, 1))
-    return flat * sel.T.astype(q.dtype)
+def _head_selector(h, d):
+    """SEL [H, H*D] f32: row h is 1 on head h's D lanes, 0 elsewhere."""
+    return jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=1)
 
 
-def _over_lanes(x, sel, pieces):
-    """Per-head values x [R, H] f32 -> [R, H*D] on each head's lanes,
-    exactly: x goes through the 0/1 selector on the MXU as `pieces`
-    successive bf16 roundings of its remainder (a bf16 x 1 product
-    and the f32 sum of one term per lane are exact; three pieces
-    carry all 24 mantissa bits of an f32)."""
-    out = None
-    for _ in range(pieces):
-        piece = x.astype(sel.dtype)
-        term = jnp.dot(piece, sel, preferred_element_type=jnp.float32)
-        out = term if out is None else out + term
-        x = x - piece.astype(jnp.float32)
-    return out
+def _pages_per_group(block_size):
+    """G: the pages of one sequence that are copied and multiplied
+    together, from the shape: enough for a 128-row tile (the MXU's
+    height, and 1 MB of f32 keys and values at H*D = 1024)."""
+    return max(1, 128 // block_size)
 
 
-def _slot_update(s, ctx, j, block_size, v, sel, m_prev, l_prev, acc_prev):
-    """One online-softmax step for one query slot over one KV block.
-    s [BS, H] f32 scaled scores; returns the new (m, l, acc)."""
-    k_pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 0)
-    s = jnp.where(k_pos < ctx, s, _NEG_INF)
-    m_cur = jnp.max(s, axis=0, keepdims=True)              # [1, H]
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                                 # [BS, H]
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=0, keepdims=True)
-    # operands stay in the pool dtype (bf16-native MXU), statistics
-    # f32 (the PR-8 rule): p is rounded to the pool dtype exactly as
-    # the reference rounds it before its PV product
-    pv = p.astype(v.dtype)
-    w = _over_lanes(pv.astype(jnp.float32), sel,
-                    1 if v.dtype == jnp.bfloat16 else 3)   # [BS, H*D]
-    acc_new = acc_prev * _over_lanes(alpha, sel, 3) + jnp.sum(
-        w * v.astype(jnp.float32), axis=0, keepdims=True)  # [1, H*D]
-    return m_new, l_new, acc_new
-
-
-def _paged_kernel(tables_ref, lens_ref, qx_ref, sel_ref, k_ref, v_ref,
-                  o_ref, acc_ref, m_ref, l_ref, *, sm_scale, block_size,
-                  num_slots):
+def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
+                  o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
+                  parity_ref, *, sm_scale, block_size, pages, num_q):
+    """One grid step: ONE sequence, its T query slots (decode is
+    T = 1) against its live page groups, which the body walks itself.
+    Row t*H + h of every [T*H, ...] value is head h of slot t; the
+    scratch holds the online-softmax state of those rows across the
+    groups, the two [R, H*D] tiles a pool's pages are copied into
+    (group j + 1 on its way while group j is multiplied), and the
+    parity of the groups walked so far: the last group of a sequence
+    starts the copies of the next sequence's first."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    ctx = lens_ref[b]
+    last = pl.num_programs(0) - 1
+    rows = pages * block_size
+    sel = sel_ref[...]               # [H, H*D]
+    heads = sel.shape[0]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def seen(i):
+        # tokens visible to the deepest slot of sequence i: pages
+        # wholly past them hold NULL_BLOCK padding for every slot and
+        # are neither copied nor multiplied
+        return lens_ref[i] + (num_q - 1)
 
-    # grid-skip dead blocks: table slots at or past the context hold
-    # NULL_BLOCK padding — no matmul, no softmax update
-    @pl.when(j * block_size < ctx)
-    def _step():
-        s = jnp.dot(k_ref[0], qx_ref[0],
-                    preferred_element_type=jnp.float32) * sm_scale
-        m_ref[...], l_ref[...], acc_ref[...] = _slot_update(
-            s, ctx, j, block_size, v_ref[0], sel_ref[...],
-            m_ref[...], l_ref[...], acc_ref[...])
+    def groups(i):
+        return (seen(i) + (rows - 1)) // rows
 
-    @pl.when(j == num_slots - 1)
-    def _finish():
-        l = _over_lanes(jnp.maximum(l_ref[...], 1e-30), sel_ref[...], 3)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def page_copies(i, j, slot, act):
+        for g in range(pages):
+            first = (j * pages + g) * block_size
+
+            @pl.when(first < seen(i))
+            def _live():
+                page = tables_ref[i, j * pages + g]
+                to = pl.ds(g * block_size, block_size)
+                for pool, buf, which in ((k_hbm, k_buf, 0),
+                                         (v_hbm, v_buf, 1)):
+                    act(pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, to],
+                        sems.at[slot, which]))
+
+    def start(i, j, slot):
+        page_copies(i, j, slot, lambda copy: copy.start())
+
+    def wait(i, j, slot):
+        page_copies(i, j, slot, lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        parity_ref[0] = 0
+
+    base = parity_ref[0]
+    n_groups = groups(b)
+    before = jnp.maximum(b - 1, 0)
+    after = jnp.minimum(b + 1, last)
+
+    # group 0 is on its way already where the sequence before had a
+    # last group to start it from
+    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
+    def _own_first():
+        start(b, 0, base)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    ctx = lens_ref[b]                # tokens visible to query slot 0
+    if num_q > 1:                    # [T*H, 1]: slot t sees t more
+        row = jax.lax.broadcasted_iota(
+            jnp.int32, (num_q * heads, 1), 0)
+        ctx = ctx + sum((row >= t * heads).astype(jnp.int32)
+                        for t in range(1, num_q))
+    # head h's query on its own D lanes of row h, zeros on the others
+    # (a 0/1 mask, exact in any dtype): ONE product over all H*D
+    # lanes gives every head's scores. They run on the pool's dtype
+    # (bf16-native MXU; an f32 pool keeps f32 scores)
+    q = q_ref[0].astype(jnp.float32)                         # [T, H*D]
+    qx = jnp.concatenate([q[t:t + 1] * sel for t in range(num_q)],
+                         axis=0).astype(k_buf.dtype)         # [T*H, H*D]
+    # an f32 pool is multiplied as f32: left to itself Mosaic rounds
+    # f32 operands to one bf16 pass (2e-3 of error on the v5e where
+    # the dense reference, a VPU reduction there, has 6e-7; PR 30),
+    # which the configuration's precision is not
+    exact = jax.lax.Precision.HIGHEST \
+        if k_buf.dtype == jnp.float32 else None
+
+    def group(j, carry):
+        slot = (base + j) % 2
+
+        @pl.when(j + 1 < n_groups)
+        def _next_group():
+            start(b, j + 1, 1 - slot)
+
+        @pl.when((j + 1 == n_groups) & (b < last) & (groups(after) > 0))
+        def _next_sequence():
+            start(after, 0, 1 - slot)
+
+        wait(b, j, slot)
+        k = k_buf[slot]                                      # [R, H*D]
+        # rows no copy wrote hold what the tile held before: masked
+        # out of the scores below, and zeroed here so that a zero
+        # weight meets a zero
+        live = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) < seen(b)
+        v = jnp.where(live, v_buf[slot], 0)
+        s = jax.lax.dot_general(
+            qx, k, (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32) * sm_scale   # [T*H, R]
+        k_pos = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        # positions past the context (for the shallower slots, past
+        # theirs) mask to -inf: p underflows to an exact zero
+        s = jnp.where(k_pos < ctx, s, _NEG_INF)
+        m_prev = m_ref[...]                                  # [T*H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+        # operands stay in the pool dtype, statistics f32 (the PR-8
+        # rule): p is rounded to the pool dtype exactly as the
+        # reference rounds it before its PV product. Every row meets
+        # every head's lanes here; the end keeps its own
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, precision=exact,
+            preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    parity_ref[0] = (base + n_groups) % 2
+
+    o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)        # [T*H, H*D]
+    for t in range(num_q):
+        o_ref[0, t:t + 1] = jnp.sum(
+            o[t * heads:(t + 1) * heads] * sel, axis=0,
+            keepdims=True).astype(o_ref.dtype)
 
 
-def _check_pool(q_heads_dim, k_pool):
-    hk, dk = k_pool.shape[2:]
-    if (hk, dk) != q_heads_dim:
-        raise ValueError(
-            f"pool heads/dim {(hk, dk)} != query {q_heads_dim}")
+def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
+                interpret):
+    """q [B, T, H, D] through the kernel: grid (B,), the tables and
+    lengths as SCALAR PREFETCH arguments, the pools left where they
+    are (`pl.ANY`): the body copies the pages it needs itself."""
+    b, t, h, d = q.shape
+    n, bs, hk, dk = k_pool.shape
+    if (hk, dk) != (h, d):
+        raise ValueError(f"pool heads/dim {(hk, dk)} != query {(h, d)}")
+    hd = h * d
+    pages = _pages_per_group(bs)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    short = -tables.shape[1] % pages
+    if short:
+        # a table width G does not divide: the last column again, at
+        # positions no context reaches
+        tables = jnp.pad(tables, ((0, 0), (0, short)), mode="edge")
+    kernel = functools.partial(
+        _paged_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
+        num_q=t)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, t, hd), lambda i, bt, cl: (i, 0, 0)),
+            pl.BlockSpec((h, hd), lambda i, bt, cl: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, t, hd), lambda i, bt, cl: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, hd), k_pool.dtype),
+            pltpu.VMEM((2, pages * bs, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((t * h, hd), jnp.float32),
+            pltpu.VMEM((t * h, 1), jnp.float32),
+            pltpu.VMEM((t * h, 1), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(tables, jnp.asarray(context_lens, jnp.int32),
+      q.reshape(b, t, hd), _head_selector(h, d),
+      k_pool.reshape(n, bs, hd), v_pool.reshape(n, bs, hd))
+    return out.reshape(b, t, h, d)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                     sm_scale=1.0, interpret=False):
     """Ragged paged-attention decode: one launch, all sequences."""
-    b, h, d = q.shape
-    n, bs = k_pool.shape[:2]
-    _check_pool((h, d), k_pool)
-    hd = h * d
-    maxb = block_tables.shape[1]
-    sel = _head_selector(h, d, jnp.bfloat16)
-    # scores run on the pool's dtype (bf16-native MXU; an f32 pool
-    # keeps f32 scores)
-    qx = _block_diag_q(q.astype(k_pool.dtype), sel)        # [B, HD, H]
-    kernel = functools.partial(
-        _paged_kernel, sm_scale=sm_scale, block_size=bs,
-        num_slots=maxb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, hd, h), lambda i, j, bt, cl: (i, 0, 0)),
-            pl.BlockSpec((h, hd), lambda i, j, bt, cl: (0, 0)),
-            pl.BlockSpec((1, bs, hd),
-                         lambda i, j, bt, cl: (bt[i, j], 0, 0)),
-            pl.BlockSpec((1, bs, hd),
-                         lambda i, j, bt, cl: (bt[i, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda i, j, bt, cl: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(context_lens, jnp.int32), qx, sel,
-      k_pool.reshape(n, bs, hd), v_pool.reshape(n, bs, hd))
-    return out.reshape(b, h, d)
+    return _paged_call(q[:, None], k_pool, v_pool, block_tables,
+                       context_lens, sm_scale, interpret)[:, 0]
 
 
 def _gather_context(pool, block_tables):
@@ -250,99 +340,22 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
 # multi-query decode slots (speculative-decode verification)
 # ---------------------------------------------------------------------------
 
-def _paged_multi_kernel(tables_ref, lens_ref, qx_ref, sel_ref, k_ref,
-                        v_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale,
-                        block_size, num_slots, num_q):
-    """Per (sequence, table slot) grid step over T query slots. The
-    scratch holds one row of online-softmax state per slot; the
-    T-loop is python-unrolled so every per-slot causal offset is a
-    constant."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    ctx0 = lens_ref[b]               # tokens visible to query slot 0
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # the deepest slot sees ctx0 + num_q - 1 tokens; blocks past that
-    # are dead for EVERY slot and grid-skip like the single-query
-    # kernel. Shallower slots mask the block's tail per-position: a
-    # block entirely past a slot's context masks to all -inf, p
-    # underflows to zero and alpha to one, so that slot's state
-    # passes through untouched.
-    @pl.when(j * block_size < ctx0 + num_q - 1)
-    def _step():
-        k = k_ref[0]
-        v = v_ref[0]
-        sel = sel_ref[...]
-        for t in range(num_q):
-            s = jnp.dot(k, qx_ref[0, t],
-                        preferred_element_type=jnp.float32) * sm_scale
-            row = slice(t, t + 1)
-            m_ref[row], l_ref[row], acc_ref[row] = _slot_update(
-                s, ctx0 + t, j, block_size, v, sel,
-                m_ref[row], l_ref[row], acc_ref[row])
-
-    @pl.when(j == num_slots - 1)
-    def _finish():
-        l = _over_lanes(jnp.maximum(l_ref[...], 1e-30), sel_ref[...], 3)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
 def paged_attention_multi(q, k_pool, v_pool, block_tables,
                           context_lens, sm_scale=1.0,
                           interpret=False):
     """Multi-query ragged paged-attention: q [B, T, H, D], slot t of
     sequence b attends `context_lens[b] + t` tokens (per-slot causal
     masking over the SAME block table). One launch verifies a whole
-    speculative window; T must be small (the slot loop unrolls)."""
-    b, t, h, d = q.shape
+    speculative window; T must be small (the kernel's state and its
+    score tile grow with T*H rows)."""
+    t = q.shape[1]
     if t > 8:
         raise ValueError(
-            f"paged_attention_multi unrolls the slot loop — T={t} "
+            f"paged_attention_multi holds T*H rows of state - T={t} "
             "query slots > 8 would bloat the kernel; use the dense "
             "reference for long windows")
-    n, bs = k_pool.shape[:2]
-    _check_pool((h, d), k_pool)
-    hd = h * d
-    maxb = block_tables.shape[1]
-    sel = _head_selector(h, d, jnp.bfloat16)
-    qx = _block_diag_q(q.astype(k_pool.dtype), sel)     # [B, T, HD, H]
-    kernel = functools.partial(
-        _paged_multi_kernel, sm_scale=sm_scale, block_size=bs,
-        num_slots=maxb, num_q=t)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, t, hd, h),
-                         lambda i, j, bt, cl: (i, 0, 0, 0)),
-            pl.BlockSpec((h, hd), lambda i, j, bt, cl: (0, 0)),
-            pl.BlockSpec((1, bs, hd),
-                         lambda i, j, bt, cl: (bt[i, j], 0, 0)),
-            pl.BlockSpec((1, bs, hd),
-                         lambda i, j, bt, cl: (bt[i, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t, hd),
-                               lambda i, j, bt, cl: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((t, hd), jnp.float32),
-            pltpu.VMEM((t, h), jnp.float32),
-            pltpu.VMEM((t, h), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(context_lens, jnp.int32), qx, sel,
-      k_pool.reshape(n, bs, hd), v_pool.reshape(n, bs, hd))
-    return out.reshape(b, t, h, d)
+    return _paged_call(q, k_pool, v_pool, block_tables, context_lens,
+                       sm_scale, interpret)
 
 
 def paged_attention_multi_reference(q, k_pool, v_pool, block_tables,

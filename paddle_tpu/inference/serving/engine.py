@@ -242,6 +242,9 @@ class LLMEngine:
                                    spec_tokens=self.spec_k)
         self._requests = {}          # req_id -> Request (all states)
         if use_kernel is None:
+            # no switch: the runner answers from platform, mesh and
+            # shape (pallas.paged_attention.paged_decode_supported);
+            # an explicit use_kernel is the tests' override
             from ...incubate.nn import pallas as _pl
 
             use_kernel = runner.kernel_supported(self.block_size)
@@ -662,20 +665,25 @@ class LLMEngine:
             decode.capture()
         with _flight.span("serve/decode/fetch"):
             # the wait for the device, used: the next step's inputs
-            self._count_sample_case()
+            self._count_target_dispatch()
             self._prepare_ahead()
             toks, stats = jax.device_get((toks, stats))
             self._count_stats(stats)
             return toks
 
-    def _count_sample_case(self):
-        """One target dispatch (decode or verify) into the counter of
-        the case its sampler takes for this batch:
-        `serve/sample/steps_{greedy,drawn,ranked}`."""
+    def _count_target_dispatch(self):
+        """One target dispatch (decode or verify) into the counters:
+        `serve/sample/steps_{greedy,drawn,ranked}`, the case its
+        sampler takes for this batch, and `serve/attn/steps`, with
+        `serve/attn/steps_paged` beside it when the program attends
+        through the block tables in the Pallas kernel."""
         case = _mr.sample_case(
             req.sampling for req in self.scheduler.running.values())
         _cmon.stat_add(
             "serve/sample/steps_" + _mr.SAMPLE_CASES[case], 1)
+        _cmon.stat_add("serve/attn/steps", 1)
+        if self.use_kernel:
+            _cmon.stat_add("serve/attn/steps_paged", 1)
 
     @staticmethod
     def _count_stats(stats):
@@ -906,7 +914,7 @@ class LLMEngine:
                 jnp.asarray(temp), jnp.asarray(topk),
                 jnp.asarray(v_seeds))()
         with _flight.span("serve/decode/fetch"):
-            self._count_sample_case()
+            self._count_target_dispatch()
             return np.asarray(toks)
 
     def _spec_decode_batch(self, emitted):

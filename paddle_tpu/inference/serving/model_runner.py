@@ -264,10 +264,10 @@ def _scan_layers_paged(params, x, k_pool, v_pool, blk, off, attend,
 
     with the pools viewed as `[L*N, BS, H, D]` and `first_block ==
     l * N`: the caller looks its block tables up at `tables +
-    first_block`, through the dense reference or the Pallas kernels
-    alike (their index maps resolve one block id per grid step, and
-    both read a block as `[BS, H*D]`, so the view's split of the
-    minor dimension folds away). No layer is sliced out or stacked
+    first_block`, through the dense reference or the Pallas kernel
+    alike (its copies resolve one block id a page, and both read a
+    block as `[BS, H*D]`, so the view's split of the minor dimension
+    folds away). No layer is sliced out or stacked
     back. L and N come from the pools handed in (the target's, or
     the shallower draft's). Returns (x after the last layer, k_pool,
     v_pool)."""
@@ -487,12 +487,11 @@ class GPT2Runner:
     def kernel_supported(self, block_size):
         """Does the Pallas paged-attention kernel take this model's
         heads at this block size, here?"""
-        from ...incubate.nn import pallas as _pl
+        from ...incubate.nn.pallas import paged_attention as _pa
 
         c = self.config
-        return _pl.kernels_available() and \
-            _pl.paged_attention.paged_decode_supported(
-                c.num_heads, c.hidden_size // c.num_heads, block_size)
+        return _pa.paged_decode_supported(
+            c.num_heads, c.hidden_size // c.num_heads, block_size)
 
 
 def runner_for(model):

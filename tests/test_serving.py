@@ -412,6 +412,245 @@ class TestPagedAttentionKernel:
 
 
 # ---------------------------------------------------------------------------
+# ISSUE 30: a grid step takes G pages of one sequence
+# ---------------------------------------------------------------------------
+
+class TestPagedKernelPageGroups:
+    """Block 16 -> groups of G = 8 pages (128 rows). A table of 20
+    slots is one G does not divide (padded to 24: three groups); the
+    tables hold the NULL block 0 past each context, as the engine's
+    do."""
+    BS, MAXB, N = 16, 20, 96
+    # 1 token; ends inside the first page of the second group;
+    # exactly one group; inside the last page; the full table
+    LENS = (1, 130, 128, 307, 320)
+
+    def _inputs(self, dtype, t=None, h=4, d=32):
+        import jax.numpy as jnp
+
+        rng = np.random.RandomState(11)
+        b = len(self.LENS)
+        q = jnp.asarray(
+            rng.randn(*((b, h, d) if t is None else (b, t, h, d))), dtype)
+        kp = jnp.asarray(rng.randn(self.N, self.BS, h, d), dtype)
+        vp = jnp.asarray(rng.randn(self.N, self.BS, h, d), dtype)
+        tables = np.zeros((b, self.MAXB), np.int32)
+        nxt = 1
+        for i, n in enumerate(self.LENS):
+            used = min(self.MAXB, -(-(n + (t or 1) - 1) // self.BS))
+            tables[i, :used] = nxt + np.arange(used)
+            nxt += used
+        assert nxt <= self.N
+        return q, kp, vp, jnp.asarray(tables), jnp.asarray(
+            np.array(self.LENS, np.int32))
+
+    def test_group_size_follows_the_block(self):
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        assert [pa._pages_per_group(bs) for bs in (8, 16, 32, 128, 256)] \
+            == [16, 8, 4, 1, 1]
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
+                                           ("bfloat16", 0.05)])
+    @pytest.mark.parametrize("t", [None, 1, 3])
+    def test_parity_at_every_edge(self, dtype, tol, t):
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        q, kp, vp, bt, cl = self._inputs(dtype, t)
+        kernel, ref = (
+            (pa.paged_attention, pa.paged_attention_reference)
+            if t is None else
+            (pa.paged_attention_multi, pa.paged_attention_multi_reference))
+        if t is not None:
+            # the deepest slot of a full table would read past it
+            cl = cl - (t - 1) * (cl + t - 1 > self.MAXB * self.BS)
+        out = kernel(q, kp, vp, bt, cl, sm_scale=0.2, interpret=True)
+        want = ref(q, kp, vp, bt, cl, sm_scale=0.2)
+        assert out.dtype == q.dtype and out.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(want, np.float32),
+            rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_dead_pages_and_null_block_never_count(self, dtype):
+        """Pages past the context inside a live group, whole dead
+        groups, the padded table columns and the NULL block they all
+        point at: poisoned, the output is the same to the bit."""
+        import jax.numpy as jnp
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        q, kp, vp, bt, cl = self._inputs(dtype)
+        out = pa.paged_attention(q, kp, vp, bt, cl, sm_scale=0.3,
+                                 interpret=True)
+        live = np.zeros((self.N, self.BS), bool)
+        bt_np = np.asarray(bt)
+        for b, n in enumerate(self.LENS):
+            for pos in range(n):
+                live[bt_np[b, pos // self.BS], pos % self.BS] = True
+        assert not live[0].any()            # the NULL block
+        mask = jnp.asarray(live)[:, :, None, None]
+        out2 = pa.paged_attention(
+            q, jnp.where(mask, kp, 1e9).astype(kp.dtype),
+            jnp.where(mask, vp, -1e9).astype(vp.dtype), bt, cl,
+            sm_scale=0.3, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32), np.asarray(out2, np.float32))
+
+    def test_slot0_of_a_window_is_the_decode_kernel(self):
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        q, kp, vp, bt, cl = self._inputs("float32", t=3)
+        cl = cl - 2 * (cl + 2 > self.MAXB * self.BS)
+        multi = pa.paged_attention_multi(q, kp, vp, bt, cl,
+                                         sm_scale=0.3, interpret=True)
+        single = pa.paged_attention(q[:, 0], kp, vp, bt, cl,
+                                    sm_scale=0.3, interpret=True)
+        np.testing.assert_allclose(np.asarray(multi[:, 0]),
+                                   np.asarray(single),
+                                   rtol=2e-6, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 30: the kernel is chosen from platform, mesh and shape
+# ---------------------------------------------------------------------------
+
+class TestPagedKernelSelection:
+    @pytest.fixture(autouse=True)
+    def _no_switches(self, monkeypatch):
+        monkeypatch.delenv("PADDLE_PALLAS_FUSION", raising=False)
+        monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
+
+    @staticmethod
+    def _runner(hidden=64, heads=4):
+        from paddle_tpu.inference.serving.model_runner import GPT2Runner
+
+        return GPT2Runner(tiny_model(hidden=hidden, heads=heads))
+
+    def test_cpu_takes_the_kernel_only_through_the_interpreter(
+            self, monkeypatch):
+        runner = self._runner()
+        assert runner.kernel_supported(8) is False
+        monkeypatch.setenv("PADDLE_PALLAS_FUSION", "1")
+        assert runner.kernel_supported(8) is False
+        monkeypatch.delenv("PADDLE_PALLAS_FUSION")
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+        assert runner.kernel_supported(8) is True
+        # the interpreter takes any shape (odd-shape parity tests)
+        assert runner.kernel_supported(4) is True
+
+    @pytest.mark.parametrize("heads,head_dim,block,mesh_size,want", [
+        (16, 64, 16, 1, True),      # the cell's
+        (12, 64, 16, 1, True),      # GPT-2 small: 768 lanes
+        (3, 64, 16, 1, False),      # H*D = 192: not whole 128-lane rows
+        (16, 64, 4, 1, False),      # a block under one sublane group
+        (16, 64, 16, 4, False),     # a live multi-device mesh
+    ])
+    def test_on_a_tpu_shape_and_mesh_decide(self, monkeypatch, heads,
+                                            head_dim, block, mesh_size,
+                                            want):
+        import types
+
+        from paddle_tpu.distributed import mesh as mesh_mod
+        from paddle_tpu.incubate.nn import pallas
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+        monkeypatch.setattr(
+            mesh_mod, "get_mesh",
+            lambda: types.SimpleNamespace(size=mesh_size))
+        assert pa.paged_decode_supported(heads, head_dim, block) is want
+        # the switch of the LayerNorm and optimizer kernels is not asked
+        monkeypatch.setenv("PADDLE_PALLAS_FUSION", "1")
+        assert pa.paged_decode_supported(heads, head_dim, block) is want
+
+    def test_live_mesh_on_the_cpu_devices(self, monkeypatch):
+        """`_partitioned()` over a real mesh of the 8 virtual devices,
+        and over none."""
+        import jax
+
+        from paddle_tpu.distributed import mesh as mesh_mod
+        from paddle_tpu.incubate.nn import pallas
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+        before = mesh_mod.get_mesh()
+        try:
+            mesh_mod.set_mesh(mesh_mod.build_mesh(
+                {"dp": len(jax.devices())}))
+            assert pa.paged_decode_supported(16, 64, 16) is False
+            mesh_mod.set_mesh(None)
+            assert pa.paged_decode_supported(16, 64, 16) is True
+        finally:
+            mesh_mod.set_mesh(before)
+
+    def test_latent_runner_has_no_kernel(self, monkeypatch):
+        from paddle_tpu.inference.serving.mla_runner import MLARunner
+
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+        assert MLARunner.kernel_supported(None, 16) is False
+
+    def test_layernorm_and_optimizer_kernels_keep_their_switch(
+            self, monkeypatch):
+        from paddle_tpu.incubate.nn import pallas
+
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+        assert not pallas.kernels_available()
+        assert not pallas.ln_supported(1024)
+        assert not pallas.optim_supported()
+        monkeypatch.setenv("PADDLE_PALLAS_FUSION", "1")
+        assert pallas.ln_supported(1024) and pallas.optim_supported()
+        monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+        monkeypatch.delenv("PADDLE_PALLAS_FUSION")
+        assert not pallas.ln_supported(1024)
+        assert not pallas.optim_supported()
+
+    def test_engine_counts_the_dispatches_that_attend_paged(
+            self, monkeypatch):
+        """Through the interpreter the engine emits the dense
+        engine's tokens and every target dispatch counts as paged; a
+        dense engine grows `serve/attn/steps` alone."""
+        model = tiny_model()
+        prompts = [[4, 5, 6, 7], [9, 10]]
+        sp = SamplingParams(max_new_tokens=5)
+
+        def run(**kw):
+            eng = LLMEngine(model, max_batch=2, block_size=8,
+                            num_blocks=32, **kw)
+            out, deltas = _counter_deltas(
+                ("serve/attn/",),
+                lambda: eng.generate(prompts, sampling=sp))
+            return eng, out, deltas
+
+        dense, want, deltas = run()
+        assert not dense.use_kernel
+        assert deltas == {"serve/attn/steps": 4}
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+        paged, got, deltas = run()
+        assert paged.use_kernel and paged._kernel_interpret
+        assert got == want
+        assert deltas == {"serve/attn/steps": 4,
+                          "serve/attn/steps_paged": 4}
+        forced, got, deltas = run(use_kernel=False)
+        assert got == want and deltas == {"serve/attn/steps": 4}
+
+    def test_verify_dispatches_count_too(self, monkeypatch):
+        model = tiny_model()
+        sp = SamplingParams(max_new_tokens=6)
+        want = LLMEngine(model, max_batch=2, block_size=8,
+                         num_blocks=32).generate([[5, 6, 7]], sampling=sp)
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+        eng = LLMEngine(model, max_batch=2, block_size=8, num_blocks=32,
+                        spec_k=3)
+        got, deltas = _counter_deltas(
+            ("serve/attn/",),
+            lambda: eng.generate([[5, 6, 7]], sampling=sp))
+        assert got == want
+        assert deltas["serve/attn/steps"] > 0
+        assert deltas["serve/attn/steps_paged"] \
+            == deltas["serve/attn/steps"]
+
+
+# ---------------------------------------------------------------------------
 # engine e2e
 # ---------------------------------------------------------------------------
 
@@ -569,7 +808,6 @@ class TestEngineE2E:
         dense = LLMEngine(model, max_batch=2, block_size=8,
                           num_blocks=32, use_kernel=False)
         want = dense.generate(prompts, sampling=sp)
-        os.environ["PADDLE_PALLAS_FUSION"] = "1"
         os.environ["PADDLE_PALLAS_INTERPRET"] = "1"
         try:
             kern = LLMEngine(model, max_batch=2, block_size=8,
@@ -577,7 +815,6 @@ class TestEngineE2E:
             assert kern.use_kernel
             got = kern.generate(prompts, sampling=sp)
         finally:
-            os.environ.pop("PADDLE_PALLAS_FUSION", None)
             os.environ.pop("PADDLE_PALLAS_INTERPRET", None)
         assert got == want
 

@@ -170,3 +170,103 @@ def test_tpu_sampler_keeps_its_switch_and_sorts_in_one_branch(one_chip):
     assert not re.search(r" sort\(", body[branches[0]])
     assert not re.search(r" sort\(", body[branches[1]])
     assert len(re.findall(big, body[branches[2]])) == 1
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 30: decode and verify attend through the block tables
+# ---------------------------------------------------------------------------
+
+# a gathered context, in any of the forms the dense reference gives it:
+# [B, MAXB*BS, H, D], [B, MAXB*BS, H*D], [B*MAXB, BS, H*D], [B, MAXB, BS, ...]
+_CELL_B = 32
+
+
+def _dense_context(width):
+    b = _CELL_B
+    return rf"\[({b},{width * BS},\d|{b * width},{BS},|{b},{width},{BS},)"
+
+
+def _cell_program(sd, program, pool_dtype):
+    """decode / verify at the offline cell's widths: hidden 1024, 16
+    heads x 64, block 16, batch 32, table 64 (65 for verify: the
+    engine's NULL column); depth, vocabulary and pool cut down."""
+    pool, programs = _programs(sd)
+    fn, donated, args = programs[program]
+    i32 = jnp.int32
+    pool = sd(pool.shape, pool_dtype)
+    width = MAXB + (program == "verify")
+    ids = (_CELL_B, T) if program == "verify" else (_CELL_B,)
+    args = (args[0], sd(ids, i32), sd((_CELL_B,), i32), pool, pool,
+            sd((_CELL_B, width), i32), sd((_CELL_B,), i32),
+            sd((_CELL_B,)), sd((_CELL_B,), i32), sd(ids, i32))
+    return fn, donated, args, pool, _dense_context(width)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("program", ["decode", "verify"])
+def test_tpu_kernel_program_gathers_no_context(one_chip, program,
+                                               pool_dtype):
+    """With the Pallas kernel as its attention the program compiled
+    for the v5e aliases both pools, keeps under 64 MiB of temporaries
+    (the dense reference: 0.4 GiB of gathered contexts), holds the
+    Mosaic call and no op over a gathered context; the dense program
+    does hold such ops, so the pattern can see them."""
+    import re
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, donated, args, pool, context = _cell_program(
+        sd, program, pool_dtype)
+
+    def compiled(use_kernel):
+        return jax.jit(
+            functools.partial(fn, use_kernel=use_kernel, **KW),
+            donate_argnums=donated).lower(*args).compile()
+
+    kernel = compiled(True)
+    mem = kernel.memory_analysis()
+    one_pool = pool.size * pool.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * one_pool
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, (
+        f"{mem.temp_size_in_bytes / 2 ** 20:.0f} MiB of temporaries")
+    hlo = kernel.as_text()
+    assert "tpu_custom_call" in hlo
+    assert not re.search(context, hlo), \
+        re.findall(context + r".*", hlo)[:3]
+    if pool_dtype == jnp.float32:
+        dense = compiled(False)
+        assert re.search(context, dense.as_text())
+        assert dense.memory_analysis().temp_size_in_bytes \
+            > 4 * mem.temp_size_in_bytes
+
+
+def test_latent_decode_never_sees_the_paged_predicate(one_chip,
+                                                      monkeypatch):
+    """GLM's runner has no kernel: `kernel_supported` answers False
+    without asking the predicate, and its decode program lowers to
+    the same text whether or not the paged kernel could even be
+    called."""
+    from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+    from paddle_tpu.inference.serving.mla_runner import MLARunner
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _, programs, kw = _mla_programs(sd)
+    fn, args = programs["decode"]
+
+    def lowered():
+        return jax.jit(functools.partial(fn, **kw),
+                       donate_argnums=(3,)).lower(*args).as_text()
+
+    want = lowered()
+
+    def refuse(*a, **k):
+        raise AssertionError("the latent runner asked for the kernel")
+
+    for name in ("paged_decode_supported", "_paged_call"):
+        monkeypatch.setattr(pa, name, refuse)
+    assert MLARunner.kernel_supported(None, 16) is False
+    assert lowered() == want
