@@ -1,5 +1,5 @@
-"""Dropless expert FFN: sigmoid scores, bias-corrected top-k, grouped
-matmuls over the experts held.
+"""Dropless expert FFN: scores, bias-corrected top-k, grouped matmuls
+over the experts held HERE.
 
 The GShard layer next door (`MoELayer`) gives every expert a static
 capacity and drops what does not fit. This one drops nothing: the
@@ -23,13 +23,28 @@ compiled for the v5e, PR 27), so the stack is instead VIEWED as
 `[L*E, ...]` groups (a reshape of leading dimensions) and the group
 sizes of every other layer are zero — the block tables' `l * N`
 shift of `serving.model_runner`, for weights.
+
+A share of a layer (expert parallelism's cut, one rank of it): the
+router keeps its published width, the ids range over every expert of
+the layer, and this chip holds experts `first .. first + E` of them
+(`dropless_expert_ffn(first=)`). An assignment to an expert held
+elsewhere sorts behind every held group, belongs to no group and
+adds nothing: the result is this chip's PART of the layer's sum, and
+nothing stands in for the other chips or for the exchange with them.
+LongCat-Flash's router also has outputs beyond the real experts,
+`zero-compute` experts of type identity, whose SwiGLU is the token
+itself: `identity_expert_sum`, no matmul. Its rule
+(`softmax_topk_route`): `s = softmax(u W_r)` over real and zero
+experts alike, the `top_k` largest of `s + b` chosen, weights `scale
+* s_i`, NOT renormalised.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["sigmoid_topk_route", "dropless_expert_ffn", "expert_counts"]
+__all__ = ["sigmoid_topk_route", "softmax_topk_route",
+           "dropless_expert_ffn", "identity_expert_sum", "expert_counts"]
 
 
 def sigmoid_topk_route(u, router_w, bias, top_k, scale):
@@ -46,18 +61,40 @@ def sigmoid_topk_route(u, router_w, bias, top_k, scale):
     return idx.astype(jnp.int32), weights
 
 
+def softmax_topk_route(u, router_w, bias, top_k, scale):
+    """u [T, H] -> (ids [T, k] int32 over ALL the router's outputs,
+    weights [T, k] float32 = `scale` x the chosen softmax scores as
+    they are). Chosen by `s + bias`; float32 at `HIGHEST`, as
+    `sigmoid_topk_route`."""
+    scores = jax.nn.softmax(jnp.dot(
+        u.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weights = scale * jnp.take_along_axis(scores, idx, axis=-1)
+    return idx.astype(jnp.int32), weights
+
+
 def expert_counts(idx, n_experts, live=None):
-    """Tokens per expert [E] int32 from the chosen ids [T, k]; rows
-    where `live` [T] is False (padding, inactive batch slots) are
-    left out."""
+    """Tokens per expert [E] int32 from the chosen ids [T, k] (an id
+    outside `0 .. E` counts nowhere: a share passes `idx - first`);
+    rows where `live` [T] is False (padding, inactive batch slots)
+    are left out."""
     hot = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32)
     if live is not None:
         hot = hot * live.astype(jnp.int32)[:, None, None]
     return hot.sum((0, 1))
 
 
-def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None):
-    """sum_i weights[t, i] * SwiGLU_{idx[t, i]}(u[t]) for every token.
+def identity_expert_sum(u, idx, weights, first_zero):
+    """What a token's picks of zero-compute experts of type identity
+    (ids >= `first_zero`) add: `(sum of their weights) * u[t]`."""
+    w = jnp.where(idx >= first_zero, weights, 0.0).sum(-1, keepdims=True)
+    return (w * u.astype(jnp.float32)).astype(u.dtype)
+
+
+def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None, first=None):
+    """sum_i weights[t, i] * SwiGLU_{idx[t, i]}(u[t]) for every token,
+    over the experts held here.
 
     u [T, H]; idx/weights [T, k]; `w13` holds each expert's gate and
     up projections side by side, `[E, H, 2F]`, `w2` its down
@@ -65,10 +102,21 @@ def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None):
     stacks `[L, E, H, 2F]` / `[L, E, F, H]` and layer `layer`'s
     experts are used, without slicing them out (module docstring).
     No token is dropped: an expert that takes every token gets a
-    group of T rows."""
+    group of T rows. `first=None`: the ids are the E held experts'
+    own numbers. With `first` the ids range over a wider layer of
+    which this chip holds experts `first .. first + E`; a pick
+    outside them is computed elsewhere (or is a zero-compute
+    expert's) and adds nothing to this partial sum."""
     t, k = idx.shape
     n_experts = w13.shape[-3]
     flat = idx.reshape(t * k)
+    if first is not None:
+        # absent picks get group number E: sorted behind every held
+        # group, in no group's size (a scatter drops an index out of
+        # bounds), weight 0
+        held = (idx >= first) & (idx < first + n_experts)
+        flat = jnp.where(held, idx - first, n_experts).reshape(t * k)
+        weights = jnp.where(held, weights, 0.0)
     order = jnp.argsort(flat, stable=True)
     rows = jnp.take(u, order // k, axis=0)              # [T*k, H]
     sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
@@ -85,5 +133,9 @@ def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None):
     # back to token order: row j of the sorted list is assignment
     # order[j]; its inverse gathers instead of scattering
     out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)
+    if first is not None:
+        # rows behind the last group are whatever the grouped matmul
+        # leaves there: 0 x that must be 0
+        out = jnp.where(held[..., None], out, 0)
     return jnp.einsum("tkh,tk->th", out.astype(jnp.float32),
                       weights).astype(u.dtype)

@@ -7,9 +7,9 @@ decode step covering all `max_batch` slots — that together serve
 many concurrent mixed-length requests. What the engine knows of the
 model (its parameters, the cache's pools and row widths, the
 programs) it reads from the RUNNER the model's type selects
-(`model_runner.runner_for`: GPT-2, or `mla_runner` for
-`glm4_moe_lite`); a runner without a verify or tail program makes
-`spec_k > 1` / `prefix_cache` raise at construction:
+(`model_runner.runner_for`: GPT-2, or `mla_runner` for a model
+with latent-attention layers); a runner without a verify or tail
+program makes `spec_k > 1` / `prefix_cache` raise at construction:
 
     engine = LLMEngine(model)
     engine.add_request([1, 2, 3], SamplingParams(max_new_tokens=8),
@@ -226,7 +226,7 @@ class LLMEngine:
                 f"prefix_cache: {name} has no tail-prefill program; "
                 "serve this model with prefix_cache=False")
         self.cache = PagedKVCache(
-            cfg.num_layers, rows=runner.pool_rows,
+            runner.pool_layers, rows=runner.pool_rows,
             block_size=block_size, num_blocks=num_blocks,
             pool_bytes=pool_bytes, dtype=dtype,
             draft_layers=self.draft_layers,
@@ -688,12 +688,20 @@ class LLMEngine:
     @staticmethod
     def _count_stats(stats):
         """What a program returned beside its tokens, into the
-        counters. `moe_counts` [expert layers, experts]: the live
-        tokens each expert of each layer took in this dispatch."""
+        counters. `moe_counts` [expert layers, experts held]: the
+        live tokens each expert of each layer took in this dispatch.
+        `moe_picks` [expert layers, 2], from a router wider than the
+        experts held here: the live tokens' top-k picks, and those
+        of them that fell on zero-compute experts; without it every
+        pick is an assignment."""
         counts = stats.get("moe_counts")
         if counts is None:
             return
         counts = np.asarray(counts)
+        choices, zero = np.reshape(
+            stats.get("moe_picks", (counts.sum(), 0)), (-1, 2)).sum(0)
+        _cmon.stat_add("serve/moe/choices", int(choices))
+        _cmon.stat_add("serve/moe/zero_choices", int(zero))
         _cmon.stat_add("serve/moe/assignments", int(counts.sum()))
         _cmon.stat_add("serve/moe/experts_hit",
                        int((counts > 0).sum()))

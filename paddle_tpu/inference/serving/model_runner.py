@@ -6,6 +6,7 @@ picks it from the model's type; nothing else selects it):
 
     runner.params, runner.config     the raw jnp tree and the config
     runner.pool_rows                 row width of each cache pool
+    runner.pool_layers               leading axis of each pool: attentions that keep rows
     runner.prefill_step(params, ids, prompt_len, pools, table, temp, top_k, seed, *, block_size)
     runner.decode_step(params, ids, positions, pools, tables, lens, temp, top_k, seeds, *, block_size, use_kernel, interpret)
     runner.verify_step, .prefill_tail_step, .draft_params    or None: no speculation / prefix cache
@@ -475,6 +476,7 @@ class GPT2Runner:
         c = self.config
         kw = dict(n_head=c.num_heads, eps=c.layer_norm_eps)
         self.pool_rows = (c.hidden_size,) * 2
+        self.pool_layers = c.num_layers
         self.prefill_step = functools.partial(
             _pooled(prefill_step, 3), **kw)
         self.decode_step = functools.partial(
@@ -495,11 +497,9 @@ class GPT2Runner:
 
 
 def runner_for(model):
-    """The runner of a model, by its type."""
-    from ...text.models import glm4_moe_lite as _glm
-
-    if isinstance(model, (_glm.Glm4MoeLiteForCausalLM,
-                          _glm.Glm4MoeLiteModel)):
+    """The runner of a model: `MLARunner` for one that says what its
+    latent-attention layers are (`mla_layers`), else GPT-2's."""
+    if hasattr(getattr(model, "model", model), "mla_layers"):
         from .mla_runner import MLARunner
 
         return MLARunner(model)

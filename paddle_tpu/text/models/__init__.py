@@ -6,3 +6,5 @@ from .bert import BertConfig, BertModel, BertForPretraining, bert_base
 from .ernie import ErnieConfig, ErnieModel, ErnieForPretraining
 from .glm4_moe_lite import (Glm4MoeLiteConfig, Glm4MoeLiteModel,
                             Glm4MoeLiteForCausalLM)
+from .longcat_flash import (LongcatFlashConfig, LongcatFlashModel,
+                            LongcatFlashForCausalLM)

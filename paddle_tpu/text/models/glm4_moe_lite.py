@@ -7,50 +7,38 @@ every projection without bias:
 
     h  = x + MLA(N(x))          x' = h + FFN(N(h))
 
-- MLA: `c_q = N(u W_qa)`, `q = c_q W_qb` per head `[nope | rope]`;
-  `[c_kv | k_r] = u W_kva`, `c_kv = N(c_kv)`; rotary (theta
-  `rope_theta`, no scaling) over the rope dims of `q` and over `k_r`,
-  which all heads share; `[k_nope | v] = c_kv W_kvb` per head; causal
-  softmax of `[q_nope | q_rope] . [k_nope | k_rope] / sqrt(nope +
-  rope)`; heads concatenated, times `W_o`. What a cache has to hold
-  of a token is `[c_kv | k_rope]` alone (`kv_lora_rank +
-  qk_rope_head_dim` values a layer): `mla_latent` makes that row,
-  `mla_attend_dense` expands it through `W_kvb` (prefill, training),
-  `mla_attend_absorbed` folds `W_kvb` into the query and the output
-  and reads nothing but the rows (decode).
+- MLA: `text/models/mla.py`, which `longcat_flash` shares, with both
+  of its scale constants 1.
 - FFN: the first `first_k_dense_replace` layers are SwiGLU of width
   `intermediate_size`; the others route each token to
   `num_experts_per_tok` of `n_routed_experts` SwiGLUs of width
   `moe_intermediate_size` (`incubate...moe.dropless`) and add the
   shared expert's.
 
-Assumed where the config is silent: the rotary pairing is half-split
-(dims `i` and `i + rope/2` rotate together); with seeded weights the
-interleaved pairing is a column permutation of `W_qb` and `W_kva`.
+Assumed where the config is silent: the rotary pairing (`mla.py`).
 The multi-token-prediction module (`num_nextn_predict_layers`) is a
 drafter and no part of the next-token forward pass: not built.
 
 The two kinds of layer have different trees, so the parameters are
 two stacks with a leading layer axis (`dense`, `moe`), each run by
-one `lax.scan`. Weights are drawn on the device, in the configured
-dtype, one layer at a time: at the published widths one expert layer
-is 635 M parameters, and a float32 construction of seven would not
-fit a 16 GB chip.
+one `lax.scan` (`layers`, which the serving runner calls with its
+own attention). Weights are drawn on the device (`mla.SeededTree`):
+at the published widths one expert layer is 635 M parameters.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
 from ...core.engine import apply_op
-from ...core.tensor import Parameter
 from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, sigmoid_topk_route)
 from ...nn.layer.layers import Layer
-from ...ops import random as _random
+from .mla import (SeededTree, attention_block,  # noqa: F401
+                  mla_attend_absorbed, mla_attend_dense, mla_latent,
+                  mla_query, rms_norm, rotate, swiglu)
 
 __all__ = ["Glm4MoeLiteConfig", "Glm4MoeLiteModel",
            "Glm4MoeLiteForCausalLM"]
@@ -100,106 +88,10 @@ class Glm4MoeLiteConfig:
         """Values one token holds in a cache, per layer."""
         return self.kv_lora_rank + self.qk_rope_head_dim
 
-
-# -- the block's mathematics (pure jnp; the serving runner reads them) -----
-
-def rms_norm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return xf.astype(x.dtype) * w
+    mla_q_scale = mla_kv_scale = 1      # class constants, no fields
 
 
-def rotate(x, positions, theta):
-    """Rotary embedding over the last dimension of `x [..., D]`,
-    half-split pairing; `positions` has x's leading shape or
-    broadcasts against it (a heads axis is `positions[..., None]`)."""
-    half = x.shape[-1] // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def swiglu(u, w13, w2):
-    gate, up = jnp.split(u @ w13, 2, axis=-1)
-    return (jax.nn.silu(gate) * up) @ w2
-
-
-def mla_query(u, ap, cfg, positions):
-    """(q_nope [..., H, nope], q_rope [..., H, rope], rotated)."""
-    c_q = rms_norm(u @ ap["wq_a"], ap["q_norm"], cfg.rms_norm_eps)
-    q = (c_q @ ap["wq_b"]).reshape(
-        u.shape[:-1] + (cfg.num_heads,
-                        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
-    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-    return q_nope, rotate(q_rope, positions[..., None], cfg.rope_theta)
-
-
-def mla_latent(u, ap, cfg, positions):
-    """The row a cache holds of each token: `[N(c_kv) | R(k_r)]`,
-    `[..., kv_lora_rank + qk_rope_head_dim]`."""
-    c_kv, k_r = jnp.split(u @ ap["wkv_a"], [cfg.kv_lora_rank], axis=-1)
-    c_kv = rms_norm(c_kv, ap["kv_norm"], cfg.rms_norm_eps)
-    return jnp.concatenate(
-        [c_kv, rotate(k_r, positions, cfg.rope_theta)], -1)
-
-
-def _wkv_b(ap, cfg):
-    """W_kvb as (W^K [rank, H, nope], W^V [rank, H, v])."""
-    w = ap["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads,
-                            cfg.qk_nope_head_dim + cfg.v_head_dim)
-    return jnp.split(w, [cfg.qk_nope_head_dim], axis=-1)
-
-
-def _sm_scale(cfg):
-    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
-
-
-def mla_attend_dense(q_nope, q_rope, latent, ap, cfg):
-    """Causal attention of S tokens over themselves, keys and values
-    expanded from the latent rows through W_kvb. q_* [S, H, .],
-    latent [S, row] -> [S, H * v_head_dim]."""
-    s = latent.shape[0]
-    c_kv, k_rope = jnp.split(latent, [cfg.kv_lora_rank], axis=-1)
-    wk, wv = _wkv_b(ap, cfg)
-    k_nope = jnp.einsum("sc,chd->shd", c_kv, wk)
-    v = jnp.einsum("sc,chd->shd", c_kv, wv)
-    scores = (jnp.einsum("qhd,khd->hqk", q_nope, k_nope,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("qhd,kd->hqk", q_rope, k_rope,
-                           preferred_element_type=jnp.float32))
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(
-        jnp.where(mask, scores * _sm_scale(cfg), -1e30), axis=-1)
-    out = jnp.einsum("hqk,khd->qhd", probs.astype(v.dtype), v)
-    return out.reshape(s, -1)
-
-
-def mla_attend_absorbed(q_nope, q_rope, ctx, lens, ap, cfg):
-    """Attention of one query token a sequence over cached latent
-    rows, W_kvb absorbed: `q_lat = q_nope W^K^T`, scores over the
-    rows as they are, `o = (P c_kv) W^V`. q_* [B, H, .], ctx
-    [B, T, row] (positions >= lens[b] masked) -> [B, H * v_head_dim].
-    The same mathematics as `mla_attend_dense`."""
-    wk, wv = _wkv_b(ap, cfg)
-    q_lat = jnp.einsum("bhd,chd->bhc", q_nope, wk)
-    q = jnp.concatenate([q_lat, q_rope], -1)            # [B, H, row]
-    # a cache may store its rows wider than they are (zero-padded to
-    # a multiple of the device's lanes): zeros against zeros
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, ctx.shape[-1] - q.shape[-1])))
-    scores = jnp.einsum("bhr,btr->bht", q, ctx,
-                        preferred_element_type=jnp.float32)
-    live = jnp.arange(ctx.shape[1])[None, :] < lens[:, None]
-    probs = jax.nn.softmax(
-        jnp.where(live[:, None, :], scores * _sm_scale(cfg), -1e30),
-        axis=-1)
-    o_lat = jnp.einsum("bht,btr->bhr", probs.astype(ctx.dtype),
-                       ctx)[..., :cfg.kv_lora_rank]
-    out = jnp.einsum("bhc,chd->bhd", o_lat, wv)
-    return out.reshape(out.shape[0], -1)
-
+# -- the block (pure jnp; the serving runner reads `layers`) ---------------
 
 def moe_ffn(u, mp, cfg, layer=None, live=None):
     """Routed experts plus the shared expert over tokens u [T, H].
@@ -218,6 +110,48 @@ def moe_ffn(u, mp, cfg, layer=None, live=None):
     with jax.named_scope("moe/shared"):
         out = out + swiglu(u, mp["shared_w13"], mp["shared_w2"])
     return out, counts
+
+
+_EXPERTS = ("w13", "w2")     # read in place, never a scan's xs
+
+
+def layers(params, x, carry, attend, live, cfg):
+    """Both stacks over `x [T, hidden]`, for the serving runner, with
+    the calling program's `attend` (`mla.attention_block`); an
+    attention's number in the cache is its layer's. The
+    routed experts' stacked weights are not among a scan's `xs`
+    (slicing a layer out would copy all its experts): `moe_ffn` reads
+    them as `[L*E, ...]` groups. Returns (x, carry, the rows of every
+    attention in cache order or None, {"moe_counts"})."""
+    eps = cfg.rms_norm_eps
+    dense, moe = params["dense"], params["moe"]
+    experts = {k: moe[k] for k in _EXPERTS}
+    moe = {k: v for k, v in moe.items() if k not in _EXPERTS}
+    n_dense = jax.tree_util.tree_leaves(dense)[0].shape[0]
+    n_moe = jax.tree_util.tree_leaves(moe)[0].shape[0]
+
+    def dense_layer(c, xs):
+        lp, layer = xs
+        h, u, carry, ys = attention_block(*c, lp["attn"], layer, attend,
+                                          eps)
+        return (h + swiglu(u, lp["w13"], lp["w2"]), carry), ys
+
+    def moe_layer(c, xs):
+        lp, layer = xs
+        h, u, carry, ys = attention_block(*c, lp["attn"], n_dense + layer,
+                                          attend, eps)
+        out, counts = moe_ffn(u, {**lp, **experts}, cfg, layer=layer,
+                              live=live)
+        return (h + out, carry), (ys, counts)
+
+    (x, carry), ys_d = jax.lax.scan(
+        dense_layer, (x, carry),
+        (dense, jnp.arange(n_dense, dtype=jnp.int32)))
+    (x, carry), (ys_m, counts) = jax.lax.scan(
+        moe_layer, (x, carry),
+        (moe, jnp.arange(n_moe, dtype=jnp.int32)))
+    rows = None if ys_m is None else jnp.concatenate([ys_d, ys_m], 0)
+    return x, carry, rows, {"moe_counts": counts}
 
 
 def _k_forward(ids, params, cfg):
@@ -257,51 +191,34 @@ def _k_forward(ids, params, cfg):
 
 # -- the Layer ---------------------------------------------------------------
 
-class Glm4MoeLiteModel(Layer):
+class Glm4MoeLiteModel(SeededTree):
     """Decoder with two stacks of layers: `first_k_dense_replace`
     dense ones, then the expert layers."""
 
+    mla_layers = staticmethod(layers)
+
     def __init__(self, config: Glm4MoeLiteConfig):
-        super().__init__()
-        self.config = c = config
+        super().__init__(config)
+        c = config
         if c.n_shared_experts != 1:
             raise ValueError("one shared expert is what glm4_moe_lite "
                              f"publishes; got {c.n_shared_experts}")
-        self._dtype = jnp.dtype(c.dtype)
-        self._key = _random.next_key()
-        self._n_leaf = 0
-        h, heads = c.hidden_size, c.num_attention_heads
+        h = c.hidden_size
         n_dense = c.first_k_dense_replace
         n_moe = c.num_hidden_layers - n_dense
         e, f = c.n_routed_experts, c.moe_intermediate_size
-
-        def attn(n):
-            return {
-                "ln1": self._ones("ln1", (n, h)),
-                "wq_a": self._normal("wq_a", (n, h, c.q_lora_rank)),
-                "q_norm": self._ones("q_norm", (n, c.q_lora_rank)),
-                "wq_b": self._normal("wq_b", (n, c.q_lora_rank, heads * (
-                    c.qk_nope_head_dim + c.qk_rope_head_dim))),
-                "wkv_a": self._normal("wkv_a", (n, h, c.latent_row)),
-                "kv_norm": self._ones("kv_norm", (n, c.kv_lora_rank)),
-                "wkv_b": self._normal("wkv_b", (n, c.kv_lora_rank, heads * (
-                    c.qk_nope_head_dim + c.v_head_dim))),
-                "wo": self._normal("wo", (n, heads * c.v_head_dim, h)),
-                "ln2": self._ones("ln2", (n, h)),
-            }
-
         self._tree = {
             "embed": self._normal("embed", (c.vocab_size, h), layered=False),
             "head": self._normal("head", (h, c.vocab_size), layered=False),
             "norm_f": self._ones("norm_f", (h,)),
             "dense": {
-                "attn": attn(n_dense),
+                "attn": self._attention(n_dense),
                 "w13": self._normal("w13", (n_dense, h,
                                             2 * c.intermediate_size)),
                 "w2": self._normal("w2", (n_dense, c.intermediate_size, h)),
             },
             "moe": {
-                "attn": attn(n_moe),
+                "attn": self._attention(n_moe),
                 # the router and its selection bias stay float32
                 "router_w": self._normal("router_w", (n_moe, h, e),
                                          dtype=jnp.float32),
@@ -314,37 +231,10 @@ class Glm4MoeLiteModel(Layer):
             },
         }
 
-    def _add(self, name, value):
-        self._n_leaf += 1
-        p = Parameter(value, name=f"{name}_{self._n_leaf}")
-        self.add_parameter(f"{name}_{self._n_leaf}", p)
-        return p
-
-    def _ones(self, name, shape):
-        return self._add(name, jnp.ones(shape, self._dtype))
-
-    def _normal(self, name, shape, layered=True, dtype=None):
-        """initializer_range x normal, drawn on the device in the
-        target dtype, one slice of the leading (layer) axis at a
-        time: a leaf never exists in float32 as a whole."""
-        dtype = dtype or self._dtype
-        std = self.config.initializer_range
-        key = jax.random.fold_in(self._key, self._n_leaf)
-
-        def draw(k, sh):
-            return (std * jax.random.normal(k, sh, jnp.float32)
-                    ).astype(dtype)
-
-        if layered:
-            value = jax.jit(lambda ks: jax.lax.map(
-                lambda k: draw(k, shape[1:]), ks))(
-                    jax.random.split(key, shape[0]))
-        else:
-            value = jax.jit(lambda k: draw(k, shape))(key)
-        return self._add(name, value)
-
-    def _params_tree(self):
-        return self._tree
+    @property
+    def n_attentions(self):
+        """Attentions that keep rows in a cache: one a layer."""
+        return self.config.num_hidden_layers
 
     def forward(self, input_ids):
         return apply_op("glm4_moe_lite_forward", _k_forward, input_ids,

@@ -104,7 +104,7 @@ def _mla_programs(sd):
     row = -(-cfg.latent_row // 128) * 128
     i32, f32, bsz, maxb = jnp.int32, jnp.float32, 64, 4096 // 16
     pool = sd((3, 16385, 16, row), jnp.bfloat16)
-    kw = dict(cfg=cfg, block_size=16)
+    kw = dict(cfg=cfg, layers=Glm4MoeLiteModel.mla_layers, block_size=16)
     return pool, {
         "decode": (mla.decode_step, (
             params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
@@ -270,3 +270,60 @@ def test_latent_decode_never_sees_the_paged_predicate(one_chip,
         monkeypatch.setattr(pa, name, refuse)
     assert MLARunner.kernel_supported(None, 16) is False
     assert lowered() == want
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: the same runner's programs at LongCat-Flash's widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_tpu_longcat_programs_fit_the_cell(one_chip, program):
+    """Decode and the longest prefill of `serve-longcat-offline-decode`
+    as the cell runs them (hidden 6144, 64 heads, ranks 1536/512, the
+    768-wide router, 16 held experts of 2048, 4 double layers = 8
+    attentions' rows, batch 64, 4096 positions, 16385 blocks, the
+    vocabulary's slice), compiled for the v5e: the one pool is
+    aliased, the temporaries are smaller than the pool (decode's
+    also than one layer's held experts, 1.13 GiB: what slicing a
+    layer out of the stack would copy; the prefill's 2.1 GiB are 64
+    heads' scores over 2048 x 2048 and 24576 rows of assignments),
+    and arguments and temporaries together fit the chip's 15.75
+    GiB."""
+    from paddle_tpu.inference.serving import mla_runner as mla
+    from paddle_tpu.text.models.longcat_flash import (LongcatFlashConfig,
+                                                      LongcatFlashModel)
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = LongcatFlashConfig(num_layers=4, vocab_size=16384,
+                             experts_held=16, dtype="bfloat16")
+    shapes = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda p: p._value, LongcatFlashModel(cfg)._tree))
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
+    i32, f32, bsz, maxb = jnp.int32, jnp.float32, 64, 4096 // 16
+    pool = sd((8, 16385, 16, 640), jnp.bfloat16)
+    args = {
+        "decode": (params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
+                   sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
+                   sd((bsz,), i32), sd((bsz,), i32)),
+        "prefill": (params, sd((1, 2048), i32), sd((), i32), (pool,),
+                    sd((maxb,), i32), sd((), f32), sd((), i32),
+                    sd((), i32)),
+    }[program]
+    fn = {"decode": mla.decode_step, "prefill": mla.prefill_step}[program]
+    mem = jax.jit(
+        functools.partial(fn, cfg=cfg, layers=LongcatFlashModel.mla_layers,
+                          block_size=16),
+        donate_argnums=(3,)).lower(*args).compile().memory_analysis()
+    one_pool = pool.size * pool.dtype.itemsize
+    one_layers_experts = 16 * 6144 * 6144 * 2
+    gib = 2 ** 30
+    assert mem.alias_size_in_bytes >= one_pool
+    limit = min(one_pool, one_layers_experts) if program == "decode" \
+        else one_pool
+    assert mem.temp_size_in_bytes < limit, (
+        f"{mem.temp_size_in_bytes / gib:.2f} GiB of temporaries")
+    assert 12.0 * gib < mem.argument_size_in_bytes < 12.3 * gib
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * gib
