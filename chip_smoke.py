@@ -41,6 +41,8 @@ import sys
 import time
 
 SEED = 0
+# what the latent-attention (MLA) runner stores of a token: 576 values in 640
+LATENT_ROW = 640
 
 # GPT-2 345M (bench.py's gpt2_345m / serving configs)
 WIDTH = dict(vocab_size=50304, hidden_size=1024, num_layers=24, num_heads=16,
@@ -475,6 +477,48 @@ def k_paged_verify(g, interpret):
     return f"B{b} T{t} H{h} D{d} block {g['block']} bf16, max err {err:.3g}"
 
 
+def k_paged_latent(g, interpret):
+    """GLM-4.7-Flash's attention widths over one pool of 640-value
+    rows: the absorbed form through the block tables against the
+    same over a dense gather."""
+    import types
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.text.models import mla
+
+    cfg = types.SimpleNamespace(
+        num_heads=20, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256)
+    b, bs = g["serve_b"], g["block"]
+    maxb = g["max_seq"] // bs
+    rng = np.random.RandomState(SEED)
+
+    def draw(*shape, scale):
+        return jnp.asarray(rng.randn(*shape) * scale, jnp.bfloat16)
+
+    pool = draw(b * maxb + 1, bs, LATENT_ROW, scale=0.5)
+    q_nope = draw(b, cfg.num_heads, cfg.qk_nope_head_dim, scale=0.3)
+    q_rope = draw(b, cfg.num_heads, cfg.qk_rope_head_dim, scale=0.3)
+    ap = {"wkv_b": draw(cfg.kv_lora_rank, cfg.num_heads * (
+        cfg.qk_nope_head_dim + cfg.v_head_dim), scale=0.05)}
+    lens = np.linspace(1, g["max_seq"], b).astype(np.int32)
+    tables = np.zeros((b, maxb), np.int32)
+    for i in range(b):
+        used = -(-int(lens[i]) // bs)
+        tables[i, :used] = 1 + i * maxb + np.arange(used)
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+    got = jax.jit(lambda *a: mla.mla_attend_paged(
+        *a, ap, cfg, interpret=interpret))(q_nope, q_rope, pool, tables,
+                                           lens)
+    want = mla.mla_attend_absorbed(
+        q_nope, q_rope, pool[tables].reshape(b, -1, LATENT_ROW), lens, ap,
+        cfg)
+    err = _close("paged latent", got, want, 2e-2)
+    return f"B{b} H{cfg.num_heads} row {LATENT_ROW} block {bs} bf16, " \
+           f"max err {err:.3g}"
+
+
 def k_int8(g, interpret):
     import numpy as np
     import jax.numpy as jnp
@@ -520,6 +564,9 @@ def _kernel_table():
         ("paged_attention_multi",
          lambda g: pallas.paged_attention.paged_decode_supported(
              g["heads"], g["head_dim"], g["block"]), k_paged_verify),
+        ("paged_latent_attention",
+         lambda g: pallas.paged_attention.paged_decode_supported(
+             1, LATENT_ROW, g["block"]), k_paged_latent),
         ("int8_block_quant", lambda g: qk._use_pallas("int8", DEFAULT_BLOCK), k_int8),
     ]
 

@@ -1,8 +1,10 @@
-"""Pallas TPU ragged paged-attention decode kernel.
+"""Pallas TPU ragged paged-attention decode kernels: one for pools
+of per-head keys and values (GPT-2's runner), one for a pool of
+latent rows (the MLA runner's; the last section of this text).
 
-The serving-side sibling of `attention_pallas.py` (PR 8): that kernel
-streams contiguous K/V tiles for TRAINING-shaped batches; this one
-reads K/V through per-request BLOCK TABLES out of the paged pools
+The serving-side siblings of `attention_pallas.py` (PR 8): that kernel
+streams contiguous K/V tiles for TRAINING-shaped batches; these
+read K/V through per-request BLOCK TABLES out of the paged pools
 (`inference.serving.kv_cache`), so ONE launch covers every sequence
 in a continuous-batching decode step at mixed context lengths — the
 Ragged Paged Attention design (PAPERS.md arxiv 2604.15464).
@@ -65,9 +67,36 @@ others:
 so there is no per-head selector product and no rounding but the
 reference's own. An f32 pool is multiplied at `Precision.HIGHEST`.
 
-`interpret=True` runs the same kernel through the Pallas interpreter
+Latent rows (`paged_latent_attention`, PR 33). What an MLA model
+caches of a token is ONE row an attention, `[c_kv | k_rope]` (576
+values stored in 640), and in the absorbed form that row is the key
+AND the value of every head: multi-query attention with one shared
+head. The same walk (grid (B,), the pool in `pl.ANY`, tables and
+lengths as scalar prefetch, live groups only, two tiles deep, the
+next sequence's first group started from the last of this one:
+`_first_copies`, `_next_copies`, `_softmax_step` are shared) over
+other operands:
+
+    q      [Hp, row]   the absorbed query `q_nope W^K | q_rope`, zeros
+                       to the stored width, H padded to the dtype's
+                       sublane tile (20 -> 32 in bf16)
+    tile   [R, row]    a group's pages, copied ONCE: both operands
+    s      [Hp, R]   = q . tile^T
+    acc    [Hp, row] += p . tile     the first `kv_lora_rank` lanes of
+                       acc / l are o_lat, which W^V expands outside
+
+There is no V pool and no head selector. A row is 1280 B and a page
+20 KB where GPT-2's is 64 KB a pool, so the rows a group come from
+the row's bytes (`_latent_pages_per_group`: 512 rows of 640 bf16
+values, where the K/V kernel takes 128) and the copies of a live
+group are issued whole, with no branch a page and ONE wait: the
+table's NULL and padded columns name real blocks, and the rows past
+the context are masked in the scores and zeroed as values.
+
+`interpret=True` runs the same kernels through the Pallas interpreter
 for CPU parity tests (the PR-8 contract; see
-`paged_attention_reference` for the dense gather it must match).
+`paged_attention_reference` for the dense gather it must match, and
+`text.models.mla.mla_attend_absorbed` for the latent kernel's).
 """
 from __future__ import annotations
 
@@ -80,7 +109,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_multi", "paged_attention_multi_reference",
-           "paged_decode_supported"]
+           "paged_latent_attention", "paged_decode_supported"]
 
 _NEG_INF = -1e30
 
@@ -93,7 +122,8 @@ def paged_decode_supported(num_heads, head_dim, block_size):
     no live multi-device mesh (GSPMD cannot partition a Mosaic call,
     and this one has no shard_map island), and the shape: a page is
     one [BS, H*D] tile, so H*D must fill whole 128-lane rows and BS
-    whole sublane groups."""
+    whole sublane groups. (The latent kernel's page is one [BS, row]
+    tile: its runner asks with one head of `row` lanes.)"""
     from . import _on_tpu, _partitioned, interpret_mode
 
     if not _on_tpu():
@@ -112,6 +142,64 @@ def _pages_per_group(block_size):
     together, from the shape: enough for a 128-row tile (the MXU's
     height, and 1 MB of f32 keys and values at H*D = 1024)."""
     return max(1, 128 // block_size)
+
+
+def _first_copies(b, last, groups, start, parity_ref):
+    """The opening of a grid step, for both kernels. `parity_ref`
+    holds the parity of the groups walked so far: `base`, the tile
+    (of two) this sequence's first group lands in. That group is on
+    its way already where the sequence before had a last group to
+    start it from; else its copies start here. Returns (base, this
+    sequence's groups, the next sequence)."""
+    @pl.when(b == 0)
+    def _first():
+        parity_ref[0] = 0
+
+    base = parity_ref[0]
+    n_groups = groups(b)
+    before = jnp.maximum(b - 1, 0)
+    after = jnp.minimum(b + 1, last)
+
+    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
+    def _own_first():
+        start(b, 0, base)
+
+    return base, n_groups, after
+
+
+def _next_copies(b, last, j, base, n_groups, after, groups, start):
+    """Inside group j: the copies of group j + 1 (from a sequence's
+    last group, of the next sequence's first) start into the other
+    tile before this one is waited for. Returns this group's tile."""
+    slot = (base + j) % 2
+
+    @pl.when(j + 1 < n_groups)
+    def _next_group():
+        start(b, j + 1, 1 - slot)
+
+    @pl.when((j + 1 == n_groups) & (b < last) & (groups(after) > 0))
+    def _next_sequence():
+        start(after, 0, 1 - slot)
+
+    return slot
+
+
+def _softmax_step(s, v, exact, acc_ref, m_ref, l_ref):
+    """One group of the online softmax: scores s [rows, R] (masked
+    to -inf past the context: p underflows to an exact zero)
+    against values v [R, lanes]. Operands stay in the pool dtype,
+    statistics f32 (the PR-8 rule): p is rounded to the pool dtype
+    exactly as the reference rounds it before its PV product."""
+    m_prev = m_ref[...]                                      # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
+                                              keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        p.astype(v.dtype), v, precision=exact,
+        preferred_element_type=jnp.float32)
 
 
 def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
@@ -160,21 +248,8 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
     def wait(i, j, slot):
         page_copies(i, j, slot, lambda copy: copy.wait())
 
-    @pl.when(b == 0)
-    def _first():
-        parity_ref[0] = 0
-
-    base = parity_ref[0]
-    n_groups = groups(b)
-    before = jnp.maximum(b - 1, 0)
-    after = jnp.minimum(b + 1, last)
-
-    # group 0 is on its way already where the sequence before had a
-    # last group to start it from
-    @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
-    def _own_first():
-        start(b, 0, base)
-
+    base, n_groups, after = _first_copies(b, last, groups, start,
+                                          parity_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -199,16 +274,8 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         if k_buf.dtype == jnp.float32 else None
 
     def group(j, carry):
-        slot = (base + j) % 2
-
-        @pl.when(j + 1 < n_groups)
-        def _next_group():
-            start(b, j + 1, 1 - slot)
-
-        @pl.when((j + 1 == n_groups) & (b < last) & (groups(after) > 0))
-        def _next_sequence():
-            start(after, 0, 1 - slot)
-
+        slot = _next_copies(b, last, j, base, n_groups, after, groups,
+                            start)
         wait(b, j, slot)
         k = k_buf[slot]                                      # [R, H*D]
         # rows no copy wrote hold what the tile held before: masked
@@ -225,20 +292,9 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         # positions past the context (for the shallower slots, past
         # theirs) mask to -inf: p underflows to an exact zero
         s = jnp.where(k_pos < ctx, s, _NEG_INF)
-        m_prev = m_ref[...]                                  # [T*H, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-        # operands stay in the pool dtype, statistics f32 (the PR-8
-        # rule): p is rounded to the pool dtype exactly as the
-        # reference rounds it before its PV product. Every row meets
-        # every head's lanes here; the end keeps its own
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, precision=exact,
-            preferred_element_type=jnp.float32)
+        # every row meets every head's lanes here; the end keeps
+        # its own
+        _softmax_step(s, v, exact, acc_ref, m_ref, l_ref)
         return carry
 
     jax.lax.fori_loop(0, n_groups, group, 0)
@@ -249,6 +305,17 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         o_ref[0, t:t + 1] = jnp.sum(
             o[t * heads:(t + 1) * heads] * sel, axis=0,
             keepdims=True).astype(o_ref.dtype)
+
+
+def _whole_groups(block_tables, pages):
+    """The tables as int32, as wide as whole groups of `pages`: a
+    width G does not divide gets its last column again, at positions
+    no context reaches."""
+    tables = jnp.asarray(block_tables, jnp.int32)
+    short = -tables.shape[1] % pages
+    if short:
+        tables = jnp.pad(tables, ((0, 0), (0, short)), mode="edge")
+    return tables
 
 
 def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
@@ -262,12 +329,7 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
         raise ValueError(f"pool heads/dim {(hk, dk)} != query {(h, d)}")
     hd = h * d
     pages = _pages_per_group(bs)
-    tables = jnp.asarray(block_tables, jnp.int32)
-    short = -tables.shape[1] % pages
-    if short:
-        # a table width G does not divide: the last column again, at
-        # positions no context reaches
-        tables = jnp.pad(tables, ((0, 0), (0, short)), mode="edge")
+    tables = _whole_groups(block_tables, pages)
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
         num_q=t)
@@ -377,3 +439,134 @@ def paged_attention_multi_reference(q, k_pool, v_pool, block_tables,
     s = jnp.where(mask[:, :, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bths,bshd->bthd", p, seq_v)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) rows: one pool, one shared head, keys that are the values
+# ---------------------------------------------------------------------------
+
+_LATENT_TILE_BYTES = 640 * 1024
+
+
+def _latent_pages_per_group(block_size, row_bytes):
+    """The pages of one sequence that one tile holds, from the shape:
+    a latent row is short (1280 B at 640 bf16 values, a page 20 KB
+    where GPT-2's is 64 KB a pool), so a 128-row group would spend
+    its time on the fixed cost of a group, not on bytes. The tile is
+    the largest power of two of rows within `_LATENT_TILE_BYTES`,
+    between 128 and 1024 rows (PERF.md, PR 33: the sweep), of at
+    most 64 pages (each is a copy in the body's text)."""
+    rows = max(128, min(1024, _LATENT_TILE_BYTES // row_bytes))
+    rows = 1 << (rows.bit_length() - 1)
+    return max(1, min(64, rows // block_size))
+
+
+def _latent_kernel(tables_ref, lens_ref, q_ref, pool_hbm, o_ref, buf,
+                   sems, acc_ref, m_ref, l_ref, parity_ref, *, sm_scale,
+                   block_size, pages):
+    """One grid step: ONE sequence, its H query rows [Hp, row] (the
+    absorbed query, `q_lat | q_rope`, zeros to the stored width)
+    against its live page groups. A group's [R, row] tile is BOTH
+    operands: `s = q . tile^T`, `acc += p . tile`, so one copy of a
+    page serves scores and values and every head reads the whole
+    row. Every page of a live group is copied, the table's NULL and
+    padded columns too (whole tiles: no branch a page, ONE wait a
+    group), and the rows past the context are masked in the scores
+    and zeroed as values."""
+    b = pl.program_id(0)
+    last = pl.num_programs(0) - 1
+    rows = pages * block_size
+
+    def groups(i):
+        return (lens_ref[i] + (rows - 1)) // rows
+
+    def start(i, j, slot):
+        for g in range(pages):
+            pltpu.make_async_copy(
+                pool_hbm.at[tables_ref[i, j * pages + g]],
+                buf.at[slot, pl.ds(g * block_size, block_size)],
+                sems.at[slot]).start()
+
+    def wait(slot):
+        # the semaphore counts what arrived: one wait for the tile
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sems.at[slot]).wait()
+
+    base, n_groups, after = _first_copies(b, last, groups, start,
+                                          parity_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    ctx = lens_ref[b]
+    q = q_ref[0]                                             # [Hp, row]
+    exact = jax.lax.Precision.HIGHEST \
+        if buf.dtype == jnp.float32 else None
+
+    def group(j, carry):
+        slot = _next_copies(b, last, j, base, n_groups, after, groups,
+                            start)
+        wait(slot)
+        tile = buf[slot]                                     # [R, row]
+        live = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) < ctx
+        s = jax.lax.dot_general(
+            q, tile, (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32) * sm_scale   # [Hp, R]
+        k_pos = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos < ctx, s, _NEG_INF)
+        _softmax_step(s, jnp.where(live, tile, 0), exact, acc_ref, m_ref,
+                      l_ref)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    parity_ref[0] = (base + n_groups) % 2
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def paged_latent_attention(q, pool, block_tables, context_lens,
+                           sm_scale=1.0, interpret=False):
+    """Absorbed latent attention through the block tables: q
+    [B, H, row] (one query token a sequence, in the pool's row
+    width), pool [N, BS, row] whose rows are key and value to every
+    head -> softmax(q . rows^T * sm_scale) . rows, [B, H, row] in
+    q's dtype: the caller keeps the lanes that are values (the
+    first `kv_lora_rank`). The heads are padded to the dtype's
+    sublane tile (20 -> 32 in bf16)."""
+    b, h, row = q.shape
+    _, bs, row_p = pool.shape
+    if row_p != row:
+        raise ValueError(f"pool row {row_p} != query row {row}")
+    q = q.astype(pool.dtype)
+    tile = 32 // pool.dtype.itemsize
+    hp = -(-h // tile) * tile
+    q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
+    pages = _latent_pages_per_group(bs, row * pool.dtype.itemsize)
+    kernel = functools.partial(
+        _latent_kernel, sm_scale=sm_scale, block_size=bs, pages=pages)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hp, row), lambda i, bt, cl: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, hp, row), lambda i, bt, cl: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, row), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((hp, row), jnp.float32),
+            pltpu.VMEM((hp, 1), jnp.float32),
+            pltpu.VMEM((hp, 1), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, hp, row), q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(_whole_groups(block_tables, pages),
+      jnp.asarray(context_lens, jnp.int32), q, pool)
+    return out[:, :h]

@@ -16,8 +16,9 @@ the Gemma-on-Cloud-TPU serving comparison (arxiv 2605.25645):
                     prefill + paged decode programs, per-request
                     in-program sampling
   * `mla_runner`    the second runner: latent attention (MLA) over
-                    one pool, sigmoid-routed experts
-                    (`text/models/glm4_moe_lite.py`)
+                    one pool, read through the block tables by the
+                    Pallas latent kernel on a TPU
+                    (`text/models/glm4_moe_lite.py`, `longcat_flash.py`)
   * `engine`        `LLMEngine.generate()` / `add_request()`
                     streaming front end, donated decode step through
                     the persistent compile cache; ISSUE-13 lifecycle
@@ -26,8 +27,9 @@ the Gemma-on-Cloud-TPU serving comparison (arxiv 2605.25645):
                     least-loaded routing, deterministic token-exact
                     failover (ISSUE 13)
 
-The ragged paged-attention decode kernel itself lives with its PR-8
-siblings in `incubate.nn.pallas.paged_attention`.
+The ragged paged-attention decode kernels themselves (K/V pools;
+latent rows) live with their PR-8 siblings in
+`incubate.nn.pallas.paged_attention`.
 """
 from __future__ import annotations
 

@@ -9,7 +9,13 @@ per-head keys, no V pool. `prefill_step` attends densely over the
 prompt (keys and values expanded through W_kvb, as in training) and
 scatters every position's row through the block table; `decode_step`
 writes the new token's row and attends in the ABSORBED form, reading
-nothing but the rows (`mla_attend_absorbed`).
+nothing but the rows: THROUGH THE BLOCK TABLES in one Pallas launch
+an attention where `MLARunner.kernel_supported` says so (a TPU; each
+live page copied once, key and value to every head:
+`mla_attend_paged`, `pallas.paged_attention.paged_latent_attention`),
+else over a dense gather of every sequence's whole table
+(`mla_attend_absorbed`: the CPU's path, and the reference the kernel
+is tested against).
 
 What a layer IS the runner reads from the model and names no model:
 `model.mla_layers(params, x, carry, attend, live, cfg)` runs the
@@ -24,7 +30,8 @@ training mathematics, and there is one prefill and one decode.
 The pool follows `model_runner._scan_layers_paged`'s rule: `[A, N,
 BS, row]` in the layer scans' carry, donated, scattered into at
 `(a, blk, off)`, read as `[A*N, BS, row]` with the block tables
-shifted by `a * N`; never sliced by attention or stacked.
+shifted by `a * N`; never sliced by attention or stacked (the kernel
+reads it so too: the pool stays where it is).
 
 Beside the tokens both programs return the model's routing counts
 (`moe_counts` `[expert layers, experts held]`, the live tokens each
@@ -102,12 +109,12 @@ def decode_step(params, ids, positions, pools, block_tables,
     positions [B]; `context_lens[b] == positions[b] + 1`. Each
     attention writes this token's latent row at (tables[b, pos //
     BS], pos % BS) BEFORE attending, then attends in the absorbed
-    form over the rows its table names. Inactive slots (table all
-    NULL) ride along and are left out of the routing counts. Returns
-    (tokens [B], (pool,), the model's routing counts)."""
-    if use_kernel:
-        raise NotImplementedError(
-            "no paged latent-attention kernel yet: use_kernel=False")
+    form over the rows its table names: with `use_kernel` through
+    the tables in the Pallas latent kernel (`interpret`: under the
+    interpreter, the CPU's parity tests), else over a dense gather.
+    Inactive slots (table all NULL) ride along and are left out of
+    the routing counts. Returns (tokens [B], (pool,), the model's
+    routing counts)."""
     (pool,) = pools
     n_layers, n_blocks = pool.shape[:2]
     flat = (n_layers * n_blocks,) + pool.shape[2:]
@@ -121,9 +128,17 @@ def decode_step(params, ids, positions, pools, block_tables,
         row = _widen(_mla.mla_latent(u, ap, cfg, positions),
                      pool.shape[-1])
         pool = pool.at[layer, blk, off].set(row.astype(pool.dtype))
+        # the whole pool as one run of blocks, this attention's at
+        # `layer * n_blocks`: never sliced, never stacked
+        rows = pool.reshape(flat)
+        tables = block_tables + layer * n_blocks
+        if use_kernel:
+            return (_mla.mla_attend_paged(
+                q_nope, q_rope, rows, tables, context_lens, ap, cfg,
+                interpret=interpret), pool, None)
         # whole blocks as they lie, in table order (indexing clamps:
         # no out-of-bounds fill pass over the gathered rows)
-        ctx = pool.reshape(flat)[block_tables + layer * n_blocks]
+        ctx = rows[tables]
         ctx = ctx.reshape(bsz, -1, ctx.shape[-1])        # [B, T, row]
         return (_mla.mla_attend_absorbed(q_nope, q_rope, ctx,
                                          context_lens, ap, cfg),
@@ -139,7 +154,8 @@ def decode_step(params, ids, positions, pools, block_tables,
 
 class MLARunner:
     """How LLMEngine serves a model with `mla_layers`: one pool of
-    `latent_row` values a token an attention; prefill and decode, no
+    `latent_row` values a token an attention; prefill and decode
+    (through the paged latent kernel where `kernel_supported`), no
     verify, tail or draft."""
 
     verify_step = prefill_tail_step = draft_params = None
@@ -161,4 +177,12 @@ class MLARunner:
         self.decode_step = functools.partial(decode_step, **kw)
 
     def kernel_supported(self, block_size):
-        return False
+        """Does decode attend through the Pallas latent kernel here?
+        `paged_decode_supported`'s answer (a TPU, or the interpreter
+        on the CPU; no live multi-device mesh; whole 128-lane rows,
+        whole sublane groups of a block) for ONE shared head as wide
+        as the stored row."""
+        from ...incubate.nn.pallas import paged_attention as _pa
+
+        return _pa.paged_decode_supported(1, self.pool_rows[0],
+                                          block_size)

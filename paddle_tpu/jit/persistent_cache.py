@@ -10,20 +10,26 @@ compile on an accelerator backend (`jit.program.arm_compile_cache`, by
 `jit.Program` and `LLMEngine`), so a second process starts warm with
 no option to set; an entry point that wants it on the CPU
 (`chip_smoke.py --preflight`, a test) calls `arm_native()` itself.
+A program that holds a Pallas kernel carries the kernel's source
+locations in its text, hence in its key: `arm_native()` has JAX strip
+the checkout's root from them (`jax_hlo_source_file_canonicalization_
+regex`), so a checkout unpacked at another path finds the entries it
+wrote at the first (PERF.md, PR 33).
 Counters: jit/native_cache/{requests,hits}; `native_cache_stats()`
 reads them.
 """
 from __future__ import annotations
 
 import os
+import re
 
 from ..core import monitor as _monitor
 
 __all__ = ["native_cache_dir", "arm_native", "native_cache_stats"]
 
-_CHECKOUT_CACHE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_CHECKOUT_CACHE = os.path.join(_CHECKOUT, ".jax_cache")
 
 _native_armed = False
 
@@ -44,7 +50,9 @@ def arm_native():
     """Arm JAX's persistent compilation cache at native_cache_dir()
     (idempotent) and return that directory. Every program is cached,
     however quick its compile: an eager run is hundreds of sub-second
-    programs whose sum is what a warm start saves."""
+    programs whose sum is what a warm start saves. Call it before the
+    first lowering: the keys of what was lowered earlier still hold
+    the checkout's path."""
     global _native_armed
     import jax
 
@@ -54,6 +62,10 @@ def arm_native():
             jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
+        # file names in a lowered module are relative to the checkout:
+        # the same program has the same key wherever the tree lies
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(_CHECKOUT + os.sep))
         jax.monitoring.register_event_listener(_on_jax_event)
         _native_armed = True
     return d

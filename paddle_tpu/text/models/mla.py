@@ -20,7 +20,8 @@ What a cache has to hold of a token is `[c_kv | k_rope]` alone
 inside): `mla_latent` makes that row, `mla_attend_dense` expands it
 through `W_kvb` (prefill, training), `mla_attend_absorbed` folds
 `W_kvb` into the query and the output and reads nothing but the rows
-(decode).
+(decode; `mla_attend_paged` is the same through a paged pool's block
+tables, in a Pallas kernel).
 
 Assumed where the configs are silent: the rotary pairing is
 half-split (dims `i` and `i + rope/2` rotate together); with seeded
@@ -39,7 +40,8 @@ from ...nn.layer.layers import Layer
 from ...ops import random as _random
 
 __all__ = ["rms_norm", "rotate", "swiglu", "mla_query", "mla_latent",
-           "mla_attend_dense", "mla_attend_absorbed", "attention_block",
+           "mla_attend_dense", "mla_attend_absorbed", "mla_attend_paged",
+           "attention_block",
            "SeededTree"]
 
 
@@ -125,6 +127,22 @@ def mla_attend_dense(q_nope, q_rope, latent, ap, cfg):
     return out.reshape(s, -1)
 
 
+def _absorbed_query(q_nope, q_rope, wk, width):
+    """`[q_nope W^K^T | q_rope]` [B, H, width]: the query in the
+    cached rows' own space. A cache may store its rows wider than
+    they are (zero-padded to a multiple of the device's lanes):
+    zeros against zeros."""
+    q_lat = jnp.einsum("bhd,chd->bhc", q_nope, wk)
+    q = jnp.concatenate([q_lat, q_rope], -1)
+    return jnp.pad(q, ((0, 0), (0, 0), (0, width - q.shape[-1])))
+
+
+def _expanded(o_lat, wv):
+    """`o_lat W^V`, heads concatenated: [B, H, rank] -> [B, H * v]."""
+    out = jnp.einsum("bhc,chd->bhd", o_lat, wv)
+    return out.reshape(out.shape[0], -1)
+
+
 def mla_attend_absorbed(q_nope, q_rope, ctx, lens, ap, cfg):
     """Attention of one query token a sequence over cached latent
     rows, W_kvb absorbed: `q_lat = q_nope W^K^T`, scores over the
@@ -132,11 +150,7 @@ def mla_attend_absorbed(q_nope, q_rope, ctx, lens, ap, cfg):
     [B, T, row] (positions >= lens[b] masked) -> [B, H * v_head_dim].
     The same mathematics as `mla_attend_dense`."""
     wk, wv = _wkv_b(ap, cfg)
-    q_lat = jnp.einsum("bhd,chd->bhc", q_nope, wk)
-    q = jnp.concatenate([q_lat, q_rope], -1)            # [B, H, row]
-    # a cache may store its rows wider than they are (zero-padded to
-    # a multiple of the device's lanes): zeros against zeros
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, ctx.shape[-1] - q.shape[-1])))
+    q = _absorbed_query(q_nope, q_rope, wk, ctx.shape[-1])
     scores = jnp.einsum("bhr,btr->bht", q, ctx,
                         preferred_element_type=jnp.float32)
     live = jnp.arange(ctx.shape[1])[None, :] < lens[:, None]
@@ -145,8 +159,25 @@ def mla_attend_absorbed(q_nope, q_rope, ctx, lens, ap, cfg):
         axis=-1)
     o_lat = jnp.einsum("bht,btr->bhr", probs.astype(ctx.dtype),
                        ctx)[..., :cfg.kv_lora_rank]
-    out = jnp.einsum("bhc,chd->bhd", o_lat, wv)
-    return out.reshape(out.shape[0], -1)
+    return _expanded(o_lat, wv)
+
+
+def mla_attend_paged(q_nope, q_rope, pool, block_tables, lens, ap, cfg,
+                     interpret=False):
+    """`mla_attend_absorbed` THROUGH the block tables: pool
+    [N, BS, row], `block_tables [B, MAXB]` naming each sequence's
+    pages in it. The Pallas kernel reads the live rows once, each
+    page both as keys and as values (`paged_latent_attention`);
+    absorbing W^K and expanding through W^V stay here."""
+    from ...incubate.nn.pallas.paged_attention import \
+        paged_latent_attention
+
+    wk, wv = _wkv_b(ap, cfg)
+    q = _absorbed_query(q_nope, q_rope, wk, pool.shape[-1])
+    o = paged_latent_attention(q, pool, block_tables, lens,
+                               sm_scale=_sm_scale(cfg),
+                               interpret=interpret)
+    return _expanded(o[..., :cfg.kv_lora_rank], wv)
 
 
 def attention_block(x, carry, ap, a, attend, eps):

@@ -240,3 +240,79 @@ def test_bench_main_fails_when_a_config_raised():
     assert "gpt2_345m FAILED: kernel refused to compile" in out.stderr
     assert '"error": "RuntimeError: kernel refused to compile"' \
         in out.stdout
+
+
+# -- a program's key does not hold the checkout's path (ISSUE 33) -------------
+
+_LOWER_KERNEL_PROGRAMS = """
+import functools, hashlib, sys
+sys.path[:0] = [sys.argv[1], sys.argv[2]]
+import jax, jax.numpy as jnp
+from paddle_tpu.jit import persistent_cache
+persistent_cache.arm_native()
+if sys.argv[3] == "cleared":
+    jax.config.update("jax_hlo_source_file_canonicalization_regex", None)
+import paddle_tpu, test_serving_tpu_compile as shapes
+assert paddle_tpu.__file__.startswith(sys.argv[1]), paddle_tpu.__file__
+
+def sd(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+_, programs, kw = shapes._mla_programs(sd)
+mla = programs["decode"] + ((3,), kw)
+fn, donated, args, _, _ = shapes._cell_program(sd, "decode", jnp.float32)
+for name, (fn, args, donated, kw) in (
+        ("mla_decode", mla),
+        ("gpt2_paged_decode", (fn, args, donated, shapes.KW))):
+    text = jax.jit(functools.partial(fn, use_kernel=True, **kw),
+                   donate_argnums=donated).trace(*args).lower(
+                       lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    print("DIGEST", name, hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+@pytest.fixture(scope="module")
+def kernel_program_digests(tmp_path_factory):
+    """{(copy, regex): {program: digest of its text}}: the MLA decode
+    and GPT-2's paged decode at their cells' widths, lowered for the
+    TPU (cross-platform: no libtpu, no topology) in a process of
+    its own from each of two copies of the package at different
+    paths, armed by `arm_native()`, with its regex kept and
+    cleared."""
+    import shutil
+
+    tmp = tmp_path_factory.mktemp("checkouts")
+    tests = os.path.join(REPO, "tests")
+    roots = [tmp / "a", tmp / "another" / "place"]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp / "jaxcache"))
+    out = {}
+    for copy, root in enumerate(roots):
+        shutil.copytree(os.path.join(REPO, "paddle_tpu"),
+                        root / "paddle_tpu",
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        for regex in ("kept", "cleared"):
+            run = subprocess.run(
+                [sys.executable, "-c", _LOWER_KERNEL_PROGRAMS, str(root),
+                 tests, regex], env=env, capture_output=True, text=True,
+                timeout=300, cwd=str(root))
+            assert run.returncode == 0, run.stderr[-2000:]
+            out[copy, regex] = dict(
+                ln.split()[1:] for ln in run.stdout.splitlines()
+                if ln.startswith("DIGEST"))
+    return out
+
+
+@pytest.mark.parametrize("program", ["mla_decode", "gpt2_paged_decode"])
+def test_a_kernel_programs_text_does_not_hold_the_checkouts_path(
+        kernel_program_digests, program):
+    """A program that holds a Pallas kernel carries the kernel's
+    source locations in its lowered text (the Mosaic module inside
+    the custom call), and the text is the persistent cache's key.
+    From two copies of the package it is ONE text: a checkout
+    unpacked elsewhere finds what it compiled. With the regex
+    cleared it is two, so this test sees what it guards."""
+    d = kernel_program_digests
+    assert d[0, "kept"][program] == d[1, "kept"][program]
+    assert d[0, "cleared"][program] != d[1, "cleared"][program]

@@ -291,13 +291,41 @@ def test_gpt2_runner_hands_over_the_same_programs():
 # -- (g) what the second runner does not have --------------------------------------------------
 
 @pytest.mark.parametrize("kw,what", [
-    (dict(spec_k=2), "spec_k"), (dict(prefix_cache=True), "prefix_cache"),
-    (dict(use_kernel=True), "kernel")])
+    (dict(spec_k=2), "spec_k"), (dict(prefix_cache=True), "prefix_cache")])
 def test_no_silent_fallback_for_missing_programs(toy, kw, what):
     _, model, _ = toy
     with pytest.raises(NotImplementedError, match=what):
         eng = _engine(model, **kw)
         eng.generate([[1, 2, 3]], SamplingParams(max_new_tokens=2))
+
+
+def test_decode_through_the_latent_kernel_emits_the_dense_tokens(
+        toy, monkeypatch):
+    """ISSUE 33: under the interpreter the engine takes the Pallas
+    latent kernel by itself, emits the dense engine's tokens and
+    counts every decode dispatch as paged; `use_kernel=False` stays
+    the dense path."""
+    from paddle_tpu.core.monitor import stat_get
+
+    _, model, _ = toy
+    prompts = [[3, 4, 5, 6, 7], [9, 8], list(range(1, 12))]
+    names = ("serve/attn/steps", "serve/attn/steps_paged")
+
+    def run(**kw):
+        before = [stat_get(n) for n in names]
+        eng = _engine(model, **kw)
+        out = eng.generate(prompts, SamplingParams(max_new_tokens=6))
+        return eng, out, [stat_get(n) - b for n, b in zip(names, before)]
+
+    monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
+    dense, want, (steps, paged) = run()
+    assert not dense.use_kernel and steps == 5 and paged == 0
+    monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    eng, got, counts = run()
+    assert eng.use_kernel and eng._kernel_interpret
+    assert got == want and counts == [5, 5]
+    eng, got, counts = run(use_kernel=False)
+    assert not eng.use_kernel and got == want and counts == [5, 0]
 
 
 def test_serving_max_seq_len_is_the_deployments(toy):

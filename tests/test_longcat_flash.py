@@ -198,6 +198,34 @@ def test_identity_picks_add_the_weighted_token():
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
+def test_decode_through_the_latent_kernel_emits_the_dense_tokens(
+        share, monkeypatch):
+    """ISSUE 33: the same decode program serves this model's two
+    attentions a layer through the Pallas latent kernel (4 attentions'
+    rows in one pool, the tables shifted to each): under the
+    interpreter the engine emits the dense engine's tokens and counts
+    every decode dispatch as paged."""
+    from paddle_tpu.core.monitor import stat_get
+
+    _, model, _ = share
+    prompts = [[3, 4, 5, 6, 7], list(range(1, 12))]
+    names = ("serve/attn/steps", "serve/attn/steps_paged")
+
+    def run():
+        before = [stat_get(n) for n in names]
+        eng = _engine(model)
+        out = eng.generate(prompts, SamplingParams(max_new_tokens=6))
+        return eng, out, [stat_get(n) - b for n, b in zip(names, before)]
+
+    monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
+    dense, want, counts = run()
+    assert not dense.use_kernel and counts == [5, 0]
+    monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    eng, got, counts = run()
+    assert eng.use_kernel and eng.cache.pools[0].shape[0] == 4
+    assert got == want and counts == [5, 5]
+
+
 # -- (d) the shares add up to the layer ----------------------------------------------
 
 def test_the_shares_add_up_to_the_uncut_layer(whole):

@@ -583,11 +583,62 @@ class TestPagedKernelSelection:
         finally:
             mesh_mod.set_mesh(before)
 
-    def test_latent_runner_has_no_kernel(self, monkeypatch):
+    @staticmethod
+    def _latent_runner(row):
+        """An `MLARunner` as far as `kernel_supported` reads it: the
+        stored row's width."""
         from paddle_tpu.inference.serving.mla_runner import MLARunner
 
+        runner = object.__new__(MLARunner)
+        runner.pool_rows = (row,)
+        return runner
+
+    def test_latent_runner_on_the_cpu_takes_the_interpreter_only(
+            self, monkeypatch):
+        runner = self._latent_runner(640)
+        assert runner.kernel_supported(16) is False
+        monkeypatch.setenv("PADDLE_PALLAS_FUSION", "1")
+        assert runner.kernel_supported(16) is False
+        monkeypatch.delenv("PADDLE_PALLAS_FUSION")
         monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
-        assert MLARunner.kernel_supported(None, 16) is False
+        assert runner.kernel_supported(16) is True
+        assert runner.kernel_supported(4) is True
+
+    @pytest.mark.parametrize("row,block,mesh_size,want", [
+        (640, 16, 1, True),         # both MLA cells: 576 stored in 640
+        (128, 8, 1, True),
+        (576, 16, 1, False),        # the row as it is: not whole lanes
+        (640, 4, 1, False),         # a block under one sublane group
+        (640, 16, 4, False),        # a live multi-device mesh
+    ])
+    def test_latent_runner_on_a_tpu_shape_and_mesh_decide(
+            self, monkeypatch, row, block, mesh_size, want):
+        import types
+
+        from paddle_tpu.distributed import mesh as mesh_mod
+        from paddle_tpu.incubate.nn import pallas
+
+        monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+        monkeypatch.setattr(
+            mesh_mod, "get_mesh",
+            lambda: types.SimpleNamespace(size=mesh_size))
+        assert self._latent_runner(row).kernel_supported(block) is want
+
+    def test_latent_runner_stores_whole_lane_rows(self):
+        """What `MLARunner` hands the predicate is the padded row:
+        a model's 40 values a token are stored in 128."""
+        from paddle_tpu.inference.serving import model_runner as mr
+        from paddle_tpu.text.models import glm4_moe_lite as glm
+
+        cfg = glm.Glm4MoeLiteConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=2, q_lora_rank=8, kv_lora_rank=32,
+            qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+            intermediate_size=32, moe_intermediate_size=16,
+            n_routed_experts=4, num_experts_per_tok=2,
+            max_position_embeddings=32)
+        runner = mr.runner_for(glm.Glm4MoeLiteForCausalLM(cfg))
+        assert cfg.latent_row == 40 and runner.pool_rows == (128,)
 
     def test_layernorm_and_optimizer_kernels_keep_their_switch(
             self, monkeypatch):

@@ -242,59 +242,19 @@ def test_tpu_kernel_program_gathers_no_context(one_chip, program,
             > 4 * mem.temp_size_in_bytes
 
 
-def test_latent_decode_never_sees_the_paged_predicate(one_chip,
-                                                      monkeypatch):
-    """GLM's runner has no kernel: `kernel_supported` answers False
-    without asking the predicate, and its decode program lowers to
-    the same text whether or not the paged kernel could even be
-    called."""
-    from paddle_tpu.incubate.nn.pallas import paged_attention as pa
-    from paddle_tpu.inference.serving.mla_runner import MLARunner
-
-    def sd(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    _, programs, kw = _mla_programs(sd)
-    fn, args = programs["decode"]
-
-    def lowered():
-        return jax.jit(functools.partial(fn, **kw),
-                       donate_argnums=(3,)).lower(*args).as_text()
-
-    want = lowered()
-
-    def refuse(*a, **k):
-        raise AssertionError("the latent runner asked for the kernel")
-
-    for name in ("paged_decode_supported", "_paged_call"):
-        monkeypatch.setattr(pa, name, refuse)
-    assert MLARunner.kernel_supported(None, 16) is False
-    assert lowered() == want
-
-
 # ---------------------------------------------------------------------------
 # ISSUE 31: the same runner's programs at LongCat-Flash's widths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_tpu_longcat_programs_fit_the_cell(one_chip, program):
+def _longcat_programs(sd):
     """Decode and the longest prefill of `serve-longcat-offline-decode`
-    as the cell runs them (hidden 6144, 64 heads, ranks 1536/512, the
+    as the cell runs them: hidden 6144, 64 heads, ranks 1536/512, the
     768-wide router, 16 held experts of 2048, 4 double layers = 8
     attentions' rows, batch 64, 4096 positions, 16385 blocks, the
-    vocabulary's slice), compiled for the v5e: the one pool is
-    aliased, the temporaries are smaller than the pool (decode's
-    also than one layer's held experts, 1.13 GiB: what slicing a
-    layer out of the stack would copy; the prefill's 2.1 GiB are 64
-    heads' scores over 2048 x 2048 and 24576 rows of assignments),
-    and arguments and temporaries together fit the chip's 15.75
-    GiB."""
+    vocabulary's slice."""
     from paddle_tpu.inference.serving import mla_runner as mla
     from paddle_tpu.text.models.longcat_flash import (LongcatFlashConfig,
                                                       LongcatFlashModel)
-
-    def sd(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     cfg = LongcatFlashConfig(num_layers=4, vocab_size=16384,
                              experts_held=16, dtype="bfloat16")
@@ -303,19 +263,34 @@ def test_tpu_longcat_programs_fit_the_cell(one_chip, program):
     params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
     i32, f32, bsz, maxb = jnp.int32, jnp.float32, 64, 4096 // 16
     pool = sd((8, 16385, 16, 640), jnp.bfloat16)
-    args = {
-        "decode": (params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
-                   sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
-                   sd((bsz,), i32), sd((bsz,), i32)),
-        "prefill": (params, sd((1, 2048), i32), sd((), i32), (pool,),
-                    sd((maxb,), i32), sd((), f32), sd((), i32),
-                    sd((), i32)),
-    }[program]
-    fn = {"decode": mla.decode_step, "prefill": mla.prefill_step}[program]
-    mem = jax.jit(
-        functools.partial(fn, cfg=cfg, layers=LongcatFlashModel.mla_layers,
-                          block_size=16),
-        donate_argnums=(3,)).lower(*args).compile().memory_analysis()
+    kw = dict(cfg=cfg, layers=LongcatFlashModel.mla_layers, block_size=16)
+    return pool, {
+        "decode": (mla.decode_step, (
+            params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
+            sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
+            sd((bsz,), i32), sd((bsz,), i32))),
+        "prefill": (mla.prefill_step, (
+            params, sd((1, 2048), i32), sd((), i32), (pool,),
+            sd((maxb,), i32), sd((), f32), sd((), i32), sd((), i32))),
+    }, kw
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_tpu_longcat_programs_fit_the_cell(one_chip, program):
+    """Decode (the dense path, the CPU's) and the longest prefill,
+    compiled for the v5e: the one pool is aliased, the temporaries
+    are smaller than the pool (decode's also than one layer's held
+    experts, 1.13 GiB: what slicing a layer out of the stack would
+    copy; the prefill's 2.1 GiB are 64 heads' scores over 2048 x
+    2048 and 24576 rows of assignments), and arguments and
+    temporaries together fit the chip's 15.75 GiB."""
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool, programs, kw = _longcat_programs(sd)
+    fn, args = programs[program]
+    mem = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
+        .lower(*args).compile().memory_analysis()
     one_pool = pool.size * pool.dtype.itemsize
     one_layers_experts = 16 * 6144 * 6144 * 2
     gib = 2 ** 30
@@ -327,3 +302,97 @@ def test_tpu_longcat_programs_fit_the_cell(one_chip, program):
     assert 12.0 * gib < mem.argument_size_in_bytes < 12.3 * gib
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 15.75 * gib
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: the latent decode attends through the block tables
+# ---------------------------------------------------------------------------
+
+def _digest(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+_LATENT = {"glm47f": _mla_programs, "longcat": _longcat_programs}
+# any form of 64 sequences' 4096 gathered rows of 640
+_LATENT_CONTEXT = r"\[(64,4096,640|64,256,16,640|16384,16,640)\]"
+
+
+@pytest.mark.parametrize("family", ["glm47f", "longcat"])
+def test_tpu_latent_kernel_program_gathers_no_context(one_chip, family):
+    """GLM-4.7-Flash's and LongCat-Flash's decode at the cells'
+    shapes (batch 64, table 256, rows of 640, 20 and 64 heads) with
+    the Pallas latent kernel as the attention, compiled for the v5e:
+    the pool is aliased, the temporaries are under 64 MiB (the dense
+    path: 0.31 GiB of gathered context a layer), the Mosaic call is
+    there and no op over a gathered context, and the program fits
+    the chip; the dense program does hold such ops, so the pattern
+    can see them."""
+    import re
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool, programs, kw = _LATENT[family](sd)
+    fn, args = programs["decode"]
+
+    def compiled(use_kernel):
+        return jax.jit(functools.partial(fn, use_kernel=use_kernel, **kw),
+                       donate_argnums=(3,)).lower(*args).compile()
+
+    kernel = compiled(True)
+    mem = kernel.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool.size * pool.dtype.itemsize
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, (
+        f"{mem.temp_size_in_bytes / 2 ** 20:.0f} MiB of temporaries")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    hlo = kernel.as_text()
+    assert "tpu_custom_call" in hlo
+    assert not re.search(_LATENT_CONTEXT, hlo), \
+        re.findall(_LATENT_CONTEXT + r".*", hlo)[:3]
+    dense = compiled(False)
+    assert re.search(_LATENT_CONTEXT, dense.as_text())
+    assert dense.memory_analysis().temp_size_in_bytes \
+        > 4 * mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("family,want", [
+    ("glm47f", "df3c6107807bfa41"), ("longcat", "f70e064758a4b937")])
+def test_tpu_latent_dense_program_is_the_parents(one_chip, family, want):
+    """`use_kernel=False` is the CPU's path and the reference the
+    kernel is tested against: its decode program lowers, for the v5e
+    at these shapes, to the text it lowered to before the kernel
+    came (fce49e0; a digest of that text, which holds no path)."""
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _, programs, kw = _LATENT[family](sd)
+    fn, args = programs["decode"]
+    text = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
+        .lower(*args).as_text()
+    assert "tpu_custom_call" not in text
+    assert _digest(text) == want
+
+
+@pytest.mark.parametrize("program,pool_dtype,want", [
+    ("decode", jnp.float32, "66cec4f467292521"),
+    ("decode", jnp.bfloat16, "501ff35cf8d196ca"),
+    ("verify", jnp.float32, "b92440df44b4b524"),
+    ("verify", jnp.bfloat16, "79f1544c60a784bf")],
+    ids=["decode-f32", "decode-bf16", "verify-f32", "verify-bf16"])
+def test_gpt2_kernel_programs_are_the_parents(program, pool_dtype, want):
+    """The second kernel shares `paged_attention.py` with GPT-2's:
+    GPT-2's decode and verify at the offline cell's shapes, with
+    their kernel, trace to the jaxpr they traced to before (fce49e0;
+    the kernel's body is in it; the lowered text also holds the
+    checkout's path and line numbers, the jaxpr does not)."""
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    fn, _, args, _, _ = _cell_program(sd, program, pool_dtype)
+    jaxpr = jax.make_jaxpr(
+        functools.partial(fn, use_kernel=True, **KW))(*args)
+    assert "pallas_call" in str(jaxpr)
+    assert _digest(str(jaxpr)) == want
