@@ -67,6 +67,16 @@ others:
 so there is no per-head selector product and no rounding but the
 reference's own. An f32 pool is multiplied at `Precision.HIGHEST`.
 
+Grouped K/V heads (PR 34): a pool may hold FEWER heads than the
+query has, `[N, BS, Hkv, D]` under `q [B, H, D]` with `H = G * Hkv`;
+query head `h` reads K/V head `h // G`. A page is then `[BS, Hkv*D]`
+(what a token leaves), and the G query heads of one K/V head are G
+more ROWS over the same lanes: the wrapper hands the kernel the
+query as `[T*G, Hkv*D]` (row `t*G + g` holds heads `g, G + g, ...`,
+one a K/V head's D lanes), the kernel spreads each row over `Hkv`
+rows exactly as it spreads a slot's, and every tile is read once
+for all G. `G = 1` is the kernel it was, to the letter.
+
 Latent rows (`paged_latent_attention`, PR 33). What an MLA model
 caches of a token is ONE row an attention, `[c_kv | k_rope]` (576
 values stored in 640), and in the absorbed form that row is the key
@@ -114,21 +124,27 @@ __all__ = ["paged_attention", "paged_attention_reference",
 _NEG_INF = -1e30
 
 
-def paged_decode_supported(num_heads, head_dim, block_size):
+def paged_decode_supported(num_heads, head_dim, block_size,
+                           num_kv_heads=None):
     """Do the decode and verify programs attend through the kernel
     here? Answered from what the code can observe, with no switch of
     its own: the platform (a TPU; on the CPU only the interpreter,
     PADDLE_PALLAS_INTERPRET=1, which takes any shape: parity tests),
     no live multi-device mesh (GSPMD cannot partition a Mosaic call,
     and this one has no shard_map island), and the shape: a page is
-    one [BS, H*D] tile, so H*D must fill whole 128-lane rows and BS
-    whole sublane groups. (The latent kernel's page is one [BS, row]
-    tile: its runner asks with one head of `row` lanes.)"""
+    one [BS, Hkv*D] tile (`num_kv_heads`, by default the query's
+    heads), so Hkv*D must fill whole 128-lane rows and BS whole
+    sublane groups, and the query heads whole groups of K/V heads.
+    (The latent kernel's page is one [BS, row] tile: its runner asks
+    with one head of `row` lanes.)"""
     from . import _on_tpu, _partitioned, interpret_mode
 
+    kv_heads = num_kv_heads or num_heads
+    if num_heads % kv_heads:
+        return False
     if not _on_tpu():
         return interpret_mode()
-    return not _partitioned() and (num_heads * head_dim) % 128 == 0 \
+    return not _partitioned() and (kv_heads * head_dim) % 128 == 0 \
         and block_size % 8 == 0
 
 
@@ -204,10 +220,14 @@ def _softmax_step(s, v, exact, acc_ref, m_ref, l_ref):
 
 def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
                   o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
-                  parity_ref, *, sm_scale, block_size, pages, num_q):
+                  parity_ref, *, sm_scale, block_size, pages, num_q,
+                  group=1):
     """One grid step: ONE sequence, its T query slots (decode is
     T = 1) against its live page groups, which the body walks itself.
-    Row t*H + h of every [T*H, ...] value is head h of slot t; the
+    Row t*H + h of every [T*H, ...] value is head h of slot t (with
+    `group` G query heads a K/V head, `sel` has the Hkv K/V heads'
+    rows and row (t*G + g)*Hkv + k is query head k*G + g of slot t:
+    H below reads G*Hkv); the
     scratch holds the online-softmax state of those rows across the
     groups, the two [R, H*D] tiles a pool's pages are copied into
     (group j + 1 on its way while group j is multiplied), and the
@@ -218,6 +238,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
     rows = pages * block_size
     sel = sel_ref[...]               # [H, H*D]
     heads = sel.shape[0]
+    q_rows = num_q * group           # rows of q: a slot's G a K/V head
 
     def seen(i):
         # tokens visible to the deepest slot of sequence i: pages
@@ -256,15 +277,15 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
     ctx = lens_ref[b]                # tokens visible to query slot 0
     if num_q > 1:                    # [T*H, 1]: slot t sees t more
         row = jax.lax.broadcasted_iota(
-            jnp.int32, (num_q * heads, 1), 0)
-        ctx = ctx + sum((row >= t * heads).astype(jnp.int32)
+            jnp.int32, (q_rows * heads, 1), 0)
+        ctx = ctx + sum((row >= t * group * heads).astype(jnp.int32)
                         for t in range(1, num_q))
     # head h's query on its own D lanes of row h, zeros on the others
     # (a 0/1 mask, exact in any dtype): ONE product over all H*D
     # lanes gives every head's scores. They run on the pool's dtype
     # (bf16-native MXU; an f32 pool keeps f32 scores)
     q = q_ref[0].astype(jnp.float32)                         # [T, H*D]
-    qx = jnp.concatenate([q[t:t + 1] * sel for t in range(num_q)],
+    qx = jnp.concatenate([q[t:t + 1] * sel for t in range(q_rows)],
                          axis=0).astype(k_buf.dtype)         # [T*H, H*D]
     # an f32 pool is multiplied as f32: left to itself Mosaic rounds
     # f32 operands to one bf16 pass (2e-3 of error on the v5e where
@@ -301,7 +322,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
     parity_ref[0] = (base + n_groups) % 2
 
     o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)        # [T*H, H*D]
-    for t in range(num_q):
+    for t in range(q_rows):
         o_ref[0, t:t + 1] = jnp.sum(
             o[t * heads:(t + 1) * heads] * sel, axis=0,
             keepdims=True).astype(o_ref.dtype)
@@ -323,16 +344,23 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
     """q [B, T, H, D] through the kernel: grid (B,), the tables and
     lengths as SCALAR PREFETCH arguments, the pools left where they
     are (`pl.ANY`): the body copies the pages it needs itself."""
-    b, t, h, d = q.shape
-    n, bs, hk, dk = k_pool.shape
-    if (hk, dk) != (h, d):
-        raise ValueError(f"pool heads/dim {(hk, dk)} != query {(h, d)}")
+    b, t, hq, d = q.shape
+    n, bs, h, dk = k_pool.shape
+    if dk != d or hq % h:
+        raise ValueError(f"pool heads/dim {(h, dk)} under query "
+                         f"{(hq, d)}")
     hd = h * d
     pages = _pages_per_group(bs)
     tables = _whole_groups(block_tables, pages)
+    group = hq // h
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
-        num_q=t)
+        num_q=t, group=group)
+    if group > 1:
+        # the G query heads of a K/V head as G rows a slot over the
+        # pool's own lanes: [B, T, Hkv, G, D] -> [B, T*G, Hkv*D]
+        q = q.reshape(b, t, h, group, d).swapaxes(2, 3)
+        t = t * group
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
@@ -361,6 +389,9 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
     )(tables, jnp.asarray(context_lens, jnp.int32),
       q.reshape(b, t, hd), _head_selector(h, d),
       k_pool.reshape(n, bs, hd), v_pool.reshape(n, bs, hd))
+    if group > 1:
+        return out.reshape(b, t // group, group, h, d).swapaxes(
+            2, 3).reshape(b, t // group, hq, d)
     return out.reshape(b, t, h, d)
 
 
@@ -390,6 +421,17 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
     paged decode step reproduces the full re-forward loop's tokens."""
     seq_k = _gather_context(k_pool, block_tables)
     seq_v = _gather_context(v_pool, block_tables)
+    if q.shape[1] != seq_k.shape[2]:
+        # grouped K/V heads: query head k*G + g reads K/V head k,
+        # the same softmax with the group as one more axis
+        b, hq, d = q.shape
+        qg = q.reshape(b, seq_k.shape[2], -1, d)
+        s = jnp.einsum("bkgd,bskd->bkgs", qg, seq_k,
+                       preferred_element_type=jnp.float32) * sm_scale
+        mask = jnp.arange(seq_k.shape[1])[None, :] < context_lens[:, None]
+        s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bkgs,bskd->bkgd", p, seq_v).reshape(b, hq, d)
     s = jnp.einsum("bhd,bshd->bhs", q, seq_k,
                    preferred_element_type=jnp.float32) * sm_scale
     mask = jnp.arange(seq_k.shape[1])[None, :] < context_lens[:, None]

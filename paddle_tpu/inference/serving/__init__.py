@@ -7,7 +7,8 @@ the Gemma-on-Cloud-TPU serving comparison (arxiv 2605.25645):
 
   * `kv_cache`      block-allocated paged KV cache: fixed-size blocks
                     in preallocated device pools, per-request block
-                    tables, alloc/free/defrag + admission control
+                    tables, alloc/free/defrag + admission control;
+                    per-slot state arrays behind the pools
   * `scheduler`     continuous batching: FIFO admit / youngest-first
                     evict / preempt between fused decode dispatches
   * `model_runner`  the RUNNER the engine reads of a model (params,
@@ -19,6 +20,10 @@ the Gemma-on-Cloud-TPU serving comparison (arxiv 2605.25645):
                     one pool, read through the block tables by the
                     Pallas latent kernel on a TPU
                     (`text/models/glm4_moe_lite.py`, `longcat_flash.py`)
+  * `state_runner`  the third runner: K/V pools with grouped heads
+                    and, beside them, a state of fixed size a
+                    sequence in per-slot arrays (short convolutions:
+                    `text/models/lfm2_moe.py`)
   * `engine`        `LLMEngine.generate()` / `add_request()`
                     streaming front end, donated decode step through
                     the persistent compile cache; ISSUE-13 lifecycle

@@ -7,9 +7,13 @@ decode step covering all `max_batch` slots — that together serve
 many concurrent mixed-length requests. What the engine knows of the
 model (its parameters, the cache's pools and row widths, the
 programs) it reads from the RUNNER the model's type selects
-(`model_runner.runner_for`: GPT-2, or `mla_runner` for a model
-with latent-attention layers); a runner without a verify or tail
-program makes `spec_k > 1` / `prefix_cache` raise at construction:
+(`model_runner.runner_for`: GPT-2, `mla_runner` for a model with
+latent-attention layers, `state_runner` for one that keeps a state
+of fixed size a sequence beside its keys and values: that state
+lives in per-SLOT arrays behind the paged pools, `cache.pools`
+carries both through every program, and a prefill is told the slot
+it fills); a runner without a verify or tail program makes
+`spec_k > 1` / `prefix_cache` raise at construction:
 
     engine = LLMEngine(model)
     engine.add_request([1, 2, 3], SamplingParams(max_new_tokens=8),
@@ -187,7 +191,7 @@ class LLMEngine:
                  static_batching=False, use_kernel=None,
                  max_queue=None, spec_k=None,
                  draft_layers=None, prefix_cache=None,
-                 max_seq_len=None):
+                 max_seq_len=None, run_ahead=False):
         arm_compile_cache()
         # everything the engine knows of the model it reads from the
         # runner its type selects (model_runner.runner_for)
@@ -230,7 +234,8 @@ class LLMEngine:
             block_size=block_size, num_blocks=num_blocks,
             pool_bytes=pool_bytes, dtype=dtype,
             draft_layers=self.draft_layers,
-            prefix_cache=self.prefix_cache)
+            prefix_cache=self.prefix_cache,
+            slot_state=runner.slot_state, max_batch=self.max_batch)
         self.block_size = self.cache.block_size
         # fixed table width: enough slots for a max-length sequence
         self.max_blocks_per_seq = math.ceil(
@@ -273,6 +278,17 @@ class LLMEngine:
         # (signature, device arrays): the next decode step's inputs,
         # prepared while the last one ran (_prepare_ahead)
         self._ahead = None
+        # run_ahead: hand the device the next decode dispatch before
+        # the last one's tokens are fetched, wherever the next step
+        # is certain to decode the same batch (_fetch_decode). Off
+        # unless asked for: one accepted benchmark cell ends with its
+        # backlog, and an engine a twentieth faster ends it before
+        # that cell's profiler starts (PERF.md section 7); the
+        # default turns with that cell's size.
+        self.run_ahead = bool(run_ahead)
+        # (signature, tokens, stats, compiled): the decode dispatch
+        # handed over so (_run_ahead); the next step fetches it
+        self._inflight = None
         # finished requests kept for result retrieval — bounded so a
         # long-lived replica's host memory doesn't grow with total
         # traffic (generate() releases its own as it returns)
@@ -364,7 +380,10 @@ class LLMEngine:
         `serve/decode/prepare`, `serve/decode` around
         `serve/decode/enqueue` and `serve/decode/fetch`,
         `serve/decode/emit`, and `serve/evict` where a dispatch ran
-        out of memory."""
+        out of memory. A step that finds its dispatch in flight
+        (`_run_ahead`) has no prepare, and its `serve/decode` holds
+        fetch (the next inputs), enqueue (the next dispatch), fetch
+        (the wait)."""
         emitted = {}
 
         def _on_admit(req):
@@ -389,7 +408,7 @@ class LLMEngine:
                     f"block(s) but the pool has only "
                     f"{self.cache.num_blocks - 1} usable — raise "
                     "PADDLE_SERVE_POOL_BYTES or num_blocks")
-        if self.scheduler.running:
+        if self.scheduler.running or self._inflight is not None:
             self._decode_batch(emitted)
         return emitted
 
@@ -463,6 +482,12 @@ class LLMEngine:
                                        self.max_blocks_per_seq)
         s = req.sampling
         prefill = self._programs("prefill", padded)
+        # a runner with per-slot state is told the slot this request
+        # will decode in: its prefill writes the state there
+        slot = ()
+        if self.cache.slot_state:
+            slot = (np.int32(req.slot),)
+            _cmon.stat_add("serve/state/slot_writes", 1)
         t0 = time.perf_counter()
         with _flight.in_flight("serve_prefill", req.req_id,
                                req=req.trace_id or req.req_id,
@@ -472,7 +497,7 @@ class LLMEngine:
                 self.params, jnp.asarray(ids), np.int32(plen),
                 self.cache.pools, jnp.asarray(table),
                 np.float32(s.temperature), np.int32(s.top_k),
-                np.uint32(_mr.seed_for(s.seed, plen)))()
+                np.uint32(_mr.seed_for(s.seed, plen)), *slot)()
             tok = int(tok)
             self._count_stats(stats)
             if self._draft_params is not None:
@@ -618,20 +643,25 @@ class LLMEngine:
 
         self._ahead = None
         sched, cache = self.scheduler, self.cache
-        grow = 0
+        grow, by_length = 0, True
         for req in sched.running.values():
-            n = req.context_len
-            if len(req.output_ids) + 1 >= req.sampling.max_new_tokens \
+            n, s = req.context_len, req.sampling
+            if len(req.output_ids) + 1 >= s.max_new_tokens \
                     or n + 2 > self.max_seq_len:
                 return
+            if s.eos_token_id is not None or s.stop_token_ids:
+                by_length = False
             grow += max(0, cache.blocks_for_tokens(n + 2)
                         - len(cache.allocator.owned(req.req_id)))
         if not cache.allocator.can_alloc(grow):
             return
         for req in list(sched.running.values()):
             sched.ensure_capacity(req, new_tokens=2)
+        # by_length: no token's VALUE ends a request of this batch, so
+        # it is certain now that the next step decodes this batch
         self._ahead = (self._signature(ahead=1),
-                       jax.device_put(self._batch_arrays(ahead=1)[1:]))
+                       jax.device_put(self._batch_arrays(ahead=1)[1:]),
+                       by_length)
 
     def _next_arrays(self):
         """This step's dispatch inputs: what `_prepare_ahead` made
@@ -647,7 +677,11 @@ class LLMEngine:
             ids[slot] = req.output_ids[-1]
         return (ids,) + ahead[1]
 
-    def _dispatch_decode(self, arrays):
+    def _enqueue_decode(self, arrays, sig):
+        """Hand one decode dispatch over `arrays` to the device. What
+        is then in flight: (`sig`, the signature of the batch it
+        decodes; its tokens and stats, on the device; whether the
+        call compiled)."""
         import jax
 
         decode = self._programs("decode")
@@ -662,11 +696,56 @@ class LLMEngine:
             toks, self.cache.pools, stats = decode.bind(
                 self.params, ids, pos, self.cache.pools, tables,
                 lens, temp, topk, seeds)()
+            compiled = decode.compiled()
             decode.capture()
+        self._count_target_dispatch()
+        return sig, toks, stats, compiled
+
+    def _run_ahead(self, toks):
+        """Called with a decode dispatch in flight whose tokens, still
+        on the device, are `toks`, and the next step's inputs
+        prepared: hands the device the NEXT dispatch now, fed those
+        tokens where they are, so that it goes from one step to the
+        next without the host (the fetch, `_emit` for every sequence,
+        the scheduler and the caller's own code then run beside it).
+        A request aborted or evicted in between rides along in that
+        dispatch as an inactive slot does; its token is dropped when
+        fetched (`_decoded_for`). An out-of-memory here makes room as
+        in `_decode_batch` and leaves the next step to dispatch as
+        ever."""
+        (sig, arrays, _), self._ahead = self._ahead, None
+        try:
+            if _chaos._armed:
+                _chaos.hit("serve_decode",
+                           batch=len(self.scheduler.running))
+            self._inflight = self._enqueue_decode((toks,) + arrays, sig)
+        except Exception as e:
+            self._evict_for_oom(e)
+
+    def _fetch_decode(self, inflight):
+        """Wait for the dispatch `inflight` and return its tokens;
+        before that, with the device busy, prepare the next step's
+        inputs and, in an engine made with `run_ahead=True`, hand the
+        next dispatch over (`_run_ahead`) where it is certain that
+        the next step decodes this very batch: no request ends with
+        the token in flight (not by length: `_prepare_ahead`; not by
+        the token's value: no request of the batch names one) and
+        the batch is full, so that nothing can be admitted."""
+        import jax
+
+        _, toks, stats, compiled = inflight
         with _flight.span("serve/decode/fetch"):
             # the wait for the device, used: the next step's inputs
-            self._count_target_dispatch()
             self._prepare_ahead()
+            ahead = self._ahead
+            if not self.run_ahead or compiled or ahead is None \
+                    or not ahead[2] \
+                    or len(self.scheduler.running) < self.max_batch:
+                toks, stats = jax.device_get((toks, stats))
+                self._count_stats(stats)
+                return toks
+        self._run_ahead(toks)
+        with _flight.span("serve/decode/fetch"):
             toks, stats = jax.device_get((toks, stats))
             self._count_stats(stats)
             return toks
@@ -720,35 +799,49 @@ class LLMEngine:
             return False
 
     def _decode_batch(self, emitted):
-        """Grow tables, dispatch once, apply stop conditions. An OOM
-        (real or chaos-injected) evicts the youngest request and
-        retries with the smaller batch; if the failed dispatch
-        consumed the DONATED pools, rebuild them and replay every
-        running request (position-keyed sampling makes the replay
-        token-exact). A persistent OOM re-raises after
+        """Grow tables, dispatch once (or take up the dispatch the
+        last step handed over ahead, `_run_ahead`), apply stop
+        conditions. An OOM (real or chaos-injected) evicts the
+        youngest request and retries with the smaller batch; if the
+        failed dispatch consumed the DONATED pools, rebuild them and
+        replay every running request (position-keyed sampling makes
+        the replay token-exact). A persistent OOM re-raises after
         max(3, max_batch) consecutive failed dispatches instead of
         spinning on evict/readmit forever."""
         if self.spec_k > 1:
             return self._spec_decode_batch(emitted)
-        # snapshot the batch, but re-check membership per request:
-        # growing request A can evict request B later in the
-        # snapshot, and growing an evicted B would strand blocks on
-        # a request the dispatch no longer covers
-        with _flight.span("serve/decode/prepare"):
-            for req in list(self.scheduler.running.values()):
-                self.scheduler.ensure_capacity(req, new_tokens=1)
+        inflight, self._inflight = self._inflight, None
+        if inflight is not None \
+                and not next(self._decoded_for(inflight[0]), None):
+            inflight = None           # every request of it has left
             if not self.scheduler.running:
                 return
-            arrays = self._next_arrays()
+        if inflight is None:
+            # snapshot the batch, but re-check membership per request:
+            # growing request A can evict request B later in the
+            # snapshot, and growing an evicted B would strand blocks
+            # on a request the dispatch no longer covers
+            with _flight.span("serve/decode/prepare"):
+                for req in list(self.scheduler.running.values()):
+                    self.scheduler.ensure_capacity(req, new_tokens=1)
+                if not self.scheduler.running:
+                    return
+                arrays = self._next_arrays()
         t0 = time.perf_counter()
         try:
             with _flight.in_flight("serve_decode", "decode",
                                    batch=len(self.scheduler.running)):
-                if _chaos._armed:
-                    _chaos.hit("serve_decode",
-                               batch=len(self.scheduler.running))
-                toks = self._dispatch_decode(arrays)
+                if inflight is None:
+                    if _chaos._armed:
+                        _chaos.hit("serve_decode",
+                                   batch=len(self.scheduler.running))
+                    inflight = self._enqueue_decode(
+                        arrays, self._signature())
+                toks = self._fetch_decode(inflight)
         except Exception as e:
+            # what was handed over after the failed dispatch fails
+            # with it
+            self._inflight = None
             if not self._evict_for_oom(e):
                 return                # next step() re-prefills
             return self._decode_batch(emitted)
@@ -756,15 +849,28 @@ class LLMEngine:
         self.heartbeat = time.monotonic()
         decode_us = int((time.perf_counter() - t0) * 1e6)
         _cmon.stat_add("serve/decode_us", decode_us)
-        decode = self._programs("decode")
-        if not decode.compiled() and _perf.dispatch_timing_enabled():
-            # _dispatch_decode's fetch already blocked: measured
-            # device time for the roofline, like prefill; a dispatch
-            # that compiled stays out of the histogram
-            _perf.observe_dispatch(decode.name, decode_us)
+        if not inflight[3] and _perf.dispatch_timing_enabled():
+            # the fetch blocked: measured device time for the
+            # roofline, like prefill; a dispatch that compiled stays
+            # out of the histogram
+            _perf.observe_dispatch(self._programs("decode").name,
+                                   decode_us)
         with _flight.span("serve/decode/emit"):
-            for slot, req in list(self.scheduler.running.items()):
-                self._emit(req, int(toks[slot]), emitted)
+            # a request that left its slot since the dispatch
+            # (aborted, evicted, exported) gets none
+            for req in list(self._decoded_for(inflight[0])):
+                self._emit(req, int(toks[req.slot]), emitted)
+
+    def _decoded_for(self, sig):
+        """The requests a dispatch of signature `sig` decoded for that
+        are still what they were then: in that slot, at that
+        length."""
+        running = self.scheduler.running
+        for slot, rid, n in sig[1:]:
+            req = running.get(slot)
+            if req is not None and req.req_id == rid \
+                    and req.context_len == n:
+                yield req
 
     def _evict_for_oom(self, e):
         """What a decode dispatch that failed with `e` costs: re-raises
@@ -1024,7 +1130,10 @@ class LLMEngine:
                 "serve/hist/itl_us",
                 (now - req.token_times[-2]) * 1e6)
         if _trace._armed:
-            _trace.note(req, "decode", n=len(req.output_ids))
+            # in the request's own timeline alone: one a token of
+            # every sequence is not for the flight ring
+            _trace.note(req, "decode", mirror=False,
+                        n=len(req.output_ids))
         if req.on_token is not None:
             try:
                 req.on_token(req.req_id, token)

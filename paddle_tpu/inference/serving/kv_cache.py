@@ -15,6 +15,21 @@ latent-attention model has ONE, `[normalised latent | rotated key]`
 (576 values for GLM-4.7-Flash), and no V pool. Allocator, tables and
 admission do not know the difference.
 
+A SECOND kind of state (PR 34): what a sequence keeps that does not
+grow with its length — the tail of a short convolution's window, a
+recurrent state — has no blocks and no table. The runner declares it
+beside `rows` as `slot_state`, `(layers, *shape a layer)` an array,
+and the cache holds one array `[layers, max_batch, *shape]` each,
+addressed by the sequence's SLOT in the decode batch (the batch row
+IS the slot; a prefill is told which slot it fills). They ride
+`pools` behind the paged pools (`n_paged` says where they start), so
+whatever zeroes, donates, re-adopts or rebuilds the pools does the
+same to them; only `defrag`, which renumbers BLOCKS, passes them by.
+Nothing frees a slot's state: the next prefill into the slot
+overwrites it whole. `serve/state/{bytes_per_seq,layers}` gauge it
+beside `serve/kv/{row_values,bytes_per_token}` (0 for a model
+without).
+
 (heads and head_dim share the minor dimension: a token's K or V row
 is `n_head * head_dim` contiguous values, stored row-major and
 unpadded on the device. With `head_dim` alone as the minor dimension
@@ -405,7 +420,7 @@ class PagedKVCache:
     def __init__(self, num_layers, num_heads=None, head_dim=None,
                  block_size=None, num_blocks=None, pool_bytes=None,
                  dtype=None, draft_layers=0, prefix_cache=False,
-                 rows=None):
+                 rows=None, slot_state=(), max_batch=0):
         import jax.numpy as jnp
 
         self.block_size = int(block_size or env_block_size())
@@ -413,6 +428,12 @@ class PagedKVCache:
         # one pool per entry, each `[L, N, BS, width]`
         self.rows = tuple(int(w) for w in (
             rows or (num_heads * head_dim,) * 2))
+        self.n_paged = len(self.rows)
+        # per-slot arrays behind the paged pools, `[layers,
+        # max_batch, *shape]` each
+        self.slot_state = tuple(
+            (int(s[0]), int(max_batch)) + tuple(int(d) for d in s[1:])
+            for s in slot_state)
         self.dtype = jnp.dtype(dtype or jnp.float32)
         self.draft_layers = int(draft_layers)
         self.prefix_cache = bool(prefix_cache)
@@ -435,6 +456,11 @@ class PagedKVCache:
         _cmon.stat_set("serve/kv/row_values", sum(self.rows))
         _cmon.stat_set("serve/kv/bytes_per_token",
                        per_block // self.block_size)
+        _cmon.stat_set("serve/state/layers",
+                       sum(s[0] for s in self.slot_state))
+        _cmon.stat_set("serve/state/bytes_per_seq", sum(
+            s[0] * math.prod(s[2:]) for s in self.slot_state)
+            * self.dtype.itemsize)
 
     def _zero_pools(self):
         import jax.numpy as jnp
@@ -444,7 +470,8 @@ class PagedKVCache:
                 jnp.zeros((layers, self.num_blocks, self.block_size, w),
                           self.dtype) for w in self.rows)
 
-        self.pools = zeros(self.num_layers)
+        self.pools = zeros(self.num_layers) + tuple(
+            jnp.zeros(s, self.dtype) for s in self.slot_state)
         if self.draft_layers:
             self.draft_pools = zeros(self.draft_layers)
 
@@ -551,11 +578,12 @@ class PagedKVCache:
         return row
 
     def reset_pools(self):
-        """Fresh zero pools — recovery after a failed DONATING
-        dispatch consumed the old ones (a real RESOURCE_EXHAUSTED
-        mid-execution deletes donated buffers). The caller must
-        re-prefill every sequence: allocator state survives but the
-        K/V contents are gone."""
+        """Fresh zero pools (and per-slot state) — recovery after a
+        failed DONATING dispatch consumed the old ones (a real
+        RESOURCE_EXHAUSTED mid-execution deletes donated buffers).
+        The caller must re-prefill every sequence: allocator state
+        survives but the K/V contents and every slot's state are
+        gone."""
         self._zero_pools()
         # zeroed pools invalidate every published prefix — serving a
         # pre-reset digest would share garbage KV
@@ -589,7 +617,9 @@ class PagedKVCache:
         import jax.numpy as jnp
 
         idx = jnp.asarray(perm)
-        self.pools = tuple(p[:, idx] for p in self.pools)
+        # the per-slot state has no blocks to renumber
+        self.pools = tuple(p[:, idx] for p in self.pools[:self.n_paged]) \
+            + self.pools[self.n_paged:]
         if self.draft_pools is not None:
             self.draft_pools = tuple(p[:, idx]
                                      for p in self.draft_pools)

@@ -159,6 +159,7 @@ class MLARunner:
     verify, tail or draft."""
 
     verify_step = prefill_tail_step = draft_params = None
+    slot_state = ()
 
     def __init__(self, model):
         model = getattr(model, "model", model)

@@ -7,6 +7,8 @@ picks it from the model's type; nothing else selects it):
     runner.params, runner.config     the raw jnp tree and the config
     runner.pool_rows                 row width of each cache pool
     runner.pool_layers               leading axis of each pool: attentions that keep rows
+    runner.slot_state                per-slot arrays beside the pools, `(layers, *shape)` each; () for none.
+                                     With any, prefill_step takes the request's slot after `seed`
     runner.prefill_step(params, ids, prompt_len, pools, table, temp, top_k, seed, *, block_size)
     runner.decode_step(params, ids, positions, pools, tables, lens, temp, top_k, seeds, *, block_size, use_kernel, interpret)
     runner.verify_step, .prefill_tail_step, .draft_params    or None: no speculation / prefix cache
@@ -17,7 +19,7 @@ arrays fetched with the tokens (routing counts; empty for GPT-2).
 `GPT2Runner` hands the engine the programs below as they are —
 `_pooled` only packs their `k_pool, v_pool` into the tuple, so they
 lower to the HLO they always did. `mla_runner.MLARunner` is the
-second runner.
+second runner, `state_runner.StateRunner` the third.
 
 The serving engine never calls `GPTModel.forward` — re-running the
 full prompt for every generated token is O(S^2) per request. Instead
@@ -470,6 +472,7 @@ class GPT2Runner:
     programs above."""
 
     draft_params = staticmethod(draft_params)
+    slot_state = ()
 
     def __init__(self, model):
         self.params, self.config = extract_params(model)
@@ -498,9 +501,16 @@ class GPT2Runner:
 
 def runner_for(model):
     """The runner of a model: `MLARunner` for one that says what its
-    latent-attention layers are (`mla_layers`), else GPT-2's."""
-    if hasattr(getattr(model, "model", model), "mla_layers"):
+    latent-attention layers are (`mla_layers`), `StateRunner` for
+    one whose layers keep per-slot state beside keys and values
+    (`state_layers`), else GPT-2's."""
+    inner = getattr(model, "model", model)
+    if hasattr(inner, "mla_layers"):
         from .mla_runner import MLARunner
 
         return MLARunner(model)
+    if hasattr(inner, "state_layers"):
+        from .state_runner import StateRunner
+
+        return StateRunner(model)
     return GPT2Runner(model)

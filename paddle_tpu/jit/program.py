@@ -7,7 +7,8 @@ copied into each of them lives here:
 
     with program.dispatch():              # count; first: arm, span
         out = program.bind(*args)()       # the jax.jit call itself
-    program.capture()                     # first: footprint, cost
+    program.capture()                     # first: footprint, cost,
+                                          # a full collection
 
   * first dispatch or not: a Program is one `jax.jit` at one argument
     signature (a caller with several — `to_static`'s cache keys, the
@@ -34,7 +35,9 @@ copied into each of them lives here:
     compile), then `mem/program/<name>/*` and `perf/program/<name>/*`
     from that one object. PADDLE_MEM_PROGRAM and PADDLE_PERF_PROGRAM
     are read here and nowhere else; a failed capture never fails the
-    caller.
+    caller. Then, asked for or not, `collect_after_compile()`: the
+    garbage of a trace and two lowerings is collected where nothing
+    waits, not in the middle of a later step.
 
 Why `bind(*args)()` and not `program(*args)`: the call has to reach
 `jax.jit` from the caller's own frame. With two frames of a
@@ -51,6 +54,7 @@ to block on; they ask `compiled()` to leave a compiling sample out.
 from __future__ import annotations
 
 import functools
+import gc
 import time
 
 import jax
@@ -73,6 +77,21 @@ def arm_compile_cache():
     the cache on CPU arms it itself."""
     if jax.default_backend() != "cpu":
         persistent_cache.arm_native()
+
+
+def collect_after_compile():
+    """A full collection now, after a first dispatch on an
+    accelerator. Tracing and lowering a program leave behind tens of
+    thousands of dead tracers, equations and MLIR wrappers, many of
+    them old enough by then to sit in the collector's last
+    generation: left there they are what makes it run its full pass
+    (30-100 ms over a heap with JAX in it) a few hundred steps later,
+    inside a decode or a train step. Here nothing waits: the compile
+    took seconds. CPU runs are left alone, as `arm_compile_cache`
+    leaves them: a test suite's thousands of tiny programs would pay
+    50 ms each."""
+    if jax.default_backend() != "cpu":
+        gc.collect()
 
 
 def specialised(name, n):
@@ -157,7 +176,7 @@ class Program:
         # what compiled() reads: None, nothing bound since it was
         # asked; () the first call; (trace cache size, time) a later
         self._bound = None
-        self._pending = None    # (avals, want_mem, want_cost)
+        self._pending = None    # (avals or None, want_mem, want_cost)
         self.memory = None      # memory_analysis() byte dict
         self.cost = None        # cost_analysis() flop/byte dict
 
@@ -179,9 +198,10 @@ class Program:
             self._bound = ()
             want_mem = _memory.program_capture_enabled()
             want_cost = _perf.program_capture_enabled()
-            if want_mem or want_cost:
-                self._pending = (tree_util.tree_map(_aval, args),
-                                 want_mem, want_cost)
+            self._pending = (
+                tree_util.tree_map(_aval, args)
+                if want_mem or want_cost else None,
+                want_mem, want_cost)
         return functools.partial(self._jit, *args)
 
     def compiled(self):
@@ -202,13 +222,19 @@ class Program:
 
     def capture(self):
         """The footprint and cost of the program a first call just
-        compiled; nothing after any other. The caller places it after
+        compiled, where asked for, then `collect_after_compile()`;
+        nothing after any other call. The caller places it after
         that call (a raise never reaches it), outside whatever it
         times itself."""
         pending, self._pending = self._pending, None
         if pending is None:
             return
         avals, want_mem, want_cost = pending
+        if avals is not None:
+            self._capture(avals, want_mem, want_cost)
+        collect_after_compile()
+
+    def _capture(self, avals, want_mem, want_cost):
         try:
             # the span lets the watchdog's in-flight table and
             # jit/<family>/mem_capture_us attribute the time

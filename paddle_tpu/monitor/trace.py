@@ -18,8 +18,9 @@ from the request's own timeline:
     to the request's bounded timeline (`Request.trace`,
     PADDLE_TRACE_EVENTS cap; drops counted per-request and under
     `trace/dropped`) and mirrors it into the flight ring (kind
-    "trace") so dump bundles show the per-request story next to the
-    engine spans. Armed by default; PADDLE_TRACE_SERVE=0 disarms —
+    "trace"; all but the event a decoded token, `mirror=False`) so
+    dump bundles show the per-request story next to the engine
+    spans. Armed by default; PADDLE_TRACE_SERVE=0 disarms —
     call sites gate on the module flag `trace._armed` (the chaos
     pattern), so the disarmed path is one attribute read and leaves
     ZERO counters behind (the PR-9/12 bench-provenance contract).
@@ -84,12 +85,21 @@ def mint():
             f"{next(_seq):x}")
 
 
-def note(req, stage, **data):
+def note(req, stage, /, *, mirror=True, **data):
     """Append one stage event to `req.trace` (bounded) and mirror it
     into the flight ring. No-op (one flag read) when disarmed; a
     request minted while disarmed (trace_id None) stays untraced even
     if tracing arms later — half a timeline would misattribute every
-    gap before the arm."""
+    gap before the arm.
+
+    `mirror=False` keeps the event in the request's own timeline
+    alone: the engine says so for its one event a TOKEN. A batch of
+    256 sequences would put 256 of them into the ring every step (a
+    ring of 4096 then holds 16 steps and none of the admissions,
+    evictions and spans a dump is read for), and each mirrored event
+    is a tuple that lives as long as the ring is deep: the objects
+    that reach the collector's oldest generation and call its full
+    collections (30-100 ms each) into the decode loop."""
     if not _armed or req.trace_id is None:
         return
     tl = req.trace
@@ -102,8 +112,9 @@ def note(req, stage, **data):
         ev.update(data)
     tl.append(ev)
     _cmon.stat_add("trace/events", 1)
-    _flight.record("trace", trace_id=req.trace_id, req=req.req_id,
-                   stage=stage, **data)
+    if mirror:
+        _flight.record("trace", trace_id=req.trace_id, req=req.req_id,
+                       stage=stage, **data)
 
 
 # ---------------------------------------------------------------------------

@@ -8,3 +8,5 @@ from .glm4_moe_lite import (Glm4MoeLiteConfig, Glm4MoeLiteModel,
                             Glm4MoeLiteForCausalLM)
 from .longcat_flash import (LongcatFlashConfig, LongcatFlashModel,
                             LongcatFlashForCausalLM)
+from .lfm2_moe import (Lfm2MoeConfig, Lfm2MoeModel,
+                       Lfm2MoeForCausalLM)

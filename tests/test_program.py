@@ -172,6 +172,37 @@ def test_capture_options(monkeypatch, off, kept):
         == {{"memory": "mem", "cost": "perf"}[k] for k in kept}
 
 
+@pytest.mark.parametrize("backend,off,want", [
+    ("tpu", (), 1), ("tpu", ("PADDLE_MEM_PROGRAM", "PADDLE_PERF_PROGRAM"), 1),
+    ("cpu", (), 0)])
+def test_a_first_dispatch_ends_with_one_full_collection(
+        monkeypatch, backend, off, want):
+    """capture() after a first call collects the garbage a trace and
+    a lowering left, once, on an accelerator, whether or not a
+    footprint was asked for; never on the CPU, never after a warm
+    call, and not where the first call raised."""
+    from paddle_tpu.jit import program as pg
+
+    for name in off:
+        monkeypatch.setenv(name, "0")
+    monkeypatch.setattr(pg.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(pg, "arm_compile_cache", lambda: None)
+    full = []
+    monkeypatch.setattr(pg.gc, "collect", lambda *a: full.append(a))
+    prog = Program(_matmul, f"t_prog:gc:{backend}:{len(off)}")
+    with pytest.raises(TypeError):
+        _call(prog, _ones(8, 8), _ones(4, 4))
+    prog.capture()
+    assert full == []
+    _call(prog, _ones(8, 8), _ones(8, 8))
+    assert full == []                    # the caller places it
+    prog.capture()
+    assert full == [()] * want
+    _call(prog, _ones(8, 8), _ones(8, 8))
+    prog.capture()
+    assert full == [()] * want
+
+
 def test_a_donated_argument_is_not_touched_by_the_capture():
     def bump(pool, x):
         return pool.at[0].add(x)

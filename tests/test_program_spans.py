@@ -269,6 +269,34 @@ def test_engine_step_leaves_the_span_tree():
     assert cmon.stat_get(f"jit/{prefill}/cache_miss") >= 1
 
 
+def test_a_step_that_runs_ahead_encloses_the_next_enqueue():
+    """A full batch, `run_ahead=True`: from the second decode on a step
+    finds its
+    dispatch in flight (no `serve/decode/prepare`, no enqueue of its
+    own) and hands over the next between two `serve/decode/fetch`
+    spans, side by side, so that a reader that adds up a step's
+    children by name counts each second once."""
+    engine = _engine(run_ahead=True)
+    for prompt in ([1, 2, 3], [4, 5]):
+        engine.add_request(prompt, _sampling(6))
+    for _ in range(4):
+        engine.step()
+    steps = _tree(flight.spans())
+    assert [name for name, _ in steps[1][1]] == [
+        "serve/schedule", "serve/decode/prepare", "serve/decode",
+        "serve/decode/emit"]
+    assert steps[1][1][2] == ("serve/decode", _leaf(
+        "serve/decode/enqueue", "serve/decode/fetch",
+        "serve/decode/enqueue", "serve/decode/fetch"))
+    for step in steps[2:]:
+        assert step == ("serve/step", [
+            ("serve/schedule", []),
+            ("serve/decode", _leaf(
+                "serve/decode/fetch", "serve/decode/enqueue",
+                "serve/decode/fetch")),
+            ("serve/decode/emit", [])])
+
+
 def test_engine_records_program_memory_and_tpubench_reads_it():
     from tpubench import core
 
@@ -293,19 +321,19 @@ def test_engine_counts_a_compile_time_oom(monkeypatch):
 
     engine = _engine()
     engine.generate([[1, 2, 3]], sampling=_sampling(2))   # warm
-    real = engine._dispatch_decode
+    real = engine._enqueue_decode
     calls = {"n": 0}
 
-    def refuse_once(arrays):
+    def refuse_once(*arrays):
         calls["n"] += 1
         if calls["n"] == 1:
             raise jax.errors.JaxRuntimeError(
                 "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. "
                 "Ran out of memory in memory space hbm. Used 16.07G of "
                 "15.75G hbm.")
-        return real(arrays)
+        return real(*arrays)
 
-    monkeypatch.setattr(engine, "_dispatch_decode", refuse_once)
+    monkeypatch.setattr(engine, "_enqueue_decode", refuse_once)
     before = (cmon.stat_get("serve/compile_oom"),
               cmon.stat_get("serve/oom_evictions"))
     flight.recorder.clear()

@@ -967,19 +967,19 @@ class TestServingChaos:
                         num_blocks=32)
         ids = [eng.add_request(p, sp) for p in prompts]
         eng.step()  # prefill both + one clean decode
-        orig = eng._dispatch_decode
+        orig = eng._enqueue_decode
         state = {"fired": False}
 
-        def boom(arrays):
+        def boom(*arrays):
             if not state["fired"]:
                 state["fired"] = True
                 eng.cache.k.delete()   # donation consumed the pools
                 eng.cache.v.delete()
                 raise chaos.XlaRuntimeError(
                     "RESOURCE_EXHAUSTED: out of memory (test)")
-            return orig(arrays)
+            return orig(*arrays)
 
-        monkeypatch.setattr(eng, "_dispatch_decode", boom)
+        monkeypatch.setattr(eng, "_enqueue_decode", boom)
         before = cmon.stat_get("serve/pool_resets")
         while eng.has_unfinished():
             eng.step()

@@ -396,3 +396,73 @@ def test_gpt2_kernel_programs_are_the_parents(program, pool_dtype, want):
         functools.partial(fn, use_kernel=True, **KW))(*args)
     assert "pallas_call" in str(jaxpr)
     assert _digest(str(jaxpr)) == want
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 34: K/V pools with grouped heads + per-slot state, at LFM2's widths
+# ---------------------------------------------------------------------------
+
+def _lfm2_programs(sd):
+    """Decode (through the paged kernel, 32 query heads over 8 K/V
+    heads) and the longest prefill of `serve-lfm2-offline-decode` as
+    the cell runs them: hidden 2048, the published layers 0-9 (8
+    short convolutions, 2 attentions; 2 dense FFNs of 11776, 8 x 64
+    experts of 1536), the whole vocabulary, batch 256, 4096
+    positions, 65537 blocks, the state of 256 slots."""
+    from paddle_tpu.inference.serving import state_runner as sr
+    from paddle_tpu.text.models.lfm2_moe import (PUBLISHED_LAYER_TYPES,
+                                                 Lfm2MoeConfig, Lfm2MoeModel)
+
+    cfg = Lfm2MoeConfig(num_hidden_layers=10,
+                        layer_types=PUBLISHED_LAYER_TYPES[:10],
+                        dtype="bfloat16")
+    shapes = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda p: p._value, Lfm2MoeModel(cfg)._tree))
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    bsz, maxb = 256, 4096 // 16
+    pools = (sd((2, 65537, 16, 512), bf16), sd((2, 65537, 16, 512), bf16),
+             sd((8, bsz, 2, 2048), bf16))
+    kw = dict(cfg=cfg, model=Lfm2MoeModel, block_size=16)
+    return pools, {
+        "decode": (functools.partial(sr.decode_step, use_kernel=True), (
+            params, sd((bsz,), i32), sd((bsz,), i32), pools,
+            sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
+            sd((bsz,), i32), sd((bsz,), i32))),
+        "prefill": (sr.prefill_step, (
+            params, sd((1, 2048), i32), sd((), i32), pools,
+            sd((maxb,), i32), sd((), f32), sd((), i32), sd((), i32),
+            sd((), i32))),
+    }, kw
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_tpu_lfm2_programs_fit_the_cell(one_chip, program):
+    """Compiled for the v5e: both pools AND the per-slot state are
+    aliased (no second copy among the temporaries, which stay under
+    a quarter of a GiB: the prefill's scores are blocked by 512
+    queries, 128 MiB), the weights are 9.81 GiB, and arguments and
+    temporaries together fit the chip's 15.75 GiB with the pools and
+    the state at the cell's size. Decode holds the Mosaic call."""
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools, programs, kw = _lfm2_programs(sd)
+    fn, args = programs[program]
+    compiled = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
+        .lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(p.size * p.dtype.itemsize for p in pools)
+    gib = 2 ** 30
+    assert 4.0 * gib < held < 4.03 * gib
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 0.25 * gib, (
+        f"{mem.temp_size_in_bytes / gib:.2f} GiB of temporaries")
+    assert 13.8 * gib < mem.argument_size_in_bytes < 13.9 * gib
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * gib
+    if program == "decode":
+        hlo = compiled.as_text()
+        assert "tpu_custom_call" in hlo
+        # no sequence's context is gathered: [256, 4096, ...] nowhere
+        assert "[256,4096," not in hlo and "[65536,16," not in hlo

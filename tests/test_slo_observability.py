@@ -402,6 +402,39 @@ class TestServingTraces:
         assert (hists["serve/hist/e2e_us"]["min"]
                 >= hists["serve/hist/ttft_us"]["min"])
 
+    def test_a_tokens_event_stays_out_of_the_flight_ring(
+            self, model, prompts):
+        """The event a decoded token leaves is in the request's own
+        timeline alone: the flight ring keeps the lifecycle events
+        (add, admit, prefill, finished) and none with stage `decode`,
+        so a wide batch neither floods it nor feeds the collector's
+        oldest generation. `mirror=False` is what says so."""
+        from paddle_tpu.inference.serving.scheduler import Request
+        from paddle_tpu.monitor import flight
+
+        cmon.registry.reset_all()
+        flight.recorder.clear()
+        eng = LLMEngine(model, max_batch=4, block_size=8,
+                        num_blocks=32)
+        rids = [eng.add_request(p, sampling=sp()) for p in prompts]
+        while eng.has_unfinished():
+            eng.step()
+        ring = [e["stage"] for e in flight.tail()
+                if e["kind"] == "trace"]
+        assert "decode" not in ring
+        for stage in ("add", "admit", "prefill", "finished"):
+            assert ring.count(stage) == len(rids), stage
+        assert cmon.stat_get("trace/events") == sum(
+            len(eng.get_request(r).trace) for r in rids)
+        req = Request([1, 2], sampling=sp())
+        flight.recorder.clear()
+        mtrace.note(req, "decode", mirror=False, n=1)
+        mtrace.note(req, "decode", n=2)
+        assert [e["stage"] for e in req.trace] \
+            == ["add", "decode", "decode"]
+        assert [e["n"] for e in flight.tail()
+                if e["kind"] == "trace"] == [2]
+
     def test_eviction_leg_recorded(self, model, prompts):
         """A chaos-injected RESOURCE_EXHAUSTED decode forces an
         eviction: the victim's timeline shows evict ->
