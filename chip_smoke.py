@@ -43,6 +43,10 @@ import time
 SEED = 0
 # what the latent-attention (MLA) runner stores of a token: 576 values in 640
 LATENT_ROW = 640
+# the grouped matmul's check: rows, groups, the stack's groups, the
+# first of them, K, N
+GROUPED_SHAPE = (1024, 8, 12, 4, 2048, 3072)
+TOY_GROUPED_SHAPE = (64, 4, 6, 2, 128, 256)
 
 # GPT-2 345M (bench.py's gpt2_345m / serving configs)
 WIDTH = dict(vocab_size=50304, hidden_size=1024, num_layers=24, num_heads=16,
@@ -519,6 +523,31 @@ def k_paged_latent(g, interpret):
            f"max err {err:.3g}"
 
 
+def k_grouped_matmul(g, interpret):
+    """LFM2-24B-A2B's decode rows and widths (1024 rows, hidden 2048
+    -> 2 x 1536) over eight experts of a longer stack, against
+    `jax.lax.ragged_dot`; two experts empty, one with a single row."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.incubate.nn.pallas import grouped_matmul as gm
+
+    m, e, stack, first, k, n = g["grouped"]
+    rng = np.random.RandomState(SEED)
+    rows = jnp.asarray(rng.randn(m, k) * 0.5, jnp.bfloat16)
+    w = jnp.asarray(rng.randn(stack, k, n) * 0.05, jnp.bfloat16)
+    sizes = np.bincount(rng.randint(2, e, m - 1), minlength=e)
+    sizes[1] = 1
+    full = np.zeros(stack, np.int32)
+    full[first:first + e] = sizes
+    got = jax.jit(lambda *a: gm.grouped_matmul(*a, interpret=interpret))(
+        rows, w, jnp.asarray(sizes, jnp.int32), jnp.int32(first))
+    want = jax.lax.ragged_dot(rows, w, jnp.asarray(full))
+    err = _close("grouped matmul", got, want, 5e-2)
+    return f"rows {m} over {e} of {stack} groups, K {k} N {n} bf16, " \
+           f"tiles {gm._tiles(m, e, k, n, 2)}, max err {err:.3g}"
+
+
 def k_int8(g, interpret):
     import numpy as np
     import jax.numpy as jnp
@@ -567,6 +596,10 @@ def _kernel_table():
         ("paged_latent_attention",
          lambda g: pallas.paged_attention.paged_decode_supported(
              1, LATENT_ROW, g["block"]), k_paged_latent),
+        ("grouped_matmul",
+         lambda g: pallas.grouped_matmul.grouped_matmul_supported(
+             *g["grouped"][:2], *g["grouped"][4:], "bfloat16"),
+         k_grouped_matmul),
         ("int8_block_quant", lambda g: qk._use_pallas("int8", DEFAULT_BLOCK), k_int8),
     ]
 
@@ -711,7 +744,8 @@ def main(argv=None):
                 head_dim=head_dim, rows=train["batch"] * train["seq"],
                 hidden=width["hidden_size"], ffn=width["ffn_hidden"],
                 serve_b=serve["max_batch"], block=16,
-                max_seq=width["max_seq_len"], spec_t=4)
+                max_seq=width["max_seq_len"], spec_t=4,
+                grouped=TOY_GROUPED_SHAPE if _PREFLIGHT else GROUPED_SHAPE)
 
     t = phase_train(width, **train)
     _free_device_memory()

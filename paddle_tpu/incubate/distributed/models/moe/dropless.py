@@ -4,10 +4,24 @@ over the experts held HERE.
 The GShard layer next door (`MoELayer`) gives every expert a static
 capacity and drops what does not fit. This one drops nothing: the
 (token, expert) assignments are sorted by expert, the rows of one
-expert lie together, and `jax.lax.ragged_dot` multiplies each group by
+expert lie together, and a grouped matmul multiplies each group by
 its own expert's matrices — M rows in, M rows out, whatever the
 routing's skew. The shapes stay static (M = tokens x top_k); only the
 group sizes are data.
+
+Two implementations multiply the groups, one a program, chosen while
+it is traced from platform, mesh and static shape
+(`expert_kernel_supported`; no switch). On a TPU, outside a
+multi-device mesh, for bf16 matrices and as many rows as fit in VMEM
+beside a block of weights (every decode program of the three served
+expert models, GLM's and LFM2's prefill buckets): the repo's Pallas
+kernel (`incubate/nn/pallas/grouped_matmul.py`), whose row tile
+follows the rows a group holds and which reads each hit expert once,
+at the HBM's speed. Everywhere else (the CPU, a live mesh, float32,
+LongCat's prefill buckets of 24 576 rows): `jax.lax.ragged_dot`,
+which on a TPU multiplies every group as a 512-row tile (PERF.md,
+PR 35).
+Neither has a derivative here (R1): the kernel raises if asked.
 
 Routing is DeepSeek-V3's `noaux_tc` with one group (what
 `glm4_moe_lite` publishes): scores `s = sigmoid(u W_r)` in float32,
@@ -40,11 +54,14 @@ experts alike, the `top_k` largest of `s + b` chosen, weights `scale
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 __all__ = ["sigmoid_topk_route", "softmax_topk_route",
-           "dropless_expert_ffn", "identity_expert_sum", "expert_counts"]
+           "dropless_expert_ffn", "identity_expert_sum", "expert_counts",
+           "expert_kernel_supported"]
 
 
 def sigmoid_topk_route(u, router_w, bias, top_k, scale):
@@ -92,6 +109,38 @@ def identity_expert_sum(u, idx, weights, first_zero):
     return (w * u.astype(jnp.float32)).astype(u.dtype)
 
 
+def expert_kernel_supported(tokens, top_k, n_experts, hidden, width,
+                            dtype):
+    """Do the two grouped matmuls of an expert layer of this static
+    shape (`tokens * top_k` rows over `n_experts` groups, `[hidden,
+    2 * width]` then `[width, hidden]` matrices of `dtype`) run in
+    the Pallas kernel here? `grouped_matmul_supported`'s answer for
+    both (platform, mesh and shape; no switch). The ONE predicate:
+    `dropless_expert_ffn` asks it while a program is traced, a
+    serving runner when the engine counts that program's dispatch
+    (`serve/moe/layer_steps_kernel`)."""
+    from ....nn.pallas import grouped_matmul as gm
+
+    rows = tokens * top_k
+    return gm.grouped_matmul_supported(
+        rows, n_experts, hidden, 2 * width, dtype) \
+        and gm.grouped_matmul_supported(rows, n_experts, width, hidden,
+                                        dtype)
+
+
+def _grouped_matmul(t, k, w13, w2, dtype):
+    """The Pallas grouped matmul where it runs (`expert_kernel_
+    supported`), else None: `jax.lax.ragged_dot`."""
+    if w13.dtype != dtype or w2.dtype != dtype \
+            or not expert_kernel_supported(
+                t, k, w13.shape[-3], w13.shape[-2], w2.shape[-2], dtype):
+        return None
+    from ....nn import pallas as _pl
+
+    return functools.partial(_pl.grouped_matmul.grouped_matmul,
+                             interpret=_pl.interpret_mode())
+
+
 def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None, first=None):
     """sum_i weights[t, i] * SwiGLU_{idx[t, i]}(u[t]) for every token,
     over the experts held here.
@@ -120,16 +169,23 @@ def dropless_expert_ffn(u, idx, weights, w13, w2, layer=None, first=None):
     order = jnp.argsort(flat, stable=True)
     rows = jnp.take(u, order // k, axis=0)              # [T*k, H]
     sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    grouped = _grouped_matmul(t, k, w13, w2, rows.dtype)
     if layer is not None:
         groups = w13.shape[0] * n_experts
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((groups,), jnp.int32), sizes,
-            (layer * n_experts,))
+        if grouped is None:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((groups,), jnp.int32), sizes,
+                (layer * n_experts,))
         w13 = w13.reshape((groups,) + w13.shape[2:])
         w2 = w2.reshape((groups,) + w2.shape[2:])
-    gate, up = jnp.split(jax.lax.ragged_dot(rows, w13, sizes), 2,
-                         axis=-1)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes)
+    if grouped is None:
+        grouped = jax.lax.ragged_dot
+    else:
+        grouped = functools.partial(
+            grouped, first_group=0 if layer is None
+            else layer * n_experts)
+    gate, up = jnp.split(grouped(rows, w13, sizes), 2, axis=-1)
+    out = grouped(jax.nn.silu(gate) * up, w2, sizes)
     # back to token order: row j of the sorted list is assignment
     # order[j]; its inverse gathers instead of scattering
     out = jnp.take(out, jnp.argsort(order), axis=0).reshape(t, k, -1)

@@ -27,6 +27,9 @@ that fails to compile fails the step.
   through the block tables. It reads no switch: its predicate
   (`paged_attention.paged_decode_supported`) answers from platform,
   mesh and shape alone (on the CPU, from `PADDLE_PALLAS_INTERPRET`).
+- `grouped_matmul`: the dropless experts' two products a layer
+  (`moe/dropless.py`), a read of the hit experts' weights. No switch
+  either: `grouped_matmul.grouped_matmul_supported`.
 """
 from __future__ import annotations
 
@@ -34,7 +37,7 @@ import os
 
 __all__ = ["fusion_enabled", "interpret_mode", "kernels_available",
            "ln_supported", "optim_supported", "layernorm", "optim",
-           "paged_attention",
+           "paged_attention", "grouped_matmul",
            "fused_layer_norm", "fused_residual_layer_norm"]
 
 def _env_on(name, default="0"):
@@ -102,7 +105,8 @@ def optim_supported():
 # the feature off; call sites go through these attributes, which load
 # on first touch (PEP 562)
 def __getattr__(name):
-    if name in ("layernorm", "optim", "paged_attention"):
+    if name in ("layernorm", "optim", "paged_attention",
+                "grouped_matmul"):
         import importlib
 
         return importlib.import_module("." + name, __name__)

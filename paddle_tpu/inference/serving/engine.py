@@ -499,7 +499,7 @@ class LLMEngine:
                 np.float32(s.temperature), np.int32(s.top_k),
                 np.uint32(_mr.seed_for(s.seed, plen)), *slot)()
             tok = int(tok)
-            self._count_stats(stats)
+            self._count_stats(stats, padded)
             if self._draft_params is not None:
                 draft = self._programs("draft_prefill", padded)
                 with draft.dispatch():
@@ -566,7 +566,7 @@ class LLMEngine:
                 np.int32(s.top_k),
                 np.uint32(_mr.seed_for(s.seed, plen)))()
             tok = int(tok)
-            self._count_stats(stats)
+            self._count_stats(stats, t_pad)
             if self._draft_params is not None:
                 draft = self._programs("draft_tail", t_pad)
                 with draft.dispatch():
@@ -742,12 +742,12 @@ class LLMEngine:
                     or not ahead[2] \
                     or len(self.scheduler.running) < self.max_batch:
                 toks, stats = jax.device_get((toks, stats))
-                self._count_stats(stats)
+                self._count_stats(stats, self.max_batch)
                 return toks
         self._run_ahead(toks)
         with _flight.span("serve/decode/fetch"):
             toks, stats = jax.device_get((toks, stats))
-            self._count_stats(stats)
+            self._count_stats(stats, self.max_batch)
             return toks
 
     def _count_target_dispatch(self):
@@ -764,15 +764,18 @@ class LLMEngine:
         if self.use_kernel:
             _cmon.stat_add("serve/attn/steps_paged", 1)
 
-    @staticmethod
-    def _count_stats(stats):
-        """What a program returned beside its tokens, into the
-        counters. `moe_counts` [expert layers, experts held]: the
-        live tokens each expert of each layer took in this dispatch.
-        `moe_picks` [expert layers, 2], from a router wider than the
-        experts held here: the live tokens' top-k picks, and those
-        of them that fell on zero-compute experts; without it every
-        pick is an assignment."""
+    def _count_stats(self, stats, tokens):
+        """What a program over `tokens` rows returned beside its
+        tokens, into the counters. `moe_counts` [expert layers,
+        experts held]: the live tokens each expert of each layer took
+        in this dispatch. `moe_picks` [expert layers, 2], from a
+        router wider than the experts held here: the live tokens'
+        top-k picks, and those of them that fell on zero-compute
+        experts; without it every pick is an assignment.
+        `serve/moe/layer_steps_kernel` counts the layers beside
+        `layer_steps` where that program multiplied its groups in the
+        Pallas grouped matmul (the runner's answer, from the
+        predicate the traced program asked)."""
         counts = stats.get("moe_counts")
         if counts is None:
             return
@@ -785,6 +788,9 @@ class LLMEngine:
         _cmon.stat_add("serve/moe/experts_hit",
                        int((counts > 0).sum()))
         _cmon.stat_add("serve/moe/layer_steps", counts.shape[0])
+        if self.runner.experts_kernel(tokens):
+            _cmon.stat_add("serve/moe/layer_steps_kernel",
+                           counts.shape[0])
         _cmon.stat_add("serve/moe/max_load",
                        int(counts.max(axis=-1).sum()))
 

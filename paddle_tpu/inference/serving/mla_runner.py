@@ -47,6 +47,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...incubate.distributed.models.moe.dropless import (
+    expert_kernel_supported)
 from ...text.models import mla as _mla
 from .kv_cache import NULL_BLOCK
 from .model_runner import _scatter_positions, sample_tokens
@@ -173,6 +175,7 @@ class MLARunner:
         # the decode program compiled for the v5e, none at 640)
         self.pool_rows = (-(-cfg.latent_row // 128) * 128,)
         self.pool_layers = model.n_attentions
+        self.routed_experts = model.routed_experts
         kw = dict(cfg=cfg, layers=model.mla_layers)
         self.prefill_step = functools.partial(prefill_step, **kw)
         self.decode_step = functools.partial(decode_step, **kw)
@@ -187,3 +190,10 @@ class MLARunner:
 
         return _pa.paged_decode_supported(1, self.pool_rows[0],
                                           block_size)
+
+    def experts_kernel(self, tokens):
+        """Does a program over `tokens` rows multiply its experts'
+        groups in the Pallas grouped matmul here? What that program
+        asked while it was traced (`dropless.expert_kernel_
+        supported`), for the model's `routed_experts`."""
+        return expert_kernel_supported(tokens, *self.routed_experts)

@@ -55,6 +55,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ...incubate.distributed.models.moe.dropless import (
+    expert_kernel_supported)
 from .kv_cache import NULL_BLOCK
 from .model_runner import _scatter_positions, sample_tokens
 
@@ -180,6 +182,7 @@ class StateRunner:
         self.heads = hq, hkv, d = model.kv_heads
         self.pool_rows = (hkv * d,) * 2
         self.pool_layers = model.n_attentions
+        self.routed_experts = model.routed_experts
         self.slot_state = tuple(model.slot_state)
         # the programs read the model's functions, not the instance
         kw = dict(cfg=cfg, model=type(model))
@@ -194,3 +197,10 @@ class StateRunner:
         hq, hkv, d = self.heads
         return _pa.paged_decode_supported(hq, d, block_size,
                                           num_kv_heads=hkv)
+
+    def experts_kernel(self, tokens):
+        """Does a program over `tokens` rows multiply its experts'
+        groups in the Pallas grouped matmul here? What that program
+        asked while it was traced (`dropless.expert_kernel_
+        supported`), for the model's `routed_experts`."""
+        return expert_kernel_supported(tokens, *self.routed_experts)

@@ -232,6 +232,14 @@ class Glm4MoeLiteModel(SeededTree):
         }
 
     @property
+    def routed_experts(self):
+        """(picks a token, experts held, hidden, an expert's width,
+        the matrices' dtype): the grouped matmuls' static shape."""
+        c = self.config
+        return (c.num_experts_per_tok, c.n_routed_experts, c.hidden_size,
+                c.moe_intermediate_size, self._dtype)
+
+    @property
     def n_attentions(self):
         """Attentions that keep rows in a cache: one a layer."""
         return self.config.num_hidden_layers

@@ -358,6 +358,14 @@ class Lfm2MoeModel(SeededTree):
         return self._add(name, jnp.full(shape, value, self._dtype))
 
     @property
+    def routed_experts(self):
+        """(picks a token, experts held, hidden, an expert's width,
+        the matrices' dtype): the grouped matmuls' static shape."""
+        c = self.config
+        return (c.num_experts_per_tok, c.num_experts, c.hidden_size,
+                c.moe_intermediate_size, self._dtype)
+
+    @property
     def n_attentions(self):
         """Attentions that keep K/V rows in a cache."""
         return self.config.count("full_attention")

@@ -268,6 +268,14 @@ class LongcatFlashModel(SeededTree):
         }
 
     @property
+    def routed_experts(self):
+        """(picks a token, experts held, hidden, an expert's width,
+        the matrices' dtype): the grouped matmuls' static shape."""
+        c = self.config
+        return (c.moe_topk, c.experts_held, c.hidden_size,
+                c.expert_ffn_hidden_size, self._dtype)
+
+    @property
     def n_attentions(self):
         """Attentions that keep rows in a cache: two a layer."""
         return 2 * self.config.num_layers

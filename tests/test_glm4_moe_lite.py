@@ -309,7 +309,8 @@ def test_decode_through_the_latent_kernel_emits_the_dense_tokens(
 
     _, model, _ = toy
     prompts = [[3, 4, 5, 6, 7], [9, 8], list(range(1, 12))]
-    names = ("serve/attn/steps", "serve/attn/steps_paged")
+    names = ("serve/attn/steps", "serve/attn/steps_paged",
+             "serve/moe/layer_steps", "serve/moe/layer_steps_kernel")
 
     def run(**kw):
         before = [stat_get(n) for n in names]
@@ -318,14 +319,20 @@ def test_decode_through_the_latent_kernel_emits_the_dense_tokens(
         return eng, out, [stat_get(n) - b for n, b in zip(names, before)]
 
     monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
-    dense, want, (steps, paged) = run()
+    dense, want, (steps, paged, layer_steps, in_kernel) = run()
     assert not dense.use_kernel and steps == 5 and paged == 0
+    # ISSUE 35: three prefills and five decode dispatches through
+    # the expert layers, none of them in the grouped-matmul kernel
+    assert layer_steps == 8 * dense.runner.params["moe"]["w2"].shape[0]
+    assert in_kernel == 0
     monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
     eng, got, counts = run()
     assert eng.use_kernel and eng._kernel_interpret
-    assert got == want and counts == [5, 5]
+    assert got == want and counts == [5, 5, layer_steps, layer_steps]
+    # the experts' kernel is the traced program's own choice
     eng, got, counts = run(use_kernel=False)
-    assert not eng.use_kernel and got == want and counts == [5, 0]
+    assert not eng.use_kernel and got == want
+    assert counts == [5, 0, layer_steps, layer_steps]
 
 
 def test_serving_max_seq_len_is_the_deployments(toy):

@@ -386,7 +386,8 @@ def test_decode_through_the_paged_kernel_emits_the_dense_tokens(
     engine's tokens and counts every decode dispatch as paged."""
     _, model, _ = toy
     prompts = [[3, 4, 5, 6, 7], [9, 8], list(range(1, 12))]
-    names = ("serve/attn/steps", "serve/attn/steps_paged")
+    names = ("serve/attn/steps", "serve/attn/steps_paged",
+             "serve/moe/layer_steps", "serve/moe/layer_steps_kernel")
 
     def run(**kw):
         before = [cmon.stat_get(n) for n in names]
@@ -396,12 +397,15 @@ def test_decode_through_the_paged_kernel_emits_the_dense_tokens(
                           for n, b in zip(names, before)]
 
     monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
-    dense, want, (steps, paged) = run()
+    dense, want, (steps, paged, layer_steps, in_kernel) = run()
     assert not dense.use_kernel and steps == 5 and paged == 0
+    # ISSUE 35: the expert layers of three prefills and five decode
+    # dispatches; in the grouped-matmul kernel under the interpreter
+    assert layer_steps > 0 and layer_steps % 8 == 0 and in_kernel == 0
     monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
     eng, got, counts = run()
     assert eng.use_kernel and eng._kernel_interpret
-    assert got == want and counts == [5, 5]
+    assert got == want and counts == [5, 5, layer_steps, layer_steps]
 
 
 @pytest.mark.parametrize("hq,hkv,dtype,tol", [

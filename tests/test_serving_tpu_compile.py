@@ -466,3 +466,75 @@ def test_tpu_lfm2_programs_fit_the_cell(one_chip, program):
         assert "tpu_custom_call" in hlo
         # no sequence's context is gathered: [256, 4096, ...] nowhere
         assert "[256,4096," not in hlo and "[65536,16," not in hlo
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 35: the routed experts' grouped matmul in the repo's own kernel
+# ---------------------------------------------------------------------------
+
+_EXPERT = {
+    # family: (programs, temporaries' limit of the fit tests above in
+    # MiB, one layer's routed experts in bytes)
+    "glm47f": (_mla_programs, 64, 64 * 2048 * 3072 * 3),
+    "longcat": (_longcat_programs, 64, 16 * 6144 * 2048 * 6),
+    "lfm2": (_lfm2_programs, 256, 64 * 2048 * 1536 * 6),
+}
+
+
+@pytest.mark.parametrize("family", list(_EXPERT))
+def test_tpu_expert_decode_multiplies_groups_in_the_kernel(
+        one_chip, family, monkeypatch):
+    """The three expert families' decode programs at the cells'
+    shapes, traced as on a TPU (the platform is the one thing a
+    described chip cannot tell the predicate) and compiled for the
+    v5e: no `ragged-dot` is left (XLA's carries `ragged_dot_tiling=
+    "512,512,512"`), the Mosaic calls are there, pools and state are
+    still aliased, the temporaries stay within the fit tests' limits
+    and far under one layer's experts (no stack is sliced or copied),
+    and the program fits the chip. The longest prefill bucket takes
+    the kernel too where its rows fit in VMEM beside a block of
+    weights. (Traced on the CPU every program keeps `ragged_dot`:
+    the digests above.)"""
+    from paddle_tpu.incubate.nn import pallas
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    make, temp_mib, one_layers_experts = _EXPERT[family]
+    pools, programs, kw = make(sd)
+    pools = pools if isinstance(pools, tuple) else (pools,)
+
+    def lowered(program, **more):
+        fn, args = programs[program]
+        return jax.jit(functools.partial(fn, **more, **kw),
+                       donate_argnums=(3,)).lower(*args)
+
+    more = {} if family == "lfm2" else {"use_kernel": True}
+    # traced as here, on the CPU, the layer keeps `ragged_dot`
+    text = lowered("decode", **more).as_text()
+    assert "ragged_dot" in text and "grouped_matmul" not in text
+    monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+    text = lowered("decode", **more).as_text()
+    assert "grouped_matmul" in text and "ragged_dot" not in text
+    compiled = lowered("decode", **more).compile()
+    hlo = compiled.as_text()
+    assert "ragged" not in hlo and "tpu_custom_call" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    assert mem.temp_size_in_bytes < temp_mib * 2 ** 20, (
+        f"{mem.temp_size_in_bytes / 2 ** 20:.0f} MiB of temporaries")
+    assert mem.temp_size_in_bytes < one_layers_experts / 4
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * 2 ** 30
+    # the longest prefill bucket: 8192 rows of 2048 values fit in
+    # VMEM beside a block of weights, LongCat's 24 576 of 6144 do not
+    prefill = lowered("prefill").as_text()
+    if family == "longcat":
+        assert "ragged_dot" in prefill and "grouped_matmul" not in prefill
+    else:
+        assert "ragged_dot" not in prefill and "grouped_matmul" in prefill
+        mem = lowered("prefill").compile().memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 15.75 * 2 ** 30
+
