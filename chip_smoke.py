@@ -11,7 +11,10 @@ published width (24 layers, hidden 1024, 16 heads x 64, FFN 4096, vocab
              agrees with dense attention on the same seeded model.
   serve      LLMEngine(max_batch=8), 8 mixed-length requests x 64 new tokens
              under continuous batching; every request returns its 64 tokens,
-             no KV block leaks, pool sized from the device's HBM.
+             no KV block leaks, pool sized from the device's HBM; the wait
+             for the device carries the host's accounting, and a traced pass
+             leaves the two clocks a non-empty interval (no program starts
+             before its enqueue opened or ends after its wait closed).
   kernels    every shipped Pallas kernel compiled by Mosaic (not interpreted)
              at GPT-2 345M geometry and compared with its in-repo reference.
   multichip  on a host with >= 4 chips: DistributedTrainStepCompiler at dp=4
@@ -260,7 +263,66 @@ def phase_serve(width, lens, new_tokens, max_batch):
                  f"gap {statistics.median(gaps) * 1e3:.1f} ms "
                  f"(max {max(gaps) * 1e3:.1f} ms)")
     _say_peak_hbm("serve")
+    _check_waits(one_pass)
     return {"compile_s": cold_s - warm_s}
+
+
+def _check_waits(one_pass):
+    """The engine's wait spans on this host: what of the host's accounting
+    their ring records carry, and, from one traced pass, the interval the
+    two inequalities leave the clocks (tpubench/readers/waits.py)."""
+    import tempfile
+
+    import jax
+    from paddle_tpu.monitor import flight
+    from tpubench import xplane
+    from tpubench.readers import waits
+
+    recs = [s for s in flight.spans()
+            if s["name"] == flight.SPAN_PREFIX + "serve/decode/wait"]
+    have = sorted(set(waits.STARVED).intersection(*(r["ids"] for r in recs)))
+    say("serve", f"{len(recs)} wait spans, each with {have}")
+    on_chip = jax.devices()[0].platform != "cpu"
+    # every wait carries what this host keeps (the v5e host of PR 36, a
+    # sandboxed kernel, kept neither file), and nothing ran backwards
+    want = {name for name, path in (("runq_us", flight._SCHEDSTAT),
+                                    ("pressure_us", flight._PRESSURE))
+            if os.path.exists(path)}
+    assert recs and set(have) == want, (have, want)
+    assert all(r["ids"][k] >= 0 for r in recs for k in have)
+    with tempfile.TemporaryDirectory() as d:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            one_pass()
+        finally:
+            jax.profiler.stop_trace()
+        import glob
+
+        (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                         "*.xplane.pb"))
+        tr = xplane.Trace.from_file(path)
+        driver, _ = waits.host_plane(path)
+    names = {n for n, *_ in driver}
+    assert {flight.SPAN_PREFIX + n for n in (
+        "serve/decode/put", "serve/decode/ahead", "serve/decode/wait")} \
+        <= names, sorted(names)
+    if not on_chip:
+        say("serve", "traced pass: the three spans are in the host plane; "
+                     "no device plane here")
+        return
+    skew = waits.clock_skew(tr, driver, "serve/decode/enqueue",
+                            "serve/decode/wait", "jit__unknown",
+                            after="serve/decode/put")
+    assert skew is not None and skew["lo_ms"] <= skew["hi_ms"], skew
+    mid = (skew["lo_ms"] + skew["hi_ms"]) / 2
+    say("serve", f"traced pass: device clock + [{skew['lo_ms']:.3f}, "
+                 f"{skew['hi_ms']:.3f}] ms = host clock over "
+                 f"{skew['waits']} waits; corrected by the middle, launch "
+                 f"gap p50 {skew['launch_ms_p50'] + mid:.3f} ms >= 0, wake "
+                 f"gap p50 {skew['wake_ms_p50'] - mid:.3f} ms >= 0")
 
 
 # ---------------------------------------------------------------------------

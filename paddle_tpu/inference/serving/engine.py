@@ -378,12 +378,14 @@ class LLMEngine:
         """step() inside its span `serve/step` (id `step`). Children:
         `serve/schedule` (with a `serve/prefill` for each admission),
         `serve/decode/prepare`, `serve/decode` around
-        `serve/decode/enqueue` and `serve/decode/fetch`,
-        `serve/decode/emit`, and `serve/evict` where a dispatch ran
-        out of memory. A step that finds its dispatch in flight
-        (`_run_ahead`) has no prepare, and its `serve/decode` holds
-        fetch (the next inputs), enqueue (the next dispatch), fetch
-        (the wait)."""
+        `serve/decode/enqueue` (> `serve/decode/put`, the inputs'
+        transfer) and `serve/decode/fetch` (> `serve/decode/ahead`,
+        the next inputs built beside the device, then
+        `serve/decode/wait`, the wait alone), `serve/decode/emit`,
+        and `serve/evict` where a dispatch ran out of memory. A step
+        that finds its dispatch in flight (`_run_ahead`) has no
+        prepare, and its `serve/decode` holds fetch > ahead, enqueue
+        > put (the next dispatch), fetch > wait."""
         emitted = {}
 
         def _on_admit(req):
@@ -691,8 +693,9 @@ class LLMEngine:
             # the seven small arrays in ONE batched transfer: each
             # transfer of its own costs a round of the runtime's
             # latency with the device idle (0.2-0.3 ms on a v5e host)
-            ids, pos, tables, lens, temp, topk, seeds = \
-                jax.device_put(arrays)
+            with _flight.span("serve/decode/put"):
+                ids, pos, tables, lens, temp, topk, seeds = \
+                    jax.device_put(arrays)
             toks, self.cache.pools, stats = decode.bind(
                 self.params, ids, pos, self.cache.pools, tables,
                 lens, temp, topk, seeds)()
@@ -731,24 +734,31 @@ class LLMEngine:
         the token in flight (not by length: `_prepare_ahead`; not by
         the token's value: no request of the batch names one) and
         the batch is full, so that nothing can be admitted."""
-        import jax
-
         _, toks, stats, compiled = inflight
         with _flight.span("serve/decode/fetch"):
             # the wait for the device, used: the next step's inputs
-            self._prepare_ahead()
+            with _flight.span("serve/decode/ahead"):
+                self._prepare_ahead()
             ahead = self._ahead
             if not self.run_ahead or compiled or ahead is None \
                     or not ahead[2] \
                     or len(self.scheduler.running) < self.max_batch:
-                toks, stats = jax.device_get((toks, stats))
-                self._count_stats(stats, self.max_batch)
-                return toks
+                return self._wait_decode(toks, stats)
         self._run_ahead(toks)
         with _flight.span("serve/decode/fetch"):
+            return self._wait_decode(toks, stats)
+
+    def _wait_decode(self, toks, stats):
+        """Block until a decode dispatch's tokens and stats are on the
+        host: `serve/decode/wait` holds the wait alone (a wait span:
+        its ring record says what the host's scheduler did to this
+        thread meanwhile), the counting follows it."""
+        import jax
+
+        with _flight.wait_span("serve/decode/wait"):
             toks, stats = jax.device_get((toks, stats))
-            self._count_stats(stats, self.max_batch)
-            return toks
+        self._count_stats(stats, self.max_batch)
+        return toks
 
     def _count_target_dispatch(self):
         """One target dispatch (decode or verify) into the counters:
@@ -1035,7 +1045,8 @@ class LLMEngine:
                 jnp.asarray(v_seeds))()
         with _flight.span("serve/decode/fetch"):
             self._count_target_dispatch()
-            return np.asarray(toks)
+            with _flight.wait_span("serve/decode/wait"):
+                return np.asarray(toks)
 
     def _spec_decode_batch(self, emitted):
         """One speculative round: k draft dispatches propose, one

@@ -848,8 +848,8 @@ class TrainStepCompiler:
     def __call__(self, *batch):
         """One dispatch, as the span `train/step` (id `step`). Its
         children say what the host was doing: `train/prepare` (here,
-        and again in _run_compiled), `train/enqueue`, `train/block`,
-        `train/finish`; on the first call `compile/train_step` around
+        and again in _run_compiled), `train/enqueue`, `train/block`
+        (a wait span: monitor.flight.wait_span), `train/finish`; on the first call `compile/train_step` around
         the first dispatch, then `compile/capture/<program>`."""
         with _flight.span("train/step", step=self._step):
             return self._step_call(batch)
@@ -948,7 +948,7 @@ class TrainStepCompiler:
             # decomposition and the fleet straggler's top-span table.
             # A dispatch that compiled is skipped: a compile-laced
             # sample would poison the p99
-            with _flight.span("train/block"):
+            with _flight.wait_span("train/block"):
                 jax.block_until_ready(loss)
             dus = int((_time.perf_counter() - t_d0) * 1e6)
             _perf.observe_dispatch(self._perf_name, dus)

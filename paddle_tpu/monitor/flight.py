@@ -17,7 +17,11 @@ Five pieces:
     "paddle_tpu/<layer>/<what>", so whenever any profiler session
     runs the span lies in the trace's host plane on the device's
     clock. in_flight()/begin()/end() and profiler.RecordEvent go
-    through it. Layers: compile, train, serve, io, comm.
+    through it. Layers: compile, train, serve, io, comm. A span
+    where the host only waits for the device is opened with
+    wait_span(): the same span, whose ring record also says what the
+    host's scheduler did to it meanwhile (runq_us, pressure_us:
+    Linux, each where the host keeps it).
 
   * FlightRecorder — a process-wide bounded ring of structured events
     (step begin/end, jit cache miss, compile begin/end, collective
@@ -75,7 +79,8 @@ from . import sanitize as _sanitize
 
 __all__ = [
     "DUMP_SCHEMA", "FlightRecorder", "recorder", "record", "tail",
-    "sync_stats", "SPAN_PREFIX", "Span", "span", "spans", "closed_span",
+    "sync_stats", "SPAN_PREFIX", "Span", "span", "wait_span", "spans",
+    "closed_span",
     "begin", "end", "in_flight", "inflight_snapshot", "Watchdog",
     "start_watchdog", "stop_watchdog", "get_watchdog", "write_dump",
     "dump_dir", "install_excepthook", "uninstall_excepthook",
@@ -308,6 +313,65 @@ def sync_stats():
 _span_ids = itertools.count(1)
 _span_tls = threading.local()
 
+# The host's own accounting of a wait (Linux; a file this host does
+# not have gives no id). Module names, so that a test can point them
+# elsewhere
+_SCHEDSTAT = "/proc/thread-self/schedstat"
+_PRESSURE = "/proc/pressure/cpu"
+_pressure_fd = False    # not looked for yet; None where the host has none
+
+
+def _open(path):
+    try:
+        return os.open(path, os.O_RDONLY)
+    except OSError:
+        return None
+
+
+class _ThreadSched:
+    """One thread's `schedstat`, kept open for the thread's life
+    (`thread-self` is resolved when the file is opened)."""
+
+    __slots__ = ("fd",)
+
+    def __init__(self):
+        self.fd = _open(_SCHEDSTAT)
+
+    def __del__(self):
+        if self.fd is not None:
+            os.close(self.fd)
+
+
+def _host_reading():
+    """{id: microseconds so far} of the host's accounting a wait span
+    keeps the growth of, each where the host has it: `runq_us`, the
+    calling thread's time runnable without a CPU (`schedstat`'s second
+    field), and `pressure_us`, the time in which some runnable task of
+    the host had none (the `some` line's total of `/proc/pressure/cpu`).
+    Two small reads of files kept open; empty outside Linux and where
+    the host keeps neither; never raises."""
+    global _pressure_fd
+    if not sys.platform.startswith("linux"):
+        return {}
+    if _pressure_fd is False:
+        _pressure_fd = _open(_PRESSURE)
+    try:
+        sched = _span_tls.sched
+    except AttributeError:
+        sched = _span_tls.sched = _ThreadSched()
+    out = {}
+    try:
+        if sched.fd is not None:
+            out["runq_us"] = int(
+                os.pread(sched.fd, 128, 0).split()[1]) // 1000
+        if _pressure_fd is not None:
+            data = os.pread(_pressure_fd, 256, 0)
+            i = data.index(b"total=") + 6
+            out["pressure_us"] = int(data[i:data.index(b"\n", i)])
+    except (OSError, ValueError, IndexError):
+        pass
+    return out
+
 
 class Span:
     """One program span: `with span(...)`, or begin()/end() where a
@@ -315,15 +379,20 @@ class Span:
     thread when it began. end() on another thread, or before a span
     opened later on the same thread has ended, still closes the ring
     record; only the annotation of such a span is not to be relied
-    on (the profiler writes it where and when end() ran)."""
+    on (the profiler writes it where and when end() ran). A span made
+    with `wait=True` (wait_span()) reads the host's accounting before
+    its clock starts and after it stops, and its ring record carries
+    the growth beside its ids."""
 
     __slots__ = ("name", "ids", "sid", "parent", "tid", "t0", "_ann",
-                 "_stack")
+                 "_stack", "_host")
 
-    def __init__(self, name, ids):
+    def __init__(self, name, ids, wait=False):
         self.name = SPAN_PREFIX + name
         self.ids = ids
         self.t0 = None
+        # a wait's reading of the host's accounting at begin()
+        self._host = {} if wait else None
 
     def begin(self):
         try:
@@ -337,6 +406,8 @@ class Span:
         stack.append(self)
         self._ann = TraceAnnotation(self.name, **self.ids)
         self._ann.__enter__()
+        if self._host is not None:
+            self._host = _host_reading()
         self.t0 = time.perf_counter()
         return self
 
@@ -344,6 +415,11 @@ class Span:
         if self.t0 is None:
             return
         t1 = time.perf_counter()
+        ids = self.ids
+        if self._host:
+            now = _host_reading()
+            ids = dict(ids, **{k: now[k] - v for k, v
+                               in self._host.items() if k in now})
         self._ann.__exit__(None, None, None)
         stack = self._stack
         if stack and stack[-1] is self:
@@ -354,7 +430,7 @@ class Span:
             except ValueError:
                 pass
         recorder.record_span((self.sid, self.parent, self.tid, self.name,
-                              self.t0, t1, self.ids or None))
+                              self.t0, t1, ids or None))
         self.t0 = None
 
     __enter__ = begin
@@ -395,6 +471,19 @@ def span(name, **ids):
     if not recorder.enabled:
         return _NO_SPAN
     return Span(name, ids)
+
+
+def wait_span(name, **ids):
+    """span() for a stretch in which the host only waits for the device
+    (`serve/decode/wait`, `train/block`): its ring record also carries
+    `runq_us` (the waiting thread's time runnable without a CPU) and
+    `pressure_us` (time in which some runnable task of the host had
+    none), each where this host keeps it (Linux; a sandboxed kernel
+    may keep neither). They tell a stall of the host's scheduler from
+    one of the runtime or the device."""
+    if not recorder.enabled:
+        return _NO_SPAN
+    return Span(name, ids, wait=True)
 
 
 def spans(since=None):

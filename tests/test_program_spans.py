@@ -6,6 +6,7 @@ profiler.RecordEvent go through it; the engine records its programs'
 memory footprints and counts a compile-time out-of-memory."""
 import glob
 import os
+import sys
 import threading
 
 import numpy as np
@@ -167,6 +168,116 @@ def test_dump_bundle_carries_the_span_tail(tmp_path, monkeypatch):
     assert tail[-1]["ids"] == {"step": 3}
 
 
+# -- a wait span: what the host's scheduler did to it ----------------------
+
+HOST_IDS = {"runq_us", "pressure_us"}
+
+
+@pytest.fixture
+def host_files(tmp_path, monkeypatch):
+    """A host made of files under tmp_path: write(name, text) puts one
+    there; flight looks for all of its accounting there and nowhere
+    else, and finds it anew."""
+    monkeypatch.setattr(flight, "_SCHEDSTAT", str(tmp_path / "schedstat"))
+    monkeypatch.setattr(flight, "_PRESSURE", str(tmp_path / "pressure"))
+    monkeypatch.setattr(flight, "_pressure_fd", False)
+    monkeypatch.setattr(flight, "_span_tls", threading.local())
+
+    def write(name, text):
+        (tmp_path / name).write_text(text)
+
+    return write
+
+
+def _wait_ids(grow=None):
+    with flight.wait_span("serve/decode/wait", step=4):
+        if grow is not None:
+            grow()
+    (rec,) = [s for s in flight.spans()
+              if s["name"] == P + "serve/decode/wait"]
+    assert rec["ids"]["step"] == 4
+    return {k: v for k, v in rec["ids"].items() if k != "step"}
+
+
+HAS_SCHED = os.path.exists("/proc/thread-self/schedstat")
+LINUX = pytest.mark.skipif(not sys.platform.startswith("linux"),
+                           reason="the host's accounting is Linux's")
+
+
+@pytest.mark.skipif(not HAS_SCHED, reason="no schedstat on this host")
+def test_a_wait_span_carries_the_threads_own_accounting():
+    def burn():
+        sum(i * i for i in range(200_000))
+
+    ids = _wait_ids(burn)
+    assert "runq_us" in ids and set(ids) <= HOST_IDS
+    assert all(isinstance(v, int) and v >= 0 for v in ids.values())
+    # on another thread the reading is that thread's own
+    got = {}
+    t = threading.Thread(target=lambda: got.update(_wait_ids()))
+    flight.recorder.clear()
+    t.start()
+    t.join(timeout=10)
+    assert got["runq_us"] >= 0
+
+
+def test_a_host_without_the_files_gives_no_id_and_raises_nothing(
+        host_files):
+    """The chip's host (PERF.md section 3): no schedstat, no pressure
+    file."""
+    assert _wait_ids() == {}
+    assert flight._pressure_fd is None
+
+
+def test_outside_linux_a_wait_span_reads_nothing(host_files, monkeypatch):
+    host_files("schedstat", "5000000 7000000 3\n")
+    host_files("pressure", "some avg10=0.00 total=10\n")
+    monkeypatch.setattr(flight.sys, "platform", "darwin")
+    assert _wait_ids() == {}
+
+
+def test_a_plain_span_reads_no_file(monkeypatch):
+    def no_read(*a, **kw):
+        raise AssertionError("a plain span read a file")
+
+    monkeypatch.setattr(flight, "_host_reading", no_read)
+    monkeypatch.setattr(flight.os, "pread", no_read)
+    with flight.span("serve/decode/fetch"):
+        with flight.in_flight("collective", "all_reduce"):
+            pass
+    assert [s["ids"] for s in flight.spans()] == [{}, {}]
+
+
+@pytest.mark.parametrize("files,grown,want", [
+    # the thread's schedstat alone: its second field, nanoseconds in,
+    # microseconds out
+    ({"schedstat": "5000000 7000000 3\n"},
+     {"schedstat": "5250000 7090000 4\n"},
+     {"runq_us": 90}),
+    # the host's pressure: the `some` line's total, not the `full` one's
+    ({"pressure": "some avg10=0.00 avg60=0.00 total=1000\n"
+                  "full avg10=0.00 total=7\n"},
+     {"pressure": "some avg10=1.50 avg60=0.10 total=91000\n"
+                  "full avg10=0.00 total=9\n"},
+     {"pressure_us": 90000}),
+    # a file that does not read as a number gives no id
+    ({"schedstat": "5000000 7000000 3\n", "pressure": "not supported\n"},
+     {"schedstat": "5001000 7002000 3\n"},
+     {"runq_us": 2}),
+])
+@LINUX
+def test_a_wait_span_keeps_the_growth_of_what_the_host_has(
+        host_files, files, grown, want):
+    for name, text in files.items():
+        host_files(name, text)
+
+    def grow():
+        for name, text in grown.items():
+            host_files(name, text)
+
+    assert _wait_ids(grow) == want
+
+
 # -- the sites -----------------------------------------------------------
 
 def _train_step():
@@ -206,6 +317,11 @@ def test_train_dispatch_leaves_the_span_tree():
         "train/block", "train/finish"))
     steps = [s for s in flight.spans() if s["name"] == P + "train/step"]
     assert [s["ids"]["step"] for s in steps] == [0, 1, 2]
+    # the block is a wait span, and the only one of the step
+    waits = [s["name"] for s in flight.spans() if HOST_IDS & set(s["ids"])]
+    assert set(waits) <= {P + "train/block"}
+    if HAS_SCHED:
+        assert waits == [P + "train/block"]
     compiles = [s for s in flight.spans()
                 if s["name"] == P + "compile/train_step"]
     assert [s["ids"] for s in compiles] == [
@@ -232,6 +348,14 @@ def _sampling(n):
     return SamplingParams(max_new_tokens=n)
 
 
+# where the engine meets the runtime: the inputs' transfer inside the
+# enqueue; inside the fetch the next inputs, built beside the device,
+# then the wait alone
+_ENQUEUE = ("serve/decode/enqueue", [("serve/decode/put", [])])
+_FETCH = ("serve/decode/fetch", [("serve/decode/ahead", []),
+                                 ("serve/decode/wait", [])])
+
+
 def test_engine_step_leaves_the_span_tree():
     engine = _engine()
     rid = engine.add_request([1, 2, 3], _sampling(3))
@@ -248,21 +372,27 @@ def test_engine_step_leaves_the_span_tree():
         ("serve/decode/prepare", []),
         ("serve/decode", [
             (f"compile/{decode}", [
-                ("serve/decode/enqueue",
-                 [(f"compile/capture/{decode}", [])])]),
-            ("serve/decode/fetch", [])]),
+                ("serve/decode/enqueue", [
+                    ("serve/decode/put", []),
+                    (f"compile/capture/{decode}", [])])]),
+            _FETCH]),
         ("serve/decode/emit", [])])
     assert second == ("serve/step", [
         ("serve/schedule", []),
         ("serve/decode/prepare", []),
-        ("serve/decode", _leaf("serve/decode/enqueue",
-                               "serve/decode/fetch")),
+        ("serve/decode", [_ENQUEUE, _FETCH]),
         ("serve/decode/emit", [])])
     spans = flight.spans()
     assert [s["ids"]["step"] for s in spans
             if s["name"] == P + "serve/step"] == [1, 2]
     pre = next(s for s in spans if s["name"] == P + "serve/prefill")
     assert pre["ids"] == {"req": req, "padded": 8, "tokens": 3}
+    # the wait, and no other span of the engine, is a wait span
+    assert {s["name"] for s in spans if HOST_IDS & set(s["ids"])} \
+        <= {P + "serve/decode/wait"}
+    if HAS_SCHED:
+        assert all("runq_us" in s["ids"] for s in spans
+                   if s["name"] == P + "serve/decode/wait")
     # the engine's programs count their dispatches as the jit's do
     assert cmon.stat_get(f"jit/{decode}/cache_miss") >= 1
     assert cmon.stat_get(f"jit/{decode}/cache_hit") >= 1
@@ -275,7 +405,8 @@ def test_a_step_that_runs_ahead_encloses_the_next_enqueue():
     dispatch in flight (no `serve/decode/prepare`, no enqueue of its
     own) and hands over the next between two `serve/decode/fetch`
     spans, side by side, so that a reader that adds up a step's
-    children by name counts each second once."""
+    children by name counts each second once: fetch > ahead, enqueue
+    > put, fetch > wait."""
     engine = _engine(run_ahead=True)
     for prompt in ([1, 2, 3], [4, 5]):
         engine.add_request(prompt, _sampling(6))
@@ -285,16 +416,29 @@ def test_a_step_that_runs_ahead_encloses_the_next_enqueue():
     assert [name for name, _ in steps[1][1]] == [
         "serve/schedule", "serve/decode/prepare", "serve/decode",
         "serve/decode/emit"]
-    assert steps[1][1][2] == ("serve/decode", _leaf(
-        "serve/decode/enqueue", "serve/decode/fetch",
-        "serve/decode/enqueue", "serve/decode/fetch"))
+    ahead = ("serve/decode/fetch", _leaf("serve/decode/ahead"))
+    wait = ("serve/decode/fetch", _leaf("serve/decode/wait"))
+    assert steps[1][1][2] == ("serve/decode", [
+        _ENQUEUE, ahead, _ENQUEUE, wait])
     for step in steps[2:]:
         assert step == ("serve/step", [
             ("serve/schedule", []),
-            ("serve/decode", _leaf(
-                "serve/decode/fetch", "serve/decode/enqueue",
-                "serve/decode/fetch")),
+            ("serve/decode", [ahead, _ENQUEUE, wait]),
             ("serve/decode/emit", [])])
+
+
+def test_a_verify_dispatch_waits_inside_its_fetch():
+    engine = _engine(spec_k=3)
+    engine.add_request([1, 2, 3], _sampling(6))
+    engine.step()
+    flight.recorder.clear()
+    engine.step()
+    by_id = {s["id"]: s for s in flight.spans()}
+    (wait,) = [s for s in by_id.values()
+               if s["name"] == P + "serve/decode/wait"]
+    fetch = by_id[wait["parent"]]
+    assert fetch["name"] == P + "serve/decode/fetch"
+    assert fetch["start"] <= wait["start"] <= wait["end"] <= fetch["end"]
 
 
 def test_engine_records_program_memory_and_tpubench_reads_it():
