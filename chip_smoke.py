@@ -346,6 +346,7 @@ def k_flash(g, interpret):
     import numpy as np
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.core.monitor import stat_get
     from paddle_tpu.incubate.nn.attention_pallas import (_attn_ref,
                                                          flash_attention)
 
@@ -363,6 +364,8 @@ def k_flash(g, interpret):
         o = _attn_ref(q, k, v, True, scale)[1]
         return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
 
+    paths = ("kernels/flash/resident", "kernels/flash/streamed")
+    before = [stat_get(p) for p in paths]
     (_, o1), g1 = jax.jit(jax.value_and_grad(
         loss_flash, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     (_, o2), g2 = jax.jit(jax.value_and_grad(
@@ -370,7 +373,10 @@ def k_flash(g, interpret):
     errs = [_close("flash fwd", o1, o2, 2e-2)]
     errs += [_close(f"flash d{n}", a, b_, 3e-2)
              for n, a, b_ in zip("qkv", g1, g2)]
-    return f"fwd+bwd B{b} H{h} S{s} D{d} bf16, max err {max(errs):.3g}"
+    resident, streamed = (stat_get(p) - n for p, n in zip(paths, before))
+    return (f"fwd+bwd B{b} H{h} S{s} D{d} bf16, default blocks, max err "
+            f"{max(errs):.3g}; calls resident {resident}, streamed "
+            f"{streamed}")
 
 
 def k_layernorm(g, interpret):

@@ -35,7 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["fused_layer_norm", "fused_residual_layer_norm"]
 
 # per-row stats ride a small trailing lane dim (TPU tiling rule: block
-# last dim == full array dim) — same layout as attention_pallas
+# last dim == full array dim)
 _STAT_LANES = 8
 
 _MAX_BLOCK_ROWS = 256

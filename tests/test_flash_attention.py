@@ -10,6 +10,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.core.monitor import stat_get
+from paddle_tpu.incubate.nn import attention_pallas as ap
 from paddle_tpu.incubate.nn.attention_pallas import (
     _attn_ref, flash_attention)
 
@@ -73,6 +75,27 @@ def test_flash_block_q_ne_block_k():
                                rtol=2e-5, atol=2e-5)
 
 
+def _flash_paths():
+    return (stat_get("kernels/flash/resident"),
+            stat_get("kernels/flash/streamed"))
+
+
+def _loss_8k(q, k, v):
+    return jnp.sum(flash_attention(q, k, v, True, 0.125).astype(
+        jnp.float32))
+
+
+def test_flash_8k_takes_the_streamed_path():
+    """8192 keys of 64 bf16 values do not fit the residency budget:
+    forward and backward stream 1024-row major blocks, chosen by shape
+    alone and counted while the program is traced (nothing runs)."""
+    sd = jax.ShapeDtypeStruct((1, 4, 8192, 64), jnp.bfloat16)
+    resident, streamed = _flash_paths()
+    jax.eval_shape(jax.value_and_grad(_loss_8k, argnums=(0, 1, 2)),
+                   sd, sd, sd)
+    assert _flash_paths() == (resident, streamed + 2)
+
+
 @pytest.mark.skipif(not ON_TPU, reason="long-seq memory test needs TPU")
 def test_flash_long_sequence_8k():
     """seq=8192: dense attention would materialize a 8k x 8k f32 score
@@ -82,16 +105,132 @@ def test_flash_long_sequence_8k():
     q = q.astype(jnp.bfloat16)
     k = k.astype(jnp.bfloat16)
     v = v.astype(jnp.bfloat16)
-    scale = 0.125
-
-    def f(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, scale).astype(
-            jnp.float32))
-
-    loss, grads = jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, v)
+    resident, streamed = _flash_paths()
+    loss, grads = jax.jit(jax.value_and_grad(
+        _loss_8k, argnums=(0, 1, 2)))(q, k, v)
+    assert _flash_paths() == (resident, streamed + 2)
     assert np.isfinite(float(loss))
     for g in grads:
         assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32))))
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 37: the walked operand whole in VMEM (resident) or in major
+# blocks (streamed), the walk to the diagonal
+# ---------------------------------------------------------------------------
+
+def _dense_top_left(q, k, v, causal, scale):
+    """Dense attention with the kernels' causal alignment: query i
+    sees keys <= i, also where sq != sk (`_attn_ref` aligns the LAST
+    query with the last key there)."""
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        sq, sk = logits.shape[-2:]
+        logits = jnp.where(jnp.tril(jnp.ones((sq, sk), bool)), logits,
+                           -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(logits, -1), v)
+
+
+def _check_fwd_and_grads(sq, sk, d, causal, bq, bk, seed=11):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(1, 2, sq, d), jnp.float32) * 0.5
+    k = jnp.asarray(rng.randn(1, 2, sk, d), jnp.float32) * 0.5
+    v = jnp.asarray(rng.randn(1, 2, sk, d), jnp.float32) * 0.5
+    w = jnp.asarray(rng.randn(1, 2, sq, d), jnp.float32)
+    scale = 1.0 / np.sqrt(d)      # a power of two at 64, not at 128
+    ref = _dense_top_left if sq != sk else (
+        lambda *a: _attn_ref(*a)[1])
+
+    def f_flash(q, k, v):
+        o = flash_attention(q, k, v, causal, scale, bq, bk, True)
+        return jnp.sum(o * w), o
+
+    def f_ref(q, k, v):
+        o = ref(q, k, v, causal, scale)
+        return jnp.sum(o * w), o
+
+    (_, o1), g1 = jax.value_and_grad(f_flash, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    (_, o2), g2 = jax.value_and_grad(f_ref, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
+                               rtol=2e-5, atol=2e-5)
+    for gf, gr, name in zip(g1, g2, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"d{name} mismatch")
+    return o1, g1
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sq,sk", [(512, 512), (129, 129), (384, 256),
+                                   (256, 384)],
+                         ids=["divides", "padded129", "cross",
+                              "cross_sk_gt_sq"])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256)],
+                         ids=["bq_eq_bk", "bq_gt_bk", "bq_lt_bk"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_resident_matches_dense(causal, bq, bk, sq, sk, d):
+    """K/V (Q/dO for dkv) whole in VMEM, the walk inside the body:
+    forward and all three gradients against dense attention."""
+    resident, streamed = _flash_paths()
+    _check_fwd_and_grads(sq, sk, d, causal, bq, bk)
+    assert _flash_paths() == (resident + 2, streamed)
+
+
+def test_flash_cell_shape_default_blocks():
+    """The train cells' own shape with few heads (1 x 2 x 1024 x 64)
+    at the default blocks: resident, forward and backward."""
+    resident, streamed = _flash_paths()
+    _check_fwd_and_grads(1024, 1024, 64, True, ap.DEFAULT_BLOCK_Q,
+                         ap.DEFAULT_BLOCK_K)
+    assert _flash_paths() == (resident + 2, streamed)
+
+
+@pytest.mark.parametrize("sq,sk,rows", [(512, 512, 256), (129, 129, 128),
+                                        (384, 256, 128), (256, 384, 128)],
+                         ids=["divides", "padded129", "cross",
+                              "cross_sk_gt_sq"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_streamed_matches_dense(monkeypatch, causal, sq, sk, rows):
+    """The same kernels with the walked operand in major blocks over
+    the innermost grid axis and the accumulators in scratch between
+    steps: the budget shrunk so that small shapes stream (on the chip
+    the shape decides: test_flash_8k_takes_the_streamed_path)."""
+    monkeypatch.setattr(ap, "_RESIDENT_BYTES", 0)
+    monkeypatch.setattr(ap, "_STREAM_ROWS", rows)
+    resident, streamed = _flash_paths()
+    _check_fwd_and_grads(sq, sk, 64, causal, 128, 128)
+    assert _flash_paths() == (resident, streamed + 2)
+
+
+@pytest.mark.parametrize("sq,sk,d,itemsize,want", [
+    (1024, 1024, 64, 2, (1024, 1024)),   # the train cells: whole
+    (2048, 2048, 128, 2, (2048, 2048)),  # the largest square unrolled
+    (2048, 2048, 256, 2, (2048, 2048)),  # 2 MiB a buffer: the last whole
+    (2048, 2048, 256, 4, (1024, 1024)),  # f32 rows weigh twice: streamed
+    (4096, 4096, 64, 2, (1024, 1024)),   # fits VMEM, too long to unroll
+    (8192, 8192, 64, 2, (1024, 1024)),
+    (8192, 256, 64, 2, (1024, 256)),     # a short side is one block
+    (1152, 1152, 64, 2, (1152, 1152)),
+    (3456, 3456, 64, 2, (384, 384)),     # 27 x 128: whole sub-blocks
+])
+def test_block_rows_follow_the_operands_bytes(sq, sk, d, itemsize, want):
+    assert ap._block_rows(sq, 256 if sq % 256 == 0 else 128, sk,
+                          256 if sk % 256 == 0 else 128, d, itemsize) == want
+
+
+@pytest.mark.parametrize("causal,padded,want", [
+    (False, False, {(None, False): (0, 0)}),
+    (False, True, {(None, False): (0, 0), (None, True): (0, 3)}),
+    # below the diagonal, on it; above it: no body, no fetch
+    (True, False, {(0, False): (0, 0), (None, False): (1, 0)}),
+    (True, True, {(0, False): (0, 0), (None, False): (1, 0),
+                  (0, True): (3, 3)}),
+])
+def test_meetings_of_a_streamed_square(causal, padded, want):
+    """4 x 4 blocks of 1024: the bodies a streamed kernel holds."""
+    assert ap._meetings(4, 1024, 4, 1024, causal, padded) == want
 
 
 # ---------------------------------------------------------------------------

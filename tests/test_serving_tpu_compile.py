@@ -538,3 +538,43 @@ def test_tpu_expert_decode_multiplies_groups_in_the_kernel(
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 15.75 * 2 ** 30
 
+
+
+@pytest.mark.parametrize("shape,sk,dtype,causal,want", [
+    ((12, 16, 1024, 64), 1024, jnp.bfloat16, True, "resident"),  # the cells
+    ((2, 16, 4096, 128), 4096, jnp.bfloat16, True, "streamed"),  # too long
+    ((1, 4, 2048, 256), 2048, jnp.bfloat16, True, "resident"),   # > 16 MiB
+    ((1, 4, 8192, 64), 8192, jnp.bfloat16, True, "streamed"),
+    ((2, 4, 4096, 64), 4096, jnp.float32, False, "streamed"),
+    # key blocks past the last query: dkv's index maps stay in range
+    ((1, 4, 4096, 64), 8192, jnp.bfloat16, True, "streamed"),
+], ids=["cell", "4k-d128", "2k-d256", "8k-streamed", "4k-f32-streamed",
+        "4k-x-8k-streamed"])
+def test_tpu_flash_kernels_compile_by_shape(one_chip, shape, sk, dtype,
+                                            causal, want):
+    """ISSUE 37: the three flash kernels at the default blocks, compiled
+    by Mosaic for the v5e at real widths (the interpreter checks the
+    mathematics, not a slice's alignment or the VMEM a body needs): a
+    train step's attention is exactly three Mosaic calls, and the
+    operands' bytes alone choose between holding the walked operand
+    whole and streaming it."""
+    from paddle_tpu.core.monitor import stat_get
+    from paddle_tpu.incubate.nn.attention_pallas import flash_attention
+
+    sd = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    kd = jax.ShapeDtypeStruct((*shape[:2], sk, shape[3]), dtype,
+                              sharding=one_chip)
+    scale = shape[-1] ** -0.5
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal, scale)
+                       .astype(jnp.float32))
+
+    paths = ("kernels/flash/resident", "kernels/flash/streamed")
+    before = [stat_get(p) for p in paths]
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sd, kd, kd).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    moved = {p.rsplit("/", 1)[1]: stat_get(p) - n
+             for p, n in zip(paths, before)}
+    assert moved == {"resident": 0, "streamed": 0, want: 2}
