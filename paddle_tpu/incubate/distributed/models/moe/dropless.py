@@ -50,7 +50,11 @@ LongCat-Flash's router also has outputs beyond the real experts,
 itself: `identity_expert_sum`, no matmul. Its rule
 (`softmax_topk_route`): `s = softmax(u W_r)` over real and zero
 experts alike, the `top_k` largest of `s + b` chosen, weights `scale
-* s_i`, NOT renormalised.
+* s_i`, NOT renormalised. Mellum's is the third combination (softmax
+scores, no bias, renormalised): all three are `topk_route(..., score,
+renormalise)`, ONE function of the score function and of whether the
+chosen scores are renormalised (PR 38), the two names above its
+cases, in the same order of operations as before.
 """
 from __future__ import annotations
 
@@ -59,36 +63,46 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["sigmoid_topk_route", "softmax_topk_route",
+__all__ = ["topk_route", "sigmoid_topk_route", "softmax_topk_route",
            "dropless_expert_ffn", "identity_expert_sum", "expert_counts",
            "expert_kernel_supported"]
 
 
-def sigmoid_topk_route(u, router_w, bias, top_k, scale):
-    """u [T, H] -> (expert ids [T, k] int32, weights [T, k] float32).
-    The matmul runs in true float32 (`HIGHEST`: the TPU's default is
-    one bf16 pass, which flips near-ties between the k-th and the
-    next expert)."""
-    scores = jax.nn.sigmoid(jnp.dot(
+def topk_route(u, router_w, bias, top_k, scale, score, renormalise):
+    """u [T, H] -> (expert ids [T, k] int32 over ALL the router's
+    outputs, weights [T, k] float32): the ONE routing rule, of which
+    the served models publish three cases. Scores `s = score(u W_r)`
+    (`jax.nn.sigmoid`, or `jax.nn.softmax` over the outputs) in
+    float32; the matmul runs in true float32 (`HIGHEST`: the TPU's
+    default is one bf16 pass, which flips near-ties between the k-th
+    and the next expert); the `top_k` largest of `s + bias` chosen
+    (`bias` None: of `s`); weights `scale * s_i`, over the sum of the
+    chosen (+ 1e-20) where `renormalise`."""
+    scores = score(jnp.dot(
         u.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    _, idx = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32),
+        top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    weights = scale * chosen
+    if renormalise:
+        weights = weights / (chosen.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), weights
+
+
+def sigmoid_topk_route(u, router_w, bias, top_k, scale):
+    """GLM-4.7-Flash's and LFM2's case: sigmoid scores, chosen by
+    `s + bias`, renormalised."""
+    return topk_route(u, router_w, bias, top_k, scale, jax.nn.sigmoid,
+                      True)
 
 
 def softmax_topk_route(u, router_w, bias, top_k, scale):
-    """u [T, H] -> (ids [T, k] int32 over ALL the router's outputs,
-    weights [T, k] float32 = `scale` x the chosen softmax scores as
-    they are). Chosen by `s + bias`; float32 at `HIGHEST`, as
-    `sigmoid_topk_route`."""
-    scores = jax.nn.softmax(jnp.dot(
-        u.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST), axis=-1)
-    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
-    weights = scale * jnp.take_along_axis(scores, idx, axis=-1)
-    return idx.astype(jnp.int32), weights
+    """LongCat-Flash's case: softmax scores over real and zero
+    experts alike, chosen by `s + bias`, NOT renormalised."""
+    return topk_route(u, router_w, bias, top_k, scale,
+                      functools.partial(jax.nn.softmax, axis=-1), False)
 
 
 def expert_counts(idx, n_experts, live=None):

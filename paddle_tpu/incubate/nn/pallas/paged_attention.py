@@ -160,13 +160,15 @@ def _pages_per_group(block_size):
     return max(1, 128 // block_size)
 
 
-def _first_copies(b, last, groups, start, parity_ref):
+def _first_copies(b, last, groups, start, parity_ref, first=None):
     """The opening of a grid step, for both kernels. `parity_ref`
     holds the parity of the groups walked so far: `base`, the tile
     (of two) this sequence's first group lands in. That group is on
     its way already where the sequence before had a last group to
-    start it from; else its copies start here. Returns (base, this
-    sequence's groups, the next sequence)."""
+    start it from; else its copies start here. `groups(i)` is where
+    sequence i's walk ends, `first(i)` where it starts (a window's
+    first live group; None: group 0). Returns (base, the end of this
+    sequence's walk, the next sequence)."""
     @pl.when(b == 0)
     def _first():
         parity_ref[0] = 0
@@ -178,16 +180,18 @@ def _first_copies(b, last, groups, start, parity_ref):
 
     @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
     def _own_first():
-        start(b, 0, base)
+        start(b, 0 if first is None else first(b), base)
 
     return base, n_groups, after
 
 
-def _next_copies(b, last, j, base, n_groups, after, groups, start):
+def _next_copies(b, last, j, base, n_groups, after, groups, start,
+                 first=None):
     """Inside group j: the copies of group j + 1 (from a sequence's
     last group, of the next sequence's first) start into the other
     tile before this one is waited for. Returns this group's tile."""
-    slot = (base + j) % 2
+    slot = (base + j) % 2 if first is None \
+        else (base + j - first(b)) % 2
 
     @pl.when(j + 1 < n_groups)
     def _next_group():
@@ -195,7 +199,7 @@ def _next_copies(b, last, j, base, n_groups, after, groups, start):
 
     @pl.when((j + 1 == n_groups) & (b < last) & (groups(after) > 0))
     def _next_sequence():
-        start(after, 0, 1 - slot)
+        start(after, 0 if first is None else first(after), 1 - slot)
 
     return slot
 
@@ -221,9 +225,13 @@ def _softmax_step(s, v, exact, acc_ref, m_ref, l_ref):
 def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
                   o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
                   parity_ref, *, sm_scale, block_size, pages, num_q,
-                  group=1):
+                  group=1, window=None):
     """One grid step: ONE sequence, its T query slots (decode is
-    T = 1) against its live page groups, which the body walks itself.
+    T = 1) against its live page groups, which the body walks itself
+    (with a `window`, decode only, from the first group that holds
+    one of the `window` newest tokens: the rows before `context -
+    window` are masked as the rows past the context are, their pages
+    not copied, the groups before them not walked).
     Row t*H + h of every [T*H, ...] value is head h of slot t (with
     `group` G query heads a K/V head, `sel` has the Hkv K/V heads'
     rows and row (t*G + g)*Hkv + k is query head k*G + g of slot t:
@@ -249,11 +257,19 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
     def groups(i):
         return (seen(i) + (rows - 1)) // rows
 
+    first_group = None
+    if window is not None:
+        def first_group(i):
+            return jnp.maximum(lens_ref[i] - window, 0) // rows
+
     def page_copies(i, j, slot, act):
         for g in range(pages):
             first = (j * pages + g) * block_size
+            holds = first < seen(i)
+            if window is not None:  # ... and one inside the window
+                holds &= first + block_size > lens_ref[i] - window
 
-            @pl.when(first < seen(i))
+            @pl.when(holds)
             def _live():
                 page = tables_ref[i, j * pages + g]
                 to = pl.ds(g * block_size, block_size)
@@ -270,11 +286,12 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         page_copies(i, j, slot, lambda copy: copy.wait())
 
     base, n_groups, after = _first_copies(b, last, groups, start,
-                                          parity_ref)
+                                          parity_ref, first_group)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     ctx = lens_ref[b]                # tokens visible to query slot 0
+    lo = 0 if window is None else first_group(b)
     if num_q > 1:                    # [T*H, 1]: slot t sees t more
         row = jax.lax.broadcasted_iota(
             jnp.int32, (q_rows * heads, 1), 0)
@@ -296,14 +313,17 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
 
     def group(j, carry):
         slot = _next_copies(b, last, j, base, n_groups, after, groups,
-                            start)
+                            start, first_group)
         wait(b, j, slot)
         k = k_buf[slot]                                      # [R, H*D]
         # rows no copy wrote hold what the tile held before: masked
         # out of the scores below, and zeroed here so that a zero
         # weight meets a zero
-        live = j * rows + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, 1), 0) < seen(b)
+        v_pos = j * rows + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0)
+        live = v_pos < seen(b)
+        if window is not None:
+            live &= v_pos >= ctx - window
         v = jnp.where(live, v_buf[slot], 0)
         s = jax.lax.dot_general(
             qx, k, (((1,), (1,)), ((), ())), precision=exact,
@@ -312,14 +332,18 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
             jnp.int32, s.shape, 1)
         # positions past the context (for the shallower slots, past
         # theirs) mask to -inf: p underflows to an exact zero
-        s = jnp.where(k_pos < ctx, s, _NEG_INF)
+        seen_k = k_pos < ctx
+        if window is not None:
+            seen_k &= k_pos >= ctx - window
+        s = jnp.where(seen_k, s, _NEG_INF)
         # every row meets every head's lanes here; the end keeps
         # its own
         _softmax_step(s, v, exact, acc_ref, m_ref, l_ref)
         return carry
 
-    jax.lax.fori_loop(0, n_groups, group, 0)
-    parity_ref[0] = (base + n_groups) % 2
+    jax.lax.fori_loop(lo, n_groups, group, 0)
+    parity_ref[0] = (base + n_groups) % 2 if window is None \
+        else (base + n_groups - lo) % 2
 
     o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)        # [T*H, H*D]
     for t in range(q_rows):
@@ -340,11 +364,13 @@ def _whole_groups(block_tables, pages):
 
 
 def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
-                interpret):
+                interpret, window=None):
     """q [B, T, H, D] through the kernel: grid (B,), the tables and
     lengths as SCALAR PREFETCH arguments, the pools left where they
     are (`pl.ANY`): the body copies the pages it needs itself."""
     b, t, hq, d = q.shape
+    if window is not None and (t > 1 or window < 1):
+        raise ValueError(f"a window ({window}) is one query token's")
     n, bs, h, dk = k_pool.shape
     if dk != d or hq % h:
         raise ValueError(f"pool heads/dim {(h, dk)} under query "
@@ -355,7 +381,8 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
     group = hq // h
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
-        num_q=t, group=group)
+        num_q=t, group=group,
+        **({} if window is None else {"window": int(window)}))
     if group > 1:
         # the G query heads of a K/V head as G rows a slot over the
         # pool's own lanes: [B, T, Hkv, G, D] -> [B, T*G, Hkv*D]
@@ -396,10 +423,15 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                    sm_scale=1.0, interpret=False):
-    """Ragged paged-attention decode: one launch, all sequences."""
+                    sm_scale=1.0, interpret=False, window=None):
+    """Ragged paged-attention decode: one launch, all sequences.
+    `window` (static; None = the whole context): the query sees the
+    `window` newest tokens, itself included, and the walk starts at
+    the first page group that holds one of them: table columns
+    before `(context - window) // BS` are never read (a cache that
+    freed those blocks leaves NULL there)."""
     return _paged_call(q[:, None], k_pool, v_pool, block_tables,
-                       context_lens, sm_scale, interpret)[:, 0]
+                       context_lens, sm_scale, interpret, window)[:, 0]
 
 
 def _gather_context(pool, block_tables):
@@ -413,12 +445,23 @@ def _gather_context(pool, block_tables):
         b, maxb * bs, h, d)
 
 
+def _seen(n_rows, context_lens, window):
+    """[B, rows]: the gathered rows one query token a sequence sees."""
+    pos = jnp.arange(n_rows)[None, :]
+    mask = pos < context_lens[:, None]
+    if window is not None:
+        mask &= pos >= context_lens[:, None] - window
+    return mask
+
+
 def paged_attention_reference(q, k_pool, v_pool, block_tables,
-                              context_lens, sm_scale=1.0):
+                              context_lens, sm_scale=1.0, window=None):
     """Dense gather reference — the math the kernel must match, and
     the engine's CPU fallback. Mirrors the training `_attention`
     softmax exactly (f32 scores, -1e30 mask, softmax, cast, PV) so a
-    paged decode step reproduces the full re-forward loop's tokens."""
+    paged decode step reproduces the full re-forward loop's tokens.
+    With a `window` the rows before `context - window` are masked
+    too (whatever their table columns name)."""
     seq_k = _gather_context(k_pool, block_tables)
     seq_v = _gather_context(v_pool, block_tables)
     if q.shape[1] != seq_k.shape[2]:
@@ -428,13 +471,13 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables,
         qg = q.reshape(b, seq_k.shape[2], -1, d)
         s = jnp.einsum("bkgd,bskd->bkgs", qg, seq_k,
                        preferred_element_type=jnp.float32) * sm_scale
-        mask = jnp.arange(seq_k.shape[1])[None, :] < context_lens[:, None]
+        mask = _seen(seq_k.shape[1], context_lens, window)
         s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
         return jnp.einsum("bkgs,bskd->bkgd", p, seq_v).reshape(b, hq, d)
     s = jnp.einsum("bhd,bshd->bhs", q, seq_k,
                    preferred_element_type=jnp.float32) * sm_scale
-    mask = jnp.arange(seq_k.shape[1])[None, :] < context_lens[:, None]
+    mask = _seen(seq_k.shape[1], context_lens, window)
     s = jnp.where(mask[:, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhs,bshd->bhd", p, seq_v)

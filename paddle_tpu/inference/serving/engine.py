@@ -99,6 +99,7 @@ the router's per-replica health signal.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import time
@@ -219,6 +220,17 @@ class LLMEngine:
         self.prefix_cache = bool(
             prefix_cache if prefix_cache is not None
             else env_prefix_cache())
+        # a window a cache group, where the runner's model has
+        # attentions of several kinds (state_runner; else one group)
+        groups = getattr(runner, "cache_groups", (None,))
+        if len(groups) > 1 and (self.spec_k > 1 or self.prefix_cache):
+            raise NotImplementedError(
+                f"spec_k={self.spec_k} / prefix_cache="
+                f"{self.prefix_cache}: this model keeps window layers' "
+                "rows in cache groups whose blocks are freed behind the "
+                "window, so a shared prefix would lose blocks a later "
+                "sharer needs and a rejected draft's rows could not be "
+                "taken back; serve it with spec_k=1, prefix_cache=False")
         name = type(runner).__name__
         if self.spec_k > 1 and (runner.verify_step is None
                                 or runner.draft_params is None):
@@ -235,7 +247,8 @@ class LLMEngine:
             pool_bytes=pool_bytes, dtype=dtype,
             draft_layers=self.draft_layers,
             prefix_cache=self.prefix_cache,
-            slot_state=runner.slot_state, max_batch=self.max_batch)
+            slot_state=runner.slot_state, max_batch=self.max_batch,
+            groups=groups, max_seq_len=self.max_seq_len)
         self.block_size = self.cache.block_size
         # fixed table width: enough slots for a max-length sequence
         self.max_blocks_per_seq = math.ceil(
@@ -402,8 +415,8 @@ class LLMEngine:
             # never make progress — a pool sized below one request's
             # footprint must be LOUD, not a silent spin
             head = self.scheduler.waiting[0]
-            need = self.cache.blocks_for_tokens(head.context_len) \
-                + self.scheduler._lookahead
+            need = self.cache.blocks_needed(head.context_len,
+                                            self.scheduler._lookahead)
             if need > self.cache.num_blocks - 1:
                 raise RuntimeError(
                     f"KV pool too small: {head.req_id} needs {need} "
@@ -603,6 +616,8 @@ class LLMEngine:
         pos = np.zeros((b,), np.int32)
         tables = np.full((b, self.max_blocks_per_seq), NULL_BLOCK,
                          np.int32)
+        if len(self.cache.groups) > 1:   # a table a cache group
+            tables = np.repeat(tables[None], len(self.cache.groups), 0)
         lens = np.ones((b,), np.int32)
         temp = np.zeros((b,), np.float32)
         topk = np.zeros((b,), np.int32)
@@ -615,7 +630,7 @@ class LLMEngine:
             if not ahead:
                 ids[slot] = (req.output_ids or req.prompt_ids)[-1]
             pos[slot] = n - 1
-            tables[slot] = self.cache.block_table(
+            tables[..., slot, :] = self.cache.block_table(
                 req.req_id, self.max_blocks_per_seq)
             lens[slot] = n
             s = req.sampling
@@ -653,17 +668,28 @@ class LLMEngine:
                 return
             if s.eos_token_id is not None or s.stop_token_ids:
                 by_length = False
-            grow += max(0, cache.blocks_for_tokens(n + 2)
-                        - len(cache.allocator.owned(req.req_id)))
+            grow += cache.short(req.req_id, n + 2)
         if not cache.allocator.can_alloc(grow):
             return
-        for req in list(sched.running.values()):
-            sched.ensure_capacity(req, new_tokens=2)
+        self._grow_tables(2)
         # by_length: no token's VALUE ends a request of this batch, so
         # it is certain now that the next step decodes this batch
         self._ahead = (self._signature(ahead=1),
                        jax.device_put(self._batch_arrays(ahead=1)[1:]),
                        by_length)
+
+    def _grow_tables(self, new_tokens):
+        """Every running request's tables made to cover `new_tokens`
+        more tokens. In a cache of several groups that is also where
+        the window groups' blocks that fell behind go back to the
+        free list, and the upkeep has a span of its own,
+        `serve/decode/blocks` (inside `serve/decode/prepare` or
+        `serve/decode/ahead`)."""
+        sched = self.scheduler
+        with _flight.span("serve/decode/blocks") \
+                if len(self.cache.groups) > 1 else contextlib.nullcontext():
+            for req in list(sched.running.values()):
+                sched.ensure_capacity(req, new_tokens=new_tokens)
 
     def _next_arrays(self):
         """This step's dispatch inputs: what `_prepare_ahead` made
@@ -701,7 +727,7 @@ class LLMEngine:
                 lens, temp, topk, seeds)()
             compiled = decode.compiled()
             decode.capture()
-        self._count_target_dispatch()
+        self._count_target_dispatch(sig)
         return sig, toks, stats, compiled
 
     def _run_ahead(self, toks):
@@ -760,12 +786,18 @@ class LLMEngine:
         self._count_stats(stats, self.max_batch)
         return toks
 
-    def _count_target_dispatch(self):
+    def _count_target_dispatch(self, sig=()):
         """One target dispatch (decode or verify) into the counters:
         `serve/sample/steps_{greedy,drawn,ranked}`, the case its
         sampler takes for this batch, and `serve/attn/steps`, with
         `serve/attn/steps_paged` beside it when the program attends
-        through the block tables in the Pallas kernel."""
+        through the block tables in the Pallas kernel. In a cache of
+        several groups also `serve/attn/steps_windowed` and, summed
+        over the sequences of the dispatch (`sig`, its signature):
+        `serve/kv/{full,window}_blocks_held`, what they hold by kind
+        of group, and `serve/kv/window_blocks_least`, the window
+        groups' blocks that hold a position this dispatch's query
+        can see."""
         case = _mr.sample_case(
             req.sampling for req in self.scheduler.running.values())
         _cmon.stat_add(
@@ -773,6 +805,20 @@ class LLMEngine:
         _cmon.stat_add("serve/attn/steps", 1)
         if self.use_kernel:
             _cmon.stat_add("serve/attn/steps_paged", 1)
+        cache = self.cache
+        if len(cache.groups) > 1:
+            _cmon.stat_add("serve/attn/steps_windowed", 1)
+            full = held = least = 0
+            running = self.scheduler.running
+            for slot, rid, n in sig[1:]:
+                req = running.get(slot)
+                if req is not None and req.req_id == rid:
+                    f, w = cache.held(rid)
+                    full, held = full + f, held + w
+                    least += cache.window_blocks_least(n)
+            _cmon.stat_add("serve/kv/full_blocks_held", full)
+            _cmon.stat_add("serve/kv/window_blocks_held", held)
+            _cmon.stat_add("serve/kv/window_blocks_least", least)
 
     def _count_stats(self, stats, tokens):
         """What a program over `tokens` rows returned beside its
@@ -838,8 +884,7 @@ class LLMEngine:
             # snapshot, and growing an evicted B would strand blocks
             # on a request the dispatch no longer covers
             with _flight.span("serve/decode/prepare"):
-                for req in list(self.scheduler.running.values()):
-                    self.scheduler.ensure_capacity(req, new_tokens=1)
+                self._grow_tables(1)
                 if not self.scheduler.running:
                     return
                 arrays = self._next_arrays()
@@ -1192,7 +1237,7 @@ class LLMEngine:
         mutated-during-iteration under the router's read."""
         lookahead = self.scheduler._lookahead
         pending = sum(
-            self.cache.blocks_for_tokens(r.context_len) + lookahead
+            self.cache.blocks_needed(r.context_len, lookahead)
             for r in list(self.scheduler.waiting))
         return self.cache.allocator.free_blocks - pending
 

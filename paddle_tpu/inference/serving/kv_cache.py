@@ -30,6 +30,29 @@ overwrites it whole. `serve/state/{bytes_per_seq,layers}` gauge it
 beside `serve/kv/{row_values,bytes_per_token}` (0 for a model
 without).
 
+CACHE GROUPS (PR 38): a model whose attentions are of two kinds, some
+over the whole context and some over a sliding window, declares its
+layers in GROUPS of equal size, each of one kind (`groups`: a window
+a group, None for the whole context; the runner reads them from the
+model). The pools then hold one group's layers, `[g, N, BS, width]`,
+and block `n` holds the rows of `block_size` tokens of `g` layers of
+WHICHEVER group owns it: one allocator, one free list, blocks of one
+size. A sequence has one table a group, `[groups, max_blocks]`,
+indexed by the LOGICAL block `position // block_size`, NULL where a
+block was never granted or was freed. A window group holds the
+blocks of the `window + 2` newest positions and no others (the
+window, the token in flight, growth's lookahead: at most `ceil(window
+/ BS) + 2` blocks, whatever the context): `grow()` returns what fell
+behind to the free list before it takes what the next token needs,
+for any sequence and any group to reuse, and `admit()` grants a long
+prompt no more than that (its prefill scatters the earlier
+positions' rows into the NULL block, the rule of a padded tail). All
+of it is host bookkeeping: a freed block is only ever written by a
+LATER dispatch than the last one that read it. A cache of one group
+(every other model's) is the cache it was. No prefix sharing and no
+speculation over a window group: a shared prefix's freed blocks and
+a rejected draft's rows are ROADMAP R6's.
+
 (heads and head_dim share the minor dimension: a token's K or V row
 is `n_head * head_dim` contiguous values, stored row-major and
 unpadded on the device. With `head_dim` alone as the minor dimension
@@ -414,17 +437,40 @@ class BlockAllocator:
         return leaked
 
 
+class _GroupTables:
+    """One sequence's tables in a cache of several groups: `rows`
+    [groups, max_blocks] int32 by logical block (NULL where none is
+    held), `lo[g]` the first logical block group g holds, `hi` the
+    logical blocks granted so far (the same in every group)."""
+
+    __slots__ = ("rows", "lo", "hi")
+
+    def __init__(self, rows, lo, hi):
+        self.rows, self.lo, self.hi = rows, lo, hi
+
+
 class PagedKVCache:
     """The device pools + the allocator + per-request block tables."""
 
     def __init__(self, num_layers, num_heads=None, head_dim=None,
                  block_size=None, num_blocks=None, pool_bytes=None,
                  dtype=None, draft_layers=0, prefix_cache=False,
-                 rows=None, slot_state=(), max_batch=0):
+                 rows=None, slot_state=(), max_batch=0, groups=(None,),
+                 max_seq_len=0):
         import jax.numpy as jnp
 
         self.block_size = int(block_size or env_block_size())
+        # `num_layers` is a GROUP's; one group: the model's
         self.num_layers = int(num_layers)
+        # a window a cache group (None: the whole context)
+        self.groups = tuple(None if w is None else int(w) for w in groups)
+        if len(self.groups) > 1 and (prefix_cache or draft_layers):
+            raise ValueError("no prefix sharing or draft pools over "
+                             "several cache groups")
+        # owner -> _GroupTables; None in a cache of one group, whose
+        # tables are the allocator's lists
+        self._seqs = {} if len(self.groups) > 1 else None
+        self._max_blocks = math.ceil(int(max_seq_len) / self.block_size)
         # one pool per entry, each `[L, N, BS, width]`
         self.rows = tuple(int(w) for w in (
             rows or (num_heads * head_dim,) * 2))
@@ -461,6 +507,9 @@ class PagedKVCache:
         _cmon.stat_set("serve/state/bytes_per_seq", sum(
             s[0] * math.prod(s[2:]) for s in self.slot_state)
             * self.dtype.itemsize)
+        _cmon.stat_set("serve/kv/groups", len(self.groups))
+        _cmon.stat_set("serve/kv/window",
+                       max((w or 0 for w in self.groups)))
 
     def _zero_pools(self):
         import jax.numpy as jnp
@@ -497,6 +546,24 @@ class PagedKVCache:
     def blocks_for_tokens(self, n_tokens):
         return max(1, math.ceil(n_tokens / self.block_size))
 
+    def _first_block(self, n_tokens, window):
+        """The first logical block a group of `window` holds of a
+        sequence whose cache is to cover `n_tokens` tokens: the block
+        of the oldest of the `window + 2` newest positions (the
+        window of the deepest query still to be made, one position
+        for a dispatch that is made again after a failure, one of
+        lookahead)."""
+        if window is None:
+            return 0
+        return max(0, n_tokens - window - 2) // self.block_size
+
+    def blocks_needed(self, n_tokens, lookahead_blocks=0):
+        """Blocks, over all groups, that a sequence of `n_tokens`
+        tokens holds, and `lookahead_blocks` more a group."""
+        top = self.blocks_for_tokens(n_tokens)
+        return sum(top - self._first_block(n_tokens, w) + lookahead_blocks
+                   for w in self.groups)
+
     def can_admit(self, n_tokens, lookahead_blocks=1,
                   cached_blocks=0):
         """Admission control: room for the prompt's blocks (less any
@@ -504,9 +571,72 @@ class PagedKVCache:
         now can generate at least one block of tokens before pool
         pressure. Speculative decoding passes a k-aware lookahead —
         a verify dispatch can land up to k tokens at once."""
-        need = max(0, self.blocks_for_tokens(n_tokens)
-                   - cached_blocks) + lookahead_blocks
+        need = max(0, self.blocks_needed(n_tokens) - cached_blocks) \
+            + lookahead_blocks * len(self.groups)
         return self.allocator.can_alloc(need)
+
+    # -- growth, freeing, release ------------------------------------
+    def short(self, owner, n_tokens):
+        """Blocks `grow(owner, n_tokens)` would take off the free
+        list (what it returns to it first is left out)."""
+        if self._seqs is None:
+            return max(0, self.blocks_for_tokens(n_tokens)
+                       - len(self.allocator.owned(owner)))
+        return max(0, self.blocks_for_tokens(n_tokens)
+                   - self._seqs[owner].hi) * len(self.groups)
+
+    def grow(self, owner, n_tokens):
+        """Make `owner`'s tables cover `n_tokens` tokens. A window
+        group first returns the blocks whose every position has
+        fallen behind (`_first_block`) to the free list. False when
+        the pool cannot cover the rest (the caller's cue to evict;
+        never a partial grant)."""
+        short = self.short(owner, n_tokens)
+        if self._seqs is None:
+            return self.allocator.alloc(owner, short) is not None
+        seq = self._seqs[owner]
+        freed = 0
+        for g, window in enumerate(self.groups):
+            first = min(self._first_block(n_tokens, window), seq.hi)
+            for b in range(seq.lo[g], first):
+                self.allocator.free_one(owner, int(seq.rows[g, b]))
+                seq.rows[g, b] = NULL_BLOCK
+                freed += 1
+            seq.lo[g] = max(seq.lo[g], first)
+        if freed:
+            _cmon.stat_add("serve/kv/window_blocks_freed", freed)
+        got = self.allocator.alloc(owner, short)
+        if got is None:
+            return False
+        if got:
+            top = seq.hi + short // len(self.groups)
+            seq.rows[:, seq.hi:top] = np.reshape(got, (len(self.groups), -1))
+            seq.hi = top
+        return True
+
+    def held(self, owner):
+        """(blocks `owner` holds in the groups over the whole
+        context, in the window groups)."""
+        if self._seqs is None:
+            return len(self.allocator.owned(owner)), 0
+        seq = self._seqs[owner]
+        counts = [seq.hi - lo for lo in seq.lo]
+        full = sum(c for c, w in zip(counts, self.groups) if w is None)
+        return full, sum(counts) - full
+
+    def window_blocks_least(self, n_tokens):
+        """The blocks, over the window groups, that hold a position
+        a query over `n_tokens` tokens can see."""
+        last = (n_tokens - 1) // self.block_size
+        return sum(last - max(0, n_tokens - w) // self.block_size + 1
+                   for w in self.groups if w is not None)
+
+    def release(self, owner):
+        """Drop every block `owner` holds, in every group; returns
+        how many references were dropped."""
+        if self._seqs is not None:
+            self._seqs.pop(owner, None)
+        return self.allocator.release(owner)
 
     # -- prefix cache ------------------------------------------------
     def probe_prefix(self, tokens):
@@ -532,6 +662,8 @@ class PagedKVCache:
         the free list. Returns the cached TOKEN count (0 when the
         cache is off or cold), or None when the pool can't cover the
         uncached remainder — never a partial grant."""
+        if self._seqs is not None:
+            return self._admit_groups(owner, len(tokens))
         total = self.blocks_for_tokens(len(tokens))
         n_shared, shared = self.probe_prefix(tokens)
         fresh = total - n_shared
@@ -547,6 +679,23 @@ class PagedKVCache:
             _cmon.stat_add("serve/prefix/hits", 1)
             _cmon.stat_add("serve/prefix/blocks_shared", n_shared)
         return n_shared * self.block_size
+
+    def _admit_groups(self, owner, n_tokens):
+        """`admit()` in a cache of several groups: every group its
+        own blocks from `_first_block` on, all or none."""
+        top = self.blocks_for_tokens(n_tokens)
+        lo = [self._first_block(n_tokens, w) for w in self.groups]
+        got = self.allocator.alloc(owner, sum(top - f for f in lo))
+        if got is None:
+            return None
+        rows = np.full((len(self.groups), self._max_blocks), NULL_BLOCK,
+                       np.int32)
+        at = 0
+        for g, f in enumerate(lo):
+            rows[g, f:top] = got[at:at + top - f]
+            at += top - f
+        self._seqs[owner] = _GroupTables(rows, lo, top)
+        return 0
 
     def register_prefix(self, owner, tokens):
         """Publish `owner`'s full prompt blocks (written, immutable
@@ -567,7 +716,12 @@ class PagedKVCache:
 
     def block_table(self, owner, max_blocks):
         """Padded int32 device-table row for one request: its owned
-        blocks in token order, NULL_BLOCK beyond."""
+        blocks in token order, NULL_BLOCK beyond. In a cache of
+        several groups a row a group, `[groups, max_blocks]`, by
+        logical block (the sequence's own array: the caller copies
+        it into its batch)."""
+        if self._seqs is not None:
+            return self._seqs[owner].rows
         blocks = self.allocator.owned(owner)
         if len(blocks) > max_blocks:
             raise ValueError(
@@ -626,6 +780,12 @@ class PagedKVCache:
         for owner in owners:
             self.allocator._owned[owner] = [
                 mapping[b] for b in self.allocator._owned[owner]]
+        if self._seqs:
+            renumber = np.arange(self.num_blocks, dtype=np.int32)
+            for old, new in mapping.items():
+                renumber[old] = new
+            for seq in self._seqs.values():
+                seq.rows = renumber[seq.rows]
         self.allocator._refcnt = {
             mapping[b]: c
             for b, c in self.allocator._refcnt.items()}

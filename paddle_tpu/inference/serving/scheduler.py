@@ -19,7 +19,11 @@ policy half (the engine owns the dispatches):
     admission-time faults inject there.
   * Block growth: a running request crossing a block boundary asks
     `ensure_capacity()` for its next block before the dispatch that
-    writes into it.
+    writes into it (in a cache of several groups one a group, after
+    its window groups gave back the blocks that fell behind the
+    window: `PagedKVCache.grow`; admission counts a sequence's need
+    the same way, `ceil(ctx / BS)` blocks in a group over the whole
+    context and at most `ceil(window / BS) + 2` in a window group).
   * Preemption: when the pool can't grow a running request (or the
     dispatch OOMs — the engine routes RESOURCE_EXHAUSTED here), the
     YOUNGEST running request is evicted: its blocks free immediately,
@@ -448,12 +452,10 @@ class Scheduler:
             return False
         if new_tokens is None:
             new_tokens = self.spec_tokens
-        need = self.cache.blocks_for_tokens(
-            request.context_len + new_tokens)
-        while len(self.cache.allocator.owned(request.req_id)) < need:
-            got = self.cache.allocator.alloc(request.req_id, 1)
-            if got is not None:
-                continue
+        # the cache's own rule of what covers so many tokens (a
+        # window group gives back what fell behind, then takes)
+        while not self.cache.grow(request.req_id,
+                                  request.context_len + new_tokens):
             victim = self._pick_victim(exclude=request)
             if victim is None:
                 self.evict(request)
@@ -480,7 +482,7 @@ class Scheduler:
         at the front with its generated tokens kept (re-prefill will
         rebuild the KV it lost)."""
         self.running.pop(request.slot, None)
-        self.cache.allocator.release(request.req_id)
+        self.cache.release(request.req_id)
         self._admitted_at.pop(request.req_id, None)
         request.cached_tokens = 0   # re-admission re-probes
         request._spec_gap = False   # re-prefill rewrites draft KV
@@ -512,7 +514,7 @@ class Scheduler:
         if was_waiting and request in self.waiting:
             self.waiting.remove(request)
             self._sync_depth()
-        self.cache.allocator.release(request.req_id)
+        self.cache.release(request.req_id)
         self._admitted_at.pop(request.req_id, None)
         if state == FINISHED:
             # e2e request latency (ISSUE 15): arrival at THIS engine

@@ -26,6 +26,22 @@ scattered at `(a, blk, off)` and read as `[A*N, ...]` with the
 tables shifted by `a * N` (`model_runner._scan_layers_paged`'s
 rule; the layers are unrolled, `a` and `c` are Python numbers).
 
+Attentions of several kinds (PR 38): a model whose attentions differ
+in what they keep (some the whole context, some a sliding window)
+says so as `model.attention_cache[a] = (group, index in the group,
+window)` and the runner, still naming no model, hands the engine one
+window a CACHE GROUP (`cache_groups`; `kv_cache.PagedKVCache`): the
+pools are then one group's layers deep, `[g, N, BS, Hkv*D]`, the
+tables come one a group (`[groups, ...]`, by logical block, NULL
+where a window group's block was freed or never granted), attention
+`a` scatters and reads through ITS group's table at pool layer
+`index`, its window goes to the dense prefill attention
+(`model.attend_dense(.., window=)`: a prompt's rows from before the
+window land in the NULL block) and to the paged kernel, which then
+walks the window's page groups alone. A model without
+`attention_cache` (one group, no window) traces to the programs it
+had.
+
 - `prefill_step` attends densely over the prompt
   (`model.attend_dense`), scatters every position's K/V rows
   through the block table, and writes the window's tail AT THE
@@ -72,9 +88,15 @@ def _window_tail(zp, prompt_len, n):
     return jax.lax.dynamic_slice_in_dim(zp, prompt_len, n)
 
 
+def _layout(layout, a):
+    """(cache group, pool layer, window) of attention `a`: the
+    model's `attention_cache`, or one group of every attention."""
+    return (0, a, None) if layout is None else layout[a]
+
+
 def prefill_step(params, ids, prompt_len, pools, block_table,
-                 temperature, top_k, seed, slot, *, cfg, model,
-                 block_size):
+                 temperature, top_k, seed, slot=None, *, cfg, model,
+                 block_size, layout=None):
     """Causal forward over one block-padded prompt, ids [1, P], for
     the request that will decode in batch row `slot`. Writes all P
     positions' K/V rows through `block_table` (the padded tail lands
@@ -85,13 +107,19 @@ def prefill_step(params, ids, prompt_len, pools, block_table,
     routing counts over the `prompt_len` real tokens)."""
     p_len = ids.shape[1]
     positions = jnp.arange(p_len)
-    blk, off = _scatter_positions(block_table, positions, block_size)
+    # a table a cache group where the model has several
+    tables = (block_table,) if layout is None else tuple(block_table)
+    blks = [_scatter_positions(t, positions, block_size) for t in tables]
 
     def attend(q, k, v, carry, a):
         kp, vp, *state = carry
-        kp = kp.at[a, blk, off].set(k.astype(kp.dtype))
-        vp = vp.at[a, blk, off].set(v.astype(vp.dtype))
-        return model.attend_dense(q, k, v), (kp, vp, *state)
+        group, layer, win = _layout(layout, a)
+        blk, off = blks[group]
+        kp = kp.at[layer, blk, off].set(k.astype(kp.dtype))
+        vp = vp.at[layer, blk, off].set(v.astype(vp.dtype))
+        out = model.attend_dense(q, k, v) if win is None \
+            else model.attend_dense(q, k, v, window=win)
+        return out, (kp, vp, *state)
 
     def window(z, carry, c):
         *kv, st = carry
@@ -115,7 +143,8 @@ def prefill_step(params, ids, prompt_len, pools, block_table,
 
 def decode_step(params, ids, positions, pools, block_tables,
                 context_lens, temperature, top_k, seeds, *, cfg, model,
-                block_size, use_kernel=False, interpret=False):
+                block_size, use_kernel=False, interpret=False,
+                layout=None):
     """One generation step for the whole running batch, ids and
     positions [B]; `context_lens[b] == positions[b] + 1`. Row b is
     slot b: its windows' tails are `state[:, b]`. Each attention
@@ -127,28 +156,36 @@ def decode_step(params, ids, positions, pools, block_tables,
 
     n_attn, n_blocks = pools[0].shape[:2]
     bsz = ids.shape[0]
-    blk = jnp.take_along_axis(
-        block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
+    # a table a cache group where the model has several: `[groups,
+    # B, MAXB]`, indexed by the logical block
+    group_tables = (block_tables,) if layout is None \
+        else tuple(block_tables)
+    blks = [jnp.take_along_axis(
+        t, (positions // block_size)[:, None], axis=1)[:, 0]
+        for t in group_tables]
     off = positions % block_size
 
     def attend(q, k, v, carry, a):
         kp, vp, *state = carry
-        kp = kp.at[a, blk, off].set(k.astype(kp.dtype))
-        vp = vp.at[a, blk, off].set(v.astype(vp.dtype))
+        group, layer, win = _layout(layout, a)
+        blk = blks[group]
+        kp = kp.at[layer, blk, off].set(k.astype(kp.dtype))
+        vp = vp.at[layer, blk, off].set(v.astype(vp.dtype))
         # the whole pools as one run of blocks, this attention's at
-        # `a * n_blocks`: never sliced, never stacked
+        # `layer * n_blocks`: never sliced, never stacked
         d = q.shape[-1]
         flat = (n_attn * n_blocks, block_size, kp.shape[-1] // d, d)
-        tables = block_tables + a * n_blocks
+        tables = group_tables[group] + layer * n_blocks
         scale = 1.0 / math.sqrt(d)
         if use_kernel:
             out = _pa.paged_attention(
                 q, kp.reshape(flat), vp.reshape(flat), tables,
-                context_lens, sm_scale=scale, interpret=interpret)
+                context_lens, sm_scale=scale, interpret=interpret,
+                window=win)
         else:
             out = _pa.paged_attention_reference(
                 q, kp.reshape(flat), vp.reshape(flat), tables,
-                context_lens, sm_scale=scale)
+                context_lens, sm_scale=scale, window=win)
         return out.reshape(bsz, -1), (kp, vp, *state)
 
     def window(z, carry, c):
@@ -159,7 +196,7 @@ def decode_step(params, ids, positions, pools, block_tables,
     x = jnp.take(params["embed"], ids, axis=0)
     x, pools, stats = model.state_layers(
         params, x, tuple(pools), attend, window, positions,
-        block_tables[:, 0] != NULL_BLOCK, cfg)
+        group_tables[0][:, 0] != NULL_BLOCK, cfg)
     tokens = sample_tokens(model.logits(params, x, cfg), temperature,
                            top_k, seeds)
     return tokens, pools, stats
@@ -182,10 +219,28 @@ class StateRunner:
         self.heads = hq, hkv, d = model.kv_heads
         self.pool_rows = (hkv * d,) * 2
         self.pool_layers = model.n_attentions
+        self.cache_groups = (None,)
         self.routed_experts = model.routed_experts
         self.slot_state = tuple(model.slot_state)
         # the programs read the model's functions, not the instance
         kw = dict(cfg=cfg, model=type(model))
+        layout = getattr(model, "attention_cache", None)
+        if layout is not None:
+            # attentions of several kinds: a window a cache group,
+            # the pools one group's layers deep
+            kw["layout"] = layout = tuple(layout)
+            windows = {}
+            for group, _, win in layout:
+                if windows.setdefault(group, win) != win:
+                    raise ValueError(
+                        f"cache group {group} holds attentions of two "
+                        f"windows: {layout}")
+            self.cache_groups = tuple(
+                windows[g] for g in range(len(windows)))
+            if self.cache_groups[0] is not None:
+                # its first block says whether a slot is live
+                raise ValueError("cache group 0 keeps the whole context")
+            self.pool_layers = 1 + max(layer for _, layer, _ in layout)
         self.prefill_step = functools.partial(prefill_step, **kw)
         self.decode_step = functools.partial(decode_step, **kw)
 

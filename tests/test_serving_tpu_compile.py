@@ -578,3 +578,80 @@ def test_tpu_flash_kernels_compile_by_shape(one_chip, shape, sk, dtype,
     moved = {p.rsplit("/", 1)[1]: stat_get(p) - n
              for p, n in zip(paths, before)}
     assert moved == {"resident": 0, "streamed": 0, want: 2}
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 38: window and full attention layers in one grouped cache, at
+# Mellum2's widths
+# ---------------------------------------------------------------------------
+
+def _mellum_programs(sd):
+    """Decode (through the paged kernel: 8 launches, 32 query heads over
+    4 K/V heads of 128, six of them over a window of 1024) and the
+    longest prefill of `serve-mellum2-offline-decode-8k` as the cell
+    runs them: hidden 2304, the published layers 0-7, 8 x 64 experts of
+    896, the whole vocabulary, batch 96, 10 240 positions, four cache
+    groups' tables, 80 449 blocks of two layers."""
+    from paddle_tpu.inference.serving import state_runner as sr
+    from paddle_tpu.text.models.mellum import (PUBLISHED_LAYER_TYPES,
+                                               MellumConfig, MellumModel,
+                                               cache_layout)
+
+    cfg = MellumConfig(num_hidden_layers=8,
+                       layer_types=PUBLISHED_LAYER_TYPES[:8],
+                       dtype="bfloat16")
+    shapes = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda p: p._value, MellumModel(cfg)._tree))
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    bsz, maxb, blocks = 96, 10240 // 16, 96 * 838 + 1
+    pools = (sd((2, blocks, 16, 512), bf16), sd((2, blocks, 16, 512), bf16))
+    kw = dict(cfg=cfg, model=MellumModel, block_size=16,
+              layout=cache_layout(cfg))
+    return pools, {
+        "decode": (functools.partial(sr.decode_step, use_kernel=True), (
+            params, sd((bsz,), i32), sd((bsz,), i32), pools,
+            sd((4, bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
+            sd((bsz,), i32), sd((bsz,), i32))),
+        "prefill": (sr.prefill_step, (
+            params, sd((1, 8192), i32), sd((), i32), pools,
+            sd((4, maxb), i32), sd((), f32), sd((), i32), sd((), i32))),
+    }, kw
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_tpu_mellum_programs_fit_the_cell(one_chip, program, monkeypatch):
+    """Compiled for the v5e, traced as on a TPU: both pools are aliased,
+    the weights and the pools are 11.98 GiB of arguments, decode's
+    temporaries stay under 64 MiB (no sequence's context is gathered,
+    no window group's table is expanded) and the longest prefill's under
+    1.6 GiB (65 536 assignment rows; a window layer's scores are 512 x
+    1536, a full layer's 512 x 8192, a query block), and arguments and
+    temporaries together fit the chip's 15.75 GiB. Decode holds the
+    Mosaic calls: 8 paged attentions with and without a window, 16
+    grouped matmuls."""
+    from paddle_tpu.incubate.nn import pallas
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+    pools, programs, kw = _mellum_programs(sd)
+    fn, args = programs[program]
+    compiled = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
+        .lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(p.size * p.dtype.itemsize for p in pools)
+    gib = 2 ** 30
+    assert 4.9 * gib < held < 4.92 * gib
+    assert mem.alias_size_in_bytes >= held
+    assert 11.9 * gib < mem.argument_size_in_bytes < 12.0 * gib
+    limit = 64 * 2 ** 20 if program == "decode" else 1.6 * gib
+    assert mem.temp_size_in_bytes < limit, (
+        f"{mem.temp_size_in_bytes / gib:.2f} GiB of temporaries")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * gib
+    if program == "decode":
+        hlo = compiled.as_text()
+        assert hlo.count("tpu_custom_call") >= 24 and "ragged" not in hlo
+        assert "[96,10240," not in hlo and "[96,640,16," not in hlo
