@@ -30,6 +30,9 @@ that fails to compile fails the step.
 - `grouped_matmul`: the dropless experts' two products a layer
   (`moe/dropless.py`), a read of the hit experts' weights. No switch
   either: `grouped_matmul.grouped_matmul_supported`.
+- `ssm_state`: a decode step's state-space update, one pass over the
+  float32 state in place (`ssm.ssm_step` for every slot). No switch:
+  `ssm_state.ssm_state_supported`.
 """
 from __future__ import annotations
 
@@ -37,7 +40,7 @@ import os
 
 __all__ = ["fusion_enabled", "interpret_mode", "kernels_available",
            "ln_supported", "optim_supported", "layernorm", "optim",
-           "paged_attention", "grouped_matmul",
+           "paged_attention", "grouped_matmul", "ssm_state",
            "fused_layer_norm", "fused_residual_layer_norm"]
 
 def _env_on(name, default="0"):
@@ -106,7 +109,7 @@ def optim_supported():
 # on first touch (PEP 562)
 def __getattr__(name):
     if name in ("layernorm", "optim", "paged_attention",
-                "grouped_matmul"):
+                "grouped_matmul", "ssm_state"):
         import importlib
 
         return importlib.import_module("." + name, __name__)

@@ -791,7 +791,11 @@ class LLMEngine:
         `serve/sample/steps_{greedy,drawn,ranked}`, the case its
         sampler takes for this batch, and `serve/attn/steps`, with
         `serve/attn/steps_paged` beside it when the program attends
-        through the block tables in the Pallas kernel. In a cache of
+        through the block tables in the Pallas kernel; for a model
+        with state-space layers `serve/state/ssm_steps`, the layers
+        the dispatch updates, with `serve/state/ssm_steps_kernel`
+        beside it where the state kernel updates them (the runner's
+        answer, from the predicate the traced program asked). In a cache of
         several groups also `serve/attn/steps_windowed` and, summed
         over the sequences of the dispatch (`sig`, its signature):
         `serve/kv/{full,window}_blocks_held`, what they hold by kind
@@ -805,6 +809,11 @@ class LLMEngine:
         _cmon.stat_add("serve/attn/steps", 1)
         if self.use_kernel:
             _cmon.stat_add("serve/attn/steps_paged", 1)
+        scans = self.runner.scan_layers
+        if scans:
+            _cmon.stat_add("serve/state/ssm_steps", scans)
+            if self.runner.scan_kernel():
+                _cmon.stat_add("serve/state/ssm_steps_kernel", scans)
         cache = self.cache
         if len(cache.groups) > 1:
             _cmon.stat_add("serve/attn/steps_windowed", 1)

@@ -18,17 +18,20 @@ admission do not know the difference.
 A SECOND kind of state (PR 34): what a sequence keeps that does not
 grow with its length — the tail of a short convolution's window, a
 recurrent state — has no blocks and no table. The runner declares it
-beside `rows` as `slot_state`, `(layers, *shape a layer)` an array,
-and the cache holds one array `[layers, max_batch, *shape]` each,
-addressed by the sequence's SLOT in the decode batch (the batch row
-IS the slot; a prefill is told which slot it fills). They ride
-`pools` behind the paged pools (`n_paged` says where they start), so
+beside `rows` as `slot_state`, `(kind, (layers, *shape a layer),
+dtype)` an array (dtype None: the pools'; a state-space layer's
+running sum is float32 beside bf16 pools), and the cache holds one
+array `[layers, max_batch, *shape]` each, in its own dtype, addressed
+by the sequence's SLOT in the decode batch (the batch row IS the
+slot; a prefill is told which slot it fills). They ride `pools`
+behind the paged pools (`n_paged` says where they start), so
 whatever zeroes, donates, re-adopts or rebuilds the pools does the
 same to them; only `defrag`, which renumbers BLOCKS, passes them by.
 Nothing frees a slot's state: the next prefill into the slot
-overwrites it whole. `serve/state/{bytes_per_seq,layers}` gauge it
-beside `serve/kv/{row_values,bytes_per_token}` (0 for a model
-without).
+overwrites it whole. `serve/state/{bytes_per_seq,layers}` gauge it,
+each array at its own itemsize, and `serve/state/ssm_bytes_per_seq`
+the arrays of kind `ssm`, beside `serve/kv/{row_values,
+bytes_per_token}` (0 for a model without).
 
 CACHE GROUPS (PR 38): a model whose attentions are of two kinds, some
 over the whole context and some over a sliding window, declares its
@@ -475,12 +478,15 @@ class PagedKVCache:
         self.rows = tuple(int(w) for w in (
             rows or (num_heads * head_dim,) * 2))
         self.n_paged = len(self.rows)
-        # per-slot arrays behind the paged pools, `[layers,
-        # max_batch, *shape]` each
-        self.slot_state = tuple(
-            (int(s[0]), int(max_batch)) + tuple(int(d) for d in s[1:])
-            for s in slot_state)
         self.dtype = jnp.dtype(dtype or jnp.float32)
+        # per-slot arrays behind the paged pools, `[layers,
+        # max_batch, *shape]` each, in its own dtype (None: the
+        # pools'), by kind
+        self.slot_state = tuple(
+            (kind, (int(shape[0]), int(max_batch))
+             + tuple(int(d) for d in shape[1:]),
+             jnp.dtype(dtype or self.dtype))
+            for kind, shape, dtype in slot_state)
         self.draft_layers = int(draft_layers)
         self.prefix_cache = bool(prefix_cache)
         per_block = bytes_per_block(num_layers, self.block_size,
@@ -502,11 +508,14 @@ class PagedKVCache:
         _cmon.stat_set("serve/kv/row_values", sum(self.rows))
         _cmon.stat_set("serve/kv/bytes_per_token",
                        per_block // self.block_size)
+        per_seq = {}
+        for kind, shape, dt in self.slot_state:
+            per_seq[kind] = per_seq.get(kind, 0) + shape[0] * math.prod(
+                shape[2:]) * dt.itemsize
         _cmon.stat_set("serve/state/layers",
-                       sum(s[0] for s in self.slot_state))
-        _cmon.stat_set("serve/state/bytes_per_seq", sum(
-            s[0] * math.prod(s[2:]) for s in self.slot_state)
-            * self.dtype.itemsize)
+                       sum(shape[0] for _, shape, _ in self.slot_state))
+        _cmon.stat_set("serve/state/bytes_per_seq", sum(per_seq.values()))
+        _cmon.stat_set("serve/state/ssm_bytes_per_seq", per_seq.get("ssm", 0))
         _cmon.stat_set("serve/kv/groups", len(self.groups))
         _cmon.stat_set("serve/kv/window",
                        max((w or 0 for w in self.groups)))
@@ -520,7 +529,7 @@ class PagedKVCache:
                           self.dtype) for w in self.rows)
 
         self.pools = zeros(self.num_layers) + tuple(
-            jnp.zeros(s, self.dtype) for s in self.slot_state)
+            jnp.zeros(shape, dt) for _, shape, dt in self.slot_state)
         if self.draft_layers:
             self.draft_pools = zeros(self.draft_layers)
 

@@ -162,6 +162,7 @@ class MLARunner:
 
     verify_step = prefill_tail_step = draft_params = None
     slot_state = ()
+    scan_layers = 0
 
     def __init__(self, model):
         model = getattr(model, "model", model)

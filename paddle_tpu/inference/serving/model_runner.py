@@ -7,8 +7,10 @@ picks it from the model's type; nothing else selects it):
     runner.params, runner.config     the raw jnp tree and the config
     runner.pool_rows                 row width of each cache pool
     runner.pool_layers               leading axis of each pool: attentions that keep rows
-    runner.slot_state                per-slot arrays beside the pools, `(layers, *shape)` each; () for none.
+    runner.slot_state                per-slot arrays beside the pools, `(kind, (layers, *shape), dtype)` each; () for none.
                                      With any, prefill_step takes the request's slot after `seed`
+    runner.scan_layers               state-space layers a decode step updates (0: none), and
+    runner.scan_kernel()             whether it updates them in the Pallas kernel here
     runner.prefill_step(params, ids, prompt_len, pools, table, temp, top_k, seed, *, block_size)
     runner.decode_step(params, ids, positions, pools, tables, lens, temp, top_k, seeds, *, block_size, use_kernel, interpret)
     runner.verify_step, .prefill_tail_step, .draft_params    or None: no speculation / prefix cache
@@ -473,6 +475,7 @@ class GPT2Runner:
 
     draft_params = staticmethod(draft_params)
     slot_state = ()
+    scan_layers = 0
 
     def __init__(self, model):
         self.params, self.config = extract_params(model)

@@ -1,30 +1,40 @@
 """The third runner: models that keep TWO kinds of state a sequence
 — keys and values in paged pools, addressed through block tables,
-and beside them a state of fixed size (the tail of a short
-convolution's window: `text/models/lfm2_moe.py`), addressed by the
+and beside them states of fixed size (the tail of a short
+convolution's window: `text/models/lfm2_moe.py`; the state of a
+state-space recurrence: `text/models/falcon_h1.py`), addressed by the
 sequence's SLOT in the decode batch.
 
 What a layer is the runner reads from the model and names no model:
-`model.state_layers(params, x, carry, attend, window, positions,
-live, cfg)` runs the model's own layers and calls the program's
+`model.embed(params, ids, cfg)` gives the first hidden rows,
+`model.state_layers(params, x, carry, attend, window, scan,
+positions, live, cfg)` runs the model's own layers and calls the
+program's
 
     attend(q [T, Hq, D], k [T, Hkv*D], v [T, Hkv*D], carry, a)
     window(z [T, H], carry, c) -> (z_{t-n} .. z_t [T, n + 1, H], carry)
+    scan(x [T, H, P], dt [T, H], A [H], B [T, G, N], C [T, G, N],
+         carry, s) -> (y [T, H, P] float32, carry)
 
-once for every attention `a` and every windowed layer `c`;
-`model.n_attentions` is the K/V pools' layer count,
-`model.kv_heads` the heads (query heads may be a multiple of the
-K/V heads: the pools hold the K/V heads' rows, `Hkv * D` a token),
-`model.slot_state` the per-slot arrays (`(layers, n, width)`: the
-last `n` rows of every windowed layer's stream). So the serving
-path is the training mathematics.
+once for every attention `a`, every windowed layer `c` and every
+state-space layer `s` (`incubate/nn/ssm.py` has the recurrence;
+`y` without the model's skip term); `model.n_attentions` is the K/V
+pools' layer count, `model.kv_heads` the heads (query heads may be a
+multiple of the K/V heads: the pools hold the K/V heads' rows, `Hkv *
+D` a token), `model.slot_state` the per-slot arrays, each `(kind,
+(layers, *shape a layer), dtype)` (dtype None: the pools'): kind
+`window` the last `n` rows of every windowed layer's stream `(layers,
+n, width)`, kind `ssm` every state-space layer's state `(layers, H, N,
+P)`. So the serving path is the training mathematics.
 
 The carry is the engine's `pools`: the K and V pools `[A, N, BS,
-Hkv*D]` and after them the state `[C, max_batch, n, H]`
-(`kv_cache.PagedKVCache`), donated and updated in place: rows
-scattered at `(a, blk, off)` and read as `[A*N, ...]` with the
-tables shifted by `a * N` (`model_runner._scan_layers_paged`'s
-rule; the layers are unrolled, `a` and `c` are Python numbers).
+Hkv*D]` and after them the per-slot arrays `[layers, max_batch,
+*shape]` in the order the model declares them (`kv_cache.
+PagedKVCache`), donated and updated in place: rows scattered at `(a,
+blk, off)` and read as `[A*N, ...]` with the tables shifted by `a *
+N` (`model_runner._scan_layers_paged`'s rule; the layers are
+unrolled, `a`, `c` and `s` are Python numbers); a callback finds its
+array in the carry by its kind.
 
 Attentions of several kinds (PR 38): a model whose attentions differ
 in what they keep (some the whole context, some a sliding window)
@@ -44,19 +54,25 @@ had.
 
 - `prefill_step` attends densely over the prompt
   (`model.attend_dense`), scatters every position's K/V rows
-  through the block table, and writes the window's tail AT THE
-  PROMPT'S END (`z[prompt_len - n : prompt_len]`, zeros before
-  position 0; not at the padded bucket's end) into the request's
-  slot: `slot` is one more argument, after `seed`.
+  through the block table, and writes each per-slot state AT THE
+  PROMPT'S END into the request's slot (not at the padded bucket's
+  end): a window's tail `z[prompt_len - n : prompt_len]` (zeros
+  before position 0), a state-space layer's state after position
+  `prompt_len - 1` (`ssm.ssd_chunked` from a zero state, under the
+  scope `ssd`, the padded tail given dt = 0). `slot` is one more
+  argument, after `seed`.
 - `decode_step`: the batch row IS the slot. A windowed layer reads
   its `[B, n, H]` state, appends this token's row, drops the oldest
-  and writes the state back whole, in place, with no gather;
-  inactive slots ride along and whatever they leave is overwritten
-  whole by the next prefill into the slot. An attention writes the
-  token's K and V rows and attends through the tables: in the
-  Pallas paged kernel with grouped heads where `kernel_supported`
-  says so (a TPU), else over a dense gather (the CPU's path, and
-  the reference the kernel is tested against).
+  and writes the state back whole, in place, with no gather; a
+  state-space layer updates its `[B, H, N, P]` state in place, under
+  the scope `state`: in the Pallas kernel (`pallas/ssm_state.py`, one
+  pass over the state) where `ssm_state_supported` says so (a TPU),
+  else `ssm.ssm_step`. Inactive slots ride along and whatever they
+  leave is overwritten whole by the next prefill into the slot. An
+  attention writes the token's K and V rows and attends through the
+  tables: in the Pallas paged kernel with grouped heads where
+  `kernel_supported` says so (a TPU), else over a dense gather (the
+  CPU's path, and the reference the kernel is tested against).
 
 No verify or tail program: a rejected draft token or a shared
 prefix would need the state as it was at another position
@@ -94,17 +110,29 @@ def _layout(layout, a):
     return (0, a, None) if layout is None else layout[a]
 
 
+def _state_at(states, kind):
+    """Where the per-slot array of `kind` is in the carry: behind the
+    K and V pools, in the order the model declares them."""
+    return 2 + states.index(kind)
+
+
+def _put(carry, i, value):
+    return carry[:i] + (value,) + carry[i + 1:]
+
+
 def prefill_step(params, ids, prompt_len, pools, block_table,
                  temperature, top_k, seed, slot=None, *, cfg, model,
-                 block_size, layout=None):
+                 block_size, layout=None, states=("window",)):
     """Causal forward over one block-padded prompt, ids [1, P], for
     the request that will decode in batch row `slot`. Writes all P
     positions' K/V rows through `block_table` (the padded tail lands
     where decode overwrites it before any masked read, or in the
-    NULL block), every windowed layer's last rows before
-    `prompt_len` into `state[:, slot]`, and samples the first token
+    NULL block), every per-slot state as the prompt's real end
+    leaves it into `state[:, slot]`, and samples the first token
     from the last real row. Returns (token [], pools, the model's
     routing counts over the `prompt_len` real tokens)."""
+    from ...incubate.nn.ssm import ssd_chunked
+
     p_len = ids.shape[1]
     positions = jnp.arange(p_len)
     # a table a cache group where the model has several
@@ -122,17 +150,26 @@ def prefill_step(params, ids, prompt_len, pools, block_table,
         return out, (kp, vp, *state)
 
     def window(z, carry, c):
-        *kv, st = carry
+        i = _state_at(states, "window")
+        st = carry[i]
         n = st.shape[2]
         zp = jnp.pad(z, ((n, 0), (0, 0)))      # zp[t + n] = z[t]
         win = jnp.stack([zp[j:j + p_len] for j in range(n + 1)], 1)
         tail = _window_tail(zp, prompt_len, n)
         st = st.at[c, slot].set(tail.astype(st.dtype))
-        return win, (*kv, st)
+        return win, _put(carry, i, st)
 
-    x = jnp.take(params["embed"], ids[0], axis=0)
+    def scan(x, dt, A, B, C, carry, s):
+        i = _state_at(states, "ssm")
+        st = carry[i]
+        with jax.named_scope("ssd"):
+            y, last = ssd_chunked(x, dt, A, B, C, length=prompt_len)
+        st = st.at[s, slot].set(last.astype(st.dtype))
+        return y, _put(carry, i, st)
+
+    x = model.embed(params, ids[0], cfg)
     x, pools, stats = model.state_layers(
-        params, x, tuple(pools), attend, window, positions,
+        params, x, tuple(pools), attend, window, scan, positions,
         positions < prompt_len, cfg)
     last = jax.lax.dynamic_index_in_dim(x, prompt_len - 1, axis=0,
                                         keepdims=False)
@@ -144,14 +181,15 @@ def prefill_step(params, ids, prompt_len, pools, block_table,
 def decode_step(params, ids, positions, pools, block_tables,
                 context_lens, temperature, top_k, seeds, *, cfg, model,
                 block_size, use_kernel=False, interpret=False,
-                layout=None):
+                layout=None, states=("window",)):
     """One generation step for the whole running batch, ids and
     positions [B]; `context_lens[b] == positions[b] + 1`. Row b is
-    slot b: its windows' tails are `state[:, b]`. Each attention
+    slot b: its per-slot states are `state[:, b]`. Each attention
     writes this token's K/V rows at (tables[b, pos // BS], pos % BS)
     BEFORE attending. Inactive slots (table all NULL) ride along and
     are left out of the routing counts. Returns (tokens [B], pools,
     the model's routing counts)."""
+    from ...incubate.nn import pallas as _pl
     from ...incubate.nn.pallas import paged_attention as _pa
 
     n_attn, n_blocks = pools[0].shape[:2]
@@ -189,13 +227,30 @@ def decode_step(params, ids, positions, pools, block_tables,
         return out.reshape(bsz, -1), (kp, vp, *state)
 
     def window(z, carry, c):
-        *kv, st = carry
+        i = _state_at(states, "window")
+        st = carry[i]
         win = jnp.concatenate([st[c], z[:, None].astype(st.dtype)], 1)
-        return win, (*kv, st.at[c].set(win[:, 1:]))
+        return win, _put(carry, i, st.at[c].set(win[:, 1:]))
 
-    x = jnp.take(params["embed"], ids, axis=0)
+    def scan(x, dt, A, B, C, carry, s):
+        from ...incubate.nn.pallas import ssm_state as _ss
+        from ...incubate.nn.ssm import ssm_step
+
+        i = _state_at(states, "ssm")
+        st = carry[i]
+        with jax.named_scope("state"):
+            if _ss.ssm_state_supported(st.shape[2], B.shape[-2],
+                                       *st.shape[3:], st.dtype):
+                y, st = _ss.ssm_state_update(
+                    st, s, x, dt, A, B, C, interpret=_pl.interpret_mode())
+            else:
+                y, new = ssm_step(st[s], x, dt, A, B, C)
+                st = st.at[s].set(new)
+        return y, _put(carry, i, st)
+
+    x = model.embed(params, ids, cfg)
     x, pools, stats = model.state_layers(
-        params, x, tuple(pools), attend, window, positions,
+        params, x, tuple(pools), attend, window, scan, positions,
         group_tables[0][:, 0] != NULL_BLOCK, cfg)
     tokens = sample_tokens(model.logits(params, x, cfg), temperature,
                            top_k, seeds)
@@ -204,10 +259,10 @@ def decode_step(params, ids, positions, pools, block_tables,
 
 class StateRunner:
     """How LLMEngine serves a model with `state_layers`: a K and a V
-    pool of `Hkv * D` values a token an attention, per-slot state
+    pool of `Hkv * D` values a token an attention, per-slot states
     beside them, prefill (told its slot) and decode (through the
-    paged kernel where `kernel_supported`); no verify, tail or
-    draft."""
+    paged kernel where `kernel_supported`, the state kernel where
+    `scan_kernel`); no verify, tail or draft."""
 
     verify_step = prefill_tail_step = draft_params = None
 
@@ -220,10 +275,19 @@ class StateRunner:
         self.pool_rows = (hkv * d,) * 2
         self.pool_layers = model.n_attentions
         self.cache_groups = (None,)
-        self.routed_experts = model.routed_experts
+        self.routed_experts = getattr(model, "routed_experts", None)
+        # what the cache allocates: (kind, (layers, *shape), dtype)
         self.slot_state = tuple(model.slot_state)
+        kinds = tuple(kind for kind, _, _ in self.slot_state)
+        # (heads, groups, d_state, head_dim) of the state-space layers,
+        # their count and their state's dtype (kind `ssm`)
+        self.ssm_heads = getattr(model, "ssm_heads", None)
+        ssm = {kind: (shape, dtype)
+               for kind, shape, dtype in self.slot_state}.get("ssm")
+        self.scan_layers = ssm[0][0] if ssm else 0
+        self._ssm_dtype = ssm and ssm[1]
         # the programs read the model's functions, not the instance
-        kw = dict(cfg=cfg, model=type(model))
+        kw = dict(cfg=cfg, model=type(model), states=kinds)
         layout = getattr(model, "attention_cache", None)
         if layout is not None:
             # attentions of several kinds: a window a cache group,
@@ -252,6 +316,16 @@ class StateRunner:
         hq, hkv, d = self.heads
         return _pa.paged_decode_supported(hq, d, block_size,
                                           num_kv_heads=hkv)
+
+    def scan_kernel(self):
+        """Does the decode program update the state-space layers'
+        states in the Pallas kernel here? What that program asked
+        while it was traced (`ssm_state.ssm_state_supported`), for the
+        model's `ssm_heads`."""
+        from ...incubate.nn.pallas import ssm_state as _ss
+
+        return bool(self.scan_layers) and _ss.ssm_state_supported(
+            *self.ssm_heads, self._ssm_dtype)
 
     def experts_kernel(self, tokens):
         """Does a program over `tokens` rows multiply its experts'

@@ -74,7 +74,7 @@ from ...core.engine import apply_op
 from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, sigmoid_topk_route)
 from ...nn.layer.layers import Layer
-from .mla import SeededTree, rms_norm, rotate, swiglu
+from .mla import SeededTree, embed, rms_norm, rotate, swiglu
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM",
            "PUBLISHED_LAYER_TYPES"]
@@ -225,9 +225,10 @@ def moe_ffn(u, mp, cfg, live=None):
                                    mp["w2"]), counts
 
 
-def layers(params, x, carry, attend, window, positions, live, cfg):
+def layers(params, x, carry, attend, window, scan, positions, live, cfg):
     """Every layer over `x [T, hidden]`, unrolled, with the calling
-    program's `attend` and `window` (module docstring). Returns (x,
+    program's `attend` and `window` (module docstring; `scan`, what the
+    runner offers a state-space layer, goes unused). Returns (x,
     carry, {"moe_counts" [expert layers, E]})."""
     eps = cfg.norm_eps
     a = c = 0
@@ -283,7 +284,7 @@ def _k_forward(ids, params, cfg):
         return win.reshape((b * s,) + win.shape[2:]), carry
 
     x = jnp.take(params["embed"], ids.reshape(b * s), axis=0)
-    x, _, _ = layers(params, x, (), attend, window,
+    x, _, _ = layers(params, x, (), attend, window, None,
                      jnp.tile(jnp.arange(s), b), None, cfg)
     return logits(params, x, cfg).reshape(b, s, -1)
 
@@ -297,6 +298,7 @@ class Lfm2MoeModel(SeededTree):
     state_layers = staticmethod(layers)
     attend_dense = staticmethod(attend_dense)
     logits = staticmethod(logits)
+    embed = staticmethod(embed)
 
     def __init__(self, config: Lfm2MoeConfig):
         super().__init__(config)
@@ -378,11 +380,12 @@ class Lfm2MoeModel(SeededTree):
 
     @property
     def slot_state(self):
-        """What a sequence keeps beside its K/V rows, as `(layers,
-        *shape a layer)`: the last L - 1 gated inputs of every
-        convolution."""
+        """What a sequence keeps beside its K/V rows, as `(kind,
+        (layers, *shape a layer), dtype)` (None: the cache's): the
+        last L - 1 gated inputs of every convolution."""
         c = self.config
-        return ((c.count("conv"), c.conv_L_cache - 1, c.hidden_size),)
+        return (("window", (c.count("conv"), c.conv_L_cache - 1,
+                            c.hidden_size), None),)
 
     def forward(self, input_ids):
         return apply_op("lfm2_moe_forward", _k_forward, input_ids,

@@ -71,7 +71,7 @@ from ...core.engine import apply_op
 from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, topk_route)
 from ...nn.layer.layers import Layer
-from .mla import SeededTree, rms_norm
+from .mla import SeededTree, embed, rms_norm
 
 __all__ = ["MellumConfig", "MellumModel", "MellumForCausalLM",
            "PUBLISHED_LAYER_TYPES", "rope_tables"]
@@ -293,11 +293,11 @@ def moe_ffn(u, mp, cfg, live=None):
                                    mp["w2"]), counts
 
 
-def layers(params, x, carry, attend, window, positions, live, cfg):
+def layers(params, x, carry, attend, window, scan, positions, live, cfg):
     """Every layer over `x [T, hidden]`, unrolled, with the calling
-    program's `attend` (module docstring; `window`, what the runner
-    offers a layer with per-slot state, goes unused: this model has
-    none). Returns (x, carry, {"moe_counts" [layers, E]})."""
+    program's `attend` (module docstring; `window` and `scan`, what
+    the runner offers a layer with per-slot state, go unused: this
+    model has none). Returns (x, carry, {"moe_counts" [layers, E]})."""
     eps = cfg.rms_norm_eps
     counts = []
     for a, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
@@ -332,7 +332,7 @@ def _k_forward(ids, params, cfg):
         return out.reshape(b * s, -1), carry
 
     x = jnp.take(params["embed"], ids.reshape(b * s), axis=0)
-    x, _, _ = layers(params, x, (), attend, None,
+    x, _, _ = layers(params, x, (), attend, None, None,
                      jnp.tile(jnp.arange(s), b), None, cfg)
     return logits(params, x, cfg).reshape(b, s, -1)
 
@@ -363,6 +363,7 @@ class MellumModel(SeededTree):
     state_layers = staticmethod(layers)
     attend_dense = staticmethod(attend_dense)
     logits = staticmethod(logits)
+    embed = staticmethod(embed)
     slot_state = ()
 
     def __init__(self, config: MellumConfig):

@@ -39,7 +39,7 @@ from ...core.tensor import Parameter
 from ...nn.layer.layers import Layer
 from ...ops import random as _random
 
-__all__ = ["rms_norm", "rotate", "swiglu", "mla_query", "mla_latent",
+__all__ = ["rms_norm", "rotate", "embed", "swiglu", "mla_query", "mla_latent",
            "mla_attend_dense", "mla_attend_absorbed", "mla_attend_paged",
            "attention_block",
            "SeededTree"]
@@ -62,6 +62,12 @@ def rotate(x, positions, theta):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            -1).astype(x.dtype)
+
+
+def embed(params, ids, cfg):
+    """The embedding rows of `ids` (a model with an embedding
+    multiplier has an `embed` of its own)."""
+    return jnp.take(params["embed"], ids, axis=0)
 
 
 def swiglu(u, w13, w2):

@@ -244,7 +244,11 @@ class TestLoopbackSmoke:
         assert "before_window" not in kinds
         assert "stats" in doc["telemetry"]
 
-    def test_tracez_weak_registry(self):
+    def test_tracez_weak_registry(self, monkeypatch):
+        # sources an earlier test left alive (a router or engine some
+        # module still holds) are not this test's: start from none
+        monkeypatch.setattr(mserver, "_trace_sources", [])
+
         class Spooler:
             def export_traces(self):
                 return {"schema": "paddle_tpu.trace/1",

@@ -655,3 +655,80 @@ def test_tpu_mellum_programs_fit_the_cell(one_chip, program, monkeypatch):
         hlo = compiled.as_text()
         assert hlo.count("tpu_custom_call") >= 24 and "ragged" not in hlo
         assert "[96,10240," not in hlo and "[96,640,16," not in hlo
+
+
+# ---------------------------------------------------------------------------
+# Attention and a Mamba-2 mixer side by side, a float32 state a sequence
+# beside the pools, at Falcon-H1-34B's widths
+# ---------------------------------------------------------------------------
+
+def _falcon_programs(sd):
+    """Decode (the paged kernel at 20 query heads over 4 K/V heads, the
+    state kernel) and the longest prefill of `serve-falconh1-offline-
+    decode` as the cell runs them: hidden 5120, the published layers 0-3
+    (attention and a mixer of 32 heads x 128 over 2 groups of 256 in
+    each, a SwiGLU of 21504), the whole vocabulary of 261 120, batch 128,
+    4096 positions, 32 769 blocks, the convolution tails in bf16 and the
+    states in float32 of 128 slots."""
+    from paddle_tpu.inference.serving import state_runner as sr
+    from paddle_tpu.text.models.falcon_h1 import (FalconH1Config,
+                                                  FalconH1Model)
+
+    cfg = FalconH1Config(num_hidden_layers=4, dtype="bfloat16")
+    shapes = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda p: p._value, FalconH1Model(cfg)._tree))
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    bsz, maxb = 128, 4096 // 16
+    pools = (sd((4, 32769, 16, 512), bf16), sd((4, 32769, 16, 512), bf16),
+             sd((4, bsz, 3, 5120), bf16), sd((4, bsz, 32, 256, 128), f32))
+    kw = dict(cfg=cfg, model=FalconH1Model, block_size=16,
+              states=("window", "ssm"))
+    return pools, {
+        "decode": (functools.partial(sr.decode_step, use_kernel=True), (
+            params, sd((bsz,), i32), sd((bsz,), i32), pools,
+            sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
+            sd((bsz,), i32), sd((bsz,), i32))),
+        "prefill": (sr.prefill_step, (
+            params, sd((1, 2048), i32), sd((), i32), pools,
+            sd((maxb,), i32), sd((), f32), sd((), i32), sd((), i32),
+            sd((), i32))),
+    }, kw
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_tpu_falcon_programs_fit_the_cell(one_chip, program, monkeypatch):
+    """Compiled for the v5e, traced as on a TPU: the pools, the
+    convolution tails and the 2 GiB of float32 state are aliased (no
+    copy among the temporaries), the weights and what the cache holds
+    are 14.2 GiB of arguments, and arguments and temporaries together
+    fit the chip's 15.75 GiB. Decode holds the Mosaic calls: four state
+    updates and four paged attentions, one each a layer."""
+    import re
+
+    from paddle_tpu.incubate.nn import pallas
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
+    pools, programs, kw = _falcon_programs(sd)
+    fn, args = programs[program]
+    compiled = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
+        .lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(p.size * p.dtype.itemsize for p in pools)
+    gib = 2 ** 30
+    assert 6.0 * gib < held < 6.02 * gib
+    assert mem.alias_size_in_bytes >= held
+    assert 14.1 * gib < mem.argument_size_in_bytes < 14.3 * gib
+    assert mem.temp_size_in_bytes < 0.6 * gib, (
+        f"{mem.temp_size_in_bytes / gib:.2f} GiB of temporaries")
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75 * gib
+    if program == "decode":
+        hlo = compiled.as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 8
+        # the names `ssm_state_roofline.falconh1` reads the launches by
+        assert len(re.findall(r"%ssm_state\.\d+ = ", hlo)) == 4
+        assert len(re.findall(r"%attend\.\d+ = ", hlo)) == 4
