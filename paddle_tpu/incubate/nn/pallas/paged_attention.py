@@ -29,18 +29,22 @@ rows of the same tiles, each masked at its own depth (T <= 8).
 
 Grid: (B,), one sequence a grid step; the body walks the sequence's
 LIVE page groups itself (`fori_loop` to `ceil(context / R)`), G
-pages a group, G from the shape: enough pages for a 128-row tile (8
-at block 16; the table is padded to a multiple of G where it is not
-one). The pools stay where they are (`pl.ANY`);
-`block_tables`/`context_lens` ride as SCALAR PREFETCH arguments
-(pltpu.PrefetchScalarGridSpec) and the body copies page
-`tables[b, j*G + g]` into row g*BS of one of two [R, H*D] tiles: the
-pages of group j + 1 (or, from a sequence's last group, of the next
-sequence's first) are on their way while group j is multiplied. Only
-pages that hold a visible token are copied — exactly the blocks each
-sequence owns, in table order, nothing else — and only groups that
-hold one are walked: a dead page costs nothing, not even a grid
-step. Inside the last live group the positions past the context
+pages a group, G from the row's bytes (`_pages_per_group`: 1024 rows
+of 1 KB, 256 of GPT-2's f32 rows of 4 KB; the table is padded to a
+multiple of G where it is not one). The pools stay where they are
+(`pl.ANY`); `block_tables`/`context_lens` ride as SCALAR PREFETCH
+arguments (pltpu.PrefetchScalarGridSpec) and the body copies page
+`tables[b, j*G + g]` into row g*BS of one of two [R, H*D] tiles, in
+a rolled loop over the group's live pages (no branch a page), and
+waits for them in one wait a pool for each binary digit of their
+number: the pages of group j + 1 (or, from a sequence's last group,
+of the next sequence's first) are on their way while group j is
+multiplied. Only pages that hold a visible token are copied —
+exactly the blocks each sequence owns, in table order, nothing else
+— and only groups that hold one are walked: a dead page costs
+nothing, not even a grid step. `kernels/paged/rows_<R>` counts the
+calls by their group's rows while a program is traced. Inside the
+last live group the positions past the context
 mask to -inf (and the rows no copy wrote are zeroed in V), so they
 contribute exactly zero weight. Online softmax (running
 max/denominator in VMEM scratch) accumulates across a sequence's
@@ -96,12 +100,12 @@ other operands:
                        acc / l are o_lat, which W^V expands outside
 
 There is no V pool and no head selector. A row is 1280 B and a page
-20 KB where GPT-2's is 64 KB a pool, so the rows a group come from
-the row's bytes (`_latent_pages_per_group`: 512 rows of 640 bf16
-values, where the K/V kernel takes 128) and the copies of a live
-group are issued whole, with no branch a page and ONE wait: the
-table's NULL and padded columns name real blocks, and the rows past
-the context are masked in the scores and zeroed as values.
+20 KB where GPT-2's is 64 KB a pool; the rows a group come from the
+same rule (`_pages_per_group`: 512 rows of 640 bf16 values) and the
+copies of a live group are issued whole, unrolled, with no branch a
+page and ONE wait: the table's NULL and padded columns name real
+blocks, and the rows past the context are masked in the scores and
+zeroed as values.
 
 `interpret=True` runs the same kernels through the Pallas interpreter
 for CPU parity tests (the PR-8 contract; see
@@ -116,6 +120,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ....core import monitor as _cmon
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_multi", "paged_attention_multi_reference",
@@ -153,11 +159,23 @@ def _head_selector(h, d):
     return jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=1)
 
 
-def _pages_per_group(block_size):
+_TILE_BYTES = 1024 * 1024
+
+
+def _pages_per_group(block_size, row_bytes):
     """G: the pages of one sequence that are copied and multiplied
-    together, from the shape: enough for a 128-row tile (the MXU's
-    height, and 1 MB of f32 keys and values at H*D = 1024)."""
-    return max(1, 128 // block_size)
+    together, for both kernels, from the shape: the largest power of
+    two of rows whose tile (`row_bytes` a row, one pool's) fits in
+    `_TILE_BYTES`, between 128 and 1024 rows, of at most 64 pages
+    (the latent kernel's copies of a group are unrolled; PERF.md, PR
+    33 and 42: the sweeps). A short row would otherwise
+    pay a group's fixed cost (its waits, a loop step, a rescale of
+    the softmax) every few bytes: K/V rows of 1 KB (4 heads of 128 in
+    bf16) take 1024 rows, a latent row of 1280 B 512, GPT-2's f32
+    K/V rows of 4 KB 256."""
+    rows = max(128, min(1024, _TILE_BYTES // row_bytes))
+    rows = 1 << (rows.bit_length() - 1)
+    return max(1, min(64, rows // block_size))
 
 
 def _first_copies(b, last, groups, start, parity_ref, first=None):
@@ -262,28 +280,46 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         def first_group(i):
             return jnp.maximum(lens_ref[i] - window, 0) // rows
 
-    def page_copies(i, j, slot, act):
-        for g in range(pages):
-            first = (j * pages + g) * block_size
-            holds = first < seen(i)
-            if window is not None:  # ... and one inside the window
-                holds &= first + block_size > lens_ref[i] - window
-
-            @pl.when(holds)
-            def _live():
-                page = tables_ref[i, j * pages + g]
-                to = pl.ds(g * block_size, block_size)
-                for pool, buf, which in ((k_hbm, k_buf, 0),
-                                         (v_hbm, v_buf, 1)):
-                    act(pltpu.make_async_copy(
-                        pool.at[page], buf.at[slot, to],
-                        sems.at[slot, which]))
+    def live_pages(i, j):
+        # [lo, hi): the table columns of group j that hold a token
+        # visible to a slot of sequence i (with a `window`, one inside
+        # it): every other page is neither copied nor waited for
+        lo = j * pages
+        hi = jnp.minimum(lo + pages,
+                         (seen(i) + (block_size - 1)) // block_size)
+        if window is not None:
+            lo = jnp.maximum(
+                lo, jnp.maximum(lens_ref[i] - window, 0) // block_size)
+        return lo, hi
 
     def start(i, j, slot):
-        page_copies(i, j, slot, lambda copy: copy.start())
+        # a rolled loop over the live pages: no branch a page, and the
+        # body's text is one page's whatever the group's width
+        def copy(p, carry):
+            page = tables_ref[i, p]
+            to = pl.ds(pl.multiple_of((p - j * pages) * block_size,
+                                      block_size), block_size)
+            for pool, buf, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                pltpu.make_async_copy(pool.at[page], buf.at[slot, to],
+                                      sems.at[slot, which]).start()
+            return carry
+
+        jax.lax.fori_loop(*live_pages(i, j), copy, 0)
 
     def wait(i, j, slot):
-        page_copies(i, j, slot, lambda copy: copy.wait())
+        # the semaphore counts what arrived: the n live pages are
+        # waited for as n's binary digits, one wait a pool for each
+        # set bit (a whole group of 2^k pages: one)
+        lo, hi = live_pages(i, j)
+        for bit in range(pages.bit_length()):
+            part = pl.ds(0, (1 << bit) * block_size)
+
+            @pl.when(((hi - lo) >> bit) % 2 == 1)
+            def _part():
+                for buf, which in ((k_buf, 0), (v_buf, 1)):
+                    pltpu.make_async_copy(buf.at[slot, part],
+                                          buf.at[slot, part],
+                                          sems.at[slot, which]).wait()
 
     base, n_groups, after = _first_copies(b, last, groups, start,
                                           parity_ref, first_group)
@@ -376,7 +412,8 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
         raise ValueError(f"pool heads/dim {(h, dk)} under query "
                          f"{(hq, d)}")
     hd = h * d
-    pages = _pages_per_group(bs)
+    pages = _pages_per_group(bs, hd * k_pool.dtype.itemsize)
+    _cmon.stat_add(f"kernels/paged/rows_{pages * bs}", 1)
     tables = _whole_groups(block_tables, pages)
     group = hq // h
     kernel = functools.partial(
@@ -530,22 +567,6 @@ def paged_attention_multi_reference(q, k_pool, v_pool, block_tables,
 # latent (MLA) rows: one pool, one shared head, keys that are the values
 # ---------------------------------------------------------------------------
 
-_LATENT_TILE_BYTES = 640 * 1024
-
-
-def _latent_pages_per_group(block_size, row_bytes):
-    """The pages of one sequence that one tile holds, from the shape:
-    a latent row is short (1280 B at 640 bf16 values, a page 20 KB
-    where GPT-2's is 64 KB a pool), so a 128-row group would spend
-    its time on the fixed cost of a group, not on bytes. The tile is
-    the largest power of two of rows within `_LATENT_TILE_BYTES`,
-    between 128 and 1024 rows (PERF.md, PR 33: the sweep), of at
-    most 64 pages (each is a copy in the body's text)."""
-    rows = max(128, min(1024, _LATENT_TILE_BYTES // row_bytes))
-    rows = 1 << (rows.bit_length() - 1)
-    return max(1, min(64, rows // block_size))
-
-
 def _latent_kernel(tables_ref, lens_ref, q_ref, pool_hbm, o_ref, buf,
                    sems, acc_ref, m_ref, l_ref, parity_ref, *, sm_scale,
                    block_size, pages):
@@ -627,7 +648,7 @@ def paged_latent_attention(q, pool, block_tables, context_lens,
     tile = 32 // pool.dtype.itemsize
     hp = -(-h // tile) * tile
     q = jnp.pad(q, ((0, 0), (0, hp - h), (0, 0)))
-    pages = _latent_pages_per_group(bs, row * pool.dtype.itemsize)
+    pages = _pages_per_group(bs, row * pool.dtype.itemsize)
     kernel = functools.partial(
         _latent_kernel, sm_scale=sm_scale, block_size=bs, pages=pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(

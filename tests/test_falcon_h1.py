@@ -440,10 +440,11 @@ def test_the_real_cell_keeps_a_16_mib_state_a_sequence():
 # sha256 of the lowered StableHLO text of the state runner's prefill and
 # decode for Mellum (attention alone, four cache groups) and of their
 # jaxprs with the paged kernel in decode, taken on the parent commit
-# (ce7bbef): the runner that now addresses several per-slot arrays and
+# (ce7bbef; decode's jaxpr again when the paged kernel's page copies became
+# a rolled loop): the runner that now addresses several per-slot arrays and
 # offers a recurrence serves a model without either the programs it served
 MELLUM_PROGRAMS = {("decode_step", "text"): "d6cfaba0c0500ef3",
-                   ("decode_step", "jaxpr"): "2b0cb0a30b4f54b1",
+                   ("decode_step", "jaxpr"): "dd2e7823cb438168",
                    ("prefill_step", "text"): "417830575ecf47bf",
                    ("prefill_step", "jaxpr"): "aba239d7fdc6b348"}
 

@@ -601,10 +601,11 @@ def test_the_route_is_softmax_top_k_renormalised(toy):
 # sha256 of the lowered StableHLO text of the state runner's prefill and
 # decode for LFM2 (no windowed attention, one cache group) and of the
 # decode's jaxpr with the paged kernel in it, taken on the parent commit
-# (7f2063d): the runner that now reads cache groups from a model serves a
-# model without them the programs it served
+# (7f2063d; decode's jaxpr again when the paged kernel's page copies became
+# a rolled loop): the runner that now reads cache groups from a model
+# serves a model without them the programs it served
 LFM2_PROGRAMS = {("decode_step", "text"): "07ff0e0d3c1c7b79",
-                 ("decode_step", "jaxpr"): "e4f752ba58b2610f",
+                 ("decode_step", "jaxpr"): "7b97a6cd83bf96d0",
                  ("prefill_step", "text"): "eef1e94d8e6cbe39",
                  ("prefill_step", "jaxpr"): "b64a57d4ed3a19c3"}
 

@@ -86,8 +86,8 @@ def _close(got, want, dtype):
 @pytest.fixture
 def small_groups(monkeypatch):
     """128 rows a group: 8 pages of 16."""
-    monkeypatch.setattr(pa, "_LATENT_TILE_BYTES", 1)
-    assert pa._latent_pages_per_group(BS, 256) == 8
+    monkeypatch.setattr(pa, "_TILE_BYTES", 1)
+    assert pa._pages_per_group(BS, 256) == 8
 
 
 @pytest.mark.parametrize("heads", [20, 64])
@@ -178,7 +178,7 @@ def test_the_cells_tile(dtype, heads):
     """Rows of 640 at block 16, as both MLA cells store them: the
     group the shape gives (512 rows in bf16), contexts around its
     boundary, a table of 84 columns that it does not divide."""
-    rows = pa._latent_pages_per_group(BS, 640 * jnp.dtype(dtype).itemsize) \
+    rows = pa._pages_per_group(BS, 640 * jnp.dtype(dtype).itemsize) \
         * BS
     lens = (1, rows - 1, rows, rows + 1, 84 * BS)
     args = _inputs(dtype, heads, lens=lens, maxb=84, n=256, row=640)
@@ -194,7 +194,7 @@ def test_the_cells_tile(dtype, heads):
     (16, 16384, 8),     # long rows: 128 rows and no fewer
 ])
 def test_group_size_follows_the_shape(block, row_bytes, pages):
-    assert pa._latent_pages_per_group(block, row_bytes) == pages
+    assert pa._pages_per_group(block, row_bytes) == pages
 
 
 def test_pool_and_query_rows_must_agree():

@@ -415,11 +415,21 @@ class TestPagedAttentionKernel:
 # ISSUE 30: a grid step takes G pages of one sequence
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def small_groups(monkeypatch):
+    """The rule's least group, whatever the row: 128 rows, 8 pages of
+    16 (toy rows would take 1024)."""
+    from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_TILE_BYTES", 1)
+    assert pa._pages_per_group(16, 512) == 8
+
+
 class TestPagedKernelPageGroups:
-    """Block 16 -> groups of G = 8 pages (128 rows). A table of 20
-    slots is one G does not divide (padded to 24: three groups); the
-    tables hold the NULL block 0 past each context, as the engine's
-    do."""
+    """Block 16 -> groups of G = 8 pages (128 rows, `small_groups`). A
+    table of 20 slots is one G does not divide (padded to 24: three
+    groups); the tables hold the NULL block 0 past each context, as
+    the engine's do."""
     BS, MAXB, N = 16, 20, 96
     # 1 token; ends inside the first page of the second group;
     # exactly one group; inside the last page; the full table
@@ -444,16 +454,97 @@ class TestPagedKernelPageGroups:
         return q, kp, vp, jnp.asarray(tables), jnp.asarray(
             np.array(self.LENS, np.int32))
 
-    def test_group_size_follows_the_block(self):
+    @pytest.mark.parametrize("block,row_bytes,pages", [
+        (16, 1024, 64),    # 4 K/V heads of 128 (or 8 of 64) in bf16: 1024 rows
+        (16, 1280, 32),    # a latent row of 640 bf16 values: 512 rows
+        (16, 2048, 32),    # GPT-2's 16 x 64 in bf16: 512 rows
+        (16, 4096, 16),    # ... in f32, the cell's: 256 rows
+        (8, 4096, 32), (32, 4096, 8), (128, 4096, 2), (256, 4096, 1),
+        (16, 8192, 8),     # 128 rows
+        (16, 16384, 8),    # long rows: 128 rows and no fewer
+        (16, 512, 64),     # short toy rows: 1024 rows and no more
+        (8, 512, 64),      # ... and at most 64 pages
+    ])
+    def test_group_size_follows_the_block(self, block, row_bytes, pages):
+        """One rule for both paged kernels: the largest power of two of
+        rows whose tile of `row_bytes` rows fits in 1 MiB, between 128
+        and 1024 rows, at most 64 pages."""
         from paddle_tpu.incubate.nn.pallas import paged_attention as pa
 
-        assert [pa._pages_per_group(bs) for bs in (8, 16, 32, 128, 256)] \
-            == [16, 8, 4, 1, 1]
+        assert pa._pages_per_group(block, row_bytes) == pages
+
+    # (query heads, K/V heads, head dim, contexts, window, query slots):
+    # the cells' layouts (rows of 1 KB in bf16: 1024-row groups of 64
+    # pages) at toy depth
+    CELL_LAYOUTS = {
+        # ends inside a group's last page, on it, on the next group's
+        # first page, on the third group's first
+        "g4": (32, 8, 64, (1, 1020, 1024, 1025, 2049), None, None),
+        "g5": (20, 4, 128, (1, 1020, 1024, 1025, 2049), None, None),
+        "g8": (32, 4, 128, (1, 1020, 1024, 1025, 2049), None, None),
+        # the window's first live page inside a group (pages 25, 75,
+        # 112), on a group's first (64), a context shorter than the window
+        "g8-window": (32, 4, 128, (1, 700, 1500, 2100, 1324, 299), 300,
+                      None),
+        # the deepest slot ends on a group's last page, on the next one's
+        # first, on the third one's first
+        "g4-t3": (32, 8, 64, (1, 1022, 1023, 2047), None, 3),
+    }
+
+    @pytest.mark.parametrize("layout", list(CELL_LAYOUTS))
+    def test_parity_at_a_cells_layout(self, layout):
+        import jax.numpy as jnp
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        hq, hkv, d, lens, window, t = self.CELL_LAYOUTS[layout]
+        assert pa._pages_per_group(self.BS, hkv * d * 2) == 64
+        rng = np.random.RandomState(7)
+        b, bf16 = len(lens), jnp.bfloat16
+        used = [-(-(n + (t or 1) - 1) // self.BS) for n in lens]
+        tables = np.zeros((b, max(used) + 2), np.int32)
+        ids = 1 + rng.permutation(sum(used))
+        for i, u in enumerate(used):
+            tables[i, :u] = ids[sum(used[:i]):sum(used[:i]) + u]
+        n = sum(used) + 1
+        q = jnp.asarray(rng.randn(*((b, hq, d) if t is None
+                                    else (b, t, hq, d))), bf16)
+        kp = jnp.asarray(rng.randn(n, self.BS, hkv, d), bf16)
+        vp = jnp.asarray(rng.randn(n, self.BS, hkv, d), bf16)
+        bt, cl = jnp.asarray(tables), jnp.asarray(np.array(lens, np.int32))
+        if t is None:
+            out = pa.paged_attention(q, kp, vp, bt, cl, sm_scale=0.1,
+                                     interpret=True, window=window)
+            want = pa.paged_attention_reference(q, kp, vp, bt, cl,
+                                                sm_scale=0.1, window=window)
+        else:
+            out = pa.paged_attention_multi(q, kp, vp, bt, cl, sm_scale=0.1,
+                                           interpret=True)
+            rep = lambda p: jnp.repeat(p, hq // hkv, axis=2)  # noqa: E731
+            want = pa.paged_attention_multi_reference(
+                q, rep(kp), rep(vp), bt, cl, sm_scale=0.1)
+        assert out.dtype == bf16 and out.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(want, np.float32),
+            rtol=0.05, atol=0.05)
+
+    def test_counts_its_calls_by_rows(self, small_groups):
+        """`kernels/paged/rows_<R>`: one count a call while a program is
+        traced, by its group's rows."""
+        import jax
+        from paddle_tpu.core.monitor import stat_get
+        from paddle_tpu.incubate.nn.pallas import paged_attention as pa
+
+        q, kp, vp, bt, cl = self._inputs("float32")
+        before = stat_get("kernels/paged/rows_128")
+        fn = jax.jit(lambda *a: pa.paged_attention(*a, interpret=True))
+        fn(q, kp, vp, bt, cl)
+        fn(q, kp, vp, bt, cl)          # a cached program: not traced again
+        assert stat_get("kernels/paged/rows_128") - before == 1
 
     @pytest.mark.parametrize("dtype,tol", [("float32", 2e-6),
                                            ("bfloat16", 0.05)])
     @pytest.mark.parametrize("t", [None, 1, 3])
-    def test_parity_at_every_edge(self, dtype, tol, t):
+    def test_parity_at_every_edge(self, small_groups, dtype, tol, t):
         from paddle_tpu.incubate.nn.pallas import paged_attention as pa
 
         q, kp, vp, bt, cl = self._inputs(dtype, t)
@@ -472,7 +563,8 @@ class TestPagedKernelPageGroups:
             rtol=tol, atol=tol)
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-    def test_dead_pages_and_null_block_never_count(self, dtype):
+    def test_dead_pages_and_null_block_never_count(self, small_groups,
+                                                   dtype):
         """Pages past the context inside a live group, whole dead
         groups, the padded table columns and the NULL block they all
         point at: poisoned, the output is the same to the bit."""
@@ -496,7 +588,7 @@ class TestPagedKernelPageGroups:
         np.testing.assert_array_equal(
             np.asarray(out, np.float32), np.asarray(out2, np.float32))
 
-    def test_slot0_of_a_window_is_the_decode_kernel(self):
+    def test_slot0_of_a_window_is_the_decode_kernel(self, small_groups):
         from paddle_tpu.incubate.nn.pallas import paged_attention as pa
 
         q, kp, vp, bt, cl = self._inputs("float32", t=3)

@@ -377,23 +377,32 @@ def test_tpu_latent_dense_program_is_the_parents(one_chip, family, want):
 
 
 @pytest.mark.parametrize("program,pool_dtype,want", [
-    ("decode", jnp.float32, "66cec4f467292521"),
-    ("decode", jnp.bfloat16, "501ff35cf8d196ca"),
-    ("verify", jnp.float32, "b92440df44b4b524"),
-    ("verify", jnp.bfloat16, "79f1544c60a784bf")],
+    ("decode", jnp.float32, "a4fd85311b5c53b5"),
+    ("decode", jnp.bfloat16, "1cbe81b217d3090b"),
+    ("verify", jnp.float32, "4de812c2f2d17380"),
+    ("verify", jnp.bfloat16, "9d272a5d93e1e97e")],
     ids=["decode-f32", "decode-bf16", "verify-f32", "verify-bf16"])
 def test_gpt2_kernel_programs_are_the_parents(program, pool_dtype, want):
-    """The second kernel shares `paged_attention.py` with GPT-2's:
-    GPT-2's decode and verify at the offline cell's shapes, with
-    their kernel, trace to the jaxpr they traced to before (fce49e0;
-    the kernel's body is in it; the lowered text also holds the
-    checkout's path and line numbers, the jaxpr does not)."""
+    """The paged K/V kernel is shared by four runners: GPT-2's decode
+    and verify at the offline cell's shapes, with their kernel, trace
+    to the jaxpr pinned here (the kernel's body is in it; the lowered
+    text also holds the checkout's path and line numbers, the jaxpr
+    does not), so that a change made for another runner shows."""
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype)
 
+    from paddle_tpu.core.monitor import stat_get
+
     fn, _, args, _, _ = _cell_program(sd, program, pool_dtype)
+    # a row of 16 heads x 64 is 4 KB in f32 (256-row groups, the
+    # cell's) and 2 KB in bf16 (512 rows); the layers' scan traces
+    # the one call once
+    counter = "kernels/paged/rows_" + (
+        "256" if pool_dtype == jnp.float32 else "512")
+    calls = stat_get(counter)
     jaxpr = jax.make_jaxpr(
         functools.partial(fn, use_kernel=True, **KW))(*args)
+    assert stat_get(counter) - calls == 1
     assert "pallas_call" in str(jaxpr)
     assert _digest(str(jaxpr)) == want
 
@@ -444,6 +453,8 @@ def test_tpu_lfm2_programs_fit_the_cell(one_chip, program):
     queries, 128 MiB), the weights are 9.81 GiB, and arguments and
     temporaries together fit the chip's 15.75 GiB with the pools and
     the state at the cell's size. Decode holds the Mosaic call."""
+    import re
+
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -466,6 +477,8 @@ def test_tpu_lfm2_programs_fit_the_cell(one_chip, program):
         assert "tpu_custom_call" in hlo
         # no sequence's context is gathered: [256, 4096, ...] nowhere
         assert "[256,4096," not in hlo and "[65536,16," not in hlo
+        # one paged launch an attention, named after its scope
+        assert len(re.findall(r"%attend\.\d+ = ", hlo)) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -630,6 +643,9 @@ def test_tpu_mellum_programs_fit_the_cell(one_chip, program, monkeypatch):
     temporaries together fit the chip's 15.75 GiB. Decode holds the
     Mosaic calls: 8 paged attentions with and without a window, 16
     grouped matmuls."""
+    import re
+
+    from paddle_tpu.core.monitor import stat_get
     from paddle_tpu.incubate.nn import pallas
 
     def sd(shape, dtype=jnp.float32):
@@ -638,8 +654,12 @@ def test_tpu_mellum_programs_fit_the_cell(one_chip, program, monkeypatch):
     monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
     pools, programs, kw = _mellum_programs(sd)
     fn, args = programs[program]
+    rows = stat_get("kernels/paged/rows_1024")
     compiled = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
         .lower(*args).compile()
+    # 1 KB rows: every paged launch walks 1024-row groups
+    assert stat_get("kernels/paged/rows_1024") - rows \
+        == (8 if program == "decode" else 0)
     mem = compiled.memory_analysis()
     held = sum(p.size * p.dtype.itemsize for p in pools)
     gib = 2 ** 30
@@ -655,6 +675,10 @@ def test_tpu_mellum_programs_fit_the_cell(one_chip, program, monkeypatch):
         hlo = compiled.as_text()
         assert hlo.count("tpu_custom_call") >= 24 and "ragged" not in hlo
         assert "[96,10240," not in hlo and "[96,640,16," not in hlo
+        # the names `attend_roofline.mellum2` reads the launches by: the
+        # scope each was traced in (`gqa/attend/{full,window}`)
+        assert len(re.findall(r"%full\.\d+ = ", hlo)) == 2
+        assert len(re.findall(r"%window\.\d+ = ", hlo)) == 6
 
 
 # ---------------------------------------------------------------------------
