@@ -21,8 +21,12 @@ range the cell's window holds):
     lfm2           256 x 1.8k-2.6k, 32 over 8 of 64, bf16
     gpt2           32 x 300-650, 16 heads of 64, float32
 
-The rows a group are set by replacing the module's `_pages_per_group`
-for the sweep; `rule_rows` is what the tree's own rule gives. Two designs
+The rows a group are set by replacing the module's rule for the sweep:
+for a shape with a window, on a tree that counts a windowed call's groups
+from the window's first page, `_window_pages` (1040 rows: one group a
+sequence; 1024: a group and a tail of one page), else `_pages_per_group`
+(on an older tree a windowed call's groups are counted from position 0);
+`rule_rows` is what the tree's own rule gives. Two designs
 of the kernel's body are compared as two trees (`--tree`): the program has
 no switch for one. Timing: `ms` is the device time of the launch's Mosaic
 op in a profiler trace of ten launches, nothing of the host in it.
@@ -112,10 +116,20 @@ def device_ms(fn, args, n=10):
     return total / n / 1e6
 
 
+def rule_name(pa, shape):
+    """The module's function that sets the shape's rows a group."""
+    window = shape[-1]
+    return "_window_pages" if window is not None \
+        and hasattr(pa, "_window_pages") else "_pages_per_group"
+
+
 def rule_rows(pa, shape):
-    _, _, hkv, d, dtype, _, _ = shape
+    _, _, hkv, d, dtype, _, window = shape
+    row_bytes = hkv * d * np.dtype(dtype).itemsize
+    if rule_name(pa, shape) == "_window_pages":
+        return pa._window_pages(window, BS, row_bytes) * BS
     try:
-        pages = pa._pages_per_group(BS, hkv * d * np.dtype(dtype).itemsize)
+        pages = pa._pages_per_group(BS, row_bytes)
     except TypeError:                 # a tree whose rule reads the block alone
         pages = pa._pages_per_group(BS)
     return pages * BS
@@ -136,7 +150,8 @@ def sweep(pa, name, rows_list, seed, peak_bytes):
         window=window))(q, k, v, tables, lens)
     want = np.asarray(want.astype(jnp.float32))
     moved = live_bytes(shape, np.asarray(lens))
-    rule = pa._pages_per_group
+    name_of_rule = rule_name(pa, shape)
+    rule = getattr(pa, name_of_rule)
     out = []
     try:
         for rows in rows_list:
@@ -144,9 +159,12 @@ def sweep(pa, name, rows_list, seed, peak_bytes):
                    "seqs": b, "heads": [hq, hkv, d], "dtype": dtype,
                    "window": window, "ctx_median": int(np.median(lens)),
                    "live_gb": round(moved / 1e9, 4)}
-            pa._pages_per_group = rule
+            setattr(pa, name_of_rule, rule)
             row["rule_rows"] = rule_rows(pa, shape)
-            pa._pages_per_group = lambda bs, *_, r=rows: max(1, r // bs)
+            if name_of_rule == "_window_pages":
+                pa._window_pages = lambda _, bs, *__, r=rows: max(1, r // bs)
+            else:
+                pa._pages_per_group = lambda bs, *_, r=rows: max(1, r // bs)
 
             def call(q, k, v, tables, lens):
                 # a function of its own a size (jit's trace cache is keyed
@@ -169,7 +187,7 @@ def sweep(pa, name, rows_list, seed, peak_bytes):
             print("[paged]", json.dumps(row), flush=True)
             out.append(row)
     finally:
-        pa._pages_per_group = rule
+        setattr(pa, name_of_rule, rule)
     return out
 
 

@@ -31,7 +31,10 @@ Grid: (B,), one sequence a grid step; the body walks the sequence's
 LIVE page groups itself (`fori_loop` to `ceil(context / R)`), G
 pages a group, G from the row's bytes (`_pages_per_group`: 1024 rows
 of 1 KB, 256 of GPT-2's f32 rows of 4 KB; the table is padded to a
-multiple of G where it is not one). The pools stay where they are
+multiple of G where it is not one). A call with a `window` counts its
+groups from the page that holds the window's first key, G from the
+window (`_window_pages`: ONE group a sequence, 65 pages of 16 for a
+window of 1024). The pools stay where they are
 (`pl.ANY`); `block_tables`/`context_lens` ride as SCALAR PREFETCH
 arguments (pltpu.PrefetchScalarGridSpec) and the body copies page
 `tables[b, j*G + g]` into row g*BS of one of two [R, H*D] tiles, in
@@ -178,15 +181,37 @@ def _pages_per_group(block_size, row_bytes):
     return max(1, min(64, rows // block_size))
 
 
-def _first_copies(b, last, groups, start, parity_ref, first=None):
+_WINDOW_TILE_BYTES = 2 * 1024 * 1024
+
+
+def _window_pages(window, block_size, row_bytes):
+    """G of a call with a `window`, whose groups are counted from the
+    page that holds the window's first key: the fewest pages that
+    always hold a window's keys, `ceil(window / BS) + 1` (the first
+    key may sit anywhere in its page), so that ONE group a sequence
+    walks the whole window: 65 pages = 1040 rows for a window of 1024
+    in pages of 16, where groups counted from position 0 straddle
+    two 1024-row tiles. A window whose tile would pass
+    `_WINDOW_TILE_BYTES` (2 MiB of one pool: the kernel's four tiles
+    within half of the 16 MiB of VMEM a Mosaic kernel has by default
+    on the v5e, where tiles of 512 rows of 4 KB run) takes the
+    row-bytes rule of `_pages_per_group`, its groups still counted
+    from the window's first page: at 1 KB rows a window up to 2032
+    keys is one group, a longer one 1024-row groups."""
+    pages = -(-window // block_size) + 1
+    if pages * block_size * row_bytes > _WINDOW_TILE_BYTES:
+        return _pages_per_group(block_size, row_bytes)
+    return pages
+
+
+def _first_copies(b, last, groups, start, parity_ref):
     """The opening of a grid step, for both kernels. `parity_ref`
     holds the parity of the groups walked so far: `base`, the tile
     (of two) this sequence's first group lands in. That group is on
     its way already where the sequence before had a last group to
-    start it from; else its copies start here. `groups(i)` is where
-    sequence i's walk ends, `first(i)` where it starts (a window's
-    first live group; None: group 0). Returns (base, the end of this
-    sequence's walk, the next sequence)."""
+    start it from; else its copies start here. `groups(i)` is the
+    number of groups sequence i walks, from its group 0. Returns
+    (base, that number for this sequence, the next sequence)."""
     @pl.when(b == 0)
     def _first():
         parity_ref[0] = 0
@@ -198,18 +223,16 @@ def _first_copies(b, last, groups, start, parity_ref, first=None):
 
     @pl.when((n_groups > 0) & ((b == 0) | (groups(before) == 0)))
     def _own_first():
-        start(b, 0 if first is None else first(b), base)
+        start(b, 0, base)
 
     return base, n_groups, after
 
 
-def _next_copies(b, last, j, base, n_groups, after, groups, start,
-                 first=None):
+def _next_copies(b, last, j, base, n_groups, after, groups, start):
     """Inside group j: the copies of group j + 1 (from a sequence's
     last group, of the next sequence's first) start into the other
     tile before this one is waited for. Returns this group's tile."""
-    slot = (base + j) % 2 if first is None \
-        else (base + j - first(b)) % 2
+    slot = (base + j) % 2
 
     @pl.when(j + 1 < n_groups)
     def _next_group():
@@ -217,7 +240,7 @@ def _next_copies(b, last, j, base, n_groups, after, groups, start,
 
     @pl.when((j + 1 == n_groups) & (b < last) & (groups(after) > 0))
     def _next_sequence():
-        start(after, 0 if first is None else first(after), 1 - slot)
+        start(after, 0, 1 - slot)
 
     return slot
 
@@ -246,10 +269,11 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
                   group=1, window=None):
     """One grid step: ONE sequence, its T query slots (decode is
     T = 1) against its live page groups, which the body walks itself
-    (with a `window`, decode only, from the first group that holds
-    one of the `window` newest tokens: the rows before `context -
-    window` are masked as the rows past the context are, their pages
-    not copied, the groups before them not walked).
+    (with a `window`, decode only, its groups counted from the page
+    that holds the first of the `window` newest tokens, not from
+    position 0: the pages before it are neither copied nor walked,
+    and the rows before `context - window` are masked as the rows
+    past the context are).
     Row t*H + h of every [T*H, ...] value is head h of slot t (with
     `group` G query heads a K/V head, `sel` has the Hkv K/V heads'
     rows and row (t*G + g)*Hkv + k is query head k*G + g of slot t:
@@ -272,32 +296,36 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         # are neither copied nor multiplied
         return lens_ref[i] + (num_q - 1)
 
-    def groups(i):
-        return (seen(i) + (rows - 1)) // rows
+    def end_page(i):
+        return (seen(i) + (block_size - 1)) // block_size
 
-    first_group = None
-    if window is not None:
-        def first_group(i):
-            return jnp.maximum(lens_ref[i] - window, 0) // rows
+    def origin(i):
+        # with a `window`: the table column of the page that holds the
+        # window's first key, where sequence i's group 0 starts
+        return jnp.maximum(lens_ref[i] - window, 0) // block_size
+
+    def groups(i):
+        if window is None:
+            return (seen(i) + (rows - 1)) // rows
+        return (end_page(i) - origin(i) + (pages - 1)) // pages
+
+    def first_page(i, j):
+        # the table column of group j's first row
+        return j * pages if window is None else origin(i) + j * pages
 
     def live_pages(i, j):
         # [lo, hi): the table columns of group j that hold a token
-        # visible to a slot of sequence i (with a `window`, one inside
-        # it): every other page is neither copied nor waited for
-        lo = j * pages
-        hi = jnp.minimum(lo + pages,
-                         (seen(i) + (block_size - 1)) // block_size)
-        if window is not None:
-            lo = jnp.maximum(
-                lo, jnp.maximum(lens_ref[i] - window, 0) // block_size)
-        return lo, hi
+        # visible to a slot of sequence i: every other page is neither
+        # copied nor waited for
+        lo = first_page(i, j)
+        return lo, jnp.minimum(lo + pages, end_page(i))
 
     def start(i, j, slot):
         # a rolled loop over the live pages: no branch a page, and the
         # body's text is one page's whatever the group's width
         def copy(p, carry):
             page = tables_ref[i, p]
-            to = pl.ds(pl.multiple_of((p - j * pages) * block_size,
+            to = pl.ds(pl.multiple_of((p - first_page(i, j)) * block_size,
                                       block_size), block_size)
             for pool, buf, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
                 pltpu.make_async_copy(pool.at[page], buf.at[slot, to],
@@ -322,12 +350,11 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
                                           sems.at[slot, which]).wait()
 
     base, n_groups, after = _first_copies(b, last, groups, start,
-                                          parity_ref, first_group)
+                                          parity_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     ctx = lens_ref[b]                # tokens visible to query slot 0
-    lo = 0 if window is None else first_group(b)
     if num_q > 1:                    # [T*H, 1]: slot t sees t more
         row = jax.lax.broadcasted_iota(
             jnp.int32, (q_rows * heads, 1), 0)
@@ -347,15 +374,20 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
     exact = jax.lax.Precision.HIGHEST \
         if k_buf.dtype == jnp.float32 else None
 
+    def first_row(j):
+        # the position of row 0 of group j's tile in the sequence
+        return j * rows if window is None \
+            else first_page(b, j) * block_size
+
     def group(j, carry):
         slot = _next_copies(b, last, j, base, n_groups, after, groups,
-                            start, first_group)
+                            start)
         wait(b, j, slot)
         k = k_buf[slot]                                      # [R, H*D]
         # rows no copy wrote hold what the tile held before: masked
         # out of the scores below, and zeroed here so that a zero
         # weight meets a zero
-        v_pos = j * rows + jax.lax.broadcasted_iota(
+        v_pos = first_row(j) + jax.lax.broadcasted_iota(
             jnp.int32, (rows, 1), 0)
         live = v_pos < seen(b)
         if window is not None:
@@ -364,7 +396,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         s = jax.lax.dot_general(
             qx, k, (((1,), (1,)), ((), ())), precision=exact,
             preferred_element_type=jnp.float32) * sm_scale   # [T*H, R]
-        k_pos = j * rows + jax.lax.broadcasted_iota(
+        k_pos = first_row(j) + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         # positions past the context (for the shallower slots, past
         # theirs) mask to -inf: p underflows to an exact zero
@@ -377,9 +409,8 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, sel_ref, k_hbm, v_hbm,
         _softmax_step(s, v, exact, acc_ref, m_ref, l_ref)
         return carry
 
-    jax.lax.fori_loop(lo, n_groups, group, 0)
-    parity_ref[0] = (base + n_groups) % 2 if window is None \
-        else (base + n_groups - lo) % 2
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    parity_ref[0] = (base + n_groups) % 2
 
     o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)        # [T*H, H*D]
     for t in range(q_rows):
@@ -412,9 +443,15 @@ def _paged_call(q, k_pool, v_pool, block_tables, context_lens, sm_scale,
         raise ValueError(f"pool heads/dim {(h, dk)} under query "
                          f"{(hq, d)}")
     hd = h * d
-    pages = _pages_per_group(bs, hd * k_pool.dtype.itemsize)
+    row_bytes = hd * k_pool.dtype.itemsize
+    if window is None:
+        pages = _pages_per_group(bs, row_bytes)
+        tables = _whole_groups(block_tables, pages)
+    else:
+        # a windowed walk reads no column past the context's last page
+        pages = _window_pages(window, bs, row_bytes)
+        tables = jnp.asarray(block_tables, jnp.int32)
     _cmon.stat_add(f"kernels/paged/rows_{pages * bs}", 1)
-    tables = _whole_groups(block_tables, pages)
     group = hq // h
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
@@ -464,9 +501,10 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     """Ragged paged-attention decode: one launch, all sequences.
     `window` (static; None = the whole context): the query sees the
     `window` newest tokens, itself included, and the walk starts at
-    the first page group that holds one of them: table columns
-    before `(context - window) // BS` are never read (a cache that
-    freed those blocks leaves NULL there)."""
+    the page that holds the first of them, in groups as wide as a
+    window (`_window_pages`): table columns before `(context -
+    window) // BS` are never read (a cache that freed those blocks
+    leaves NULL there)."""
     return _paged_call(q[:, None], k_pool, v_pool, block_tables,
                        context_lens, sm_scale, interpret, window)[:, 0]
 

@@ -441,10 +441,12 @@ def test_the_real_cell_keeps_a_16_mib_state_a_sequence():
 # decode for Mellum (attention alone, four cache groups) and of their
 # jaxprs with the paged kernel in decode, taken on the parent commit
 # (ce7bbef; decode's jaxpr again when the paged kernel's page copies became
-# a rolled loop): the runner that now addresses several per-slot arrays and
-# offers a recurrence serves a model without either the programs it served
+# a rolled loop, and when a windowed call's groups came to be counted from
+# the window's first page): the runner that now addresses several per-slot
+# arrays and offers a recurrence serves a model without either the
+# programs it served
 MELLUM_PROGRAMS = {("decode_step", "text"): "d6cfaba0c0500ef3",
-                   ("decode_step", "jaxpr"): "dd2e7823cb438168",
+                   ("decode_step", "jaxpr"): "fd314034b6b6b910",
                    ("prefill_step", "text"): "417830575ecf47bf",
                    ("prefill_step", "jaxpr"): "aba239d7fdc6b348"}
 

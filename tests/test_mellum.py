@@ -342,23 +342,38 @@ def test_reference_catches_what_the_programs_must_not_do(
 
 # -- (c) the paged kernel with a window -------------------------------------------
 
+@pytest.mark.parametrize("walk", ["one-group", "fallback"])
 @pytest.mark.parametrize("hq,hkv,dtype,tol", [
     (4, 4, jnp.float32, 2e-5), (8, 1, jnp.float32, 2e-5),
     (8, 1, jnp.bfloat16, 2e-2)])
-def test_the_kernel_walks_the_window_alone(hq, hkv, dtype, tol):
+def test_the_kernel_walks_the_window_alone(hq, hkv, dtype, tol, walk,
+                                           monkeypatch):
     """`paged_attention(window=)` in the interpreter against the dense
-    gather, at contexts below, at and above the window and across a
-    page group's boundary (128 rows: 16 pages of 8), 1 and 8 query
-    heads a K/V head; the table's columns before the window name the
-    NULL block, whose rows are NaN: never copied, never read."""
+    gather, at contexts below, at, one above and a page above the
+    window, a window's first key mid-page, and a context at the end of
+    a 512-row group counted from position 0 (where the window sat in
+    one such group), 1 and 8 query heads a K/V head; the
+    table's columns before the window name the NULL block, whose rows
+    are NaN: never copied, never read. `one-group`: a window of 40 in
+    pages of 8, ONE group of ceil(40 / 8) + 1 pages a sequence from
+    the window's first page; `fallback`: a window whose tile passes
+    the limit, in 128-row groups (the rule's least) from that page:
+    a window of 200 over two or three."""
     rng = np.random.RandomState(hq + hkv)
-    b, d, n, bs, maxb, window = 8, 32, 200, 8, 40, 40
+    b, d, n, bs, maxb = 8, 32, 300, 8, 66
+    if walk == "one-group":
+        window, rows = 40, 48
+    else:
+        monkeypatch.setattr(pa, "_WINDOW_TILE_BYTES", 1)
+        monkeypatch.setattr(pa, "_TILE_BYTES", 1)
+        window, rows = 200, 128
+    lens = np.asarray([1, window - 1, window, window + 1, window + bs,
+                       2 * window + 5, 512, 517], np.int32)
     q = jnp.asarray(rng.randn(b, hq, d), dtype)
     kp = jnp.asarray(rng.randn(n, bs, hkv, d), dtype)
     vp = jnp.asarray(rng.randn(n, bs, hkv, d), dtype)
     tables = rng.permutation(n - 1)[:b * maxb // 2].reshape(b, -1) + 1
     tables = np.concatenate([tables, tables[:, ::-1]], 1).astype(np.int32)
-    lens = np.asarray([1, 39, 40, 41, 128, 130, 167, 300], np.int32)
     want = pa.paged_attention_reference(
         q, kp, vp, jnp.asarray(tables), jnp.asarray(lens), sm_scale=0.2,
         window=window)
@@ -376,21 +391,46 @@ def test_the_kernel_walks_the_window_alone(hq, hkv, dtype, tol):
     for row, n_ in zip(freed, lens):
         row[:max(0, n_ - window) // bs] = NULL_BLOCK
     nan = jnp.asarray(np.nan, dtype)
+    counter = f"kernels/paged/rows_{rows}"
+    before = cmon.stat_get(counter)
     got = pa.paged_attention(
         q, kp.at[NULL_BLOCK].set(nan), vp.at[NULL_BLOCK].set(nan),
         jnp.asarray(freed), jnp.asarray(lens), sm_scale=0.2,
         interpret=True, window=window)
+    assert cmon.stat_get(counter) - before == 1
     np.testing.assert_allclose(f32(got), f32(want), atol=tol)
     # a window as long as every context is no window
     whole = pa.paged_attention(q, kp, vp, jnp.asarray(tables),
                                jnp.asarray(lens), sm_scale=0.2,
-                               interpret=True, window=512)
+                               interpret=True, window=maxb * bs)
     np.testing.assert_allclose(f32(whole), f32(pa.paged_attention_reference(
         q, kp, vp, jnp.asarray(tables), jnp.asarray(lens), sm_scale=0.2)),
         atol=tol)
     with pytest.raises(ValueError, match="window"):
         pa._paged_call(q[:, None].repeat(2, 1), kp, vp, jnp.asarray(tables),
                        jnp.asarray(lens), 0.2, True, window=8)
+
+
+# sha256 of `str(jax.make_jaxpr(...))` of calls without a window at 4
+# query heads a K/V head, decode and three verify slots, taken on the
+# parent commit (2c397c0): the window's own walk leaves them as they were
+NO_WINDOW_JAXPRS = {(1, "float32"): "2306afb9b2218748",
+                    (1, "bfloat16"): "611fc825113c7985",
+                    (3, "float32"): "4d201bc788bd6205",
+                    (3, "bfloat16"): "64218e4574be94f6"}
+
+
+@pytest.mark.parametrize("t_q,dtype", list(NO_WINDOW_JAXPRS))
+def test_a_call_without_a_window_traces_the_kernel_that_was(t_q, dtype):
+    q = jnp.zeros((4, t_q, 8, 32) if t_q > 1 else (4, 8, 32), dtype)
+    pool = jnp.zeros((24, 8, 2, 32), dtype)
+    fn = pa.paged_attention_multi if t_q > 1 else pa.paged_attention
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, t, n: fn(q, k, v, t, n, sm_scale=0.25))(
+            q, pool, pool, jnp.zeros((4, 5), jnp.int32),
+            jnp.ones((4,), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == NO_WINDOW_JAXPRS[t_q, dtype]
 
 
 def test_decode_through_the_windowed_kernel_emits_the_dense_tokens(

@@ -9,8 +9,10 @@ table. Groups are 128 rows here (the tile's size is set small, as
 columns at block 16 is one the group does not divide (padded to 24:
 three groups); one test runs the tile the cells' shape gives.
 """
+import hashlib
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -195,6 +197,25 @@ def test_the_cells_tile(dtype, heads):
 ])
 def test_group_size_follows_the_shape(block, row_bytes, pages):
     assert pa._pages_per_group(block, row_bytes) == pages
+
+
+# sha256 of `str(jax.make_jaxpr(...))` at both cells' rows (20 heads, 640
+# values), taken on the parent commit (2c397c0): the K/V kernel's walk of
+# a window shares the copy helpers with this kernel and leaves its program
+# as it was
+LATENT_JAXPRS = {"float32": "673f2ee4733e472c",
+                 "bfloat16": "57bab619f6c0bbfe"}
+
+
+@pytest.mark.parametrize("dtype", list(LATENT_JAXPRS))
+def test_the_kernel_traces_to_the_program_it_had(dtype):
+    text = str(jax.make_jaxpr(
+        lambda q, p, t, n: pa.paged_latent_attention(q, p, t, n,
+                                                     sm_scale=0.3))(
+        jnp.zeros((3, 20, 640), dtype), jnp.zeros((24, BS, 640), dtype),
+        jnp.zeros((3, 5), jnp.int32), jnp.ones((3,), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == LATENT_JAXPRS[dtype]
 
 
 def test_pool_and_query_rows_must_agree():

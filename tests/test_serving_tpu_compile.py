@@ -654,12 +654,14 @@ def test_tpu_mellum_programs_fit_the_cell(one_chip, program, monkeypatch):
     monkeypatch.setattr(pallas, "_on_tpu", lambda: True)
     pools, programs, kw = _mellum_programs(sd)
     fn, args = programs[program]
-    rows = stat_get("kernels/paged/rows_1024")
+    counters = ("kernels/paged/rows_1024", "kernels/paged/rows_1040")
+    rows = [stat_get(c) for c in counters]
     compiled = jax.jit(functools.partial(fn, **kw), donate_argnums=(3,)) \
         .lower(*args).compile()
-    # 1 KB rows: every paged launch walks 1024-row groups
-    assert stat_get("kernels/paged/rows_1024") - rows \
-        == (8 if program == "decode" else 0)
+    # 1 KB rows: the two full launches walk 1024-row groups, the six
+    # window launches one group of 65 pages from the window's first
+    assert [stat_get(c) - r for c, r in zip(counters, rows)] \
+        == ([2, 6] if program == "decode" else [0, 0])
     mem = compiled.memory_analysis()
     held = sum(p.size * p.dtype.itemsize for p in pools)
     gib = 2 ** 30
