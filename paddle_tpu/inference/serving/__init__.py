@@ -13,17 +13,15 @@ the Gemma-on-Cloud-TPU serving comparison (arxiv 2605.25645):
                     evict / preempt between fused decode dispatches
   * `model_runner`  the RUNNER the engine reads of a model (params,
                     cache rows, prefill/decode/verify/tail programs),
-                    chosen by the model's type; GPT-2's compiled
-                    prefill + paged decode programs, per-request
-                    in-program sampling
-  * `mla_runner`    the second runner: latent attention (MLA) over
-                    one pool, read through the block tables by the
-                    Pallas latent kernel on a TPU
-                    (`text/models/glm4_moe_lite.py`, `longcat_flash.py`)
-  * `state_runner`  the third runner: K/V pools with grouped heads
-                    and, beside them, a state of fixed size a
-                    sequence in per-slot arrays (short convolutions:
-                    `text/models/lfm2_moe.py`)
+                    one of two; GPT-2's compiled prefill + paged
+                    decode programs, per-request in-program sampling
+  * `state_runner`  the other runner, of every model that hands the
+                    serving path its layers (`decoder_layers`: GLM,
+                    LongCat, LFM2, Mellum, Falcon-H1): one pool of
+                    latent rows or K/V pools with grouped heads,
+                    read through the block tables by a Pallas kernel
+                    on a TPU, and beside them states of fixed size a
+                    sequence in per-slot arrays
   * `engine`        `LLMEngine.generate()` / `add_request()`
                     streaming front end, donated decode step through
                     the persistent compile cache; ISSUE-13 lifecycle

@@ -6,13 +6,13 @@ and two compiled programs — per-bucket prefill and ONE fixed-shape
 decode step covering all `max_batch` slots — that together serve
 many concurrent mixed-length requests. What the engine knows of the
 model (its parameters, the cache's pools and row widths, the
-programs) it reads from the RUNNER the model's type selects
-(`model_runner.runner_for`: GPT-2, `mla_runner` for a model with
-latent-attention layers, `state_runner` for one that keeps a state
-of fixed size a sequence beside its keys and values: that state
-lives in per-SLOT arrays behind the paged pools, `cache.pools`
-carries both through every program, and a prefill is told the slot
-it fills); a runner without a verify or tail program makes
+programs) it reads from the RUNNER the model selects
+(`model_runner.runner_for`: GPT-2's, or `state_runner` for a model
+that hands the serving path its layers; a state of fixed size a
+sequence beside the keys and values lives in per-SLOT arrays behind
+the paged pools, `cache.pools` carries both through every program,
+and a prefill is told the slot it fills); a runner without a verify
+or tail program makes
 `spec_k > 1` / `prefix_cache` raise at construction:
 
     engine = LLMEngine(model)
@@ -195,7 +195,7 @@ class LLMEngine:
                  max_seq_len=None, run_ahead=False):
         arm_compile_cache()
         # everything the engine knows of the model it reads from the
-        # runner its type selects (model_runner.runner_for)
+        # runner it selects (model_runner.runner_for)
         self.runner = runner = _mr.runner_for(model)
         self.params, self.config = runner.params, runner.config
         cfg = self.config
@@ -221,8 +221,8 @@ class LLMEngine:
             prefix_cache if prefix_cache is not None
             else env_prefix_cache())
         # a window a cache group, where the runner's model has
-        # attentions of several kinds (state_runner; else one group)
-        groups = getattr(runner, "cache_groups", (None,))
+        # attentions of several kinds (else one group)
+        groups = runner.cache_groups
         if len(groups) > 1 and (self.spec_k > 1 or self.prefix_cache):
             raise NotImplementedError(
                 f"spec_k={self.spec_k} / prefix_cache="

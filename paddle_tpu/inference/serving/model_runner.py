@@ -2,26 +2,35 @@
 passes (prefill + single-token decode): the first runner.
 
 A RUNNER is what the engine knows of a model (`runner_for(model)`
-picks it from the model's type; nothing else selects it):
+picks it: `state_runner.StateRunner` for a model that hands the
+serving path its layers, `GPT2Runner` otherwise; nothing else selects
+it):
 
     runner.params, runner.config     the raw jnp tree and the config
     runner.pool_rows                 row width of each cache pool
     runner.pool_layers               leading axis of each pool: attentions that keep rows
+    runner.cache_groups              a window a cache group ((None,): one group, the whole context)
     runner.slot_state                per-slot arrays beside the pools, `(kind, (layers, *shape), dtype)` each; () for none.
                                      With any, prefill_step takes the request's slot after `seed`
     runner.scan_layers               state-space layers a decode step updates (0: none), and
     runner.scan_kernel()             whether it updates them in the Pallas kernel here
+    runner.kernel_supported(bs)      whether decode attends through a Pallas paged kernel here
+    runner.experts_kernel(tokens)    whether a program over `tokens` rows runs its experts in the grouped matmul
     runner.prefill_step(params, ids, prompt_len, pools, table, temp, top_k, seed, *, block_size)
     runner.decode_step(params, ids, positions, pools, tables, lens, temp, top_k, seeds, *, block_size, use_kernel, interpret)
     runner.verify_step, .prefill_tail_step, .draft_params    or None: no speculation / prefix cache
+
+(`GPT2Runner` has no `scan_kernel` or `experts_kernel`: its
+`scan_layers` is 0 and its programs count no routing, so the engine
+never asks.)
 
 Every step returns `(tokens, pools, stats)`: `pools` the tuple it
 was handed (donated, updated in place), `stats` a dict of small
 arrays fetched with the tokens (routing counts; empty for GPT-2).
 `GPT2Runner` hands the engine the programs below as they are —
 `_pooled` only packs their `k_pool, v_pool` into the tuple, so they
-lower to the HLO they always did. `mla_runner.MLARunner` is the
-second runner, `state_runner.StateRunner` the third.
+lower to the HLO they always did. `state_runner.StateRunner` is the
+other runner.
 
 The serving engine never calls `GPTModel.forward` — re-running the
 full prompt for every generated token is O(S^2) per request. Instead
@@ -476,6 +485,7 @@ class GPT2Runner:
     draft_params = staticmethod(draft_params)
     slot_state = ()
     scan_layers = 0
+    cache_groups = (None,)
 
     def __init__(self, model):
         self.params, self.config = extract_params(model)
@@ -503,16 +513,10 @@ class GPT2Runner:
 
 
 def runner_for(model):
-    """The runner of a model: `MLARunner` for one that says what its
-    latent-attention layers are (`mla_layers`), `StateRunner` for
-    one whose layers keep per-slot state beside keys and values
-    (`state_layers`), else GPT-2's."""
-    inner = getattr(model, "model", model)
-    if hasattr(inner, "mla_layers"):
-        from .mla_runner import MLARunner
-
-        return MLARunner(model)
-    if hasattr(inner, "state_layers"):
+    """The runner of a model: `state_runner.StateRunner` for one that
+    hands the serving path its layers (`decoder_layers`), else
+    GPT-2's."""
+    if hasattr(getattr(model, "model", model), "decoder_layers"):
         from .state_runner import StateRunner
 
         return StateRunner(model)

@@ -60,8 +60,7 @@ import numpy as np
 from ...core.engine import apply_op
 from ...incubate.nn.ssm import ssd_chunked
 from ...nn.layer.layers import Layer
-from .mellum import attend_dense
-from .mla import SeededTree, _times, rms_norm, rotate
+from .common import SeededTree, _times, attend_dense, rms_norm, rotate
 
 __all__ = ["FalconH1Config", "FalconH1Model", "FalconH1ForCausalLM",
            "draw_stds"]
@@ -261,7 +260,8 @@ def layers(params, x, carry, attend, window, scan, positions, live, cfg):
     """Every layer over `x [T, hidden]`, unrolled, with the calling
     program's `attend`, `window` and `scan` (module docstring; layer
     `l` is attention `l`, windowed layer `l` and state-space layer
-    `l`). Returns (x, carry, {}): no routing to count."""
+    `l`). Returns (x, carry, None: no rows for the runner to write,
+    {}: no routing to count)."""
     eps = cfg.rms_norm_eps
     for i, lp in enumerate(params["layers"]):
         u = rms_norm(x, lp["ln_in"], eps)
@@ -271,7 +271,7 @@ def layers(params, x, carry, attend, window, scan, positions, live, cfg):
                                   cfg)
         x = x + a + m
         x = x + mlp(rms_norm(x, lp["ln_ff"], eps), lp["mlp"], cfg)
-    return x, carry, {}
+    return x, carry, None, {}
 
 
 def logits(params, x, cfg):
@@ -314,8 +314,8 @@ def _k_forward(ids, params, cfg):
         return y.reshape((b * s,) + y.shape[2:]), carry
 
     x = embed(params, ids.reshape(b * s), cfg)
-    x, _, _ = layers(params, x, (), attend, window, scan,
-                     jnp.tile(jnp.arange(s), b), None, cfg)
+    x, _, _, _ = layers(params, x, (), attend, window, scan,
+                        jnp.tile(jnp.arange(s), b), None, cfg)
     return logits(params, x, cfg).reshape(b, s, -1)
 
 
@@ -325,10 +325,11 @@ class FalconH1Model(SeededTree):
     """Decoder of `num_hidden_layers` layers, each its own tree."""
 
     # what the serving runner reads (state_runner.StateRunner)
-    state_layers = staticmethod(layers)
+    decoder_layers = staticmethod(layers)
     attend_dense = staticmethod(attend_dense)
     logits = staticmethod(logits)
     embed = staticmethod(embed)
+    routed_experts = None        # no experts
 
     def __init__(self, config: FalconH1Config):
         super().__init__(config)

@@ -21,9 +21,10 @@ drafter and no part of the next-token forward pass: not built.
 
 The two kinds of layer have different trees, so the parameters are
 two stacks with a leading layer axis (`dense`, `moe`), each run by
-one `lax.scan` (`layers`, which the serving runner calls with its
-own attention). Weights are drawn on the device (`mla.SeededTree`):
-at the published widths one expert layer is 635 M parameters.
+one `lax.scan` (`layers`, which the serving runner and `_k_forward`
+call with their own attention). Weights are drawn on the device
+(`common.SeededTree`): at the published widths one expert layer is
+635 M parameters.
 """
 from __future__ import annotations
 
@@ -36,9 +37,8 @@ from ...core.engine import apply_op
 from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, sigmoid_topk_route)
 from ...nn.layer.layers import Layer
-from .mla import (SeededTree, attention_block,  # noqa: F401
-                  mla_attend_absorbed, mla_attend_dense, mla_latent,
-                  mla_query, rms_norm, rotate, swiglu)
+from .common import SeededTree, embed, logits, swiglu
+from .mla import attention_block, attention_params, forward
 
 __all__ = ["Glm4MoeLiteConfig", "Glm4MoeLiteModel",
            "Glm4MoeLiteForCausalLM"]
@@ -115,11 +115,12 @@ def moe_ffn(u, mp, cfg, layer=None, live=None):
 _EXPERTS = ("w13", "w2")     # read in place, never a scan's xs
 
 
-def layers(params, x, carry, attend, live, cfg):
-    """Both stacks over `x [T, hidden]`, for the serving runner, with
-    the calling program's `attend` (`mla.attention_block`); an
-    attention's number in the cache is its layer's. The
-    routed experts' stacked weights are not among a scan's `xs`
+def layers(params, x, carry, attend, window, scan, positions, live, cfg):
+    """Both stacks over `x [T, hidden]` with the calling program's
+    `attend` (`mla.attention_block`; `window`, `scan` and `positions`,
+    what the runner offers every model, go unused: `attend` has the
+    positions); an attention's number in the cache is its layer's.
+    The routed experts' stacked weights are not among a scan's `xs`
     (slicing a layer out would copy all its experts): `moe_ffn` reads
     them as `[L*E, ...]` groups. Returns (x, carry, the rows of every
     attention in cache order or None, {"moe_counts"})."""
@@ -155,38 +156,8 @@ def layers(params, x, carry, attend, live, cfg):
 
 
 def _k_forward(ids, params, cfg):
-    """Full causal forward, ids [B, S] -> logits [B, S, V] float32:
-    what training and the tests run, through the same functions the
-    serving programs use. Attention runs a sequence at a time
-    (vmap); the FFNs see all B x S tokens as one list."""
-    eps = cfg.rms_norm_eps
-    b, s = ids.shape
-    positions = jnp.arange(s)
-
-    def attend_one(x, ap):
-        u = rms_norm(x, ap["ln1"], eps)
-        q_nope, q_rope = mla_query(u, ap, cfg, positions)
-        latent = mla_latent(u, ap, cfg, positions)
-        return mla_attend_dense(q_nope, q_rope, latent, ap, cfg)
-
-    def attend(x, ap):
-        attn = jax.vmap(attend_one, in_axes=(0, None))(x, ap)
-        h = x + attn @ ap["wo"]
-        return h, rms_norm(h, ap["ln2"], eps).reshape(b * s, -1)
-
-    def dense(x, lp):
-        h, u = attend(x, lp["attn"])
-        return h + swiglu(u, lp["w13"], lp["w2"]).reshape(h.shape), None
-
-    def moe(x, lp):
-        h, u = attend(x, lp["attn"])
-        return h + moe_ffn(u, lp, cfg)[0].reshape(h.shape), None
-
-    x = jnp.take(params["embed"], ids, axis=0)
-    x, _ = jax.lax.scan(dense, x, params["dense"])
-    x, _ = jax.lax.scan(moe, x, params["moe"])
-    x = rms_norm(x, params["norm_f"], eps)
-    return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)
+    """Full causal forward through `layers` (`mla.forward`)."""
+    return forward(layers, ids, params, cfg)
 
 
 # -- the Layer ---------------------------------------------------------------
@@ -195,7 +166,10 @@ class Glm4MoeLiteModel(SeededTree):
     """Decoder with two stacks of layers: `first_k_dense_replace`
     dense ones, then the expert layers."""
 
-    mla_layers = staticmethod(layers)
+    # what the serving runner reads (state_runner.StateRunner)
+    decoder_layers = staticmethod(layers)
+    embed = staticmethod(embed)
+    logits = staticmethod(logits)
 
     def __init__(self, config: Glm4MoeLiteConfig):
         super().__init__(config)
@@ -212,13 +186,13 @@ class Glm4MoeLiteModel(SeededTree):
             "head": self._normal("head", (h, c.vocab_size), layered=False),
             "norm_f": self._ones("norm_f", (h,)),
             "dense": {
-                "attn": self._attention(n_dense),
+                "attn": attention_params(self, n_dense),
                 "w13": self._normal("w13", (n_dense, h,
                                             2 * c.intermediate_size)),
                 "w2": self._normal("w2", (n_dense, c.intermediate_size, h)),
             },
             "moe": {
-                "attn": self._attention(n_moe),
+                "attn": attention_params(self, n_moe),
                 # the router and its selection bias stay float32
                 "router_w": self._normal("router_w", (n_moe, h, e),
                                          dtype=jnp.float32),
@@ -243,6 +217,11 @@ class Glm4MoeLiteModel(SeededTree):
     def n_attentions(self):
         """Attentions that keep rows in a cache: one a layer."""
         return self.config.num_hidden_layers
+
+    @property
+    def latent_row(self):
+        """A token's row in the cache an attention, key and value."""
+        return self.config.latent_row
 
     def forward(self, input_ids):
         return apply_op("glm4_moe_lite_forward", _k_forward, input_ids,

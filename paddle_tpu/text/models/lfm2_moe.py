@@ -74,7 +74,7 @@ from ...core.engine import apply_op
 from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, sigmoid_topk_route)
 from ...nn.layer.layers import Layer
-from .mla import SeededTree, embed, rms_norm, rotate, swiglu
+from .common import SeededTree, embed, rms_norm, rotate, swiglu
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM",
            "PUBLISHED_LAYER_TYPES"]
@@ -229,7 +229,8 @@ def layers(params, x, carry, attend, window, scan, positions, live, cfg):
     """Every layer over `x [T, hidden]`, unrolled, with the calling
     program's `attend` and `window` (module docstring; `scan`, what the
     runner offers a state-space layer, goes unused). Returns (x,
-    carry, {"moe_counts" [expert layers, E]})."""
+    carry, None: no rows for the runner to write, {"moe_counts"
+    [expert layers, E]})."""
     eps = cfg.norm_eps
     a = c = 0
     counts = []
@@ -250,7 +251,8 @@ def layers(params, x, carry, attend, window, scan, positions, live, cfg):
         else:
             y = swiglu(u, lp["ffn"]["w13"], lp["ffn"]["w2"])
         x = x + y
-    return x, carry, {"moe_counts": jnp.stack(counts)} if counts else {}
+    return x, carry, None, \
+        {"moe_counts": jnp.stack(counts)} if counts else {}
 
 
 def logits(params, x, cfg):
@@ -284,8 +286,8 @@ def _k_forward(ids, params, cfg):
         return win.reshape((b * s,) + win.shape[2:]), carry
 
     x = jnp.take(params["embed"], ids.reshape(b * s), axis=0)
-    x, _, _ = layers(params, x, (), attend, window, None,
-                     jnp.tile(jnp.arange(s), b), None, cfg)
+    x, _, _, _ = layers(params, x, (), attend, window, None,
+                        jnp.tile(jnp.arange(s), b), None, cfg)
     return logits(params, x, cfg).reshape(b, s, -1)
 
 
@@ -295,7 +297,7 @@ class Lfm2MoeModel(SeededTree):
     """Decoder of `num_hidden_layers` layers, each its own tree."""
 
     # what the serving runner reads (state_runner.StateRunner)
-    state_layers = staticmethod(layers)
+    decoder_layers = staticmethod(layers)
     attend_dense = staticmethod(attend_dense)
     logits = staticmethod(logits)
     embed = staticmethod(embed)

@@ -40,10 +40,10 @@ Assumed where the config is silent: the rotary pairing (`mla.py`);
 `norm_topk_prob` false (the key is absent); an untied head.
 
 The parameters are one stack of double layers with a leading layer
-axis, run by one `lax.scan` (`layers`, which the serving runner
-calls with its own attention, twice a layer); the two attentions and
-the two dense FFNs of a layer are two trees side by side, so no
-weight is sliced out of a pair.
+axis, run by one `lax.scan` (`layers`, which the serving runner and
+`_k_forward` call with their own attention, twice a layer); the two
+attentions and the two dense FFNs of a layer are two trees side by
+side, so no weight is sliced out of a pair.
 """
 from __future__ import annotations
 
@@ -57,8 +57,8 @@ from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, identity_expert_sum,
     softmax_topk_route)
 from ...nn.layer.layers import Layer
-from .mla import (SeededTree, attention_block, mla_attend_dense,
-                  mla_latent, mla_query, rms_norm, swiglu)
+from .common import SeededTree, embed, logits, swiglu
+from .mla import attention_block, attention_params, forward
 
 __all__ = ["LongcatFlashConfig", "LongcatFlashModel",
            "LongcatFlashForCausalLM"]
@@ -167,11 +167,12 @@ def scmoe_ffn(u, mp, cfg, layer=None, live=None):
 _EXPERTS = ("w13", "w2")     # read in place, never a scan's xs
 
 
-def layers(params, x, carry, attend, live, cfg):
-    """The stack of double layers over `x [T, hidden]`, for the
-    serving runner, with the calling program's `attend`
-    (`mla.attention_block`); an attention's number in the cache is
-    `2 l` or `2 l + 1` for layer `l`. Returns (x, carry, the rows of every attention in
+def layers(params, x, carry, attend, window, scan, positions, live, cfg):
+    """The stack of double layers over `x [T, hidden]` with the
+    calling program's `attend` (`mla.attention_block`; `window`,
+    `scan` and `positions` go unused, as in `glm4_moe_lite.layers`);
+    an attention's number in the cache is `2 l` or `2 l + 1` for
+    layer `l`. Returns (x, carry, the rows of every attention in
     cache order `[2 L, ...]` or None, the routing counts a layer)."""
     eps = cfg.rms_norm_eps
     stack = params["layers"]
@@ -201,28 +202,8 @@ def layers(params, x, carry, attend, live, cfg):
 
 
 def _k_forward(ids, params, cfg):
-    """Full causal forward, ids [B, S] -> logits [B, S, V] float32:
-    what training and the tests run, through `layers`. Attention runs
-    a sequence at a time (vmap); the FFNs see all B x S tokens as
-    one list."""
-    b, s = ids.shape
-    positions = jnp.arange(s)
-
-    def attend_one(u, ap):
-        q_nope, q_rope = mla_query(u, ap, cfg, positions)
-        latent = mla_latent(u, ap, cfg, positions)
-        return mla_attend_dense(q_nope, q_rope, latent, ap, cfg)
-
-    def attend(u, carry, ap, a):
-        out = jax.vmap(attend_one, in_axes=(0, None))(
-            u.reshape(b, s, -1), ap)
-        return out.reshape(b * s, -1), carry, None
-
-    x = jnp.take(params["embed"], ids.reshape(b * s), axis=0)
-    x, _, _, _ = layers(params, x, (), attend, None, cfg)
-    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    return jnp.dot(x, params["head"],
-                   preferred_element_type=jnp.float32).reshape(b, s, -1)
+    """Full causal forward through `layers` (`mla.forward`)."""
+    return forward(layers, ids, params, cfg)
 
 
 # -- the Layer ---------------------------------------------------------------
@@ -230,7 +211,10 @@ def _k_forward(ids, params, cfg):
 class LongcatFlashModel(SeededTree):
     """Decoder of `num_layers` double layers."""
 
-    mla_layers = staticmethod(layers)
+    # what the serving runner reads (state_runner.StateRunner)
+    decoder_layers = staticmethod(layers)
+    embed = staticmethod(embed)
+    logits = staticmethod(logits)
 
     def __init__(self, config: LongcatFlashConfig):
         super().__init__(config)
@@ -248,7 +232,8 @@ class LongcatFlashModel(SeededTree):
             "head": self._normal("head", (h, c.vocab_size), layered=False),
             "norm_f": self._ones("norm_f", (h,)),
             "layers": {
-                "attn": [self._attention(n), self._attention(n)],
+                "attn": [attention_params(self, n),
+                         attention_params(self, n)],
                 "ffn": [ffn(), ffn()],
                 # the router keeps its published width whatever is
                 # held here; it and its selection bias stay float32.
@@ -279,6 +264,11 @@ class LongcatFlashModel(SeededTree):
     def n_attentions(self):
         """Attentions that keep rows in a cache: two a layer."""
         return 2 * self.config.num_layers
+
+    @property
+    def latent_row(self):
+        """A token's row in the cache an attention, key and value."""
+        return self.config.latent_row
 
     def forward(self, input_ids):
         return apply_op("longcat_flash_forward", _k_forward, input_ids,

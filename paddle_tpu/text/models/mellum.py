@@ -71,7 +71,7 @@ from ...core.engine import apply_op
 from ...incubate.distributed.models.moe.dropless import (
     dropless_expert_ffn, expert_counts, topk_route)
 from ...nn.layer.layers import Layer
-from .mla import SeededTree, embed, rms_norm
+from .common import SeededTree, attend_dense, embed, logits, rms_norm
 
 __all__ = ["MellumConfig", "MellumModel", "MellumForCausalLM",
            "PUBLISHED_LAYER_TYPES", "rope_tables"]
@@ -202,66 +202,6 @@ def rotate(x, positions, inv_freq, factor):
 
 # -- the block (pure jnp; the serving runner reads `layers`) ---------------
 
-def attend_dense(q, k, v, window=None, q_block=512, k_block=1024):
-    """Causal attention of S tokens over themselves, grouped heads:
-    q [S, Hq, D], k / v [S, Hkv*D] -> [S, Hq*D]; with a `window`,
-    query `i` sees keys `i - window < j <= i`. Queries in blocks (the
-    largest power of two under `q_block` that divides S), and a query
-    block meets its keys a block of `k_block` at a time, from the one
-    that holds the oldest key one of its queries can see to the one
-    that holds its newest, under a running softmax (maximum, sum and
-    weighted values in float32): the key blocks ahead of a query
-    block, and those behind its window, are never read, so that a
-    window layer's attention is linear in S and a full layer's half
-    the square, and no score tile is wider than `k_block` (a
-    `[heads, 512, 8192]` float32 tile costs a v5e 56 ms where eight
-    of 1024 columns cost 3: PERF.md, PR 38)."""
-    s, hq, d = q.shape
-    hkv = k.shape[-1] // d
-    k, v = k.reshape(s, hkv, d), v.reshape(s, hkv, d)
-    qg = q.reshape(s, hkv, hq // hkv, d)
-    qb = math.gcd(s, 1 << (max(1, q_block).bit_length() - 1))
-    kb = math.gcd(s, 1 << (max(1, k_block).bit_length() - 1))
-    rows = (hkv, hq // hkv, qb)
-
-    def block(i):
-        qi = jax.lax.dynamic_slice_in_dim(qg, i, qb)
-        q_pos = (i + jnp.arange(qb))[:, None]
-
-        def tile(j, carry):
-            m, l, acc = carry
-            ki = jax.lax.dynamic_slice_in_dim(k, j * kb, kb)
-            vi = jax.lax.dynamic_slice_in_dim(v, j * kb, kb)
-            scores = jnp.einsum("qkgd,skd->kgqs", qi, ki,
-                                preferred_element_type=jnp.float32)
-            behind = q_pos - (j * kb + jnp.arange(kb))
-            seen = behind >= 0
-            if window is not None:
-                seen &= behind < window
-            scores = jnp.where(seen, scores / math.sqrt(d), -1e30)
-            # a row that has met no visible key yet weighs its masked
-            # ones 1; its first visible key's `alpha` is an exact 0
-            m_new = jnp.maximum(m, scores.max(-1))
-            p = jnp.exp(scores - m_new[..., None])
-            alpha = jnp.exp(m - m_new)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "kgqs,skd->kgqd", p.astype(vi.dtype), vi,
-                preferred_element_type=jnp.float32)
-            return m_new, l * alpha + p.sum(-1), acc
-
-        first = 0 if window is None \
-            else jnp.maximum(i - window + 1, 0) // kb
-        _, l, acc = jax.lax.fori_loop(
-            first, (i + qb - 1) // kb + 1, tile,
-            (jnp.full(rows, -1e30, jnp.float32),
-             jnp.zeros(rows, jnp.float32),
-             jnp.zeros(rows + (d,), jnp.float32)))
-        return jnp.moveaxis(acc / l[..., None], 2, 0).astype(v.dtype)
-
-    out = block(0) if qb == s else jax.lax.map(block, jnp.arange(0, s, qb))
-    return out.reshape(s, hq * d)
-
-
 def attention_operator(u, carry, ap, a, kind, attend, positions, cfg):
     """Grouped-query attention over tokens u [T, H] at `positions`
     [T]: q and k normalised per head, then rotated by the layer
@@ -297,7 +237,8 @@ def layers(params, x, carry, attend, window, scan, positions, live, cfg):
     """Every layer over `x [T, hidden]`, unrolled, with the calling
     program's `attend` (module docstring; `window` and `scan`, what
     the runner offers a layer with per-slot state, go unused: this
-    model has none). Returns (x, carry, {"moe_counts" [layers, E]})."""
+    model has none). Returns (x, carry, None: no rows for the runner
+    to write, {"moe_counts" [layers, E]})."""
     eps = cfg.rms_norm_eps
     counts = []
     for a, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
@@ -308,13 +249,7 @@ def layers(params, x, carry, attend, window, scan, positions, live, cfg):
         y, n = moe_ffn(rms_norm(x, lp["ln_ffn"], eps), lp["moe"], cfg, live)
         counts.append(n)
         x = x + y
-    return x, carry, {"moe_counts": jnp.stack(counts)}
-
-
-def logits(params, x, cfg):
-    """Final norm and the untied head, float32."""
-    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)
+    return x, carry, None, {"moe_counts": jnp.stack(counts)}
 
 
 def _k_forward(ids, params, cfg):
@@ -332,8 +267,8 @@ def _k_forward(ids, params, cfg):
         return out.reshape(b * s, -1), carry
 
     x = jnp.take(params["embed"], ids.reshape(b * s), axis=0)
-    x, _, _ = layers(params, x, (), attend, None, None,
-                     jnp.tile(jnp.arange(s), b), None, cfg)
+    x, _, _, _ = layers(params, x, (), attend, None, None,
+                        jnp.tile(jnp.arange(s), b), None, cfg)
     return logits(params, x, cfg).reshape(b, s, -1)
 
 
@@ -360,7 +295,7 @@ class MellumModel(SeededTree):
     """Decoder of `num_hidden_layers` layers, each its own tree."""
 
     # what the serving runner reads (state_runner.StateRunner)
-    state_layers = staticmethod(layers)
+    decoder_layers = staticmethod(layers)
     attend_dense = staticmethod(attend_dense)
     logits = staticmethod(logits)
     embed = staticmethod(embed)

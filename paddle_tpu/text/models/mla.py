@@ -1,8 +1,8 @@
-"""Latent attention (MLA) as DeepSeek-V2 published it, and what the
+"""Latent attention (MLA) as DeepSeek-V2 published it, which the
 models built on it share (`glm4_moe_lite`, `longcat_flash`): the
 block's mathematics in pure `jax.numpy`, which the serving runner
-(`inference/serving/mla_runner.py`) reads too, and the seeded
-parameter tree.
+(`inference/serving/state_runner.py`) reads too, the attention's
+seeded weights and the models' full forward (`forward`).
 
 `N` being RMSNorm (weight, no bias), every projection without bias:
 `c_q = N(u W_qa)`, `q = a_q c_q W_qb` per head `[nope | rope]`;
@@ -35,50 +35,11 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ...core.tensor import Parameter
-from ...nn.layer.layers import Layer
-from ...ops import random as _random
+from .common import _times, embed, logits, rms_norm, rotate
 
-__all__ = ["rms_norm", "rotate", "embed", "swiglu", "mla_query", "mla_latent",
-           "mla_attend_dense", "mla_attend_absorbed", "mla_attend_paged",
-           "attention_block",
-           "SeededTree"]
-
-
-def rms_norm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return xf.astype(x.dtype) * w
-
-
-def rotate(x, positions, theta):
-    """Rotary embedding over the last dimension of `x [..., D]`,
-    half-split pairing; `positions` has x's leading shape or
-    broadcasts against it (a heads axis is `positions[..., None]`)."""
-    half = x.shape[-1] // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def embed(params, ids, cfg):
-    """The embedding rows of `ids` (a model with an embedding
-    multiplier has an `embed` of its own)."""
-    return jnp.take(params["embed"], ids, axis=0)
-
-
-def swiglu(u, w13, w2):
-    gate, up = jnp.split(u @ w13, 2, axis=-1)
-    return (jax.nn.silu(gate) * up) @ w2
-
-
-def _times(x, scale):
-    """`x * scale` in x's dtype; a scale of 1 leaves the program as
-    it was."""
-    return x if scale == 1 else x * jnp.asarray(scale, x.dtype)
+__all__ = ["mla_query", "mla_latent", "mla_attend_dense",
+           "mla_attend_absorbed", "mla_attend_paged", "attention_block",
+           "attention_params", "forward"]
 
 
 def mla_query(u, ap, cfg, positions):
@@ -200,70 +161,46 @@ def attention_block(x, carry, ap, a, attend, eps):
     return h, rms_norm(h, ap["ln2"], eps), carry, row
 
 
-class SeededTree(Layer):
-    """A model whose parameters are one tree of stacked leaves (a
-    leading layer axis, run by `lax.scan`), drawn on the device in
-    the configured dtype, one layer at a time: at the published
-    widths a float32 construction of a few expert layers would not
-    fit a 16 GB chip. `config` has `dtype`, `initializer_range` and
-    the MLA widths."""
+def forward(layers, ids, params, cfg):
+    """Full causal forward of a model's `layers` (`glm4_moe_lite`,
+    `longcat_flash`), ids [B, S] -> logits [B, S, V] float32: what
+    training and the tests run. Attention runs a sequence at a time
+    (vmap); the FFNs see all B x S tokens as one list."""
+    b, s = ids.shape
+    positions = jnp.arange(s)
 
-    def __init__(self, config):
-        super().__init__()
-        self.config = config
-        self._dtype = jnp.dtype(config.dtype)
-        self._key = _random.next_key()
-        self._n_leaf = 0
-        self._tree = {}
+    def attend_one(u, ap):
+        q_nope, q_rope = mla_query(u, ap, cfg, positions)
+        latent = mla_latent(u, ap, cfg, positions)
+        return mla_attend_dense(q_nope, q_rope, latent, ap, cfg)
 
-    def _add(self, name, value):
-        self._n_leaf += 1
-        p = Parameter(value, name=f"{name}_{self._n_leaf}")
-        self.add_parameter(f"{name}_{self._n_leaf}", p)
-        return p
+    def attend(u, carry, ap, a):
+        out = jax.vmap(attend_one, in_axes=(0, None))(
+            u.reshape(b, s, -1), ap)
+        return out.reshape(b * s, -1), carry, None
 
-    def _ones(self, name, shape):
-        return self._add(name, jnp.ones(shape, self._dtype))
+    x = embed(params, ids.reshape(b * s), cfg)
+    x, _, _, _ = layers(params, x, (), attend, None, None, None, None, cfg)
+    return logits(params, x, cfg).reshape(b, s, -1)
 
-    def _normal(self, name, shape, layered=True, dtype=None, std=None):
-        """`std` (initializer_range) x normal, drawn on the device in
-        the target dtype, one slice of the leading (layer) axis at a
-        time: a leaf never exists in float32 as a whole."""
-        dtype = dtype or self._dtype
-        std = std or self.config.initializer_range
-        key = jax.random.fold_in(self._key, self._n_leaf)
 
-        def draw(k, sh):
-            return (std * jax.random.normal(k, sh, jnp.float32)
-                    ).astype(dtype)
-
-        if layered:
-            value = jax.jit(lambda ks: jax.lax.map(
-                lambda k: draw(k, shape[1:]), ks))(
-                    jax.random.split(key, shape[0]))
-        else:
-            value = jax.jit(lambda k: draw(k, shape))(key)
-        return self._add(name, value)
-
-    def _attention(self, n):
-        """`n` layers' attention weights and the two norms around
-        them (`ln1` before the attention, `ln2` before the FFN that
-        follows it)."""
-        c = self.config
-        h, heads = c.hidden_size, c.num_heads
-        return {
-            "ln1": self._ones("ln1", (n, h)),
-            "wq_a": self._normal("wq_a", (n, h, c.q_lora_rank)),
-            "q_norm": self._ones("q_norm", (n, c.q_lora_rank)),
-            "wq_b": self._normal("wq_b", (n, c.q_lora_rank, heads * (
-                c.qk_nope_head_dim + c.qk_rope_head_dim))),
-            "wkv_a": self._normal("wkv_a", (n, h, c.latent_row)),
-            "kv_norm": self._ones("kv_norm", (n, c.kv_lora_rank)),
-            "wkv_b": self._normal("wkv_b", (n, c.kv_lora_rank, heads * (
-                c.qk_nope_head_dim + c.v_head_dim))),
-            "wo": self._normal("wo", (n, heads * c.v_head_dim, h)),
-            "ln2": self._ones("ln2", (n, h)),
-        }
-
-    def _params_tree(self):
-        return self._tree
+def attention_params(tree, n):
+    """`n` layers' attention weights and the two norms around them
+    (`ln1` before the attention, `ln2` before the FFN that follows
+    it), drawn by `tree` (a `common.SeededTree` whose config has the
+    MLA widths)."""
+    c = tree.config
+    h, heads = c.hidden_size, c.num_heads
+    return {
+        "ln1": tree._ones("ln1", (n, h)),
+        "wq_a": tree._normal("wq_a", (n, h, c.q_lora_rank)),
+        "q_norm": tree._ones("q_norm", (n, c.q_lora_rank)),
+        "wq_b": tree._normal("wq_b", (n, c.q_lora_rank, heads * (
+            c.qk_nope_head_dim + c.qk_rope_head_dim))),
+        "wkv_a": tree._normal("wkv_a", (n, h, c.latent_row)),
+        "kv_norm": tree._ones("kv_norm", (n, c.kv_lora_rank)),
+        "wkv_b": tree._normal("wkv_b", (n, c.kv_lora_rank, heads * (
+            c.qk_nope_head_dim + c.v_head_dim))),
+        "wo": tree._normal("wo", (n, heads * c.v_head_dim, h)),
+        "ln2": tree._ones("ln2", (n, h)),
+    }

@@ -485,3 +485,48 @@ def test_mellum_traces_to_the_programs_it_had(step, form):
             getattr(runner, step), block_size=4, **kernel))(*args))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == MELLUM_PROGRAMS[step, form]
+
+
+# -- Falcon-H1's programs, pinned ---------------------------------------------------
+
+# sha256 of the lowered StableHLO text of the runner's prefill and decode
+# for the toy Falcon-H1 (attention and a Mamba-2 mixer in every layer, two
+# per-slot arrays) and of their jaxprs, decode's with the paged kernel and
+# the state kernel in it (both in the interpreter), taken on the parent
+# commit (05b7560) before the latent models joined the one runner
+FALCON_PROGRAMS = {("decode_step", "text"): "979c6ba54cc33c66",
+                   ("decode_step", "jaxpr"): "b6b27a78f4896509",
+                   ("prefill_step", "text"): "cae013d2b6e2524a",
+                   ("prefill_step", "jaxpr"): "2d41ed5fa8891ac8"}
+
+
+@pytest.mark.parametrize("step,form", list(FALCON_PROGRAMS))
+def test_falcon_traces_to_the_programs_it_had(toy, step, form, monkeypatch):
+    _, model, _ = toy
+    runner = mr.runner_for(model)
+    i32, f32 = jnp.int32, jnp.float32
+    pools = (jnp.zeros((3, 16, 4, 32)), jnp.zeros((3, 16, 4, 32)),
+             jnp.zeros((3, 4, 3, 96)), jnp.zeros((3, 4, 4, 8, 16), f32))
+    args = {
+        "decode_step": (
+            runner.params, jnp.zeros((4,), i32), jnp.zeros((4,), i32), pools,
+            jnp.zeros((4, 8), i32), jnp.ones((4,), i32), jnp.zeros((4,)),
+            jnp.zeros((4,), i32), jnp.zeros((4,), jnp.uint32)),
+        "prefill_step": (
+            runner.params, jnp.zeros((1, 16), i32), jnp.int32(5), pools,
+            jnp.zeros((8,), i32), jnp.float32(0), jnp.int32(0),
+            jnp.uint32(0), jnp.int32(1))}[step]
+    kernel = form == "jaxpr" and step == "decode_step"
+    if kernel:
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
+    if form == "text":
+        text = jax.jit(functools.partial(getattr(runner, step), block_size=4),
+                       donate_argnums=(3,)).lower(*args).as_text()
+    else:
+        kw = {"use_kernel": True, "interpret": True} if kernel else {}
+        text = str(jax.make_jaxpr(functools.partial(
+            getattr(runner, step), block_size=4, **kw))(*args))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == FALCON_PROGRAMS[step, form]

@@ -26,10 +26,11 @@ import paddle_tpu as paddle
 from paddle_tpu.incubate.distributed.models.moe import dropless
 from paddle_tpu.inference.serving import (LLMEngine, PagedKVCache,
                                           SamplingParams)
-from paddle_tpu.inference.serving import mla_runner
+from paddle_tpu.inference.serving import state_runner
 from paddle_tpu.inference.serving import model_runner as mr
 from paddle_tpu.inference.serving.kv_cache import bytes_per_block
 from paddle_tpu.text.models import glm4_moe_lite as glm
+from paddle_tpu.text.models import mla
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -128,16 +129,16 @@ def test_absorbed_attention_equals_expanded(toy):
     u = jnp.asarray(np.random.RandomState(3).randn(s, cfg.hidden_size),
                     jnp.float32)
     pos = jnp.arange(s)
-    q_nope, q_rope = glm.mla_query(u, ap, cfg, pos)
-    latent = glm.mla_latent(u, ap, cfg, pos)
+    q_nope, q_rope = mla.mla_query(u, ap, cfg, pos)
+    latent = mla.mla_latent(u, ap, cfg, pos)
     assert latent.shape == (s, cfg.latent_row)
-    dense = glm.mla_attend_dense(q_nope, q_rope, latent, ap, cfg)
+    dense = mla.mla_attend_dense(q_nope, q_rope, latent, ap, cfg)
     # every position as one decode query over the rows before it,
     # the rows stored wider than they are and a garbage tail masked
     ctx = jnp.pad(latent, ((0, 5), (0, 128 - cfg.latent_row)),
                   constant_values=0.0).at[s:, :cfg.latent_row].set(7.0)
     ctx = jnp.broadcast_to(ctx, (s,) + ctx.shape)
-    absorbed = glm.mla_attend_absorbed(q_nope, q_rope, ctx, pos + 1, ap,
+    absorbed = mla.mla_attend_absorbed(q_nope, q_rope, ctx, pos + 1, ap,
                                        cfg)
     np.testing.assert_allclose(absorbed, dense, atol=2e-5)
 
@@ -207,7 +208,7 @@ def test_latent_pool_is_one_pool_with_the_same_tables(toy):
     cfg, model, _ = toy
     eng = _engine(model)
     cache = eng.cache
-    assert isinstance(eng.runner, mla_runner.MLARunner)
+    assert isinstance(eng.runner, state_runner.StateRunner)
     # 40 values a token a layer, stored in whole 128-lane rows
     assert cfg.latent_row == 40 and cache.rows == (128,)
     assert len(cache.pools) == 1
@@ -288,7 +289,7 @@ def test_gpt2_runner_hands_over_the_same_programs():
         seq.append(tok)
 
 
-# -- (g) what the second runner does not have --------------------------------------------------
+# -- (g) what the runner does not have for this model ----------------------------------------
 
 @pytest.mark.parametrize("kw,what", [
     (dict(spec_k=2), "spec_k"), (dict(prefix_cache=True), "prefix_cache")])

@@ -1,6 +1,6 @@
 """ISSUE 34: LFM2-24B-A2B (`lfm2_moe`) — gated short convolutions whose
 state lives in per-slot arrays beside the paged keys and values,
-grouped K/V heads through the paged kernel, the third runner and the
+grouped K/V heads through the paged kernel, the state runner and the
 engine against the plain float32 reference of the benchmark
 (`tpubench/models/lfm2_moe.py`), at toy widths with seeded weights on
 the CPU.
@@ -547,6 +547,34 @@ def test_the_accepted_runners_serve_the_programs_they_did(build, step):
                    donate_argnums=(3,)).lower(*args).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PROGRAMS[build, step]
+
+
+# sha256 of the jaxpr of the latent models' decode step through the paged
+# latent kernel (in the interpreter) at the toy shapes above, taken on the
+# parent commit (05b7560) before the latent models joined the one runner:
+# the path the cells run on the chip, which `PROGRAMS` (the dense gather)
+# does not trace
+KERNEL_PROGRAMS = {_glm: "4c7703c3960bc1da", _longcat: "13edbf44cdb32b26"}
+
+
+@pytest.mark.parametrize("build", list(KERNEL_PROGRAMS),
+                         ids=[b.__name__[1:] for b in KERNEL_PROGRAMS])
+def test_the_latent_models_decode_through_the_kernel_as_they_did(
+        build, monkeypatch):
+    monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
+    paddle.seed(27)
+    model, layers = build()
+    model.eval()
+    runner = mr.runner_for(model)
+    i32 = jnp.int32
+    pools = tuple(jnp.zeros((layers, 16, 4, w)) for w in runner.pool_rows)
+    text = str(jax.make_jaxpr(functools.partial(
+        runner.decode_step, block_size=4, use_kernel=True, interpret=True))(
+            runner.params, jnp.zeros((4,), i32), jnp.zeros((4,), i32), pools,
+            jnp.zeros((4, 8), i32), jnp.ones((4,), i32), jnp.zeros((4,)),
+            jnp.zeros((4,), i32), jnp.zeros((4,), jnp.uint32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == KERNEL_PROGRAMS[build]
 
 
 def test_the_route_is_glms_rule_with_no_shared_expert(toy):

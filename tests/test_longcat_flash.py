@@ -26,7 +26,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.incubate.distributed.models.moe import dropless
 from paddle_tpu.inference.serving import LLMEngine, SamplingParams
-from paddle_tpu.inference.serving import mla_runner
+from paddle_tpu.inference.serving import state_runner
 from paddle_tpu.inference.serving import model_runner as mr
 from paddle_tpu.text.models import glm4_moe_lite as glm
 from paddle_tpu.text.models import longcat_flash as lc
@@ -98,7 +98,7 @@ def test_engine_prefill_then_decode_against_the_reference(share):
     prompts = [list(rng.randint(1, cfg.vocab_size, n))
                for n in (5, 9, 14, 7)]
     eng = _engine(model)
-    assert isinstance(eng.runner, mla_runner.MLARunner)
+    assert isinstance(eng.runner, state_runner.StateRunner)
     # two attentions a layer keep rows: the pool's layer count is the
     # runner's, not the config's
     assert eng.runner.pool_layers == 4 and cfg.num_layers == 2
@@ -330,7 +330,7 @@ def _old_dropless_expert_ffn(u, idx, weights, w13, w2, layer=None):
 
 def test_glm_is_served_by_the_programs_it_was(monkeypatch):
     """GLM-4.7-Flash through the generalised `dropless_expert_ffn`
-    and `MLARunner`: its decode and prefill lower to the same text as
+    and the one runner (`state_runner`): its decode and prefill lower to the same text as
     with the expert function of before, the outputs of a whole-range
     share (`first=0`) are bit-equal to the plain call's, and both MLA
     scales are 1 and multiply nothing."""
@@ -346,7 +346,7 @@ def test_glm_is_served_by_the_programs_it_was(monkeypatch):
     model = glm.Glm4MoeLiteForCausalLM(cfg)
     model.eval()
     runner = mr.runner_for(model)
-    assert isinstance(runner, mla_runner.MLARunner)
+    assert isinstance(runner, state_runner.StateRunner)
     assert runner.pool_layers == 3
     pool = jnp.zeros((3, 16, 4, 128))
     i32 = jnp.int32

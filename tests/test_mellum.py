@@ -36,7 +36,7 @@ from paddle_tpu.inference.serving import state_runner
 from paddle_tpu.inference.serving.kv_cache import NULL_BLOCK, PagedKVCache
 from paddle_tpu.text.models import lfm2_moe as lfm
 from paddle_tpu.text.models import mellum as ml
-from paddle_tpu.text.models.mla import swiglu
+from paddle_tpu.text.models.common import swiglu
 from paddle_tpu.text.models.gpt import GPTConfig, GPTForCausalLM
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(

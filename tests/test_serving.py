@@ -677,12 +677,13 @@ class TestPagedKernelSelection:
 
     @staticmethod
     def _latent_runner(row):
-        """An `MLARunner` as far as `kernel_supported` reads it: the
-        stored row's width."""
-        from paddle_tpu.inference.serving.mla_runner import MLARunner
+        """The runner of a model with latent rows as far as
+        `kernel_supported` reads it: one head as wide as the stored
+        row."""
+        from paddle_tpu.inference.serving.state_runner import StateRunner
 
-        runner = object.__new__(MLARunner)
-        runner.pool_rows = (row,)
+        runner = object.__new__(StateRunner)
+        runner.heads = (1, 1, row)
         return runner
 
     def test_latent_runner_on_the_cpu_takes_the_interpreter_only(
@@ -717,7 +718,7 @@ class TestPagedKernelSelection:
         assert self._latent_runner(row).kernel_supported(block) is want
 
     def test_latent_runner_stores_whole_lane_rows(self):
-        """What `MLARunner` hands the predicate is the padded row:
+        """What the runner hands the predicate is the padded row:
         a model's 40 values a token are stored in 128."""
         from paddle_tpu.inference.serving import model_runner as mr
         from paddle_tpu.text.models import glm4_moe_lite as glm

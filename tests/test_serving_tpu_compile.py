@@ -88,11 +88,12 @@ def test_tpu_program_updates_pools_in_place(one_chip, program):
 # ---------------------------------------------------------------------------
 
 def _mla_programs(sd):
-    """decode and one prefill of `mla_runner` at the published widths
-    (hidden 2048, 20 heads, ranks 768/512, 64 experts of 1536, block
-    16, batch 64, 4096 positions); depth 1 + 2 layers, vocabulary and
-    pool cut down. The pool's row is what `MLARunner` stores."""
-    from paddle_tpu.inference.serving import mla_runner as mla
+    """decode and one prefill of the runner's latent kind at the
+    published widths (hidden 2048, 20 heads, ranks 768/512, 64 experts
+    of 1536, block 16, batch 64, 4096 positions); depth 1 + 2 layers,
+    vocabulary and pool cut down. The pool's row is what the runner
+    stores."""
+    from paddle_tpu.inference.serving import state_runner as sr
     from paddle_tpu.text.models.glm4_moe_lite import (Glm4MoeLiteConfig,
                                                       Glm4MoeLiteModel)
 
@@ -104,13 +105,13 @@ def _mla_programs(sd):
     row = -(-cfg.latent_row // 128) * 128
     i32, f32, bsz, maxb = jnp.int32, jnp.float32, 64, 4096 // 16
     pool = sd((3, 16385, 16, row), jnp.bfloat16)
-    kw = dict(cfg=cfg, layers=Glm4MoeLiteModel.mla_layers, block_size=16)
+    kw = dict(cfg=cfg, model=Glm4MoeLiteModel, block_size=16, latent=True)
     return pool, {
-        "decode": (mla.decode_step, (
+        "decode": (sr.decode_step, (
             params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
             sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
             sd((bsz,), i32), sd((bsz,), i32))),
-        "prefill": (mla.prefill_step, (
+        "prefill": (sr.prefill_step, (
             params, sd((1, 1536), i32), sd((), i32), (pool,),
             sd((maxb,), i32), sd((), f32), sd((), i32), sd((), i32))),
     }, kw
@@ -252,7 +253,7 @@ def _longcat_programs(sd):
     768-wide router, 16 held experts of 2048, 4 double layers = 8
     attentions' rows, batch 64, 4096 positions, 16385 blocks, the
     vocabulary's slice."""
-    from paddle_tpu.inference.serving import mla_runner as mla
+    from paddle_tpu.inference.serving import state_runner as sr
     from paddle_tpu.text.models.longcat_flash import (LongcatFlashConfig,
                                                       LongcatFlashModel)
 
@@ -263,13 +264,14 @@ def _longcat_programs(sd):
     params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype), shapes)
     i32, f32, bsz, maxb = jnp.int32, jnp.float32, 64, 4096 // 16
     pool = sd((8, 16385, 16, 640), jnp.bfloat16)
-    kw = dict(cfg=cfg, layers=LongcatFlashModel.mla_layers, block_size=16)
+    kw = dict(cfg=cfg, model=LongcatFlashModel, block_size=16,
+              latent=True)
     return pool, {
-        "decode": (mla.decode_step, (
+        "decode": (sr.decode_step, (
             params, sd((bsz,), i32), sd((bsz,), i32), (pool,),
             sd((bsz, maxb), i32), sd((bsz,), i32), sd((bsz,), f32),
             sd((bsz,), i32), sd((bsz,), i32))),
-        "prefill": (mla.prefill_step, (
+        "prefill": (sr.prefill_step, (
             params, sd((1, 2048), i32), sd((), i32), (pool,),
             sd((maxb,), i32), sd((), f32), sd((), i32), sd((), i32))),
     }, kw
